@@ -239,11 +239,12 @@ def test_knob_classification_and_config_validation():
         assert knob not in knob_exclude('pool_key')
     with pytest.raises(ValueError, match='aot_dir'):
         load_config('resnet', overrides={
-            'video_paths': ['v.live'], 'aot_enabled': True,
-            'aot_dir': None})
+            'video_paths': ['v.live'], 'device': 'cpu',
+            'aot_enabled': True, 'aot_dir': None})
     with pytest.raises(ValueError, match='aot_max_bytes'):
         load_config('resnet', overrides={
-            'video_paths': ['v.live'], 'aot_max_bytes': -5})
+            'video_paths': ['v.live'], 'device': 'cpu',
+            'aot_max_bytes': -5})
     from video_features_tpu.config import split_serve_config
     with pytest.raises(ValueError, match='serve_prewarm'):
         split_serve_config({'serve_prewarm': ['nosuchfamily']})

@@ -98,10 +98,14 @@ def test_timm_requires_model_name(tmp_path):
 
 
 def test_device_never_leaks_cuda(tmp_path):
-    # 'cuda:0' (torch-style) maps to the accelerator if present, else cpu.
+    # 'cuda:0' (torch-style) means "the accelerator". This lane has none
+    # (conftest pins cpu), and an accelerator request must RAISE naming
+    # what was found — never carry on on the CPU (the yml default is
+    # 'tpu', so the same holds with no device override at all).
     v = _mk_video(tmp_path)
-    args = load_config('resnet', overrides={'video_paths': v, 'device': 'cuda:0'})
-    assert args.device in ('cpu', 'tpu')
+    for overrides in ({'device': 'cuda:0'}, {'device': 'tpu'}, {}):
+        with pytest.raises(RuntimeError, match=r"only platform\(s\) \['cpu'\]"):
+            load_config('resnet', overrides={'video_paths': v, **overrides})
 
 
 def test_device_cpu_stays_cpu(tmp_path):

@@ -507,6 +507,49 @@ def test_farm_unparks_duplicate_while_stream_stays_open(tmp_path):
     assert farm.stats()['deduped'] == 1
 
 
+# -- the workers' real import footprint ---------------------------------------
+
+
+def test_worker_side_decode_never_imports_jax(tmp_path):
+    """What a decode worker RUNS — unpickle a recipe, open a video, drain
+    its windows — must not import jax: workers are started after the
+    parent touched jax, and a TPU belongs to one process. The static
+    spawn-purity rule cannot see lazy imports below the root modules
+    (``stream_windows`` used to import ``parallel.packing``, whose package
+    __init__ is the whole jax stack — found on the chip, PR 21), so this
+    replays every recipe kind in a fresh interpreter and looks."""
+    import pickle
+    import subprocess
+    import sys
+
+    from video_features_tpu.farm.recipes import (
+        FramewiseRecipe, FusedRecipe, StackRecipe,
+    )
+    clip = _write_clip(tmp_path / 'fp.mp4', 9, seed=5)
+    common = dict(batch_size=4, fps=None, total=None,
+                  tmp_path=str(tmp_path / 'tmp'), keep_tmp=False,
+                  backend='auto')
+    resize = ('edge_resize', 32, 'bilinear')
+    recipes = [StackRecipe(win=4, step=4, transform=resize, **common),
+               FramewiseRecipe(transform=resize, **common),
+               FusedRecipe(transforms={'a': resize, 'b': None}, **common)]
+    blob = tmp_path / 'recipes.pkl'
+    blob.write_bytes(pickle.dumps(recipes))
+    child = (
+        'import pickle, sys\n'
+        'import video_features_tpu.farm.worker\n'
+        f'for recipe in pickle.load(open({str(blob)!r}, "rb")):\n'
+        f'    info, windows = recipe.open({clip!r})\n'
+        '    assert sum(1 for _ in windows) > 0, recipe\n'
+        'print(sorted(m for m in sys.modules '
+        'if m.split(".")[0] in ("jax", "jaxlib", "flax")))\n')
+    proc = subprocess.run([sys.executable, '-c', child], text=True,
+                          capture_output=True, timeout=120,
+                          cwd=str(Path(__file__).resolve().parents[1]))
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.strip() == '[]', proc.stdout
+
+
 # -- packed-path parity: byte-identical to decode_workers=1 ------------------
 
 

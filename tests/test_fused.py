@@ -335,15 +335,19 @@ def test_cli_features_routes_fused(tmp_path, tmp_path_factory):
     d = tmp_path_factory.mktemp('clifused')
     clip_path = str(_write_clip(d / 'c.mp4', 4, seed=91))
     out = tmp_path / 'out'
-    rc = main(['features=[resnet]', f'video_paths=[{clip_path}]',
-               'device=cpu', 'model_name=resnet18', 'batch_size=4',
-               'allow_random_weights=true', 'on_extraction=save_numpy',
-               f'output_path={out}', f'tmp_path={tmp_path / "tmp"}'])
-    assert rc == 0
+    argv = ['features=[resnet]', 'device=cpu', 'model_name=resnet18',
+            'batch_size=4', 'allow_random_weights=true',
+            'on_extraction=save_numpy', f'output_path={out}',
+            f'tmp_path={tmp_path / "tmp"}']
+    assert main(argv + [f'video_paths=[{clip_path}]']) == 0
     # sanity_check appends <family>/<model_name> to the output root
     final = out / 'resnet' / 'resnet18'
     for k in KEYS['resnet']:
         assert Path(make_path(str(final), clip_path, k, '.npy')).exists(), k
+    # a failed video keeps the fused worklist going (the saved clip is a
+    # resume skip here) but shows in the exit code
+    gone = tmp_path / 'gone.mp4'              # never created
+    assert main(argv + [f'video_paths=[{gone},{clip_path}]']) == 1
 
 
 @pytest.mark.slow

@@ -54,16 +54,6 @@ def test_sharded_two_stream_step_matches_single_device():
     unsharded one — sharding is a layout choice, not a numerics choice."""
     from functools import partial
 
-    if not (hasattr(jax.lax, 'pvary') or hasattr(jax.lax, 'pcast')):
-        # jax 0.4.x (pre-pvary): the (data>1, time>1) sharded program's
-        # FLOW stream diverges materially from single-device (measured
-        # max abs 5.49 on 0.4.37; data-only meshes stay within float32
-        # noise) — the time-axis resharding semantics this graph was
-        # validated against do not hold there. parallel/mesh.py warns at
-        # mesh build; this parity pin applies on the targeted jax only.
-        pytest.skip('(data, time) sharded two-stream numerics are not '
-                    'valid on jax 0.4.x (no pvary/pcast)')
-
     from video_features_tpu.extract.i3d import fused_two_stream_step
     from video_features_tpu.models import i3d as i3d_model
     from video_features_tpu.models import raft as raft_model
@@ -338,7 +328,7 @@ def test_raft_halo_shard_dp_matches_single_device():
     boundary frames duplicated host-side) must reproduce the single-device
     forward_consecutive at few iterations (same fp-noise caveat as the
     pair-sharding test)."""
-    from video_features_tpu.utils.device import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from video_features_tpu.models import raft as raft_model

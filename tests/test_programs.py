@@ -64,8 +64,7 @@ def test_clean_toy_has_no_findings_and_full_signature():
 
 
 def test_no_f64_rule_catches_planted_promotion():
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         f = jax.jit(lambda p, b: b.astype(np.float64).sum() * p)
         _, findings = sig_and_findings(ProgramSpec('step', f, (P, B4)))
     assert rules_of(findings) == {'no-f64'}

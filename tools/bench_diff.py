@@ -4,7 +4,7 @@
 The driver stamps one ``BENCH_r{N}.json`` per round; this tool turns two
 of them into an honest regression report instead of eyeballing JSON:
 
-    python tools/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_diff.py BENCH_r05.json BENCH_r06.json
     python tools/bench_diff.py old.json new.json --fail-on-regression 10
 
 Direction-aware: throughput-like rungs (``*clips_per_sec*``,

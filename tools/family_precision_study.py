@@ -172,7 +172,7 @@ def main() -> None:
 
     platform = jax.devices()[0].platform
     on_accel = platform != 'cpu'
-    enable_compilation_cache('~/.cache/video_features_tpu/xla', platform)
+    enable_compilation_cache('auto', platform)
     iters = int(os.environ.get('BENCH_ITERS', 8 if on_accel else 2))
 
     specs = _family_specs(on_accel)

@@ -37,7 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 from video_features_tpu.utils.device import enable_compilation_cache, jax_device
 
 platform = jax.devices()[0].platform
-enable_compilation_cache('~/.cache/video_features_tpu/xla', platform)
+enable_compilation_cache('auto', platform)
 dev = jax_device(platform)
 interpret = platform != 'tpu'
 

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""MFU accounting: FLOPs/clip → achieved TFLOP/s → % of v5e bf16 peak.
+"""MFU accounting: FLOPs/clip → achieved TFLOP/s → % of the chip's bf16 peak.
 
 VERDICT r4 weak-point 4: rates like "289 clips/s" are unanchored without
 a FLOP denominator — good, or 10× off peak? This tool computes, for
@@ -10,8 +10,11 @@ every BASELINE family plus the fused i3d step at BOTH geometries:
     counts multiply+add as 2 FLOPs, so resnet50@224 reports ~8.0 G —
     the canonical number.
   * the measured in-graph rate (bench.py's shared scan harness, fresh).
-  * achieved TFLOP/s = FLOPs/unit × rate, and % of the v5e chip's dense
-    bf16 peak (197 TFLOP/s, the public spec).
+  * achieved TFLOP/s = FLOPs/unit × rate, and % of the running chip's
+    dense bf16 peak, looked up by ``device_kind`` in
+    :data:`BF16_PEAK_TFLOPS` — a device that is not in the table is an
+    error, never a default (so there is no CPU mode: a CPU rate over a
+    TPU peak is not a number).
 
 Precision caveat printed with the table: at ``mixed`` (3-pass bf16)
 every matmul EXECUTES ~3× its nominal FLOPs, so hardware occupancy on
@@ -19,8 +22,8 @@ matmul-dominated graphs is ≈3× the quoted model-FLOPs utilization —
 MFU here is deliberately model-FLOPs-based (the useful-work number),
 matching how the scaling literature quotes it.
 
-    python tools/mfu_table.py                 # real TPU, full table
-    BENCH_PLATFORM=cpu python tools/mfu_table.py s3d   # smoke, one family
+    python tools/mfu_table.py                 # full table
+    python tools/mfu_table.py s3d             # one family
 
 Prints one JSON line per row (family, unit, gflops_per_unit, rate,
 achieved_tflops, mfu_pct) then a markdown table on stderr for docs.
@@ -38,7 +41,20 @@ import numpy as np
 REPO = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(REPO))
 
-V5E_BF16_PEAK_TFLOPS = 197.0   # dense bf16, public v5e spec
+# dense bf16 peak per chip, keyed by jax's ``device_kind``. Source: Google
+# Cloud documentation, "TPU v5e" (197 TFLOP/s bf16).
+BF16_PEAK_TFLOPS = {'TPU v5 lite': 197.0}
+
+
+def bf16_peak_tflops(device_kind: str) -> float:
+    try:
+        return BF16_PEAK_TFLOPS[device_kind]
+    except KeyError:
+        raise SystemExit(
+            f'mfu_table: no bf16 peak recorded for device_kind '
+            f'{device_kind!r} (known: {sorted(BF16_PEAK_TFLOPS)}) — add '
+            'its published peak with the source, do not borrow another '
+            "chip's") from None
 
 
 def _flops_of(jitted_lowered) -> float:
@@ -82,7 +98,7 @@ def fused_i3d_row(jax, ambient, pins, device, platform, h, w, batch,
     return label, 'clips', flops, rate
 
 
-def family_rows(jax, ambient, device, on_accel, picks):
+def family_rows(jax, ambient, device, picks):
     """picks: None → every family; a list (possibly empty) → exactly
     those families (so `mfu_table.py i3d` runs NO family rows, not all)."""
     from bench import bench_family_ingraph
@@ -91,7 +107,7 @@ def family_rows(jax, ambient, device, on_accel, picks):
 
     iters = int(os.environ.get('BENCH_ITERS', 4))
     for fam, (init_fn, step_fn, bshape, unit, imap,
-              count) in _family_specs(on_accel).items():
+              count) in _family_specs(on_accel=True).items():
         if picks is not None and fam not in picks:
             continue
         params = jax.device_put(transplant(init_fn()), device)
@@ -111,16 +127,15 @@ def family_rows(jax, ambient, device, on_accel, picks):
 
 def main() -> int:
     import jax
-    if os.environ.get('BENCH_PLATFORM'):
-        jax.config.update('jax_platforms', os.environ['BENCH_PLATFORM'])
     from video_features_tpu.ops.precision import MIXED_AMBIENT, MIXED_PINS
     from video_features_tpu.utils.device import (
         enable_compilation_cache, jax_device,
     )
 
     platform = jax.devices()[0].platform
-    on_accel = platform != 'cpu'
-    enable_compilation_cache('~/.cache/video_features_tpu/xla', platform)
+    device_kind = jax.devices()[0].device_kind
+    peak = bf16_peak_tflops(device_kind)     # before any measurement
+    enable_compilation_cache('auto', platform)
     device = jax_device(platform)
     precision = os.environ.get('BENCH_PRECISION', 'mixed')
     ambient, pins = ((MIXED_AMBIENT, MIXED_PINS) if precision == 'mixed'
@@ -129,29 +144,29 @@ def main() -> int:
 
     rows = []
     if not picks or 'i3d' in picks:
-        h, w = (256, 340) if on_accel else (64, 86)
-        batch = 16 if on_accel else 1
+        h, w, batch = 256, 340, 16
         rows.append(fused_i3d_row(jax, ambient, pins, device, platform,
                                   h, w, batch, f'i3d_fused_{h}x{w}'))
-        if on_accel:
-            rows.append(fused_i3d_row(jax, ambient, pins, device,
-                                      platform, 224, 224, batch,
-                                      'i3d_fused_224px'))
+        rows.append(fused_i3d_row(jax, ambient, pins, device,
+                                  platform, 224, 224, batch,
+                                  'i3d_fused_224px'))
     rows.extend(family_rows(
-        jax, ambient, device, on_accel,
+        jax, ambient, device,
         None if not picks else [p for p in picks if p != 'i3d']))
 
     md = ['| step | GFLOPs/unit | measured rate | achieved TFLOP/s | '
-          '% of v5e bf16 peak |', '|---|---|---|---|---|']
+          f'% of {device_kind} bf16 peak |', '|---|---|---|---|---|']
     for label, unit, flops, rate in rows:
         tflops = flops * rate / 1e12
-        mfu = tflops / V5E_BF16_PEAK_TFLOPS * 100
+        mfu = tflops / peak * 100
         print(json.dumps({
             'step': label, 'unit': unit, 'precision': precision,
             'gflops_per_unit': round(flops / 1e9, 2),
             'rate': round(rate, 2),
             'achieved_tflops': round(tflops, 2),
-            'mfu_pct_v5e_bf16': round(mfu, 2),
+            'device_kind': device_kind,
+            'bf16_peak_tflops': peak,
+            'mfu_pct_bf16': round(mfu, 2),
         }), flush=True)
         md.append(f'| {label} | {flops / 1e9:.1f} | {rate:.1f} {unit}/s '
                   f'| {tflops:.1f} | {mfu:.1f}% |')

@@ -93,7 +93,7 @@ def main() -> int:
 
     platform = jax.devices()[0].platform
     on_accel = platform != 'cpu'
-    enable_compilation_cache('~/.cache/video_features_tpu/xla', platform)
+    enable_compilation_cache('auto', platform)
     device = jax_device(platform)
     ambient = os.environ.get('BENCH_PRECISION_AMBIENT', MIXED_AMBIENT)
     iters = int(os.environ.get('BENCH_ITERS', 4 if on_accel else 1))

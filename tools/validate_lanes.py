@@ -80,8 +80,7 @@ def main() -> int:
     import jax
 
     from video_features_tpu.utils.device import enable_compilation_cache
-    enable_compilation_cache('~/.cache/video_features_tpu/xla',
-                             jax.devices()[0].platform)
+    enable_compilation_cache('auto', jax.devices()[0].platform)
     rels = measure_drift()
     ok = True
     for impl, rel in rels.items():

@@ -456,7 +456,7 @@ def main() -> int:
 
     platform = jax.devices()[0].platform
     on_accel = platform != 'cpu'
-    enable_compilation_cache('~/.cache/video_features_tpu/xla', platform)
+    enable_compilation_cache('auto', platform)
     n = int(os.environ.get('N_VIDEOS', 4 if on_accel else 2))
     seconds = float(os.environ.get('WORKLIST_SECONDS',
                                    10 if on_accel else 2))

@@ -32,8 +32,8 @@ from typing import Dict, Iterable, List, Optional, Set
 
 from video_features_tpu.analysis.core import (
     CACHE_KEY_PY, CONFIG_PY, FARM_RECIPES_PY, FARM_WORKER_PY,
-    HOST_TRANSFORMS_PY, INGRESS_HTTP_PY, OBS_MANIFEST_PY, SERVE_CLIENT_PY,
-    SERVE_METRICS_PY, SERVE_PROTOCOL_PY, SERVE_SERVER_PY,
+    HOST_TRANSFORMS_PY, INGRESS_HTTP_PY, OBS_MANIFEST_PY, REENCODE_CLI_PY,
+    SERVE_CLIENT_PY, SERVE_METRICS_PY, SERVE_PROTOCOL_PY, SERVE_SERVER_PY,
     TRACING_PY, Finding, Module, Package, assigned_dict_keys,
     callable_name, dict_literal_str_keys, find_assignment, find_function,
     module_constants, module_level_statements, set_literal_values,
@@ -45,7 +45,8 @@ from video_features_tpu.analysis.imports import (
 
 # -- spawn-purity ------------------------------------------------------------
 
-SPAWN_ROOTS = (FARM_WORKER_PY, FARM_RECIPES_PY, HOST_TRANSFORMS_PY)
+SPAWN_ROOTS = (FARM_WORKER_PY, FARM_RECIPES_PY, HOST_TRANSFORMS_PY,
+               REENCODE_CLI_PY)
 FORBIDDEN_SPAWN_IMPORTS = ('jax', 'flax')
 
 
@@ -74,12 +75,15 @@ def closure_forbidden_imports(package: Package, roots: Iterable[str],
 
 
 def check_spawn_purity(package: Package) -> List[Finding]:
-    """The decode-farm worker contract (PR 6): ``farm/worker.py``,
-    ``farm/recipes.py``, and ``ops/host_transforms.py`` run in spawned
-    processes whose import footprint must stay at numpy/cv2 — their
-    transitive static import closure (function-level intra-package
-    imports included: a recipe's lazy helper import runs in the worker
-    at decode time) must never reach a module-level jax/flax import."""
+    """The child-process contract: ``farm/worker.py``,
+    ``farm/recipes.py`` and ``ops/host_transforms.py`` (decode-farm
+    workers, PR 6) and ``io/reencode_cli.py`` (the re-encode subprocess)
+    run in processes started AFTER the parent touched jax. A TPU belongs
+    to one process at a time, so a child that imported jax would fail or
+    hang waiting for the parent's chip: their transitive static import
+    closure (function-level intra-package imports included: a recipe's
+    lazy helper import runs in the worker at decode time) must never
+    reach a module-level jax/flax import."""
     return closure_forbidden_imports(
         package, SPAWN_ROOTS, 'spawn-purity',
         'spawn-worker (decode workers must stay jax-free — '

@@ -59,6 +59,7 @@ TRACING_PY = 'utils/tracing.py'
 FARM_WORKER_PY = 'farm/worker.py'
 FARM_RECIPES_PY = 'farm/recipes.py'
 HOST_TRANSFORMS_PY = 'ops/host_transforms.py'
+REENCODE_CLI_PY = 'io/reencode_cli.py'
 # the wire surface (vft-wire, analysis/wire.py, + the wire-literal rule):
 # the loopback protocol/client and the ingress transport/routes
 SERVE_PROTOCOL_PY = 'serve/protocol.py'

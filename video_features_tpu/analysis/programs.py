@@ -330,6 +330,7 @@ def program_signature(spec: ProgramSpec) -> Dict[str, Any]:
     ``jax.make_jaxpr`` would not — and its ClosedJaxpr carries both the
     weak-typed output avals and the closed-over consts."""
     import jax
+    import numpy as np
     traced = spec.jitted.trace(*spec.args, **spec.kwargs)
     lowered = traced.lower()
     text = lowered.as_text()
@@ -355,8 +356,11 @@ def program_signature(spec: ProgramSpec) -> Dict[str, Any]:
     # compiled HLO on every geometry. Recorded at EVERY width — the
     # jaxpr is already built, and width-conditional fields would make a
     # --mesh-widths subset run drift against a full-width lock.
-    sig['const_bytes'] = int(sum(getattr(c, 'nbytes', 0)
-                                 for c in traced.jaxpr.consts))
+    # (size × itemsize, not .nbytes: closed-over numpy arrays arrive as
+    # jax TypedNdArray, which has shape/dtype but no nbytes)
+    sig['const_bytes'] = int(sum(
+        int(c.size) * np.dtype(c.dtype).itemsize
+        for c in traced.jaxpr.consts if hasattr(c, 'dtype')))
     # keep the text around for the rule pass without re-lowering
     sig['_text'] = text
     return sig

@@ -68,21 +68,22 @@ def runtime_environment(devices: Tuple[int, ...]) -> Dict[str, Any]:
     }
 
 
-def arg_device_ids(args) -> Tuple[int, ...]:
-    """Sorted device ids across every array leaf of ``args`` — committed
+def arg_devices(args) -> list:
+    """The devices ``args`` are committed to, ordered by id — committed
     ``jax.Array`` leaves and sharded ``ShapeDtypeStruct``s both count;
     plain numpy leaves (uncommitted) contribute nothing. Empty means
-    'backend default device'."""
+    'backend default device'. These are both the store key's device ids
+    and the ``execution_devices`` a loaded executable is bound to."""
     import jax
-    ids = set()
+    devices = {}
     for leaf in jax.tree_util.tree_leaves(args):
         sharding = getattr(leaf, 'sharding', None)
-        device_set = getattr(sharding, 'device_set', None)
-        if device_set:
-            ids.update(d.id for d in device_set)
-    if not ids:
-        ids.add(jax.devices()[0].id)
-    return tuple(sorted(ids))
+        for d in getattr(sharding, 'device_set', None) or ():
+            devices[d.id] = d
+    if not devices:
+        d = jax.devices()[0]
+        devices[d.id] = d
+    return [devices[i] for i in sorted(devices)]
 
 
 def serialize_compiled(compiled) -> bytes:
@@ -96,16 +97,21 @@ def serialize_compiled(compiled) -> bytes:
     return pickle.dumps((PAYLOAD_VERSION, payload, in_tree, out_tree))
 
 
-def deserialize_compiled(blob: bytes):
-    """Inverse of :func:`serialize_compiled`; raises on any mismatch
-    (version skew, foreign pickle, truncation) — callers treat every
-    raise as a corrupt entry to evict + a compile to fall back on."""
+def deserialize_compiled(blob: bytes, devices):
+    """Inverse of :func:`serialize_compiled`, bound to ``devices`` (the
+    keyed :func:`arg_devices` — left to its default the loader binds
+    EVERY local device, wrong for a one-chip program on a four-chip
+    host); raises on any mismatch (version skew, foreign pickle,
+    truncation) — callers treat every raise as a corrupt entry to evict
+    + a compile to fall back on."""
     version, payload, in_tree, out_tree = pickle.loads(blob)
     if version != PAYLOAD_VERSION:
         raise ValueError(f'aot payload version {version} != '
                          f'{PAYLOAD_VERSION}')
     from jax.experimental import serialize_executable as se
-    return se.deserialize_and_load(payload, in_tree, out_tree)
+    return se.deserialize_and_load(payload, in_tree, out_tree,
+                                   backend=devices[0].client,
+                                   execution_devices=devices)
 
 
 class AotProgram:
@@ -146,14 +152,15 @@ def ensure_program(store: ExecStore, name: str, jitted, args: tuple,
     lowered = jitted.trace(*args, **statics).lower()
     from video_features_tpu.analysis.programs import stablehlo_sha256
     program_sha = stablehlo_sha256(lowered.as_text())
+    devices = arg_devices(args)
     components = {'program_sha': program_sha, 'lane': lane}
-    components.update(runtime_environment(arg_device_ids(args)))
+    components.update(runtime_environment(tuple(d.id for d in devices)))
     digest = exec_digest(components)
 
     blob = store.fetch(digest)
     if blob is not None:
         try:
-            compiled = deserialize_compiled(blob)
+            compiled = deserialize_compiled(blob, devices)
             return (AotProgram(name, compiled, program_sha, 'loaded'),
                     'loaded')
         except Exception:

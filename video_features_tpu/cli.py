@@ -2,7 +2,8 @@
 
 Reference main.py:7-55 behavior: load per-feature YAML, merge dotlist CLI
 (CLI wins), sanity-check, build the one extractor, shuffle the video list,
-loop ``_extract`` per video with fault isolation.
+loop ``_extract`` per video with fault isolation. Unlike the reference the
+exit code is 1 when any video failed (:func:`_exit_code`).
 """
 from __future__ import annotations
 
@@ -128,7 +129,20 @@ def main(argv: Optional[List[str]] = None) -> int:
         if jax.process_count() > 1:
             from jax.experimental import multihost_utils
             multihost_utils.sync_global_devices('extraction_done')
-    return 0
+    return _exit_code([extractor])
+
+
+def _exit_code(extractors) -> int:
+    """0 when every video was saved, skipped or served from cache; 1 when
+    any video's outcome was ``failed``. The loops keep going past a bad
+    video (the reference's fault isolation), but a fault that fails EVERY
+    video — a kernel the compiler refuses, a missing accelerator runtime —
+    must not report success."""
+    failed = sum(ex.failed_videos for ex in extractors)
+    if failed:
+        print(f'{failed} video(s) failed — see the tracebacks above',
+              file=sys.stderr)
+    return 1 if failed else 0
 
 
 def _fused_main(cli_args: dict, multihost: bool) -> int:
@@ -226,7 +240,7 @@ def _fused_main(cli_args: dict, multihost: bool) -> int:
         if jax.process_count() > 1:
             from jax.experimental import multihost_utils
             multihost_utils.sync_global_devices('extraction_done')
-    return 0
+    return _exit_code(exs.values())
 
 
 if __name__ == '__main__':

@@ -562,31 +562,24 @@ def resolve_device(device: str) -> str:
     """Map a user device string onto a JAX platform.
 
     The reference accepts torch strings ('cuda:0', 'cpu'); we keep accepting
-    them for drop-in compatibility (reference utils/utils.py:83-92 maps
-    unavailable CUDA → CPU): 'cuda*'/'tpu' → the accelerator platform if one
-    is present, else 'cpu'.
+    them for drop-in compatibility: 'cuda*'/'tpu'/'gpu' → the accelerator
+    platform. Unlike the reference (utils/utils.py:83-92 maps unavailable
+    CUDA → CPU) a missing accelerator RAISES, naming the platforms found:
+    every family yml ships ``device: 'tpu'``, and a run that quietly
+    carried on on the CPU would report success at a thousandth of the
+    speed. ``device=cpu`` is the explicit CPU path.
     """
-    import jax
-
-    from video_features_tpu.utils.device import pin_cpu_platform
+    from video_features_tpu.utils.device import (
+        accelerator_platform, pin_cpu_platform,
+    )
 
     device = str(device).lower()
-    if device == 'cpu':
-        # Pin before backends initialize: probing for accelerators here
-        # would spin up every registered plugin (a remote-TPU tunnel can
-        # block a pure-CPU run for minutes).
-        pin_cpu_platform()
-        return 'cpu'
-    platforms = {d.platform for d in jax.devices()}
-    accel = next((p for p in platforms if p != 'cpu'), None)
     if device.startswith(('cuda', 'tpu', 'gpu', 'accel')):
-        if accel is not None:
-            return accel
-        # warnings.warn (→ stderr), not print: with on_extraction=print
-        # the feature stream owns stdout (vft-lint: stdout-purity)
-        warnings.warn('An accelerator was requested but the system does '
-                      'not have one. Going to use CPU...')
-        return 'cpu'
+        return accelerator_platform()
+    # Pin before backends initialize: probing for accelerators here would
+    # take the chip away from the process that needs it
+    # (utils/device.pin_cpu_platform).
+    pin_cpu_platform()
     return 'cpu'
 
 

@@ -108,6 +108,10 @@ class BaseExtractor:
         # be enabled (tables off) by configure_obs for trace/manifest runs
         self.profile = profile
         self.tracer = Tracer(enabled=True) if profile else NULL_TRACER
+        # videos whose outcome was 'failed' (per-video loop and packed
+        # finalize both count here): fault isolation keeps the worklist
+        # going, the CLI's exit code must still say that it happened
+        self.failed_videos = 0
         self._mesh = None  # set by _ensure_mesh for data_parallel extractors
         # mesh-sharded packed execution (mesh_devices=): resolved device
         # count for the packed loop's data-parallel mesh; 1 = today's
@@ -883,6 +887,7 @@ class BaseExtractor:
             raise
         except Exception:
             outcome = 'failed'
+            self.failed_videos += 1
             log_extraction_error(video_path)
         finally:
             # report+reset even on failure so one bad video's timings never

@@ -114,7 +114,7 @@ class ExtractRAFT(BaseExtractor):
         device d's shard holds frames [d·k, d·k + k] inclusive, so its k
         flows concatenate to the global (B, Hp, Wp, 2) result in order.
         """
-        from video_features_tpu.utils.device import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         return jax.jit(shard_map(
             partial(raft_model.forward_consecutive,

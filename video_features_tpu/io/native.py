@@ -52,9 +52,9 @@ def load_library() -> Optional[ctypes.CDLL]:
         if _lib is not None or _build_failed:
             return _lib
         # Always run make: a no-op when the cached .so is fresh, a rebuild
-        # when vfdecode.cc is newer (stale libs would otherwise miss newer
-        # symbols). If make is unavailable but a prebuilt .so exists, still
-        # try it.
+        # when vfdecode.cc or the tables header is newer (stale libs would
+        # otherwise miss newer symbols or carry old tables). If make is
+        # unavailable but a prebuilt .so exists, still try it.
         if not _build() and not LIB_PATH.exists():
             _build_failed = True
             return None

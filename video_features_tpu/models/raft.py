@@ -349,14 +349,14 @@ def _lookup_impl() -> str:
     'lanes'}:
       * auto   — 'lanes' on TPU while the kernel's level-0 VMEM block fits
         ``VFT_RAFT_LANES_VMEM_MB`` (default 8 MiB); 'dense' otherwise
-        (including all non-TPU backends, where the Pallas kernels would run
+        (including the CPU, where the Pallas kernels would run
         interpreted);
       * dense  — :func:`lookup_corr_dense`, gather-free batched matmuls
         (measured ~300× faster than gather on TPU; also fastest on CPU);
       * gather — :func:`lookup_corr`, the XLA gather lowering (reference
         semantics oracle, kept for tests);
       * pallas — the Pallas window-slice kernel (ops/pallas_corr.py;
-        interpret mode automatically off-TPU);
+        interpret mode on the CPU only — :func:`_pallas_interpret`);
       * lanes  — lane-packed Pallas kernel (mask-reduce window sums, 128
         pixels per lane tile): measured 14.3 → 26.9 clips/sec/chip on the
         fused I3D two-stream bench on v5e (the lookup dominates the GRU
@@ -384,6 +384,21 @@ def _resolve_auto_lookup(h8: int, w8: int, platform: str) -> str:
     if platform == 'tpu' and block_mb <= budget:
         return 'lanes'
     return 'dense'
+
+
+def _pallas_interpret(platform: str) -> bool:
+    """Whether the Pallas lookups run in the interpreter on ``platform``:
+    compiled by Mosaic on 'tpu', interpreted on 'cpu' (the test path), and
+    an error anywhere else — a misspelt or unknown platform must not turn
+    the production kernel into an interpreted one without a word."""
+    if platform == 'tpu':
+        return False
+    if platform == 'cpu':
+        return True
+    raise ValueError(
+        f'Pallas corr lookup: unknown platform {platform!r} (the kernels '
+        "compile for 'tpu' and interpret on 'cpu'); use "
+        'VFT_RAFT_LOOKUP=dense elsewhere')
 
 
 def _normalize_frames(img: jax.Array) -> jax.Array:
@@ -481,6 +496,10 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
     (ops/precision.py): 'corr', 'iter', 'upsample'."""
     from video_features_tpu.ops.precision import pin_scope
     platform = platform or jax.default_backend()
+    if platform not in ('tpu', 'cpu', 'gpu'):
+        raise ValueError(
+            f'raft: unknown platform {platform!r} — the lookup dispatch '
+            "knows 'tpu', 'cpu' and 'gpu' (a jax.Device.platform value)")
     net, inp = jnp.split(cnet, [HIDDEN_DIM], axis=-1)
     net = jnp.tanh(net)
     inp = relu(inp)
@@ -504,7 +523,8 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
             prepped = pallas_corr.prep_pyramid_lanes_fused(
                 fmap1, fmap2, levels=CORR_LEVELS)
         lookup = partial(pallas_corr.lookup_corr_lanes, prepped,
-                         radius=CORR_RADIUS, interpret=platform != 'tpu')
+                         radius=CORR_RADIUS,
+                         interpret=_pallas_interpret(platform))
     else:
         with pin_scope(pins, 'corr'):
             pyramid = build_corr_pyramid(fmap1, fmap2)
@@ -515,7 +535,7 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
                                                    radius=CORR_RADIUS)
             lookup = partial(pallas_corr.lookup_corr, prepped,
                              radius=CORR_RADIUS,
-                             interpret=platform != 'tpu')
+                             interpret=_pallas_interpret(platform))
         elif impl == 'gather':
             lookup = partial(lookup_corr, pyramid)
         else:

@@ -202,7 +202,7 @@ def forward_sequence_parallel(params: Params, x: jax.Array, mesh,
     softmax; padded keys are masked out of every softmax and the mask
     rotates with its shard.
     """
-    from video_features_tpu.utils.device import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from video_features_tpu.ops.attention import ring_attention

@@ -145,18 +145,9 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return m, l, o, kb, vb, maskb
 
     # mark the constant-valued init as device-varying so the loop carry
-    # type-checks under shard_map's varying-axis typing (pcast is the
-    # non-deprecated spelling of pvary from jax 0.9)
-    if hasattr(lax, 'pcast'):
-        def cast(t):
-            return lax.pcast(t, axis_name, to='varying')
-    elif hasattr(lax, 'pvary'):
-        def cast(t):
-            return lax.pvary(t, axis_name)
-    else:
-        # jax 0.4.x shard_map has no varying-axis typing; no cast needed
-        def cast(t):
-            return t
+    # type-checks under shard_map's varying-axis typing
+    def cast(t):
+        return lax.pcast(t, axis_name, to='varying')
     m, l, o = (cast(t) for t in _online_init(q))
     if synthesized_mask:   # caller-provided masks are already device-varying
         kv_valid = cast(kv_valid)

@@ -18,7 +18,6 @@ work-list and the output files.
 """
 from __future__ import annotations
 
-import warnings
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -61,18 +60,6 @@ def make_mesh(n_devices: Optional[int] = None,
                 f'requested {n_devices} devices, have {len(devices)}')
         devices = devices[:n_devices]
     shape = factor_mesh_shape(len(devices), time_parallel)
-    if (shape[0] > 1 and shape[1] > 1
-            and not (hasattr(jax.lax, 'pvary') or hasattr(jax.lax, 'pcast'))):
-        # jax 0.4.x: the (data>1, time>1) sharded two-stream program was
-        # measured to diverge on the flow stream (tests/test_parallel.py
-        # test_sharded_two_stream_step_matches_single_device documents
-        # the number) — the time-axis resharding this layer was validated
-        # against postdates 0.4. Surface it loudly; data-only meshes
-        # (time_parallel=1) are verified on 0.4.x.
-        warnings.warn(
-            '(data, time) meshes are numerically unvalidated on this '
-            'jax version — flow-stream divergence was measured on '
-            '0.4.x. Use time_parallel=1 (data-only) or upgrade jax.')
     grid = np.asarray(devices, dtype=object).reshape(shape)
     return Mesh(grid, (DATA_AXIS, TIME_AXIS))
 

@@ -52,15 +52,14 @@ from typing import Callable, Dict, Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
 
+# the stream sentinels live with the windowers that pass them through
+# (a jax-free module: decode-farm workers run it); re-exported here, where
+# the scheduler consumes them and most callers import them from
+from video_features_tpu.extract.streaming import FLUSH, NUDGE  # noqa: F401
 from video_features_tpu.obs.context import trace_attrs, trace_ids_of
 from video_features_tpu.obs.events import event
 from video_features_tpu.utils.tracing import NULL_TRACER, Tracer
 
-# Stream sentinel: "no more input for now — flush partial pools". Yielded
-# by dynamic sources (the serve request feed) between arrival bursts;
-# passes through the windower/prefetch layers untouched and is consumed
-# by ``packed_batches``. Identity-compared everywhere (``is FLUSH``).
-FLUSH = object()
 
 def _request_id(task) -> Optional[str]:
     """The originating request id of a serve task (None for CLI tasks) —
@@ -68,15 +67,6 @@ def _request_id(task) -> Optional[str]:
     request as well as by video."""
     req = getattr(task, 'request', None)
     return getattr(req, 'id', None)
-
-
-# Stream marker: "a video exhausted without emitting any window" (resume
-# skip, zero-window clip, failed open). It must REACH the consumer — all
-# finalization runs on the consumer thread, and with no batch to carry the
-# news a dynamic stream would otherwise not finalize such videos until
-# drain (an all-skip request would hang). ``packed_batches`` forwards it
-# as a batchless ``(None, [], 0)`` item that triggers a sweep.
-NUDGE = object()
 
 
 def segment_name(path: str, segment) -> str:
@@ -410,6 +400,8 @@ def _finalize_task(ex, t: VideoTask, recorder=None, manifest=None,
                    else 'skipped' if t.skipped
                    else 'saved' if ex.on_extraction in ACTION_TO_EXT
                    else 'printed')
+        if t.failed:
+            ex.failed_videos += 1     # read by cli.main for the exit code
         if recorder is not None:
             recorder.instant('video_done', video=str(t.path),
                              outcome=outcome,

@@ -16,9 +16,8 @@ from functools import partial
 from typing import Optional
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from video_features_tpu.utils.device import shard_map
 
 from video_features_tpu.ops.attention import ring_attention
 from video_features_tpu.parallel.mesh import TIME_AXIS

@@ -84,7 +84,7 @@ def create_extractor(args: 'Config') -> 'BaseExtractor':
     if hasattr(args, 'get'):
         from video_features_tpu.utils.device import enable_compilation_cache
         enable_compilation_cache(args.get('compilation_cache_dir'),
-                                 str(args.get('device') or 'any'))
+                                 str(args.get('device') or 'cpu'))
     module = importlib.import_module(module_name)
     extractor = getattr(module, class_name)(args)
     if hasattr(args, 'get'):
