@@ -696,8 +696,9 @@ MANIFEST_KEYS = {'schema', 'version', 'started_at_unix_s', 'wall_s',
 
 
 CANONICAL_STAGES = {'decode', 'decode+preprocess', 'audio_dsp',
-                    'queue_idle', 'pack', 'h2d', 'model', 'd2h', 'save',
-                    'cache_lookup', 'cache_publish'}
+                    'queue_idle', 'pack', 'h2d', 'input_wait', 'model',
+                    'device_wait', 'd2h', 'save', 'cache_lookup',
+                    'cache_publish'}
 
 
 def test_stage_vocabulary_contract():

@@ -111,3 +111,152 @@ def test_stream_windows_overlapping_steps():
     # starts at 0, 2, 4, 6; start 8 would need frame 11 -> dropped
     assert [int(w[0, 0]) for w in wins] == [0, 2, 4, 6]
     assert all(w.shape == (4, 1) for w in wins)
+
+
+# -- the waiting spans: input_wait, device_wait, and the step ordinal --------
+
+def _traced():
+    from video_features_tpu.obs.spans import SpanRecorder
+    from video_features_tpu.utils.tracing import Tracer
+    return Tracer(enabled=True, recorder=SpanRecorder())
+
+
+def _spans(tracer, name):
+    return [e for e in tracer.recorder.snapshot()
+            if e['ph'] == 'X' and e['name'] == name]
+
+
+def test_input_wait_is_the_consumers_wait_for_a_slow_producer():
+    """``input_wait`` is recorded on the thread that consumes
+    ``transfer_batches`` and is about as long as the producer slept; the
+    producer's own ``h2d`` spans sit on another thread."""
+    import threading
+    import time
+    tracer = _traced()
+    nap, n = 0.03, 4
+
+    def slow_items():
+        for i in range(n):
+            time.sleep(nap)
+            yield np.full((2,), i), i
+
+    out = [meta for _, _, meta in
+           transfer_batches(slow_items(), lambda b: b, tracer=tracer)]
+    assert out == list(range(n))
+    waits, puts = _spans(tracer, 'input_wait'), _spans(tracer, 'h2d')
+    assert len(waits) == n + 1               # the last next() finds the end
+    assert {e['tid'] for e in waits} == {threading.get_ident()}
+    assert threading.get_ident() not in {e['tid'] for e in puts}
+    waited = tracer.report()['input_wait']['total_s']
+    assert 0.8 * n * nap <= waited <= n * nap + 0.25
+
+
+def test_disabled_tracer_fetches_once_and_never_syncs(monkeypatch):
+    """Tracing off: ``fetch_step`` is the one ``fetch`` call of before — no
+    ``block_until_ready`` on the hot path — and ``transfer_batches`` hands
+    back the prefetch iterator itself, so nothing is recorded anywhere."""
+    import jax
+
+    from video_features_tpu.extract.streaming import fetch_step
+    from video_features_tpu.utils.tracing import NULL_TRACER
+    synced, fetched = [], []
+    monkeypatch.setattr(jax, 'block_until_ready', synced.append)
+    out = list(overlap_fetch(
+        ((f'dev{i}', i) for i in range(3)),
+        lambda dev: fetched.append(dev) or dev.upper(), depth=2,
+        step_of=lambda: {'step': 1, 'program': 'jit_x'}))
+    assert out == [(f'DEV{i}', i) for i in range(3)]
+    assert fetched == ['dev0', 'dev1', 'dev2'] and synced == []
+    assert fetch_step(lambda o: o + 1, 41) == 42 and synced == []
+    batches = transfer_batches(iter([(np.zeros(1), 0)]), lambda b: b)
+    assert batches.gi_code.co_name == 'prefetch'     # not wrapped
+    assert len(list(batches)) == 1 and NULL_TRACER.report() == {}
+
+
+def test_device_wait_and_d2h_tile_the_old_d2h_interval():
+    """Tracing on: the wait (``block_until_ready``) and the copy (``fetch``)
+    are two spans, back to back, that together cover what ``d2h`` used to;
+    the batch's provenance rides on both, the ordinal on the wait."""
+    import time
+
+    from video_features_tpu.extract.streaming import fetch_step
+    tracer = _traced()
+
+    class Out:                       # a leaf jax.block_until_ready blocks on
+        def block_until_ready(self):
+            time.sleep(0.03)
+            return self
+
+    def fetch(out):
+        time.sleep(0.01)
+        return 'host'
+
+    t0 = time.perf_counter()
+    assert fetch_step(fetch, Out(), tracer,
+                      {'step': 7, 'program': 'jit_x'}, valid=3) == 'host'
+    whole = (time.perf_counter() - t0) * 1e6
+    (wait,), (copy,) = _spans(tracer, 'device_wait'), _spans(tracer, 'd2h')
+    assert wait['args'] == {'valid': 3, 'step': 7, 'program': 'jit_x'}
+    assert copy['args'] == {'valid': 3}
+    assert wait['dur'] >= 0.03e6 and copy['dur'] >= 0.01e6
+    # the copy follows the wait, and nothing of the fetch lies outside them
+    # (bounds loose enough for a loaded test host)
+    gap = copy['ts'] - (wait['ts'] + wait['dur'])
+    assert 0 <= gap < 50e3
+    assert copy['ts'] + copy['dur'] - wait['ts'] <= whole
+
+
+def test_step_ordinals_are_consecutive_and_shared_by_each_pair(tmp_path):
+    """Every ``model`` span and the ``device_wait`` span of the same step
+    carry one ordinal and the program's name; ordinals count on across
+    videos (the tracer is reset a video, the counter is not)."""
+    from video_features_tpu.extract.base import BaseExtractor
+
+    class Stub(BaseExtractor):
+        pass
+
+    ex = Stub('stub', 'print', str(tmp_path), str(tmp_path), False, 'cpu')
+    assert ex.step_attrs(3, 4) == {} and ex.last_step() is None   # off
+    ex.tracer = _traced()
+
+    def video(n):
+        def dispatched():
+            for i in range(n):
+                with ex.tracer.stage('model', **ex.step_attrs(3, 4)):
+                    out = f'dev{i}'
+                yield out, i
+        return list(overlap_fetch(dispatched(), str.upper, 2, ex.tracer,
+                                  ex.last_step))
+
+    assert [m for _, m in video(3)] == [0, 1, 2]
+    ex.tracer.reset()
+    video(2)
+    models, waits = _spans(ex.tracer, 'model'), _spans(ex.tracer,
+                                                       'device_wait')
+    assert [e['args']['step'] for e in models] == [1, 2, 3, 4, 5]
+    assert [e['args']['step'] for e in waits] == [1, 2, 3, 4, 5]
+    assert {e['args']['program'] for e in models + waits} == {'jit_stub_step'}
+    assert models[0]['args']['valid'] == 3
+    assert models[0]['args']['capacity'] == 4
+    for m, w in zip(models, waits):          # a step is waited for after it
+        assert w['ts'] >= m['ts'] + m['dur']  # was dispatched
+
+
+def test_h2d_span_is_the_transfer_not_its_enqueue():
+    """Tracing on: the ``h2d`` span, on the producer thread, lasts until the
+    batch has landed (``block_until_ready``), so no step is dispatched ahead
+    of its own input; tracing off, ``put`` is called and nothing waits (the
+    disabled-tracer test above spies on that)."""
+    import time
+    tracer = _traced()
+
+    class Landing:
+        def block_until_ready(self):
+            time.sleep(0.03)
+            return self
+
+    out = list(transfer_batches(iter([(np.zeros(1), 7)]),
+                                lambda b: Landing(), tracer=tracer))
+    assert isinstance(out[0][0], Landing) and out[0][2] == 7
+    (put,) = _spans(tracer, 'h2d')
+    assert put['dur'] >= 0.03e6 and put['args'] == {'staged': True}

@@ -145,3 +145,106 @@ def test_jax_profiler_trace_writes(tmp_path):
     with jax_profiler_trace(str(tmp_path)):
         jax.block_until_ready(jnp.ones((8, 8)) @ jnp.ones((8, 8)))
     assert any(tmp_path.rglob('*')), 'profiler wrote nothing'
+
+
+# -- the recorder behind manifest_out, attached(), and the programs' names ----
+
+def _stub(tmp_path):
+    from video_features_tpu.extract.base import BaseExtractor
+
+    class Stub(BaseExtractor):
+        pass
+
+    return Stub('stub', 'save_numpy', str(tmp_path), str(tmp_path), False,
+                'cpu')
+
+
+def test_no_obs_knob_no_recorder(tmp_path, monkeypatch):
+    """With ``manifest_out`` and ``trace_out`` both unset nothing is created:
+    the tracer stays the disabled singleton and ``attached()`` is empty."""
+    from video_features_tpu.obs import spans
+    monkeypatch.setattr(spans, '_ATTACHED', type(spans._ATTACHED)(maxlen=4))
+    ex = _stub(tmp_path)
+    ex.configure_obs({})
+    assert ex.tracer is NULL_TRACER
+    assert getattr(ex.tracer, 'recorder', None) is None
+    assert spans.attached() == []
+
+
+@pytest.mark.parametrize('knob', ['manifest_out', 'trace_out'])
+def test_attached_outlives_the_extractor_and_is_bounded(tmp_path,
+                                                        monkeypatch, knob):
+    """Either knob attaches a recorder (exported only under ``trace_out``)
+    that a reader in the same process still finds after the extractor is
+    freed; only the last four are kept."""
+    import gc
+    import weakref
+
+    from video_features_tpu.obs import spans
+    monkeypatch.setattr(spans, '_ATTACHED', type(spans._ATTACHED)(maxlen=4))
+    ex = _stub(tmp_path)
+    ex.configure_obs({knob: str(tmp_path / 'out.json')})
+    assert ex.tracer.enabled and ex.tracer.recorder is not None
+    assert (ex.trace_out is not None) == (knob == 'trace_out')
+    with ex.tracer.stage('save', video='a.mp4'):
+        pass
+    gone = weakref.ref(ex)
+    del ex
+    gc.collect()
+    assert gone() is None
+    (rec,) = spans.attached()
+    assert [e['name'] for e in rec.snapshot() if e['ph'] == 'X'] == ['save']
+    for i in range(5):
+        _stub(tmp_path).configure_obs({knob: str(tmp_path / f'{i}.json')})
+    assert len(spans.attached()) == 4 and rec not in spans.attached()
+
+
+@pytest.mark.parametrize('family, name', [
+    ('i3d', 'i3d_two_stream_step'), ('resnet', 'resnet_step'),
+    ('r21d', 'r21d_step'), ('s3d', 's3d_step'), ('raft', 'raft_step'),
+    ('clip', 'clip_step'), ('timm', 'timm_step'), ('vggish', 'vggish_step'),
+])
+def test_every_family_names_its_step_program(family, name):
+    """The jitted hot-path step lowers to the module ``jit_<name>`` (what the
+    device trace shows, where it showed ``jit__unknown``), and the ``program``
+    attr of the step's spans says the same."""
+    from video_features_tpu.analysis.programs import (
+        abstract_lowering, build_family,
+    )
+    ex = build_family(family)
+    (spec,) = ex.program_specs()
+    text = abstract_lowering(spec.jitted, *spec.args, **spec.kwargs).as_text()
+    assert text.startswith(f'module @jit_{name} ')
+    ex.tracer = Tracer(enabled=True)
+    assert ex.step_attrs()['program'] == f'jit_{name}'
+
+
+@pytest.mark.parametrize('lookup, prep_name, name', [
+    ('lookup_corr_lanes', 'prep_pyramid_lanes', 'raft_corr_lookup_lanes'),
+    ('lookup_corr', 'prep_pyramid', 'raft_corr_lookup'),
+], ids=['lanes', 'pallas'])
+def test_lookup_kernels_are_named_in_the_lowered_module(lookup, prep_name,
+                                                        name):
+    """Each Mosaic call of RAFT's correlation lookup carries the kernel's name
+    (one call per pyramid level), so a trace reduction can find the lookup by
+    name and not as "the step's only custom call". Lowered for the TPU from
+    the CPU: no chip, nothing runs. (Here and not in tests/test_pallas_corr.py,
+    which is in the slow lane as a whole.)"""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from video_features_tpu.models import raft
+    from video_features_tpu.ops import pallas_corr
+    rng = np.random.RandomState(1)
+    f1, f2 = (jnp.asarray(rng.randn(2, 12, 9, 32).astype(np.float32))
+              for _ in range(2))
+    pyramid = raft.build_corr_pyramid(f1, f2)
+    prepped = (pallas_corr.prep_pyramid(pyramid, 4)
+               if prep_name == 'prep_pyramid'
+               else pallas_corr.prep_pyramid_lanes(pyramid))
+    coords = jnp.zeros((2, 12, 9, 2), jnp.float32)
+    text = jax.jit(getattr(pallas_corr, lookup)).trace(
+        prepped, coords).lower(lowering_platforms=('tpu',)).as_text()
+    assert text.count(f'kernel_name = "{name}"') == len(prepped)
+    assert text.count('tpu_custom_call') == len(prepped)

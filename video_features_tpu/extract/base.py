@@ -27,7 +27,7 @@ import sys
 import time as _time
 import warnings
 from pathlib import Path
-from typing import Any, Dict, List, Union
+from typing import Any, Dict, List, Optional, Union
 
 import numpy as np
 
@@ -54,6 +54,19 @@ def log_extraction_error(video_path, request_id=None, stage=None) -> None:
     byte-clean."""
     from video_features_tpu.obs.events import log_extraction_error as _log
     _log(video_path, request_id=request_id, stage=stage)
+
+
+def named_step(fn, name: str):
+    """``fn`` under a stable function name, for ``jax.jit``: a
+    ``functools.partial`` has no ``__name__``, so every family's module
+    showed in the device trace as ``jit__unknown``. The returned callable
+    makes it ``jit_<name>`` — the name the ``program`` attr of the
+    ``model`` / ``device_wait`` spans repeats. A fresh partial, so a
+    function shared with other callers is never renamed."""
+    from functools import partial
+    step = partial(fn)
+    step.__name__ = step.__qualname__ = name
+    return step
 
 
 class BaseExtractor:
@@ -156,6 +169,11 @@ class BaseExtractor:
         # configure_obs, None = legacy behavior
         self.trace_ctx = None
         self.blackbox = None
+        # device steps dispatched over this extractor's life, counted
+        # only while the tracer is enabled (step_attrs): the ordinal
+        # that joins the span timeline to the device trace
+        self._steps_dispatched = 0
+        self._last_step = None
         # stall-watchdog feed for farm decode workers: the serve layer
         # installs ``watchdog_pending(worker_idx, n_queued)`` and the
         # DecodeFarm mirrors each worker's backlog into it (None = no
@@ -212,6 +230,38 @@ class BaseExtractor:
             from video_features_tpu.ops.precision import MIXED_PINS
             return MIXED_PINS
         return None
+
+    @property
+    def step_name(self) -> str:
+        """Function name of the family's jitted hot-path step
+        (``named_step``): the device trace shows the program as
+        ``jit_<step_name>``."""
+        return f'{self.feature_type}_step'
+
+    def step_attrs(self, valid=None, capacity=None) -> Dict[str, Any]:
+        """Attrs of the ``model`` span of the device step about to be
+        dispatched (empty, and nothing counted, under a disabled
+        tracer): ``step``, this extractor's ordinal of dispatched steps,
+        and ``program``, the name under which the device trace shows the
+        step — the k-th ``program`` module event on the device IS the
+        k-th such span, which is the only clock the host timeline and
+        the device trace share. ``valid``/``capacity`` are the batch's
+        slot counts."""
+        if not self.tracer.enabled:
+            return {}
+        self._steps_dispatched += 1
+        self._last_step = {'step': self._steps_dispatched,
+                           'program': f'jit_{self.step_name}'}
+        attrs = dict(self._last_step)
+        if capacity is not None:
+            attrs.update(valid=valid, capacity=capacity)
+        return attrs
+
+    def last_step(self) -> Optional[Dict[str, Any]]:
+        """``step``/``program`` of the step dispatched last (None until
+        one was, with the tracer enabled): what its ``device_wait`` span
+        carries (``streaming.overlap_fetch``'s ``step_of``)."""
+        return self._last_step
 
     def fetch_outputs(self, out):
         """Materialize one dispatched device step's outputs on the host —
@@ -629,7 +679,8 @@ class BaseExtractor:
         """Attach the flight recorder when the ``trace_out`` /
         ``manifest_out`` knobs are set: a span recorder on the tracer
         (enabling timing if profiling is off — the printed tables stay
-        gated on ``profile``) and a per-run manifest collector. Called by
+        gated on ``profile``; also reachable through
+        ``obs.spans.attached()``) and a per-run manifest collector. Called by
         ``registry.create_extractor``; extractors constructed directly
         stay legacy."""
         trace_out = args.get('trace_out')
@@ -657,13 +708,15 @@ class BaseExtractor:
         self.trace_ctx = mint()
         if not self.tracer.enabled:
             self.tracer = Tracer(enabled=True)
+        # the timeline is kept under either knob (a stage table says how
+        # long, only spans say when); it is EXPORTED only under trace_out
+        from video_features_tpu.obs.spans import (
+            DEFAULT_CAPACITY, SpanRecorder, attach,
+        )
+        self.tracer.recorder = attach(SpanRecorder(
+            int(args.get('trace_capacity') or DEFAULT_CAPACITY)))
         if trace_out:
-            from video_features_tpu.obs.spans import (
-                DEFAULT_CAPACITY, SpanRecorder,
-            )
             self.trace_out = str(trace_out)
-            self.tracer.recorder = SpanRecorder(
-                int(args.get('trace_capacity') or DEFAULT_CAPACITY))
         if manifest_out:
             from video_features_tpu.obs.manifest import RunManifest
             self.manifest_out = str(manifest_out)

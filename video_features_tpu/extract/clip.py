@@ -18,6 +18,7 @@ from typing import List, Optional
 import jax
 import numpy as np
 
+from video_features_tpu.extract.base import named_step
 from video_features_tpu.extract.framewise import BaseFrameWiseExtractor
 from video_features_tpu.models import clip as clip_model
 from video_features_tpu.ops.transforms import (
@@ -56,8 +57,9 @@ class ExtractCLIP(BaseFrameWiseExtractor):
                                 no_transpose=set(clip_model.NO_TRANSPOSE),
                                 dtype=self.param_dtype)
         self.params = jax.device_put(params, self._device)
-        self._step = jax.jit(partial(self._forward, arch=self.arch,
-                                     dtype=self.compute_jnp_dtype))
+        self._step = jax.jit(named_step(
+            partial(self._forward, arch=self.arch,
+                    dtype=self.compute_jnp_dtype), self.step_name))
         self._text_feats: Optional[np.ndarray] = None
 
     def _load_state_dict(self, args):

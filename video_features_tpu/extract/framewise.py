@@ -202,13 +202,16 @@ class BaseFrameWiseExtractor(BaseExtractor):
             # the deferred readback is the 'd2h' stage in overlap_fetch
             for batch, _, valid, times in transfer_batches(
                     assembled(), self.put_input, tracer=self.tracer):
-                with self.tracer.stage('model'):
+                with self.tracer.stage(
+                        'model', **self.step_attrs(valid, self.batch_size)):
                     dev = self.device_step(batch)
+                self.tracer.add_occupancy('model', valid, self.batch_size)
                 yield dev, valid, times
 
         with self.precision_scope():
             for out, valid, times in overlap_fetch(
-                    dispatched(), self.fetch_outputs, depth, self.tracer):
+                    dispatched(), self.fetch_outputs, depth, self.tracer,
+                    self.last_step):
                 out = out[:valid]
                 feats.append(out)
                 timestamps.extend(times)

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from video_features_tpu.extract.base import BaseExtractor
+from video_features_tpu.extract.base import BaseExtractor, named_step
 from video_features_tpu.io.video import VideoLoader
 from video_features_tpu.models import i3d as i3d_model
 from video_features_tpu.models import raft as raft_model
@@ -235,9 +235,11 @@ class ExtractI3D(BaseExtractor):
             # the resolved device's platform drives the RAFT corr-lookup
             # dispatch (not the process default backend)
             self._step = jax.jit(
-                partial(self._stack_batch, platform=self._device.platform,
-                        pins=self.precision_pins,
-                        raft_iters=self.raft_iters),
+                named_step(
+                    partial(self._stack_batch,
+                            platform=self._device.platform,
+                            pins=self.precision_pins,
+                            raft_iters=self.raft_iters), self.step_name),
                 static_argnames=('pads', 'streams', 'resize_to'))
 
     def load_params(self, args):
@@ -267,6 +269,7 @@ class ExtractI3D(BaseExtractor):
     # -- the fused device step ----------------------------------------------
 
     _stack_batch = staticmethod(fused_two_stream_step)
+    step_name = 'i3d_two_stream_step'
 
     # -- extraction ---------------------------------------------------------
 
@@ -343,14 +346,16 @@ class ExtractI3D(BaseExtractor):
             # its own 'd2h' stage inside overlap_fetch
             for stacks, _, valid, window_idx in transfer_batches(
                     iter_batched_windows(self._stream_windows(loader),
-                                         self.batch_size),
+                                         self.batch_size, self.tracer),
                     self.put_input, tracer=self.tracer):
                 pads, resize_to = self._geometry(*stacks.shape[2:4])
-                with self.tracer.stage('model'):
+                with self.tracer.stage(
+                        'model', **self.step_attrs(valid, self.batch_size)):
                     out = self.aot_call('step', self._step,
                                         self.params, stacks, pads=pads,
                                         streams=tuple(self.streams),
                                         resize_to=resize_to)
+                self.tracer.add_occupancy('model', valid, self.batch_size)
                 # carry the input batch only for show_pred — holding it
                 # across the in-flight window would pin input HBM
                 yield (out, stacks if self.show_pred else None,
@@ -359,7 +364,7 @@ class ExtractI3D(BaseExtractor):
         with self.precision_scope():
             for out, stacks, valid, window_idx, pads, resize_to in \
                     overlap_fetch(dispatched(), self.fetch_outputs, depth,
-                                  self.tracer):
+                                  self.tracer, self.last_step):
                 for s in self.streams:
                     feats[s].append(out[s][:valid])
                 if self.show_pred:

@@ -17,7 +17,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from video_features_tpu.extract.base import BaseExtractor, StackPackingMixin
+from video_features_tpu.extract.base import (
+    BaseExtractor, StackPackingMixin, named_step,
+)
 from video_features_tpu.models import r21d as r21d_model
 from video_features_tpu.ops.transforms import (
     center_crop, normalize, resize_bilinear, to_float_zero_one,
@@ -71,9 +73,9 @@ class ExtractR21D(StackPackingMixin, BaseExtractor):
         self.params = jax.device_put(self.load_params(args), self._device)
         # dtype rides the partial as a trace-time constant: the float32
         # lane's jitted program is byte-identical to the pre-knob graph
-        self._step = jax.jit(
+        self._step = jax.jit(named_step(
             partial(self._forward_batch, arch=self.model_def['arch'],
-                    dtype=self.compute_jnp_dtype))
+                    dtype=self.compute_jnp_dtype), self.step_name))
 
     # -- model --------------------------------------------------------------
 
@@ -151,16 +153,20 @@ class ExtractR21D(StackPackingMixin, BaseExtractor):
             # the device runs k (see streaming.transfer_batches); 'model'
             # is dispatch only, the deferred readback is the 'd2h' stage
             for stacks, _, valid, window_idx in transfer_batches(
-                    iter_batched_windows(windows, self.stack_batch),
+                    iter_batched_windows(windows, self.stack_batch,
+                                         self.tracer),
                     self.put_input, tracer=self.tracer):
-                with self.tracer.stage('model'):
+                with self.tracer.stage(
+                        'model', **self.step_attrs(valid, self.stack_batch)):
                     dev = self.aot_call('step', self._step,
                                         self.params, stacks)
+                self.tracer.add_occupancy('model', valid, self.stack_batch)
                 yield dev, valid, window_idx
 
         with self.precision_scope():
             for out, valid, window_idx in overlap_fetch(
-                    dispatched(), self.fetch_outputs, depth, self.tracer):
+                    dispatched(), self.fetch_outputs, depth, self.tracer,
+                    self.last_step):
                 out = out[:valid]
                 feats.append(out)
                 if self.show_pred:

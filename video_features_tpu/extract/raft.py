@@ -27,7 +27,7 @@ from typing import Dict
 import jax
 import numpy as np
 
-from video_features_tpu.extract.base import BaseExtractor
+from video_features_tpu.extract.base import BaseExtractor, named_step
 from video_features_tpu.extract.streaming import transfer_batches
 from video_features_tpu.io.video import VideoLoader
 from video_features_tpu.models import raft as raft_model
@@ -87,10 +87,10 @@ class ExtractRAFT(BaseExtractor):
         self.params = jax.device_put(self.load_params(args), self._device)
         # thread the resolved device's platform so the corr-lookup dispatch
         # matches where the operands actually live, not the process default
-        self._step = jax.jit(partial(self._flow_batch,
-                                     platform=self._device.platform,
-                                     pins=self.precision_pins,
-                                     iters=self.raft_iters))
+        self._step = jax.jit(named_step(
+            partial(self._flow_batch, platform=self._device.platform,
+                    pins=self.precision_pins, iters=self.raft_iters),
+            self.step_name))
 
     def load_params(self, args):
         # RAFT checkpoints were saved from nn.DataParallel — prefixes are
@@ -116,12 +116,13 @@ class ExtractRAFT(BaseExtractor):
         """
         from jax import shard_map
         from jax.sharding import PartitionSpec as P
-        return jax.jit(shard_map(
+        return jax.jit(named_step(shard_map(
             partial(raft_model.forward_consecutive,
                     iters=self.raft_iters,
                     platform=self._device.platform,
                     pins=self.precision_pins),
-            mesh=self._mesh, in_specs=(P(), P('data')), out_specs=P('data')))
+            mesh=self._mesh, in_specs=(P(), P('data')),
+            out_specs=P('data')), self.step_name))
 
     def _halo_shards(self, padded: np.ndarray) -> np.ndarray:
         """(B+1, ...) frames → (n·(k+1), ...) per-device runs with the
@@ -224,7 +225,8 @@ class ExtractRAFT(BaseExtractor):
                 timestamps.extend(ts)
                 if dev is None:
                     continue
-                with self.tracer.stage('model'):
+                with self.tracer.stage(
+                        'model', **self.step_attrs(valid, self.batch_size)):
                     # aot_call on the single-device path only: the dp
                     # shard_map program keeps its direct jit dispatch
                     flow = (self._dp_step(self.params, dev)

@@ -12,6 +12,7 @@ from functools import partial
 import jax
 import numpy as np
 
+from video_features_tpu.extract.base import named_step
 from video_features_tpu.extract.framewise import BaseFrameWiseExtractor
 from video_features_tpu.models import resnet as resnet_model
 from video_features_tpu.ops.transforms import (
@@ -38,8 +39,9 @@ class ExtractResNet(BaseFrameWiseExtractor):
         self.params = jax.device_put(self.load_params(args), self._device)
         # dtype rides the partial as a trace-time constant: the float32
         # lane's jitted program is byte-identical to the pre-knob graph
-        self._step = jax.jit(partial(self._forward, arch=self.model_name,
-                                     dtype=self.compute_jnp_dtype))
+        self._step = jax.jit(named_step(
+            partial(self._forward, arch=self.model_name,
+                    dtype=self.compute_jnp_dtype), self.step_name))
 
     def load_params(self, args):
         from video_features_tpu.extract.weights import load_or_init

@@ -13,7 +13,9 @@ from typing import Dict
 import jax
 import numpy as np
 
-from video_features_tpu.extract.base import BaseExtractor, StackPackingMixin
+from video_features_tpu.extract.base import (
+    BaseExtractor, StackPackingMixin, named_step,
+)
 from video_features_tpu.models import s3d as s3d_model
 from video_features_tpu.ops.transforms import (
     center_crop, resize_bilinear_scale, to_float_zero_one,
@@ -98,9 +100,10 @@ class ExtractS3D(StackPackingMixin, BaseExtractor):
                 self._aot_invalidate()
             scale = 224.0 / min(h, w)
             resize_hw = (math.floor(h * scale), math.floor(w * scale))
-            step = jax.jit(partial(self._forward, resize_hw=resize_hw,
-                                   resize_scale=scale,
-                                   dtype=self.compute_jnp_dtype))
+            step = jax.jit(named_step(
+                partial(self._forward, resize_hw=resize_hw,
+                        resize_scale=scale, dtype=self.compute_jnp_dtype),
+                self.step_name))
             cached = self._geom_steps[(h, w)] = (step, resize_hw, scale)
         return cached
 
@@ -153,19 +156,22 @@ class ExtractS3D(StackPackingMixin, BaseExtractor):
             # (see streaming.transfer_batches). 'model' is dispatch only;
             # the deferred readback is the 'd2h' stage in overlap_fetch.
             for stacks, host_stacks, valid, window_idx in transfer_batches(
-                    iter_batched_windows(windows, self.stack_batch),
+                    iter_batched_windows(windows, self.stack_batch,
+                                         self.tracer),
                     self.put_input, keep_host=self.show_pred,
                     tracer=self.tracer):
                 step, resize_hw, scale = \
                     self._geometry_step(*stacks.shape[2:4])
-                with self.tracer.stage('model'):
+                with self.tracer.stage(
+                        'model', **self.step_attrs(valid, self.stack_batch)):
                     dev = self.aot_call('step', step, self.params, stacks)
+                self.tracer.add_occupancy('model', valid, self.stack_batch)
                 yield dev, host_stacks, valid, window_idx, resize_hw, scale
 
         with self.precision_scope():
             for out, host_stacks, valid, window_idx, resize_hw, scale in \
                     overlap_fetch(dispatched(), self.fetch_outputs, depth,
-                                  self.tracer):
+                                  self.tracer, self.last_step):
                 out = out[:valid]
                 feats.append(out)
                 if self.show_pred:

@@ -46,23 +46,28 @@ FLUSH = object()
 NUDGE = object()
 
 
-def iter_batched_windows(windows: Iterable[np.ndarray],
-                         batch: int) -> Iterator[tuple]:
+def iter_batched_windows(windows: Iterable[np.ndarray], batch: int,
+                         tracer: Tracer = NULL_TRACER) -> Iterator[tuple]:
     """Group streamed windows into fixed-size ``(stacks, valid, window_idx)``
     batches: a (batch, ...) array whose tail is padded by repeating the last
     window (mask with ``[:valid]``) plus the absolute index of the batch's
     first window. Generator form so a caller can map a device transfer over
     it inside ``io.video.prefetch`` — batch assembly AND host→device copy
-    then run on the producer thread, overlapped with device compute.
+    then run on the producer thread, overlapped with device compute. The
+    assembly copy is timed as the ``pack`` stage, like the packed
+    scheduler's (35 MB a batch in the i3d loop: with ``stream_windows``'
+    window copies, 60 ms of every video start that no span covered; my
+    chip run, PR 25).
     """
     pending: List[np.ndarray] = []
     window_idx = 0
 
     def flush():
         valid = len(pending)
-        while len(pending) < batch:
-            pending.append(pending[-1])
-        out = (np.stack(pending), valid, window_idx)
+        with tracer.stage('pack', valid=valid, capacity=batch):
+            while len(pending) < batch:
+                pending.append(pending[-1])
+            out = (np.stack(pending), valid, window_idx)
         pending.clear()
         return out, valid
 
@@ -97,10 +102,17 @@ def transfer_batches(items: Iterable[tuple], put, keep_host: bool = False,
     like show_pred read pixels without paying a D2H round trip). The
     single home for this transfer policy — every batched extractor
     drives its device loop through here. ``tracer`` attributes the
-    producer-thread transfer time to the ``h2d`` stage (it runs outside
-    the extract loop, so without this it would be invisible in the
-    profile table); the span's ``staged`` attr records whether the
-    transfer was issued ahead of need (depth > 1) or on demand.
+    producer-thread transfer time to the ``h2d`` stage (``put_traced``;
+    it runs outside the extract loop, so without this it would be
+    invisible in the profile table); the span's ``staged`` attr records
+    whether the transfer was issued ahead of need (depth > 1) or on
+    demand. The
+    CONSUMER side of the same queue is the ``input_wait`` stage: each
+    ``next()`` of the returned iterator is timed on the thread that
+    calls it (the dispatch thread), so the profile says not only that
+    decode/pack/h2d were busy but how long the device loop stood
+    waiting for them. With a disabled tracer the prefetch iterator is
+    returned as is.
     """
     from video_features_tpu.io.video import prefetch
 
@@ -113,24 +125,71 @@ def transfer_batches(items: Iterable[tuple], put, keep_host: bool = False,
             # batchless scheduler marker (packed NUDGE): nothing to copy
             return (None, None) + tuple(item[1:])
         host = batch if keep_host else None
-        with tracer.stage('h2d', staged=staged):
-            dev = put(batch)
+        dev = put_traced(put, batch, tracer, staged=staged)
         return (dev, host) + tuple(item[1:])
 
-    return prefetch(map(to_device, items), depth=depth)
+    ready = prefetch(map(to_device, items), depth=depth)
+    if not tracer.enabled:
+        return ready
+    return tracer.wrap_iter('input_wait', ready)
+
+
+def put_traced(put, batch, tracer: Tracer = NULL_TRACER, **attrs):
+    """Place one host batch on the device under the ``h2d`` stage. With a
+    disabled tracer this is the bare ``put(batch)``: ``device_put`` only
+    ENQUEUES the copy and returns. With tracing on the span waits for
+    the copy (``jax.block_until_ready``), so ``h2d`` is the transfer and
+    not its enqueue: a 35 MB i3d batch is enqueued in 2 ms and lands 27
+    ms later, during which the step that needs it cannot start (my chip
+    run, PR 25) — time no span covered. It also means a step is never
+    dispatched ahead of its own input, which is what makes "a step
+    cannot start before it is dispatched" a tight bound for joining the
+    timeline to the device trace. The wait is on the producer thread,
+    not the dispatch thread."""
+    if not tracer.enabled:
+        return put(batch)
+    import jax
+    with tracer.stage('h2d', **attrs):
+        return jax.block_until_ready(put(batch))
+
+
+def fetch_step(fetch, out, tracer: Tracer = NULL_TRACER,
+               step: Optional[dict] = None, **attrs):
+    """Materialize one dispatched step's outputs on the host — the one
+    home of the sync point, shared by ``overlap_fetch`` and the packed
+    schedulers. With a disabled tracer this is the single ``fetch(out)``
+    call (no extra sync on the hot path). With tracing on, the wait and
+    the copy are told apart: ``jax.block_until_ready`` under
+    ``device_wait`` (carrying ``step``: the ordinal and program name of
+    the step's ``model`` span), then ``fetch`` under ``d2h``, which then
+    only copies. ``attrs`` (batch provenance) ride on both spans. An
+    asynchronously raised execution error surfaces from either call, so
+    callers keep their fault isolation around this one."""
+    if not tracer.enabled:
+        return fetch(out)
+    import jax
+    with tracer.stage('device_wait', **attrs, **(step or {})):
+        jax.block_until_ready(out)
+    with tracer.stage('d2h', **attrs):
+        return fetch(out)
 
 
 def overlap_fetch(dispatched: Iterable[tuple], fetch, depth: int,
-                  tracer: Tracer = NULL_TRACER) -> Iterator[tuple]:
+                  tracer: Tracer = NULL_TRACER,
+                  step_of: Optional[Callable] = None) -> Iterator[tuple]:
     """Defer device→host readback ``depth`` dispatches behind compute.
 
     ``dispatched`` yields ``(device_out, *meta)`` where ``device_out``
     is a just-dispatched step's output (device arrays — no forced
     readback yet); items queue until ``depth`` of them are in flight,
-    then the OLDEST is materialized with ``fetch`` (timed as the ``d2h``
-    stage) and yielded as ``(host_out, *meta)`` — so on async backends
+    then the OLDEST is materialized with ``fetch`` (through
+    ``fetch_step``: the ``device_wait`` + ``d2h`` stages) and yielded as
+    ``(host_out, *meta)`` — so on async backends
     the readback + whatever the consumer does with the results (feature
     append, save) overlap the device computing the next batches.
+    ``step_of()`` gives the span attrs of the step just dispatched
+    (``BaseExtractor.last_step``), so a step's ``device_wait`` span
+    carries the ordinal of its ``model`` span.
     ``depth=1`` is the old synchronous order: every dispatch is
     immediately followed by its fetch. Results always come back in
     dispatch order, so consumers are unchanged beyond the deferral.
@@ -144,13 +203,12 @@ def overlap_fetch(dispatched: Iterable[tuple], fetch, depth: int,
     pending: 'deque' = deque()
 
     def materialize():
-        item = pending.popleft()
-        with tracer.stage('d2h'):
-            host = fetch(item[0])
+        item, step = pending.popleft()
+        host = fetch_step(fetch, item[0], tracer, step)
         return (host,) + tuple(item[1:])
 
     for item in dispatched:
-        pending.append(item)
+        pending.append((item, step_of() if step_of is not None else None))
         if len(pending) >= depth:
             yield materialize()
     while pending:
@@ -272,7 +330,8 @@ def stream_windows(batches: Iterable, win: int, step: int,
     """Yield (win, ...)-shaped frame windows from a loader's batch stream.
 
     ``batches`` iterates ``(batch, times, indices)`` tuples (the VideoLoader
-    protocol); decode work inside ``next()`` is timed under ``stage``.
+    protocol); decode work inside ``next()`` is timed under ``stage``, the
+    copy that assembles each window under ``pack``.
 
     ``frame_range`` (segment queries) restricts the emitted windows to
     those OVERLAPPING the half-open frame range ``[start_f, end_f)``:
@@ -315,7 +374,9 @@ def stream_windows(batches: Iterable, win: int, step: int,
             offset += d
         while next_start + win <= offset + len(buf):
             s = next_start - offset
-            yield np.stack(buf[s:s + win])
+            with tracer.stage('pack'):
+                window = np.stack(buf[s:s + win])
+            yield window
             next_start += step
             if end_f is not None and next_start >= end_f:
                 return      # past the range: stop decoding the tail

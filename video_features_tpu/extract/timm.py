@@ -21,6 +21,7 @@ from typing import Any, Dict
 import jax
 import numpy as np
 
+from video_features_tpu.extract.base import named_step
 from video_features_tpu.extract.framewise import BaseFrameWiseExtractor
 from video_features_tpu.models import beit as beit_model
 from video_features_tpu.models import convnext as convnext_model
@@ -235,12 +236,12 @@ class ExtractTIMM(BaseFrameWiseExtractor):
                     params, x, mesh, arch=arch)
 
             self.params = put_replicated(mesh, self.params)
-            self._step = jax.jit(_sp_forward)
+            self._step = jax.jit(named_step(_sp_forward, self.step_name))
             return
-        self._step = jax.jit(partial(
+        self._step = jax.jit(named_step(partial(
             self._forward, family=self.family, arch=self.arch,
             mean=self.data_cfg['mean'], std=self.data_cfg['std'],
-            dtype=self.compute_jnp_dtype))
+            dtype=self.compute_jnp_dtype), self.step_name))
 
     def _load_params(self, args):
         from video_features_tpu.transplant.torch2jax import (

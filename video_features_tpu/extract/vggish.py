@@ -18,7 +18,7 @@ from typing import Dict
 import jax
 import numpy as np
 
-from video_features_tpu.extract.base import BaseExtractor
+from video_features_tpu.extract.base import BaseExtractor, named_step
 from video_features_tpu.models import vggish as vggish_model
 from video_features_tpu.ops.audio import waveform_to_examples
 from video_features_tpu.utils.device import jax_device
@@ -81,9 +81,10 @@ class ExtractVGGish(BaseExtractor):
             def _bf16_forward(params, x):
                 return features_to_f32(vggish_model.forward(params, x))
 
-            self._step = jax.jit(_bf16_forward)
+            self._step = jax.jit(named_step(_bf16_forward, self.step_name))
         else:
-            self._step = jax.jit(vggish_model.forward)
+            self._step = jax.jit(named_step(vggish_model.forward,
+                                            self.step_name))
         if self.post_process:
             pca = np.load(pca_path)
             self._pca_eig = jax.device_put(
@@ -180,9 +181,8 @@ class ExtractVGGish(BaseExtractor):
             # which is exactly the invisible promotion seam the rule
             # exists to keep pinned. Byte-identical to the implicit
             # path — tests/test_programs.py holds the parity.
-            with self.tracer.stage('model'):
-                feats = self._run_batched(
-                    examples.astype(np.float32)[..., None])  # NHWC
+            feats = self._run_batched(
+                examples.astype(np.float32)[..., None])  # NHWC
             if self.post_process:
                 feats = np.asarray(vggish_model.postprocess(
                     self._pca_eig, self._pca_means, feats)).astype(np.uint8)
@@ -217,7 +217,11 @@ class ExtractVGGish(BaseExtractor):
                 if self._mesh is not None:
                     chunk = self._put_batch(chunk)
                 # aot_call: resident/store-loaded executable when the
-                # aot store is on (byte-identical), else the jit call
-                out.append(np.asarray(self.aot_call(
-                    'step', self._step, self.params, chunk))[:valid])
+                # aot store is on (byte-identical), else the jit call.
+                # One 'model' span a chunk (dispatch AND readback: this
+                # loop is synchronous), so each carries a step ordinal
+                with self.tracer.stage('model',
+                                       **self.step_attrs(valid, B)):
+                    out.append(np.asarray(self.aot_call(
+                        'step', self._step, self.params, chunk))[:valid])
         return np.concatenate(out, axis=0)

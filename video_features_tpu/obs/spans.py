@@ -221,6 +221,26 @@ def _jsonable(v: Any) -> Any:
 NULL_RECORDER = SpanRecorder(capacity=1, enabled=False)
 
 
+# the recorders extractors attached in this process (configure_obs),
+# newest last. Strong references on purpose: a same-process reader (the
+# benchmark's idle_by_span) asks AFTER the extractor was freed. Bounded,
+# so a process that builds extractors all day keeps four rings, not all.
+_ATTACHED: 'deque' = deque(maxlen=4)
+
+
+def attach(recorder: SpanRecorder) -> SpanRecorder:
+    """Register ``recorder`` for :func:`attached` and return it."""
+    _ATTACHED.append(recorder)
+    return recorder
+
+
+def attached() -> List[SpanRecorder]:
+    """The recorders attached in this process, newest last (at most the
+    last four) — the one door for a reader in the same process. The
+    black box and serve's ``/trace`` route keep their own lists."""
+    return list(_ATTACHED)
+
+
 def merge_traces(recorders: Iterable[SpanRecorder],
                  limit: Optional[int] = None) -> List[Dict[str, Any]]:
     """One ts-sorted event list over several recorders (the serve daemon

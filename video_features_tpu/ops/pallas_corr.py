@@ -155,6 +155,9 @@ def _lookup_level(corr_t: jax.Array, coords: jax.Array, radius: int,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_pad, p1, p1), corr_t.dtype),
         interpret=interpret,
+        # the name the device trace and the lowered module show the
+        # Mosaic call under (one call per pyramid level)
+        name='raft_corr_lookup',
     )(xs, ys, wx, wy, corr_t)
     return out[:n].reshape(n, p1 * p1)
 
@@ -311,6 +314,7 @@ def _lookup_level_lanes(corr_t: jax.Array, coords: jax.Array, radius: int,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((p1 * p1, n_pad), corr_t.dtype),
         interpret=interpret,
+        name='raft_corr_lookup_lanes',
     )(xi, yi, fx, fy, corr_t)
     return out[:, :n].T                                  # (N, 81)
 

@@ -492,7 +492,7 @@ def run_packed(ex, video_paths: Iterable,
     extractor has no farm recipe or the host can't spawn workers.
     """
     from video_features_tpu.extract.streaming import (
-        stream_windows_across_videos, transfer_batches,
+        fetch_step, stream_windows_across_videos, transfer_batches,
     )
     from video_features_tpu.io.video import prefetch_across_videos
 
@@ -763,7 +763,7 @@ def run_packed(ex, video_paths: Iterable,
     from collections import deque
     depth = max(int(inflight if inflight is not None
                     else getattr(ex, 'inflight', 1) or 1), 1)
-    # (out_dev, prov, valid, batch_videos, batch_traces)
+    # (out_dev, prov, valid, batch_videos, batch_traces, step attrs)
     pending: 'deque' = deque()
     ex._inflight_now = 0
 
@@ -790,21 +790,20 @@ def run_packed(ex, video_paths: Iterable,
             task.done += 1
 
     def sync_oldest() -> None:
-        """Materialize the OLDEST in-flight batch: the deferred D2H (its
-        own ``d2h`` stage — readback must not launder into compute time)
-        plus row scatter; asynchronously raised execution faults surface
-        here and doom only this batch's videos."""
-        out_dev, prov, valid, batch_videos, batch_traces = \
+        """Materialize the OLDEST in-flight batch: the wait for the
+        step and the deferred D2H (``fetch_step``: the ``device_wait``
+        and ``d2h`` stages — neither may launder into compute time) plus
+        row scatter; asynchronously raised execution faults surface here
+        and doom only this batch's videos."""
+        out_dev, prov, valid, batch_videos, batch_traces, step = \
             pending.popleft()
         ex._inflight_now = len(pending)
         try:
-            with ex.tracer.stage(
-                    'd2h', videos=batch_videos, valid=valid,
-                    capacity=batch,
-                    **({'trace_ids': batch_traces} if batch_traces
-                       else {}),
-                    **mesh_attrs(valid)):
-                out = ex.fetch_outputs(out_dev)
+            out = fetch_step(
+                ex.fetch_outputs, out_dev, ex.tracer, step,
+                videos=batch_videos, valid=valid, capacity=batch,
+                **({'trace_ids': batch_traces} if batch_traces else {}),
+                **mesh_attrs(valid))
         except KeyboardInterrupt:
             raise
         except Exception:
@@ -868,14 +867,16 @@ def run_packed(ex, video_paths: Iterable,
             try:
                 # 'model' times dispatch + any compute the backend runs
                 # synchronously; the wait-for-results tail lands on the
-                # 'd2h' stage at the sync point (their shares sum to the
-                # old all-in 'model' share)
+                # 'device_wait' stage at the sync point, under the same
+                # step ordinal (model + device_wait + d2h shares sum to
+                # the old all-in 'model' share)
+                step = ex.step_attrs()
                 with ex.tracer.stage(
                         'model', videos=batch_videos, valid=valid,
                         capacity=batch,
                         **({'trace_ids': batch_traces} if batch_traces
                            else {}),
-                        **mesh_attrs(valid)):
+                        **mesh_attrs(valid), **step):
                     out = ex.packed_step(dev)
             except KeyboardInterrupt:
                 raise
@@ -906,7 +907,7 @@ def run_packed(ex, video_paths: Iterable,
                         costed[identity] = (tuple(shape),
                                             getattr(dev, 'dtype', None))
             pending.append((out, prov, valid, batch_videos,
-                            batch_traces))
+                            batch_traces, step))
             ex._inflight_now = len(pending)
             while len(pending) >= depth:
                 sync_oldest()
@@ -1041,10 +1042,11 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
     ``run_packed``: H2D runs inline per batch (its own ``h2d`` stage)
     rather than through ``transfer_batches`` — with N families
     interleaving on one device loop there is no single "next batch" to
-    overlap against.
+    overlap against (so ``input_wait`` is recorded here, on the lead
+    tracer, around the window prefetch).
     """
     from video_features_tpu.extract.streaming import (
-        stream_windows_across_videos,
+        fetch_step, put_traced, stream_windows_across_videos,
     )
     from video_features_tpu.io.video import prefetch_across_videos
 
@@ -1221,6 +1223,11 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
             yield item
 
     ahead = prefetch_across_videos(counted(timed), decode_ahead * max_batch)
+    if lead.tracer.enabled:
+        # the consumer side of the decode queue: this loop packs and
+        # transfers inline (no transfer_batches), so the dispatch
+        # thread's wait for input is its next() on the window prefetch
+        ahead = lead.tracer.wrap_iter('input_wait', ahead)
 
     from collections import deque
     depth = {fam: max(int(inflight if inflight is not None
@@ -1278,13 +1285,12 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
 
     def sync_oldest(fam: str) -> None:
         ex = exs[fam]
-        out_dev, prov, valid, batch_videos = pending[fam].popleft()
+        out_dev, prov, valid, batch_videos, step = pending[fam].popleft()
         ex._inflight_now = len(pending[fam])
         try:
-            with ex.tracer.stage('d2h', videos=batch_videos,
-                                 valid=valid, capacity=fam_batch[fam],
-                                 family=fam):
-                out = ex.fetch_outputs(out_dev)
+            out = fetch_step(ex.fetch_outputs, out_dev, ex.tracer, step,
+                             videos=batch_videos, valid=valid,
+                             capacity=fam_batch[fam], family=fam)
         except KeyboardInterrupt:
             raise
         except Exception:
@@ -1326,15 +1332,14 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
             # per-batch precision scope: adjacent batches may belong to
             # families on different precision lanes
             with ex.precision_scope():
-                with ex.tracer.stage('h2d', videos=batch_videos,
-                                     valid=valid,
-                                     capacity=fam_batch[fam],
-                                     family=fam):
-                    dev = ex.put_input(stacked)
+                dev = put_traced(ex.put_input, stacked, ex.tracer,
+                                 videos=batch_videos, valid=valid,
+                                 capacity=fam_batch[fam], family=fam)
+                step = ex.step_attrs()
                 with ex.tracer.stage('model', videos=batch_videos,
                                      valid=valid,
                                      capacity=fam_batch[fam],
-                                     family=fam):
+                                     family=fam, **step):
                     out = ex.packed_step(dev)
         except KeyboardInterrupt:
             raise
@@ -1352,7 +1357,7 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
                             f'{getattr(dev, "dtype", "")}{lane}')
                 costed[fam].setdefault(
                     identity, (tuple(shape), getattr(dev, 'dtype', None)))
-        pending[fam].append((out, prov, valid, batch_videos))
+        pending[fam].append((out, prov, valid, batch_videos, step))
         ex._inflight_now = len(pending[fam])
         while len(pending[fam]) >= depth[fam]:
             sync_oldest(fam)

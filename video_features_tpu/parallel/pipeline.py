@@ -51,7 +51,9 @@ def build_sharded_two_stream_step(mesh: Mesh,
     # corr-lookup dispatch from them, not the process default backend
     platform = mesh.devices.flat[0].platform
 
-    def step(params, stacks, pads, crop_size, resize_to):
+    # named like the single-device step (ExtractI3D.step_name), so the
+    # device trace shows jit_i3d_two_stream_step on either path
+    def i3d_two_stream_step(params, stacks, pads, crop_size, resize_to):
         kw = {} if raft_iters is None else {'raft_iters': raft_iters}
         return fused_two_stream_step(params, stacks, pads, streams,
                                      constrain_pairs=constrain_pairs,
@@ -59,7 +61,7 @@ def build_sharded_two_stream_step(mesh: Mesh,
                                      pins=pins, resize_to=resize_to, **kw)
 
     jitted = jax.jit(
-        step,
+        i3d_two_stream_step,
         static_argnums=(2, 3, 4),
         in_shardings=(replicated(mesh), batch_sharding(mesh)),
         out_shardings=replicated(mesh),

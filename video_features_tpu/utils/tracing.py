@@ -31,20 +31,28 @@ from typing import Dict, Iterable, Iterator, List, Optional
 # Canonical pipeline stage names — the shared vocabulary across the stage
 # table, the span timeline (obs/spans), the serve metrics families
 # (vft_stage_*), and bench stage_reports. A stage either appears under
-# one of these names everywhere or under its own new name everywhere; in
-# particular `model` is DISPATCH + compute-up-to-sync only, and `d2h` is
-# the deferred device→host readback + host copy (split out so readback
-# can overlap compute without laundering into compute time — the async
-# device loop, parallel/packing.py). Pinned by tests/test_obs.py.
+# one of these names everywhere or under its own new name everywhere.
+# Each device step is three spans on the dispatch thread: `model` is
+# DISPATCH only (plus whatever the backend computes synchronously),
+# `device_wait` is the host blocked until the step's outputs are ready,
+# and `d2h` is the device→host copy of outputs that are ready (split
+# out so neither the wait nor the readback launders into compute time —
+# the async device loop, parallel/packing.py). `input_wait` is the
+# consumer side of the transfer queue: the dispatch thread blocked for
+# the next batch, where `decode+preprocess`, `pack` and `h2d` time the
+# producer side of the same queue (time busy, not time waited for).
+# Pinned by tests/test_obs.py.
 STAGES = (
     'decode',             # raw decode (stack families without preprocess)
     'decode+preprocess',  # decode + host transform on the prefetch thread
     'audio_dsp',          # vggish: host-side mel/log-mel DSP on the wav
     'queue_idle',         # serve: blocking waits on an idle request feed
-    'pack',               # packed batch assembly (pool flush + np.stack)
-    'h2d',                # host→device input transfer (producer thread)
-    'model',              # device-step dispatch + compute until the sync
-    'd2h',                # deferred device→host readback of step outputs
+    'pack',               # batch assembly copies (window and batch np.stack)
+    'h2d',                # host→device input transfer, until it has landed
+    'input_wait',         # dispatch thread blocked for the next batch
+    'model',              # device-step dispatch (step=, program= attrs)
+    'device_wait',        # host blocked until that step's outputs are ready
+    'd2h',                # device→host copy of a finished step's outputs
     'save',               # output materialization (.npy/.pkl writes)
     'cache_lookup',       # content-addressed cache consult
     'cache_publish',      # content-addressed cache publish
