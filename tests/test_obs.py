@@ -692,7 +692,11 @@ TRACE_EVENT_KEYS = {'name', 'ph', 'ts', 'dur', 'pid', 'tid', 'args', 's'}
 MANIFEST_KEYS = {'schema', 'version', 'started_at_unix_s', 'wall_s',
                  'config', 'fingerprints', 'videos', 'outcomes', 'stages',
                  'compile', 'executables', 'farm', 'mesh', 'ingress',
-                 'programs_lock', 'aot', 'index', 'slo'}
+                 'programs_lock', 'aot', 'index', 'slo',
+                 # in-process decode lanes of packed runs
+                 # (extract/streaming.py): the lane plan + per-lane
+                 # counters, {} on farm-backed and per-video runs
+                 'decode'}
 
 
 CANONICAL_STAGES = {'decode', 'decode+preprocess', 'audio_dsp',

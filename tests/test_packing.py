@@ -500,3 +500,405 @@ def test_cli_routes_packed(tmp_path, tmp_path_factory, capsys):
         feats = np.load(make_path(str(out / 'resnet' / 'resnet18'), p,
                                   'resnet', '.npy'))
         assert feats.shape[1] == 512
+
+
+# -- decode lanes: several videos of a packed worklist at once ---------------
+
+@pytest.mark.parametrize('cores,videos,explicit,lanes,farm_workers', [
+    (8, None, None, 4, 0),    # unset, a serve feed: cores halved
+    (8, 32, None, 4, 0),
+    (16, 32, None, 4, 0),     # at most 4
+    (6, 32, None, 3, 0),
+    (2, 32, None, 1, 0),
+    (1, 32, None, 1, 0),      # at least 1
+    (8, 1, None, 1, 0),       # never more than the videos at hand
+    (8, 3, None, 3, 0),
+    (8, 0, None, 1, 0),
+    (8, 32, 1, 1, 0),         # explicit 1: the serial windower
+    (8, 32, 4, 1, 4),         # explicit N > 1: the farm's route, as before
+])
+def test_decode_lane_plan(cores, videos, explicit, lanes, farm_workers):
+    from video_features_tpu.extract.streaming import decode_lane_plan
+    plan = decode_lane_plan(explicit, videos=videos, cores=cores)
+    assert (plan['lanes'], plan['farm_workers']) == (lanes, farm_workers)
+    assert plan['cores'] == cores and plan['videos'] == videos
+    assert plan['decode_workers'] == explicit and plan['why']
+
+
+def test_decode_plan_reads_the_extractor_and_the_worklist(monkeypatch):
+    """The run-level value wins over the extractor's; unset on both means
+    lanes from the cores the process may use; only a sized worklist says
+    how many videos are at hand; an extractor built without the attribute
+    keeps the serial default."""
+    import os as _os
+    import types
+
+    from video_features_tpu.parallel.packing import _decode_plan
+    monkeypatch.setattr(_os, 'sched_getaffinity', lambda pid: set(range(8)),
+                        raising=False)
+    unset = types.SimpleNamespace(decode_workers=None)
+    assert _decode_plan(unset, None, ['a', 'b', 'c'])['lanes'] == 3
+    assert _decode_plan(unset, None, iter(['a']))['lanes'] == 4
+    assert _decode_plan(unset, 1, ['a'] * 9)['lanes'] == 1
+    assert _decode_plan(unset, 2, ['a'] * 9)['farm_workers'] == 2
+    farm = types.SimpleNamespace(decode_workers=2)
+    assert _decode_plan(farm, None, ['a'] * 9)['farm_workers'] == 2
+    assert _decode_plan(farm, 1, ['a'] * 9)['farm_workers'] == 0
+    assert _decode_plan(types.SimpleNamespace(), None,
+                        ['a'] * 9)['lanes'] == 1
+
+
+def _cores(monkeypatch, n):
+    import os as _os
+    monkeypatch.setattr(_os, 'sched_getaffinity', lambda pid: set(range(n)),
+                        raising=False)
+
+
+@pytest.fixture(scope='module')
+def lane_worklist(tmp_path_factory):
+    """Eight entries of mixed lengths: one file that cannot be opened,
+    one clip too short for a single stack, six that pack across
+    boundaries."""
+    d = tmp_path_factory.mktemp('lanevids')
+    paths = [_write_clip(d / f'l{i}.mp4', n, seed=40 + i)
+             for i, n in enumerate((9, 21, 5, 13, 30, 17))]
+    gone = str(d / 'gone.mp4')                # never created
+    short = _write_clip(d / 'short.mp4', 2, seed=50)
+    return paths[:2] + [gone] + paths[2:4] + [short] + paths[4:]
+
+
+@pytest.fixture(scope='module')
+def lane_reference(lane_worklist, tmp_path_factory):
+    """ONE r21d extractor for every lane count (the compile dominates) and
+    the serial windower's saved bytes (explicit ``decode_workers=1``)."""
+    from video_features_tpu.parallel.packing import VideoTask
+    root = tmp_path_factory.mktemp('laneout')
+    ex = create_extractor(_r21d_args(lane_worklist, root / 'unused',
+                                     root / 'tmp'))
+    assert ex.decode_workers is None          # the shipped default: unset
+    done = []
+    ex.extract_packed([VideoTask(p, out_root=str(root / 'serial'))
+                       for p in lane_worklist],
+                      decode_workers=1, on_video_done=done.append)
+    failed = {Path(t.path).name for t in done if t.failed}
+    return ex, _output_bytes(root / 'serial'), failed
+
+
+@pytest.mark.parametrize('cores,lanes', [(2, 1), (6, 3), (8, 4)])
+def test_lanes_save_the_serial_windowers_bytes(
+        lane_worklist, lane_reference, tmp_path, monkeypatch, capsys,
+        cores, lanes):
+    """The derived default at 1, 3 and 4 lanes: the saved files are the
+    serial run's byte for byte, the same video fails, every task is
+    finalised and the scheduler loses no window."""
+    from video_features_tpu.extract import streaming
+    from video_features_tpu.parallel.packing import VideoTask
+    ex, serial_bytes, serial_failed = lane_reference
+    assert serial_failed == {'gone.mp4'}
+    assert len(serial_bytes) == len(lane_worklist) - 1
+
+    _cores(monkeypatch, cores)
+    engaged = []
+    real = streaming.stream_windows_across_lanes
+
+    def spy(tasks, open_windows, n, **kw):
+        engaged.append(n)
+        return real(tasks, open_windows, n, **kw)
+
+    monkeypatch.setattr(streaming, 'stream_windows_across_lanes', spy)
+    done = []
+    ex.failed_videos = 0
+    ex.extract_packed([VideoTask(p, out_root=str(tmp_path / 'lanes'))
+                       for p in lane_worklist],
+                      on_video_done=done.append)      # must not raise
+    assert engaged == ([lanes] if lanes > 1 else [])
+    assert _output_bytes(tmp_path / 'lanes') == serial_bytes
+    assert {Path(t.path).name for t in done if t.failed} == serial_failed
+    assert len(done) == len(lane_worklist)
+    assert all(t.finalized and t.exhausted and t.done == t.emitted
+               for t in done)
+    assert ex.failed_videos == 1
+
+
+class _LaneTask:
+    """The task fields the windowers touch."""
+
+    def __init__(self, path):
+        self.path, self.emitted = path, 0
+        self.exhausted = self.failed = False
+
+
+def test_lanes_keep_running_videos_flowing_while_the_source_blocks():
+    """A dynamic source that blocks in ``next()``: the windows of a video
+    already running arrive, and it ends, while the dispatcher waits; a
+    ``FLUSH`` of the source comes after the last window of every video
+    dispatched before it, in arrival order."""
+    import threading
+
+    from video_features_tpu.extract.streaming import (
+        FLUSH, NUDGE, stream_windows_across_lanes,
+    )
+    gate = threading.Event()
+    slow, quick, late, empty = (_LaneTask(n) for n in
+                                ('slow', 'quick', 'late', 'empty'))
+
+    def source():
+        yield slow
+        yield quick
+        yield FLUSH               # behind slow's and quick's last windows
+        assert gate.wait(30)      # the feed is idle
+        yield late
+        yield empty
+        yield FLUSH
+
+    def open_windows(task):
+        n = {'slow': 70, 'quick': 3, 'late': 2, 'empty': 0}[task.path]
+        for i in range(n):
+            if task.path == 'slow':
+                time.sleep(0.002)
+            yield np.full((2,), i), i
+
+    seen = []
+    for item in stream_windows_across_lanes(source(), open_windows, 3):
+        seen.append(item)
+        if item is FLUSH and not gate.is_set():
+            # everything dispatched before the FLUSH has ended, while the
+            # source still blocks
+            assert slow.exhausted and quick.exhausted
+            assert (slow.emitted, quick.emitted) == (70, 3)
+            assert not late.exhausted and late.emitted == 0
+            gate.set()
+    first_flush = seen.index(FLUSH)
+    assert sum(1 for s in seen[:first_flush] if s is not NUDGE) == 73
+    assert [m for t, _, m in seen[:first_flush] if t is slow] == \
+        list(range(70))                       # a video's windows in order
+    tail = seen[first_flush + 1:]          # late ‖ empty, then the FLUSH
+    assert tail[-1] is FLUSH and tail.count(NUDGE) == 1 and len(tail) == 4
+    assert [(t.path, m) for t, _, m in
+            (s for s in tail[:-1] if s is not NUDGE)] == \
+        [('late', 0), ('late', 1)]
+    assert all(t.exhausted for t in (slow, quick, late, empty))
+    assert empty.emitted == 0
+
+
+def test_lane_handover_is_bounded_and_close_joins_every_lane(monkeypatch):
+    """The hand-over queue never holds more than ``lanes`` chunks however
+    slowly the consumer reads, and closing the merged generator early
+    stops and joins every lane and closes every window source."""
+    import queue as _queue
+    import threading
+
+    from video_features_tpu.extract import streaming
+    depths = []
+
+    class SpyQueue(_queue.Queue):
+        def put(self, item, block=True, timeout=None):
+            super().put(item, block, timeout)
+            depths.append((self.qsize(), self.maxsize))
+
+    monkeypatch.setattr(streaming.queue, 'Queue', SpyQueue)
+    monkeypatch.setattr(streaming, 'CHUNK_WINDOWS', 4)
+    opened, closed = [], []
+
+    def open_windows(task):
+        opened.append(task.path)
+        try:
+            for i in range(400):
+                yield np.zeros((8,), np.uint8), i
+        finally:
+            closed.append(task.path)
+
+    tasks = [_LaneTask(f'v{i}') for i in range(9)]
+    lanes = 3
+    gen = streaming.stream_windows_across_lanes(iter(tasks), open_windows,
+                                                lanes)
+    for _ in range(50):
+        next(gen)
+        time.sleep(0.002)         # a slow consumer: lanes run into the bound
+    assert depths and max(d for d, _ in depths) <= lanes
+    assert {m for _, m in depths} == {lanes}
+    gen.close()
+    alive = [t.name for t in threading.enumerate()
+             if t.name.startswith('vft-decode-')]
+    assert alive == []
+    assert sorted(opened) == sorted(closed) and 0 < len(opened) <= lanes + 1
+    # a byte bound too: one window over CHUNK_BYTES is a chunk of its own
+    monkeypatch.setattr(streaming, 'CHUNK_BYTES', 4)
+    depths.clear()
+    big = [_LaneTask('big')]
+    out = list(streaming.stream_windows_across_lanes(
+        iter(big), lambda t: ((np.zeros((8,), np.uint8), i)
+                              for i in range(5)), 2))
+    assert len(out) == 5 and big[0].emitted == 5 and big[0].exhausted
+    assert len(depths) >= 5 + 1           # 5 chunks of one, and the end
+
+
+def test_lane_spans_carry_the_lane_and_sum_to_its_busy_seconds():
+    """Tracer on: one ``decode+preprocess`` span a chunk, under the lane's
+    ``span_tid``, with the video's provenance; the spans' seconds are the
+    lanes' busy seconds (the manifest's ``decode`` section), and a live
+    source's lull is ``queue_idle``, not decode."""
+    from video_features_tpu.extract import streaming
+    from video_features_tpu.obs.spans import SpanRecorder
+    from video_features_tpu.utils.tracing import Tracer
+    tracer = Tracer(enabled=True, recorder=SpanRecorder())
+    tasks = [_LaneTask(f'v{i}') for i in range(5)]
+
+    def open_windows(task):
+        for i in range(70):
+            if task.path == 'v2' and i == 40:
+                time.sleep(0.05)
+                yield streaming.FLUSH           # a live session's lull
+            yield np.zeros((8,), np.uint8), i
+
+    stats = []
+    out = list(streaming.stream_windows_across_lanes(
+        iter(tasks), open_windows, 2, tracer=tracer,
+        span_attrs=lambda t: {'video': t.path}, stats=stats))
+    assert sum(1 for o in out if o is not streaming.FLUSH) == 350
+    spans = [e for e in tracer.recorder.snapshot()
+             if e.get('ph') == 'X' and e['name'] == 'decode+preprocess']
+    assert {e['tid'] for e in spans} == {0, 1}
+    assert all(e['args']['lane'] == e['tid'] for e in spans)
+    assert {e['args']['video'] for e in spans} == {t.path for t in tasks}
+    # 70 windows are chunks of 32, 32 and 6; v2's lull cuts 32, 8 and 30
+    assert len(spans) == 5 * 3 == sum(s['chunks'] for s in stats)
+    assert sorted(e['args']['windows'] for e in spans
+                  if e['args']['video'] == 'v2') == [8, 30, 32]
+    assert sum(e['args']['windows'] for e in spans) == 350
+    assert sum(s['videos'] for s in stats) == 5
+    assert sum(s['windows'] for s in stats) == 350
+    busy = sum(s['busy_s'] for s in stats)
+    assert busy == pytest.approx(sum(e['dur'] for e in spans) / 1e6,
+                                 abs=1e-4)
+    assert busy == pytest.approx(
+        tracer.report()['decode+preprocess']['total_s'])
+    idle = [e for e in tracer.recorder.snapshot()
+            if e.get('ph') == 'X' and e['name'] == 'queue_idle']
+    assert len(idle) == 1 and idle[0]['dur'] >= 0.04e6
+    assert idle[0]['tid'] in (0, 1)
+    assert all(s['blocked_s'] >= 0 for s in stats)
+
+
+def test_lanes_read_no_clock_with_the_tracer_off(monkeypatch):
+    """Tracer off: the lanes read no clock and record nothing; the counts
+    of videos, windows and chunks are kept all the same."""
+    import types
+
+    from video_features_tpu.extract import streaming
+    from video_features_tpu.utils.tracing import NULL_TRACER
+    reads = []
+    monkeypatch.setattr(streaming, 'time', types.SimpleNamespace(
+        perf_counter=lambda: reads.append(1) or 0.0))
+    tasks = [_LaneTask(f'v{i}') for i in range(4)]
+    stats = []
+    out = list(streaming.stream_windows_across_lanes(
+        iter(tasks), lambda t: ((np.zeros((4,), np.uint8), i)
+                                for i in range(40)), 3, stats=stats))
+    assert len(out) == 160 and reads == []
+    assert NULL_TRACER.report() == {}
+    assert sum(s['windows'] for s in stats) == 160
+    assert all(s['busy_s'] == 0.0 == s['blocked_s'] for s in stats)
+
+
+def test_lane_failure_is_the_videos_own_and_consumer_failure_stops_it():
+    """A video whose window source raises fails its own task and its lane
+    takes the next one; a task the consumer fails stops being decoded."""
+    from video_features_tpu.extract.streaming import (
+        NUDGE, stream_windows_across_lanes,
+    )
+    tasks = [_LaneTask(n) for n in ('ok0', 'bad', 'doomed', 'ok1')]
+    pulled = {'doomed': 0}
+
+    def open_windows(task):
+        if task.path == 'bad':
+            raise IOError('cannot open')
+        for i in range(5000 if task.path == 'doomed' else 40):
+            if task.path == 'doomed':
+                pulled['doomed'] += 1
+            yield np.zeros((4,), np.uint8), i
+
+    got = {t.path: 0 for t in tasks}
+    nudges = 0
+    for item in stream_windows_across_lanes(iter(tasks), open_windows, 2):
+        if item is NUDGE:
+            nudges += 1
+            continue
+        task = item[0]
+        got[task.path] += 1
+        if task.path == 'doomed' and got['doomed'] == 3:
+            task.failed = True        # a device-step fault, at the consumer
+    assert got['ok0'] == got['ok1'] == 40 and got['bad'] == 0
+    assert nudges == 1 and tasks[1].failed and tasks[1].exhausted
+    assert got['doomed'] == 3 and tasks[2].emitted == 3
+    assert pulled['doomed'] < 5000    # its lane stopped early
+    assert all(t.exhausted for t in tasks)
+
+
+def test_lanes_under_a_short_switch_interval_lose_no_window():
+    """More lanes than cores, threads switched every 10 us: every window of
+    every video arrives once, in the video's order, the counts the merging
+    generator keeps are the videos' own, and every zero-window video is
+    told by one NUDGE."""
+    import sys
+
+    from video_features_tpu.extract.streaming import (
+        NUDGE, stream_windows_across_lanes,
+    )
+    tasks = [_LaneTask(f'v{i}') for i in range(60)]
+    sizes = {t.path: (i * 7) % 45 for i, t in enumerate(tasks)}
+
+    def open_windows(task):
+        for i in range(sizes[task.path]):
+            yield np.full((3,), i, np.int32), i
+
+    seen, nudges = {}, 0
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    t0 = time.monotonic()
+    try:
+        for item in stream_windows_across_lanes(iter(tasks), open_windows,
+                                                12):
+            if item is NUDGE:
+                nudges += 1
+                continue
+            assert int(item[1][0]) == item[2]
+            seen.setdefault(item[0].path, []).append(item[2])
+    finally:
+        sys.setswitchinterval(interval)
+    assert time.monotonic() - t0 < 60
+    for t in tasks:
+        assert seen.get(t.path, []) == list(range(sizes[t.path])), t.path
+        assert t.emitted == sizes[t.path] and t.exhausted
+    assert nudges == sum(1 for n in sizes.values() if n == 0) > 0
+
+
+def test_packed_run_names_its_lanes_in_manifest_and_header(
+        mixed_worklist, tmp_path, monkeypatch, capsys):
+    """The run manifest's ``decode`` section says how many lanes ran and
+    why, with per-lane counters whose busy seconds are the stage table's
+    ``decode+preprocess`` total; the stage-table header names the count."""
+    import json
+    _cores(monkeypatch, 8)
+    manifest = tmp_path / 'manifest.json'
+    ex = create_extractor(_resnet_args(
+        mixed_worklist, tmp_path / 'mo', tmp_path / 'tmpmo', profile=True,
+        manifest_out=str(manifest)))
+    ex.extract_packed(mixed_worklist)
+    ex.finish_obs()
+    assert '3 decode lanes)' in capsys.readouterr().err
+    doc = json.loads(manifest.read_text())
+    dec = doc['decode']
+    assert dec['lanes'] == 3 and dec['cores'] == 8 and dec['videos'] == 3
+    assert dec['decode_workers'] is None and dec['calls'] == 1
+    assert 'video(s) at hand' in dec['why']
+    assert [lane['videos'] for lane in dec['per_lane']] == [1, 1, 1]
+    assert sum(lane['windows'] for lane in dec['per_lane']) == 27
+    assert sum(lane['busy_s'] for lane in dec['per_lane']) == \
+        pytest.approx(doc['stages']['decode+preprocess']['total_s'],
+                      abs=1e-4)
+    assert doc['farm'] == {}
+    # explicit 1: the serial windower says so, and keeps no lane counters
+    ex.extract_packed(mixed_worklist, decode_workers=1)
+    ex.finish_obs()
+    assert '1 decode lane)' in capsys.readouterr().err

@@ -91,13 +91,18 @@ PIPELINE_DEFAULTS: Dict[str, Any] = {
 # whose YAML already carries decode_workers (i3d ships 2) keep their
 # tuned value.
 FARM_DEFAULTS: Dict[str, Any] = {
-    # host decode/preprocess parallelism. 1 = in-process decode exactly
-    # as before. >1 on the per-video loop = the in-process transform
+    # host decode/preprocess parallelism. Unset (null, the default):
+    # the packed/serve paths decode up to K videos of the worklist at
+    # once on in-process threads (decode lanes), K = the usable cores
+    # halved, at most 4, never more than the videos of a sized worklist
+    # (extract/streaming.py decode_lane_plan); the per-video loop reads
+    # unset as 1. 1 = serial in-process decode on every path, exactly as
+    # before. >1 on the per-video loop = the in-process transform
     # thread pool; >1 on the packed/serve paths = the multi-process
     # decode farm (N worker processes feeding the packer over
     # shared-memory rings — GIL- and swscale-unbound). Outputs are
     # byte-identical at any value.
-    'decode_workers': 1,
+    'decode_workers': None,
     # per-worker shared-memory ring size (MiB): bounds decoded bytes in
     # flight per worker; a slow consumer stalls decode instead of
     # growing memory. See docs/decode_farm.md for sizing.
@@ -731,7 +736,8 @@ def sanity_check(args: Config) -> None:
         args['decode_workers'] = int(args['decode_workers'])
         if args['decode_workers'] < 1:
             raise ValueError(
-                f'decode_workers must be >= 1 (1 = in-process decode); '
+                f'decode_workers must be >= 1 (1 = serial in-process '
+                f'decode; null = decode lanes from the cores); '
                 f'got {args["decode_workers"]}')
     if args.get('decode_farm_ring_mb') is not None:
         args['decode_farm_ring_mb'] = int(args['decode_farm_ring_mb'])

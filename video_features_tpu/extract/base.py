@@ -646,9 +646,13 @@ class BaseExtractor:
         one knob, one meaning: how much host-decode parallelism to buy)
         and ``decode_farm_ring_mb`` (per-worker SHM ring size). Called by
         ``registry.create_extractor``; extractors constructed directly
-        keep the in-process default (``decode_workers=1``)."""
-        self.decode_workers = max(
-            int(args.get('decode_workers', 1) or 1), 1)
+        keep the in-process default (``decode_workers=1``). Unset
+        (``None``) stays ``None``: the packed scheduler then derives its
+        decode lanes from the cores (``streaming.decode_lane_plan``) and
+        the per-video loaders read it as 1."""
+        workers = args.get('decode_workers')
+        self.decode_workers = (None if workers is None
+                               else max(int(workers), 1))
         self.decode_farm_ring_mb = max(
             int(args.get('decode_farm_ring_mb', 64) or 64), 1)
 
@@ -1045,8 +1049,12 @@ class BaseExtractor:
         wait for batch-mates (dynamic sources only — a static worklist
         wants maximally full batches); ``inflight`` overrides the
         extractor's output-side pipelining depth (1 = synchronous);
-        ``decode_workers`` overrides the input side's parallelism (>1 =
-        the multi-process decode farm, 1 = in-process decode)."""
+        ``decode_workers`` overrides the input side's parallelism: >1 =
+        the multi-process decode farm, 1 = the serial in-process
+        windower, and ``None`` = the extractor's own setting — which,
+        unset in the config too, means in-process decode lanes: up to K
+        videos at once on threads, K from the usable cores (halved, at
+        most 4) and the videos at hand."""
         if not self.supports_packing:
             raise NotImplementedError(
                 f'{type(self).__name__} does not support pack_across_videos')

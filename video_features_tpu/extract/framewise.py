@@ -39,7 +39,7 @@ class BaseFrameWiseExtractor(BaseExtractor):
             compute_dtype=args.get('compute_dtype', 'float32'),
         )
         self.batch_size = args.batch_size
-        self.decode_workers = int(args.get('decode_workers', 1))
+        self.decode_workers = args.get('decode_workers')    # None: unset
         self.decode_backend = args.get('decode_backend', 'auto')
         # data_parallel=true shards frame batches over ALL local devices:
         # params are re-placed replicated and batches arrive with a
@@ -74,7 +74,7 @@ class BaseFrameWiseExtractor(BaseExtractor):
             tmp_path=self.tmp_path,
             keep_tmp=self.keep_tmp_files,
             transform=self.host_transform,
-            transform_workers=self.decode_workers,
+            transform_workers=self.decode_workers or 1,
             backend=self.decode_backend,
         )
 

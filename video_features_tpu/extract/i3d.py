@@ -183,7 +183,7 @@ class ExtractI3D(BaseExtractor):
         self.raft_iters = raft_model.resolve_iters(args.get('raft_iters'))
         self.extraction_fps = args.extraction_fps
         self.batch_size = args.get('batch_size', 1)
-        self.decode_workers = int(args.get('decode_workers', 1))
+        self.decode_workers = args.get('decode_workers')    # None: unset
         self.decode_backend = args.get('decode_backend', 'auto')
         # device_resize=true ships RAW decode-geometry uint8 frames and
         # runs the short-side-256 resize inside the fused graph — lifting
@@ -296,7 +296,7 @@ class ExtractI3D(BaseExtractor):
             keep_tmp=self.keep_tmp_files,
             transform=(None if self.device_resize
                        else lambda f: resize_pil(f, MIN_SIDE_SIZE)),
-            transform_workers=self.decode_workers,
+            transform_workers=self.decode_workers or 1,
             backend=self.decode_backend)
 
     def _geometry(self, h: int, w: int) -> tuple:

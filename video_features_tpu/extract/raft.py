@@ -51,7 +51,7 @@ class ExtractRAFT(BaseExtractor):
             precision=args.get('precision', 'highest'),
         )
         self.batch_size = args.batch_size
-        self.decode_workers = int(args.get('decode_workers', 1))
+        self.decode_workers = args.get('decode_workers')    # None: unset
         self.decode_backend = args.get('decode_backend', 'auto')
         self.side_size = args.get('side_size')
         self.resize_to_smaller_edge = args.get('resize_to_smaller_edge', True)
@@ -180,7 +180,7 @@ class ExtractRAFT(BaseExtractor):
             tmp_path=self.tmp_path,
             keep_tmp=self.keep_tmp_files,
             transform=self.host_transform,
-            transform_workers=self.decode_workers,
+            transform_workers=self.decode_workers or 1,
             backend=self.decode_backend,
             overlap=1,
         )

@@ -15,11 +15,21 @@ are dropped, like the reference, extract_i3d.py:126-129).
 boundaries for the packed corpus mode (``parallel.packing``): one
 fault-isolated stream over the whole worklist, so device batches can fill
 with windows from several videos instead of padding at every video's tail.
+``stream_windows_across_lanes`` is the same stream with up to K videos
+decoding at once, each on its own thread (a decode lane);
+``decode_lane_plan`` derives K from the cores and the worklist.
 """
 from __future__ import annotations
 
 import math
-from typing import Callable, Iterable, Iterator, List, Optional, Tuple
+import os
+import queue
+import threading
+import time
+from collections import deque
+from typing import (
+    Callable, Dict, Iterable, Iterator, List, Optional, Tuple,
+)
 
 import numpy as np
 
@@ -252,6 +262,17 @@ def framewise_segment_windows(batches: Iterable,
             yield np.asarray(frame), t_ms
 
 
+def _fail_decode(task) -> None:
+    """A video failed to open or decode (call inside the ``except``): its
+    task fails, and the structured fault report carries the serve request
+    id (None for CLI tasks) and the stage that died."""
+    from video_features_tpu.extract.base import log_extraction_error
+    task.failed = True
+    log_extraction_error(
+        task.path, stage='decode',
+        request_id=getattr(getattr(task, 'request', None), 'id', None))
+
+
 def stream_windows_across_videos(tasks: Iterable,
                                  open_windows: Callable) -> Iterator[tuple]:
     """The corpus-mode windower: yield ``(task, window, meta)`` across video
@@ -278,7 +299,6 @@ def stream_windows_across_videos(tasks: Iterable,
     request feed marks an arrival lull) passes straight through to the
     downstream packer, which flushes its partial geometry pools.
     """
-    from video_features_tpu.extract.base import log_extraction_error
     for task in tasks:
         if task is FLUSH:
             yield FLUSH
@@ -306,13 +326,7 @@ def stream_windows_across_videos(tasks: Iterable,
         except KeyboardInterrupt:
             raise
         except Exception:
-            task.failed = True
-            # structured fault report: the serve request id (None for CLI
-            # tasks) and the stage that died ride on the log record
-            log_extraction_error(
-                task.path, stage='decode',
-                request_id=getattr(getattr(task, 'request', None), 'id',
-                                   None))
+            _fail_decode(task)
         finally:
             task.exhausted = True
         if task.emitted == 0:
@@ -320,6 +334,291 @@ def stream_windows_across_videos(tasks: Iterable,
             # skip / too-short clip / failed open): NUDGE the consumer so
             # it finalizes NOW — a dynamic stream may not end for hours
             yield NUDGE
+
+
+# -- decode lanes: several videos of the worklist at once --------------------
+#
+# One video's decode + host preprocess is ~0.6 ms a frame on one thread and
+# bounded the packed resnet50 cell at 1,543 frames/s under a step that does
+# 4,650 (ledger, PR 25). The ctypes call into libvfdecode and Pillow's
+# resize release the GIL, so K videos on K threads scale almost linearly to
+# four (ISSUE 26's host probe); threads start in microseconds and copy
+# nothing, where the farm's processes need seconds to their first window.
+
+MAX_LANES = 4
+# A lane hands its windows over in CHUNKS: a hand-over a frame made lock
+# convoys on the queue that collapsed at six threads (ISSUE 26's probe).
+# Bounded in windows for frames (32 × 150 KB) and in bytes for stacks (an
+# i3d window of 17 × 256 × 344 × 3 is 4.5 MB: two a chunk).
+CHUNK_WINDOWS = 32
+CHUNK_BYTES = 8 << 20
+_POLL_S = 0.1       # how often a blocked lane looks at the stop flag
+_JOIN_S = 5.0       # how long closing the stream waits for a thread
+_CHUNK, _FLUSH_MARK, _END, _ERROR = object(), object(), object(), object()
+
+
+def decode_lane_plan(decode_workers: Optional[int],
+                     videos: Optional[int] = None,
+                     cores: Optional[int] = None) -> Dict:
+    """How the packed path's input side runs, from what the code can see.
+
+    ``decode_workers`` unset (``None``, the shipped default of the
+    framewise ymls): in-process decode lanes, half the usable cores
+    (``os.sched_getaffinity``), at most ``MAX_LANES``, at least 1, and
+    never more than the ``videos`` at hand (``None``: a dynamic source,
+    not known). Explicit 1: the serial windower. Explicit N > 1: the
+    decode farm's N worker processes (``farm_workers``), as before.
+    Returns ``{'lanes', 'farm_workers', 'decode_workers', 'cores',
+    'videos', 'why'}`` — the run manifest's ``decode`` section."""
+    if cores is None:
+        cores = (len(os.sched_getaffinity(0))
+                 if hasattr(os, 'sched_getaffinity')
+                 else os.cpu_count() or 1)
+    plan = {'lanes': 1, 'farm_workers': 0,
+            'decode_workers': decode_workers, 'cores': int(cores),
+            'videos': videos}
+    if decode_workers is None:
+        lanes = max(1, min(MAX_LANES, int(cores) // 2))
+        plan['why'] = f'unset: {cores} cores halved, at most {MAX_LANES}'
+        if videos is not None and videos < lanes:
+            lanes = max(1, int(videos))
+            plan['why'] += f', {videos} video(s) at hand'
+        plan['lanes'] = lanes
+    elif int(decode_workers) <= 1:
+        plan['why'] = 'explicit 1: the serial windower'
+    else:
+        plan['farm_workers'] = int(decode_workers)
+        plan['why'] = f'explicit {int(decode_workers)}: the decode farm'
+    return plan
+
+
+def stream_windows_across_lanes(tasks: Iterable, open_windows: Callable,
+                                lanes: int,
+                                tracer: Tracer = NULL_TRACER,
+                                span_attrs: Optional[Callable] = None,
+                                stats: Optional[List[Dict]] = None,
+                                ) -> Iterator[tuple]:
+    """``stream_windows_across_videos`` with up to ``lanes`` videos of the
+    worklist decoding at once, each on its own thread, merged into the one
+    stream the packer reads. Same items (``(task, window, meta)``, ``FLUSH``,
+    ``NUDGE``), same per-video fault isolation.
+
+      * One video is one lane's from open to close, so its windows stay in
+        order. A dispatcher thread pulls the next task from ``tasks`` only
+        when a lane is idle, so videos start in worklist order and a source
+        that blocks in ``next()`` (serve) holds back nothing of the videos
+        already running.
+      * Lanes hand over LISTS of windows (``CHUNK_WINDOWS`` / ``CHUNK_BYTES``)
+        through one queue bounded at ``lanes`` chunks; a full queue blocks
+        the lane (back-pressure). Beside the prefetch buffer downstream,
+        at most ``lanes`` chunks queued and one in each lane's hands exist.
+      * This generator, on the caller's thread (the prefetch producer),
+        re-yields single items and is the ONE place that writes
+        ``task.emitted`` / ``task.exhausted`` and yields ``NUDGE``.
+      * A ``FLUSH`` of the task source is held until every video dispatched
+        before it has ended — it must not overtake windows still decoding,
+        or a feed that goes idle right after it would leave them pooled (as
+        ``farm._append_flush``). A ``FLUSH`` of a live window source rides
+        in its lane's chunk, behind that video's own windows, and hands the
+        chunk over at once.
+      * A video that fails to open or decode fails its own task and its
+        lane takes the next; ``task.failed`` set by the consumer stops that
+        video's lane. Closing the generator stops and joins every lane and
+        closes every window source (``packed_windows`` closes its loader).
+
+    With the tracer on each lane records one ``decode+preprocess`` span a
+    CHUNK under ``span_tid`` = lane (``span_attrs(task)`` ride on it), the
+    wait that ends in a ``FLUSH`` as ``queue_idle``, and ``stats`` (one dict
+    a lane: ``videos``, ``windows``, ``chunks`` and, tracer on, ``busy_s``
+    and ``blocked_s``: seconds blocked on the full hand-over queue) is
+    filled in place. Tracer off: no clock is read.
+    """
+    lanes = max(int(lanes), 1)
+    timed = tracer.enabled
+    out: 'queue.Queue' = queue.Queue(maxsize=lanes)
+    todo: 'queue.SimpleQueue' = queue.SimpleQueue()
+    idle = threading.Semaphore(lanes)
+    stop = threading.Event()
+    if stats is None:
+        stats = []
+    stats[:] = [{'videos': 0, 'windows': 0, 'chunks': 0,
+                 'busy_s': 0.0, 'blocked_s': 0.0} for _ in range(lanes)]
+
+    def hand_over(msg) -> bool:
+        """Blocking put that gives up once the stream is closed."""
+        while not stop.is_set():
+            try:
+                out.put(msg, timeout=_POLL_S)
+                return True
+            except queue.Full:
+                continue
+        return False
+
+    def dispatch() -> None:
+        n = 0
+        try:
+            it = iter(tasks)
+            while True:
+                # a lane takes the next task when it finishes one: the
+                # source is not read ahead of an idle lane
+                while not idle.acquire(timeout=_POLL_S):
+                    if stop.is_set():
+                        return
+                while True:
+                    t0 = time.perf_counter() if timed else 0.0
+                    item = next(it, _END)
+                    if item is not FLUSH:
+                        break
+                    if timed:
+                        tracer.add('queue_idle', time.perf_counter() - t0,
+                                   t0=t0)
+                    if not hand_over((_FLUSH_MARK, n)):
+                        return
+                if item is _END or stop.is_set():
+                    break
+                todo.put((n, item))
+                n += 1
+            hand_over((_END, n))
+        # vft-lint: ok=swallowed-exception — shipped, not swallowed: the
+        # merging generator re-raises what a thread posts
+        except BaseException as e:
+            hand_over((_ERROR, e))
+        finally:
+            for _ in range(lanes):
+                todo.put(None)
+
+    def run_video(lane: int, seq: int, task) -> None:
+        st = stats[lane]
+        st['videos'] += 1
+        attrs = span_attrs(task) if timed and span_attrs is not None else {}
+        chunk: list = []
+        nbytes = 0
+        t_chunk = t_prev = time.perf_counter() if timed else 0.0
+
+        def send(ended: bool, t_end: float) -> None:
+            nonlocal chunk, nbytes, t_chunk, t_prev
+            if timed:
+                dt = t_end - t_chunk
+                st['busy_s'] += dt
+                tracer.add('decode+preprocess', dt, t0=t_chunk,
+                           span_tid=lane, lane=lane,
+                           windows=len(chunk) - (chunk[-1:] == [FLUSH]),
+                           **attrs)
+                t_put = time.perf_counter()
+            st['chunks'] += 1
+            hand_over((_CHUNK, seq, task, chunk, ended))
+            chunk, nbytes = [], 0
+            if timed:
+                t_chunk = t_prev = time.perf_counter()
+                st['blocked_s'] += t_chunk - t_put
+
+        windows = None
+        try:
+            windows = open_windows(task)
+            for item in windows:
+                now = time.perf_counter() if timed else 0.0
+                if stop.is_set() or task.failed:
+                    # closed, or the consumer failed this video mid-run
+                    # (device-step fault): stop decoding the rest of it
+                    break
+                if item is FLUSH:
+                    # a LIVE window source marks an arrival lull: the
+                    # wait was the session's, not decode, and the chunk
+                    # must not sit in this lane until the next frame
+                    chunk.append(FLUSH)
+                    idle_from = t_prev
+                    send(False, idle_from)
+                    if timed:
+                        tracer.add('queue_idle', now - idle_from,
+                                   t0=idle_from, span_tid=lane)
+                    continue
+                # the stream's item is built here, not on the one
+                # thread that merges
+                chunk.append((task, item[0], item[1]))
+                st['windows'] += 1
+                nbytes += getattr(item[0], 'nbytes', 0)
+                t_prev = now
+                if len(chunk) >= CHUNK_WINDOWS or nbytes >= CHUNK_BYTES:
+                    send(False, now)
+        except Exception:
+            _fail_decode(task)
+        finally:
+            try:
+                close = getattr(windows, 'close', None)
+                if close is not None:
+                    close()       # the window source closes its loader
+            finally:
+                # always: only the merging generator may end the task
+                send(True, time.perf_counter() if timed else 0.0)
+
+    def lane_loop(lane: int) -> None:
+        try:
+            while True:
+                job = todo.get()
+                if job is None or stop.is_set():
+                    return
+                run_video(lane, *job)
+                idle.release()
+        # vft-lint: ok=swallowed-exception — shipped, not swallowed
+        except BaseException as e:
+            hand_over((_ERROR, e))
+
+    threads = [threading.Thread(target=dispatch, daemon=True,
+                                name='vft-decode-dispatch')]
+    threads += [threading.Thread(target=lane_loop, args=(i,), daemon=True,
+                                 name=f'vft-decode-lane-{i}')
+                for i in range(lanes)]
+    for t in threads:
+        t.start()
+    try:
+        total = None            # videos dispatched, once the source ended
+        ended = 0
+        low = 0                 # every video with seq < low has ended
+        done: set = set()
+        held: 'deque' = deque()     # FLUSH watermarks not yet passed
+        while total is None or ended < total:
+            msg = out.get()
+            kind = msg[0]
+            if kind is _ERROR:
+                raise msg[1]
+            if kind is _END:
+                total = msg[1]
+                continue
+            if kind is _FLUSH_MARK:
+                if not held and msg[1] <= low:
+                    yield FLUSH
+                else:
+                    held.append(msg[1])
+                continue
+            _, seq, task, chunk, last = msg
+            for item in chunk:
+                if item is FLUSH:
+                    yield FLUSH
+                elif not task.failed:
+                    task.emitted += 1
+                    yield item
+            if last:
+                task.exhausted = True
+                if task.emitted == 0:
+                    # no batch will ever carry this video's completion
+                    yield NUDGE
+                ended += 1
+                done.add(seq)
+                while low in done:
+                    done.remove(low)
+                    low += 1
+                while held and held[0] <= low:
+                    held.popleft()
+                    yield FLUSH
+    finally:
+        stop.set()
+        for _ in range(lanes):
+            todo.put(None)      # wake lanes waiting for a task
+        # a lane inside one long decode call, or the dispatcher inside a
+        # source's next() that nobody can interrupt (an idle serve feed),
+        # is abandoned after _JOIN_S: both are daemons
+        for t in threads:
+            t.join(_JOIN_S)
 
 
 def stream_windows(batches: Iterable, win: int, step: int,

@@ -527,6 +527,13 @@ def prefetch(iterable, depth: int = 2):
         # the consumer re-raises whatever the producer thread posts
         except BaseException as e:  # re-raised by the consumer
             put_or_abort(e)
+        finally:
+            # an abandoned source is closed HERE, on the thread that ran
+            # it, not whenever its last reference goes: the lane windower
+            # joins its decode threads and closes their loaders there
+            close = getattr(iterable, 'close', None)
+            if close is not None:
+                close()
 
     thread = threading.Thread(target=producer, daemon=True)
     thread.start()

@@ -123,6 +123,7 @@ class RunManifest:
         self.stages: Dict[str, Dict[str, float]] = {}
         self.executables: Dict[str, Dict[str, Any]] = {}
         self.farm: Dict[str, Any] = {}
+        self.decode: Dict[str, Any] = {}
         self.mesh: Dict[str, Any] = {}
         self.ingress: Dict[str, Any] = {}
         self.programs_lock: Dict[str, Any] = {}
@@ -188,6 +189,25 @@ class RunManifest:
         persists across request waves)."""
         with self._lock:
             self.farm.update({k: _jsonable(v) for k, v in info.items()})
+
+    def note_decode(self, plan: Dict[str, Any],
+                    per_lane: Optional[list] = None) -> None:
+        """Record how an in-process packed run decoded: the lane plan
+        (``streaming.decode_lane_plan``: lanes resolved and why, cores
+        seen, videos at hand) of the NEWEST ``run_packed`` call, the
+        number of calls, and per lane the videos, windows, chunks, busy
+        seconds and seconds blocked on the full hand-over queue, summed
+        over calls (a benchmark calls ``extract_packed`` once a pass).
+        The section stays ``{}`` on farm-backed and per-video runs."""
+        with self._lock:
+            self.decode.update({k: _jsonable(v) for k, v in plan.items()})
+            self.decode['calls'] = self.decode.get('calls', 0) + 1
+            totals = self.decode.setdefault('per_lane', [])
+            for i, lane in enumerate(per_lane or []):
+                if i == len(totals):
+                    totals.append({k: 0 for k in lane})
+                for k, v in lane.items():
+                    totals[i][k] = round(totals[i][k] + v, 6)
 
     def note_ingress(self, info: Dict[str, Any]) -> None:
         """Record the ingress view of a run (per-tenant request/shed
@@ -265,6 +285,9 @@ class RunManifest:
             stages = {k: dict(v) for k, v in self.stages.items()}
             executables = {k: dict(v) for k, v in self.executables.items()}
             farm = dict(self.farm)
+            decode = dict(self.decode, per_lane=[
+                dict(lane) for lane in self.decode.get('per_lane', [])]) \
+                if self.decode else {}
             mesh = dict(self.mesh)
             ingress = dict(self.ingress)
             programs_lock = dict(self.programs_lock)
@@ -290,6 +313,9 @@ class RunManifest:
             # decode farm (farm/): config + lifetime stats for
             # farm-backed runs, {} on in-process decode
             'farm': farm,
+            # in-process decode lanes (extract/streaming.py): the lane
+            # plan and per-lane counters of packed runs, {} otherwise
+            'decode': decode,
             # mesh-sharded packed execution (mesh_devices > 1): the
             # device mesh the run executed on, {} single-device
             'mesh': mesh,

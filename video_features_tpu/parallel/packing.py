@@ -8,9 +8,13 @@ pays the pipeline ramp (prefetch fill, cache warm, H2D latency) again.
 
 This module inverts the loop — batch-major over the whole worklist:
 
-  * a cross-video window stream (``extract.streaming.
-    stream_windows_across_videos``) drains clip stacks / frames from one
-    video after another, with per-video fault isolation;
+  * a cross-video window stream drains clip stacks / frames from the
+    worklist with per-video fault isolation: up to K videos at once on
+    in-process decode lanes (``extract.streaming.
+    stream_windows_across_lanes``; K from the cores and the worklist,
+    ``decode_lane_plan``), one video after another at K = 1
+    (``stream_windows_across_videos``), or the decode farm's worker
+    processes (``farm/``) at an explicit ``decode_workers`` > 1;
   * a decode-ahead thread (``io.video.prefetch_across_videos``) keeps the
     decoder busy across video boundaries under a bounded window buffer;
   * the packer fills every device batch to capacity with
@@ -413,6 +417,88 @@ def _finalize_task(ex, t: VideoTask, recorder=None, manifest=None,
             on_video_done(t)
 
 
+def _decode_plan(ex, decode_workers: Optional[int], video_paths) -> Dict:
+    """The input side's plan for one packed run
+    (``streaming.decode_lane_plan``): the run-level ``decode_workers``
+    wins over the extractor's (a directly constructed extractor without
+    the attribute keeps the serial default); the videos at hand are
+    known only for a sized worklist, not for a serve feed."""
+    from video_features_tpu.extract.streaming import decode_lane_plan
+    if decode_workers is None:
+        decode_workers = getattr(ex, 'decode_workers', 1)
+    return decode_lane_plan(
+        decode_workers,
+        videos=len(video_paths) if hasattr(video_paths, '__len__')
+        else None)
+
+
+def _without_farm(plan: Dict) -> Dict:
+    """The plan of a run whose farm could not be had (no recipe, no
+    shared memory): the serial windower, and the manifest says so."""
+    if not plan['farm_workers']:
+        return plan
+    return dict(plan, farm_workers=0,
+                why=plan['why'] + ', not to be had: the serial windower')
+
+
+def _decode_note(plan: Dict, farm) -> str:
+    """The stage-table header's word on the input side."""
+    if farm is not None:
+        return f'{farm.n_workers} decode-farm workers'
+    return (f'{plan["lanes"]} decode lane'
+            + ('s' if plan['lanes'] != 1 else ''))
+
+
+def _in_process_windows(tasks: Iterator, open_windows: Callable,
+                        plan: Dict, tracer: Tracer,
+                        decode_attrs: Callable,
+                        lane_stats: List[Dict]) -> Iterator:
+    """The in-process window stream of both packed drivers, as the plan
+    has it: ``plan['lanes']`` videos at once on decode lanes (which time
+    their own work, a span a chunk), or the serial windower — under a
+    consumer-side timing wrapper when the tracer is on."""
+    from video_features_tpu.extract.streaming import (
+        stream_windows_across_lanes, stream_windows_across_videos,
+    )
+    if plan['lanes'] > 1:
+        return stream_windows_across_lanes(
+            tasks, open_windows, plan['lanes'], tracer=tracer,
+            span_attrs=decode_attrs, stats=lane_stats)
+    source = stream_windows_across_videos(tasks, open_windows)
+    if not tracer.enabled:
+        return source
+
+    def timed_source():
+        # decode (and host preprocessing) runs on the prefetch producer
+        # thread, ahead of the device across video boundaries; timed here
+        # (inside the prefetch) so decode cost lands on the thread that
+        # spends it. A dynamic source (serve) also BLOCKS in next() while
+        # its request queue is idle — those spans surface as FLUSH items
+        # and are attributed to a separate ``queue_idle`` stage, not
+        # laundered into decode time. (Under lanes this wrapper would
+        # time waits on the hand-over queue and call them decode, and
+        # the farm traces its workers' own timings: neither uses it.)
+        import time as _time
+        it = iter(source)
+        while True:
+            t0 = _time.perf_counter()
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+            dt = _time.perf_counter() - t0
+            if item is FLUSH:
+                tracer.add('queue_idle', dt, t0=t0)
+            elif item is NUDGE:
+                tracer.add('decode+preprocess', dt, t0=t0)
+            else:
+                tracer.add('decode+preprocess', dt, t0=t0,
+                           **decode_attrs(item[0], item[2]))
+            yield item
+
+    return timed_source()
+
+
 def run_packed(ex, video_paths: Iterable,
                batch_size: Optional[int] = None,
                decode_ahead: int = 2,
@@ -482,17 +568,25 @@ def run_packed(ex, video_paths: Iterable,
     each doom exactly the videos of the batch that produced them.
 
     ``decode_workers`` (default: the extractor's ``decode_workers``
-    attribute) selects the INPUT side's parallelism: ``1`` is the
-    in-process cross-video windower exactly as before; ``>1`` routes
+    attribute) selects the INPUT side's parallelism
+    (``streaming.decode_lane_plan``). Unset (``None``, the framewise
+    ymls' default): in-process decode LANES — up to K videos of the
+    worklist decode at once, each on its own thread
+    (``stream_windows_across_lanes``), K from the usable cores (halved,
+    at most 4) and never more than the videos of a sized worklist; K = 1
+    is the serial windower. ``1`` is the serial in-process windower
+    exactly as before; ``>1`` routes
     decode through the multi-process decode farm (``farm/``) — N worker
     processes running the extractor's published decode recipe, feeding
     this scheduler over shared-memory rings with the same stream
     contract, per-video fault isolation, and byte-identical outputs.
-    Falls back to in-process decode (with a structured warning) when the
-    extractor has no farm recipe or the host can't spawn workers.
+    Falls back to serial in-process decode (with a structured warning)
+    when the extractor has no farm recipe or the host can't spawn
+    workers. Outputs are byte-identical on every route: only which
+    video's window sits in which batch slot changes.
     """
     from video_features_tpu.extract.streaming import (
-        fetch_step, stream_windows_across_videos, transfer_batches,
+        fetch_step, transfer_batches,
     )
     from video_features_tpu.io.video import prefetch_across_videos
 
@@ -645,8 +739,8 @@ def run_packed(ex, video_paths: Iterable,
     # per-video fault isolation, task accounting), so everything below
     # this point is identical on both paths and outputs stay
     # byte-identical at any worker count.
-    n_decode = max(int(decode_workers if decode_workers is not None
-                       else getattr(ex, 'decode_workers', 1) or 1), 1)
+    plan = _decode_plan(ex, decode_workers, video_paths)
+    n_decode = plan['farm_workers']
     farm = None
     if n_decode > 1:
         from video_features_tpu.farm import farm_available
@@ -710,48 +804,20 @@ def run_packed(ex, video_paths: Iterable,
                 # stats stay readable after the run ends
                 ex._farm = farm
 
+    # span provenance: the video (and serve request + trace) a decode
+    # slice worked for
+    def decode_attrs(task: VideoTask, meta=None) -> Dict:
+        return dict(video=str(task.path), request_id=_request_id(task),
+                    **trace_attrs(task))
+
+    lane_stats: List[Dict] = []
     if farm is not None:
         source = farm.stream(task_stream(), admit)
     else:
-        source = stream_windows_across_videos(task_stream(), open_windows)
-
-    def timed_source():
-        # decode (and host preprocessing) runs on the prefetch producer
-        # thread, ahead of the device across video boundaries; timed here
-        # (inside the prefetch) so decode cost lands on the thread that
-        # spends it. A dynamic source (serve) also BLOCKS in next() while
-        # its request queue is idle — those spans surface as FLUSH items
-        # and are attributed to a separate ``queue_idle`` stage, not
-        # laundered into decode time.
-        import time as _time
-        it = iter(source)
-        while True:
-            t0 = _time.perf_counter()
-            try:
-                item = next(it)
-            except StopIteration:
-                return
-            if item is FLUSH:
-                ex.tracer.add('queue_idle', _time.perf_counter() - t0,
-                              t0=t0)
-            elif item is NUDGE:
-                ex.tracer.add('decode+preprocess',
-                              _time.perf_counter() - t0, t0=t0)
-            else:
-                # span provenance: the video (and serve request + trace)
-                # this decode slice worked for
-                ex.tracer.add('decode+preprocess',
-                              _time.perf_counter() - t0, t0=t0,
-                              video=str(item[0].path),
-                              request_id=_request_id(item[0]),
-                              **trace_attrs(item[0]))
-            yield item
-
-    # the farm traces per-worker 'decode' spans from the workers' own
-    # timings; the consumer-side wrapper would only launder queue waits
-    # into decode time, so it stays on the in-process path
-    timed = timed_source() if ex.tracer.enabled and farm is None else source
-    ahead = prefetch_across_videos(timed, decode_ahead * batch)
+        plan = _without_farm(plan)
+        source = _in_process_windows(task_stream(), open_windows, plan,
+                                     ex.tracer, decode_attrs, lane_stats)
+    ahead = prefetch_across_videos(source, decode_ahead * batch)
 
     # the in-flight queue: dispatched-but-unmaterialized batches, oldest
     # first. ``depth=1`` degenerates to the old synchronous loop (every
@@ -955,6 +1021,9 @@ def run_packed(ex, video_paths: Iterable,
         manifest.note_farm({'decode_workers': farm.n_workers,
                             'ring_bytes_per_worker': farm.ring_bytes,
                             'stats': farm.stats()})
+    elif manifest is not None:
+        # ... and an in-process run its lanes (the 'decode' section)
+        manifest.note_decode(plan, lane_stats)
 
     if ex.tracer.enabled and ex.tracer.report():
         if manifest is not None:
@@ -966,7 +1035,8 @@ def run_packed(ex, video_paths: Iterable,
             # stderr: the stage table is a diagnostic, and with
             # on_extraction=print stdout carries features
             print(f'--- stage timing: packed worklist ({n_started[0]} '
-                  f'videos, batch {batch}{mesh_note})', file=sys.stderr)
+                  f'videos, batch {batch}{mesh_note}, '
+                  f'{_decode_note(plan, farm)})', file=sys.stderr)
             print(ex.tracer.summary(), file=sys.stderr)
         ex.tracer.reset()
 
@@ -1045,9 +1115,7 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
     overlap against (so ``input_wait`` is recorded here, on the lead
     tracer, around the window prefetch).
     """
-    from video_features_tpu.extract.streaming import (
-        fetch_step, put_traced, stream_windows_across_videos,
-    )
+    from video_features_tpu.extract.streaming import fetch_step, put_traced
     from video_features_tpu.io.video import prefetch_across_videos
 
     if not exs:
@@ -1131,8 +1199,8 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
         return bool(active)
 
     # -- input side: one shared decode, farm or in-process ------------------
-    n_decode = max(int(decode_workers if decode_workers is not None
-                       else getattr(lead, 'decode_workers', 1) or 1), 1)
+    plan = _decode_plan(lead, decode_workers, video_paths)
+    n_decode = plan['farm_workers']
     farm = None
     if n_decode > 1:
         from video_features_tpu.farm import farm_available
@@ -1163,9 +1231,11 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
                   'cannot spawn shared-memory workers — running '
                   'in-process decode', subsystem='farm')
 
+    lane_stats: List[Dict] = []
     if farm is not None:
         source = farm.stream(task_stream(), admit_fused)
     else:
+        plan = _without_farm(plan)
         recipe = build_fused_recipe(exs)
 
         def fused_open_windows(c: FusedTask):
@@ -1180,34 +1250,16 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
             c.info.update(info)
             return windows
 
-        source = stream_windows_across_videos(task_stream(),
-                                              fused_open_windows)
+        # in-process decode+branch cost on the lead tracer: per family
+        # window from the serial windower, per chunk (all families of
+        # one decode) from a lane; the farm traces in-worker spans
+        def decode_attrs(c: FusedTask, meta=None) -> Dict:
+            fam = {'family': meta[0]} if meta is not None else {}
+            return dict(video=str(c.path), **fam, **trace_attrs(c))
 
-    def timed_source():
-        # in-process decode+branch cost, attributed per family window on
-        # the lead tracer (the farm path traces in-worker spans itself)
-        import time as _time
-        it = iter(source)
-        while True:
-            t0 = _time.perf_counter()
-            try:
-                item = next(it)
-            except StopIteration:
-                return
-            dt = _time.perf_counter() - t0
-            if item is FLUSH:
-                lead.tracer.add('queue_idle', dt, t0=t0)
-            elif item is NUDGE:
-                lead.tracer.add('decode+preprocess', dt, t0=t0)
-            else:
-                lead.tracer.add('decode+preprocess', dt, t0=t0,
-                                video=str(item[0].path),
-                                family=item[2][0],
-                                **trace_attrs(item[0]))
-            yield item
-
-    timed = (timed_source() if lead.tracer.enabled and farm is None
-             else source)
+        source = _in_process_windows(task_stream(), fused_open_windows,
+                                     plan, lead.tracer, decode_attrs,
+                                     lane_stats)
 
     def counted(src):
         # PRODUCER-side per-family emit accounting: runs between the
@@ -1215,14 +1267,18 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
         # so by the time the consumer can observe ``carrier.exhausted``
         # every subtask's ``emitted`` is final — the sweep's readiness
         # check (done >= emitted per active family) cannot fire early
-        for item in src:
-            if item is not FLUSH and item is not NUDGE:
-                sub = item[0].subtasks.get(item[2][0])
-                if sub is not None:
-                    sub.emitted += 1
-            yield item
+        try:
+            for item in src:
+                if item is not FLUSH and item is not NUDGE:
+                    sub = item[0].subtasks.get(item[2][0])
+                    if sub is not None:
+                        sub.emitted += 1
+                yield item
+        finally:
+            src.close()       # an abandoned run joins its decode lanes
 
-    ahead = prefetch_across_videos(counted(timed), decode_ahead * max_batch)
+    ahead = prefetch_across_videos(counted(source),
+                                   decode_ahead * max_batch)
     if lead.tracer.enabled:
         # the consumer side of the decode queue: this loop packs and
         # transfers inline (no transfer_batches), so the dispatch
@@ -1386,13 +1442,17 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
                                 'ring_bytes_per_worker': farm.ring_bytes,
                                 'stats': farm.stats(),
                                 'fused_families': fams})
+        elif manifest is not None:
+            manifest.note_decode(dict(plan, fused_families=fams),
+                                 lane_stats)
         if ex.tracer.enabled and ex.tracer.report():
             if manifest is not None:
                 manifest.fold_stages(ex.tracer.report())
             if getattr(ex, 'profile', True):
                 print(f'--- stage timing: fused worklist '
                       f'[{fam}] ({n_started[0]} videos, batch '
-                      f'{fam_batch[fam]})', file=sys.stderr)
+                      f'{fam_batch[fam]}, {_decode_note(plan, farm)})',
+                      file=sys.stderr)
                 print(ex.tracer.summary(), file=sys.stderr)
             if ex is not lead:
                 ex.tracer.reset()
