@@ -31,8 +31,10 @@ def test_expected_hash_conventions():
 
 def test_registry_covers_every_family():
     from video_features_tpu.config import KNOWN_FEATURE_TYPES
-    # timm weights come via the pip-timm bridge, not this tool
-    assert set(fc.SOURCES) == set(KNOWN_FEATURE_TYPES) - {'timm'}
+    # timm weights come via the pip-timm bridge, not this tool; lm reads a
+    # converted .npz of a trunk's share (the published model is 48 B
+    # parameters: nothing this tool should pull)
+    assert set(fc.SOURCES) == set(KNOWN_FEATURE_TYPES) - {'timm', 'lm'}
 
 
 def test_file_url_download_and_verify(tmp_path):

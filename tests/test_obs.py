@@ -702,7 +702,10 @@ MANIFEST_KEYS = {'schema', 'version', 'started_at_unix_s', 'wall_s',
 CANONICAL_STAGES = {'decode', 'decode+preprocess', 'audio_dsp',
                     'queue_idle', 'pack', 'h2d', 'input_wait', 'model',
                     'device_wait', 'd2h', 'save', 'cache_lookup',
-                    'cache_publish'}
+                    'cache_publish',
+                    # PR 27, the lm family: its tokeniser's span and the
+                    # routing counters of its expert layers
+                    'tokenise', 'moe_route', 'moe_held'}
 
 
 def test_stage_vocabulary_contract():

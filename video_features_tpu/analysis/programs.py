@@ -145,6 +145,17 @@ _FAMILY_OVERRIDES: Dict[str, Dict[str, Any]] = {
     # the registry arch the timm lane is tuned around; pretrained=False
     # skips the pip-timm download path (shapes come from the native init)
     'timm': {'model_name': 'vit_base_patch16_224', 'pretrained': False},
+    # lm.yml ships a 48 B-parameter model (192 GB): the lock pins the
+    # step's contract at a trunk a host can build — every kind of layer
+    # once more than once (1 dense + 2 expert layers), a share of the
+    # experts held, windows of 256 ids — not at the published widths
+    'lm': {'vocab_size': 1024, 'hidden_size': 128, 'num_hidden_layers': 3,
+           'intermediate_size': 256, 'moe_intermediate_size': 64,
+           'n_routed_experts': 16, 'n_experts_held': 8,
+           'num_experts_per_tok': 4, 'num_attention_heads': 4,
+           'q_lora_rank': 96, 'kv_lora_rank': 64, 'qk_nope_head_dim': 32,
+           'qk_rope_head_dim': 16, 'v_head_dim': 32, 'stack_size': 4,
+           'step_size': 4, 'patch_grid': 8},
 }
 
 

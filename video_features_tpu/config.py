@@ -21,7 +21,7 @@ CONFIG_DIR = Path(__file__).parent / 'configs'
 # vft-programs contract checker pins PROGRAMS.lock.json against
 # (analysis/programs.py) — adding a family here obliges an abstract
 # step spec (BaseExtractor.program_specs) and a lock re-pin.
-KNOWN_FEATURE_TYPES = ('i3d', 'r21d', 's3d', 'vggish', 'resnet', 'raft', 'clip', 'timm')
+KNOWN_FEATURE_TYPES = ('i3d', 'r21d', 's3d', 'vggish', 'resnet', 'raft', 'clip', 'timm', 'lm')
 
 # -- content-addressed feature cache (cache/; docs/caching.md) ---------------
 # Injected into every merged config (CLI dotlist wins, as always) rather

@@ -1,0 +1,211 @@
+"""Token-trunk extractor: a sparse-expert, latent-attention language trunk
+run over ids cut from decoded frames (``feature_type=lm``).
+
+The ninth family, and the first whose device step takes integers: a window
+is ``stack_size`` consecutive decoded frames (step ``step_size``, a partial
+tail dropped as the stack families drop it), the host preprocess is a
+tokeniser, and the step runs ``models/latent_moe.py`` over
+``(batch, stack_size · patch_grid²) int32`` → one ``(hidden_size,)`` row a
+window. Everything around the step is the stack families' path: decode
+lanes → windows → host preprocess → pack → H2D → step → D2H → scatter →
+save (``StackPackingMixin``, ``parallel/packing.py::run_packed``).
+
+**The ids are traffic, not model.** No tokeniser ships with a trunk's
+``config.json``, so the ids are cut from the pixels by a fixed rule: of each
+RGB frame the centred region of ``g·(H div g)`` rows × ``g·(W div g)``
+columns is cut into a ``g × g`` grid of patches (``g = patch_grid``), and a
+patch's id is ``((sum of its bytes) · 2654435761 mod 2³²) mod vocab_size``,
+patches row-major, frames in order — integer arithmetic only, so any
+decoder that is bit-exact gives the same ids.
+
+**The share.** ``n_experts_held`` (None: all) experts from ``first_expert``
+on are held here; the router keeps its width (``ops/moe.py``). The build
+refuses, with the sizes, a trunk whose parameters exceed the device's
+memory: the shipped yml is the whole published model, 48 B parameters.
+
+Telemetry: the ``tokenise`` span, and two counters on the stage table
+filled from the step's own per-expert counts — ``moe_route`` (mean ÷
+largest number of assignments on one held expert, summed over layers and
+steps) and ``moe_held`` (assignments on held experts ÷ all assignments).
+"""
+from __future__ import annotations
+
+from functools import partial
+from typing import Dict
+
+import jax
+import numpy as np
+
+from video_features_tpu.extract.base import (
+    BaseExtractor, StackPackingMixin, named_step,
+)
+from video_features_tpu.models import latent_moe
+from video_features_tpu.utils.device import jax_device
+
+TOKEN_HASH = 2654435761
+# the step's second output: (expert layers, held) assignment counts of the
+# batch; taken off again in fetch_outputs, never scattered to a video
+COUNTS_KEY = 'moe_counts'
+
+
+def tokenise_frames(frames: np.ndarray, grid: int,
+                    vocab_size: int) -> np.ndarray:
+    """(n, H, W, 3) uint8 frames → (n · grid²,) int32 ids (module doc)."""
+    n, h, w, c = frames.shape
+    ph, pw = h // grid, w // grid
+    if not ph or not pw:
+        raise ValueError(f'a {w}x{h} frame is smaller than the '
+                         f'{grid}x{grid} patch grid')
+    top, left = (h - grid * ph) // 2, (w - grid * pw) // 2
+    region = frames[:, top:top + grid * ph, left:left + grid * pw]
+    sums = region.reshape(n, grid, ph, grid, pw, c).sum(
+        axis=(2, 4, 5), dtype=np.uint64)
+    ids = (sums * np.uint64(TOKEN_HASH) % np.uint64(1 << 32)
+           % np.uint64(vocab_size))
+    return ids.reshape(-1).astype(np.int32)
+
+
+def check_params_fit(need_bytes: int, limit_bytes, what: str) -> None:
+    """Refuse at build what would fail in the first step: parameters larger
+    than the device's memory (``limit_bytes`` None or 0: not known, e.g. the
+    CPU backend — nothing to check)."""
+    if limit_bytes and need_bytes > limit_bytes:
+        raise ValueError(
+            f'{what}: {need_bytes / 1e9:.2f} GB of float32 parameters do '
+            f'not fit the device\'s {limit_bytes / 1e9:.2f} GB. Hold a '
+            f'share (n_experts_held, first_expert: the experts of a layer '
+            f'divided over chips) and run fewer layers here '
+            f'(num_hidden_layers: the rest are further pipeline stages).')
+
+
+class ExtractLM(StackPackingMixin, BaseExtractor):
+
+    def __init__(self, args) -> None:
+        super().__init__(
+            feature_type=args.feature_type,
+            on_extraction=args.on_extraction,
+            tmp_path=args.tmp_path,
+            output_path=args.output_path,
+            keep_tmp_files=args.keep_tmp_files,
+            device=args.device,
+            profile=args.get('profile', False),
+            precision=args.get('precision', 'highest'),
+            inflight=args.get('inflight', 2),
+            compute_dtype=args.get('compute_dtype', 'float32'),
+        )
+        self.stack_size = int(args.stack_size)
+        self.step_size = int(args.step_size)
+        self.patch_grid = int(args.patch_grid)
+        self.extraction_fps = args.extraction_fps
+        self.output_feat_keys = [self.feature_type]
+        self.stack_batch = int(args.get('batch_size') or 1)
+        self.decode_backend = args.get('decode_backend', 'auto')
+        self.data_parallel = False       # not in DATA_PARALLEL_FEATURES
+        self.cfg = latent_moe.TrunkConfig.from_args(args)
+        self.window_ids = self.stack_size * self.patch_grid ** 2
+        self.packed_feat_dim = self.cfg.hidden_size
+        self._device = jax_device(self.device)
+        check_params_fit(
+            latent_moe.param_count(self.cfg) * 4,
+            (self._device.memory_stats() or {}).get('bytes_limit'),
+            f'feature_type=lm with {self.cfg.num_hidden_layers} layers and '
+            f'{self.cfg.n_experts_held} of {self.cfg.n_routed_experts} '
+            f'experts a layer')
+        self.params = self.load_params(args)
+        self._step = jax.jit(named_step(
+            partial(self._forward, cfg=self.cfg), self.step_name))
+
+    def load_params(self, args):
+        """The flat ``{checkpoint name: device array}`` dict: read from the
+        ``.npz`` one array at a time straight onto the device (a 6.7 GB
+        share must not stand on the host twice), or seeded random."""
+        from video_features_tpu.extract.weights import (
+            load_npz_to_device, require_checkpoint,
+        )
+        shapes = latent_moe.param_shapes(self.cfg)
+        ckpt = require_checkpoint(args, 'checkpoint_path', feature_type='lm')
+        if ckpt:
+            return load_npz_to_device(ckpt, shapes, self._device)
+        return jax.device_put(latent_moe.init_params(self.cfg),
+                              self._device)
+
+    @staticmethod
+    def _forward(params, ids, cfg):
+        feats, counts = latent_moe.forward(params, ids, cfg)
+        return {'lm': feats, COUNTS_KEY: counts}
+
+    # -- the host preprocess: frames → ids ----------------------------------
+
+    def _tokenise(self, window: np.ndarray) -> np.ndarray:
+        with self.tracer.stage('tokenise'):
+            return tokenise_frames(window, self.patch_grid,
+                                   self.cfg.vocab_size)
+
+    def packed_windows(self, task):
+        for window, meta in super().packed_windows(task):
+            yield self._tokenise(window), meta
+
+    def live_window_spec(self):
+        return None      # raw network frames would skip the tokeniser
+
+    def farm_recipe(self):
+        return None      # the tokeniser is no named transform spec
+
+    # -- the device step ------------------------------------------------------
+
+    def program_specs(self, mesh=None):
+        from video_features_tpu.analysis.programs import ProgramSpec
+        batch = self._abstract_batch(
+            (self._program_batch_slots(mesh), self.window_ids), np.int32,
+            mesh)
+        return [ProgramSpec('step', self._step,
+                            (self._abstract_params(mesh), batch))]
+
+    def packed_step(self, ids):
+        return self.aot_call('step', self._step, self.params, ids)
+
+    def fetch_outputs(self, out):
+        out = dict(super().fetch_outputs(out))
+        counts = out.pop(COUNTS_KEY, None)
+        if counts is not None and self.tracer.enabled and counts.size:
+            # per layer: the held experts' mean load against the fullest
+            # one's (the one the layer waits for), and how many of all
+            # assignments fell on experts held here
+            counts = np.asarray(counts, np.int64)
+            layers, held = counts.shape
+            assigned = (self.stack_batch * self.window_ids
+                        * self.cfg.num_experts_per_tok * layers)
+            self.tracer.add_occupancy('moe_route', int(counts.sum()),
+                                      int(counts.max(axis=1).sum()) * held)
+            self.tracer.add_occupancy('moe_held', int(counts.sum()),
+                                      assigned)
+        return out
+
+    def extract(self, video_path: str) -> Dict[str, np.ndarray]:
+        from video_features_tpu.extract.streaming import (
+            iter_batched_windows, overlap_fetch, stream_windows,
+            transfer_batches,
+        )
+        loader = self._make_loader(video_path)
+        ids = (self._tokenise(w) for w in stream_windows(
+            loader, self.stack_size, self.step_size, self.tracer, 'decode'))
+
+        def dispatched():
+            for batch, _, valid, _ in transfer_batches(
+                    iter_batched_windows(ids, self.stack_batch, self.tracer),
+                    self.put_input, tracer=self.tracer):
+                with self.tracer.stage(
+                        'model', **self.step_attrs(valid, self.stack_batch)):
+                    dev = self.packed_step(batch)
+                self.tracer.add_occupancy('model', valid, self.stack_batch)
+                yield dev, valid
+
+        feats = []
+        with self.precision_scope():
+            for out, valid in overlap_fetch(dispatched(), self.fetch_outputs,
+                                            self.inflight, self.tracer,
+                                            self.last_step):
+                feats.append(out[self.feature_type][:valid])
+        feats = (np.concatenate(feats, axis=0) if feats
+                 else np.zeros((0, self.packed_feat_dim), np.float32))
+        return {self.feature_type: feats}

@@ -64,6 +64,11 @@ def require_checkpoint(args: Any, key: str, *, feature_type: str,
             provision = (f'Provision real weights with `python '
                          f'tools/fetch_checkpoints.py {feature_type}` '
                          f'(see docs/checkpoints.md).')
+        elif feature_type == 'lm':
+            provision = ('`lm` reads a flat `.npz` of the trunk\'s share '
+                         '(dotted checkpoint names, matrices laid out (in, '
+                         f'out)); pass it via `{key}` (see '
+                         'docs/models/lm.md).')
         else:
             # timm (and any future bridge-fed family): weights come from
             # pip-timm via the bridge or a user-supplied converted file,
@@ -118,3 +123,56 @@ def load_or_init(args: Any, key: str, init_fn: Callable[[], Dict[str, Any]],
         return (load_torch_checkpoint(ckpt) if dtype is None
                 else load_torch_checkpoint(ckpt, dtype=dtype))
     return transplant(init_fn(), dtype=dtype)
+
+
+def load_npz_to_device(path: str, shapes: Dict[str, tuple], device,
+                       ) -> Dict[str, Any]:
+    """``{name: device array}`` for every name of ``shapes``, read from a
+    flat ``.npz`` (dotted names, matrices as (in, out)) one array at a
+    time and put on ``device`` as it is read: the host holds two arrays at
+    most (the one being read and the one in flight), never a second copy of
+    the checkpoint. For families whose parameters are a large share of the
+    device's memory (``extract/lm.py``).
+
+    A layer's held experts are one stacked array
+    (``….mlp.experts.gate_proj.weight``: (held, in, out)); a checkpoint
+    that keeps them one by one (``….mlp.experts.<j>.gate_proj.weight``,
+    the published layout) is stacked here, expert 0 of the archive first.
+    Missing names and wrong shapes are errors that name them."""
+    import jax
+    import numpy as np
+    if not str(path).endswith('.npz'):
+        raise ValueError(
+            f'{path}: this family reads a flat .npz archive (dotted '
+            f'checkpoint names, matrices laid out (in, out)); convert '
+            f'other formats first (docs/checkpoints.md)')
+    out: Dict[str, Any] = {}
+    in_flight = None
+    with np.load(path) as data:
+        have = set(data.files)
+
+        def read(name: str, shape: tuple):
+            if name in have:
+                return data[name]
+            head, sep, tail = name.partition('.experts.')
+            one = [f'{head}.experts.{j}.{tail}' for j in range(shape[0])]
+            if sep and all(k in have for k in one):
+                stacked = np.empty(shape, np.float32)
+                for j, k in enumerate(one):
+                    stacked[j] = data[k]
+                return stacked
+            raise KeyError(f'{path} has no parameter {name!r}')
+
+        for name, shape in shapes.items():
+            arr = read(name, tuple(shape))
+            if tuple(arr.shape) != tuple(shape):
+                raise ValueError(f'{path}: {name} has shape '
+                                 f'{tuple(arr.shape)}, the config needs '
+                                 f'{tuple(shape)}')
+            if arr.dtype != np.float32:
+                arr = arr.astype(np.float32)
+            placed = jax.device_put(arr, device)
+            if in_flight is not None:
+                in_flight.block_until_ready()
+            out[name] = in_flight = placed
+    return out

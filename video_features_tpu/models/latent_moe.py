@@ -1,0 +1,291 @@
+"""A sparse-expert, latent-attention token trunk (DeepSeek-V3 lineage).
+
+The decoder trunk of ``joyai_llm_flash`` / ``deepseek_v3``-style language
+models as a feature extractor: token ids in, one hidden-state row a window
+out. Pre-norm residual blocks, RMSNorm, no biases:
+
+* **latent attention (MLA)** — queries through a low-rank bottleneck
+  (``q_lora_rank``), keys and values expanded from one shared latent
+  (``kv_lora_rank``) plus one rotary key head shared by all heads; heads of
+  ``qk_nope_head_dim + qk_rope_head_dim`` for q/k and ``v_head_dim`` for v;
+  rotary on the rope dims only, interleaved pairs; causal. Prefill only —
+  no cache, so the expanded form (``ops.attention.blockwise_attention``).
+* **the feed-forward** — a dense SwiGLU in the first
+  ``first_k_dense_replace`` layers; after them a mixture of
+  ``n_routed_experts`` SwiGLU experts, ``num_experts_per_tok`` a token
+  (``ops/moe.py``), plus ``n_shared_experts`` shared ones every token takes.
+* **the share** — ``n_experts_held`` experts from ``first_expert`` on are
+  held here (all of them when None); the router keeps its full width, and
+  what the absent experts would add is left out.
+* **output** — final RMSNorm, mean over the window's positions. The output
+  head and the multi-token-prediction module are not part of a feature
+  extractor and are neither held nor run.
+
+Parameters are a flat ``{dotted name: array}`` dict under the checkpoint's
+own names (``model.layers.3.self_attn.q_b_proj.weight`` …), matrices as
+(in, out); a layer's held experts are stacked: ``mlp.experts.gate_proj.weight``
+is (held, hidden, moe_intermediate).
+"""
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from video_features_tpu.ops import moe
+from video_features_tpu.ops.attention import (
+    blockwise_attention, rotary_interleaved,
+)
+
+Params = Dict[str, jax.Array]
+
+# the config keys a trunk is built from, under the names the published
+# config.json uses (configs/lm.yml ships JoyAI-LLM-Flash's values)
+CONFIG_KEYS = (
+    'vocab_size', 'hidden_size', 'num_hidden_layers', 'first_k_dense_replace',
+    'intermediate_size', 'moe_intermediate_size', 'n_routed_experts',
+    'n_shared_experts', 'num_experts_per_tok', 'routed_scaling_factor',
+    'norm_topk_prob', 'num_attention_heads', 'q_lora_rank', 'kv_lora_rank',
+    'qk_nope_head_dim', 'qk_rope_head_dim', 'v_head_dim', 'rope_theta',
+    'rms_norm_eps', 'n_experts_held', 'first_expert',
+)
+
+
+@dataclass(frozen=True)
+class TrunkConfig:
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    first_k_dense_replace: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int
+    n_shared_experts: int
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    rms_norm_eps: float
+    n_experts_held: Optional[int] = None     # None: all of them
+    first_expert: int = 0
+
+    def __post_init__(self):
+        held = (self.n_routed_experts if self.n_experts_held is None
+                else int(self.n_experts_held))
+        object.__setattr__(self, 'n_experts_held', held)
+        if not 0 < held <= self.n_routed_experts - self.first_expert:
+            raise ValueError(
+                f'n_experts_held={held} from first_expert='
+                f'{self.first_expert} does not lie inside the router\'s '
+                f'{self.n_routed_experts} experts')
+        if self.num_experts_per_tok > self.n_routed_experts:
+            raise ValueError('num_experts_per_tok exceeds n_routed_experts')
+
+    @classmethod
+    def from_args(cls, args) -> 'TrunkConfig':
+        values = {k: args.get(k) for k in CONFIG_KEYS}
+        values['first_expert'] = values['first_expert'] or 0
+        missing = [k for k, v in values.items()
+                   if v is None and k != 'n_experts_held']
+        if missing:
+            raise ValueError(f'the lm trunk needs config keys {missing}')
+        return cls(**values)
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    def is_dense(self, layer: int) -> bool:
+        return layer < self.first_k_dense_replace
+
+
+def param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
+    """{name: shape} of every parameter held, in checkpoint order."""
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    shapes: Dict[str, Tuple[int, ...]] = {
+        'model.embed_tokens.weight': (cfg.vocab_size, d)}
+    for i in range(cfg.num_hidden_layers):
+        p = f'model.layers.{i}'
+        a = f'{p}.self_attn'
+        shapes.update({
+            f'{p}.input_layernorm.weight': (d,),
+            f'{a}.q_a_proj.weight': (d, cfg.q_lora_rank),
+            f'{a}.q_a_layernorm.weight': (cfg.q_lora_rank,),
+            f'{a}.q_b_proj.weight': (cfg.q_lora_rank, h * cfg.qk_head_dim),
+            f'{a}.kv_a_proj_with_mqa.weight':
+                (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+            f'{a}.kv_a_layernorm.weight': (cfg.kv_lora_rank,),
+            f'{a}.kv_b_proj.weight':
+                (cfg.kv_lora_rank,
+                 h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            f'{a}.o_proj.weight': (h * cfg.v_head_dim, d),
+            f'{p}.post_attention_layernorm.weight': (d,),
+        })
+        m = f'{p}.mlp'
+        if cfg.is_dense(i):
+            f = cfg.intermediate_size
+            shapes.update({f'{m}.gate_proj.weight': (d, f),
+                           f'{m}.up_proj.weight': (d, f),
+                           f'{m}.down_proj.weight': (f, d)})
+            continue
+        f, e = cfg.moe_intermediate_size, cfg.n_experts_held
+        shapes.update({
+            f'{m}.gate.weight': (d, cfg.n_routed_experts),
+            f'{m}.gate.e_score_correction_bias': (cfg.n_routed_experts,),
+            f'{m}.experts.gate_proj.weight': (e, d, f),
+            f'{m}.experts.up_proj.weight': (e, d, f),
+            f'{m}.experts.down_proj.weight': (e, f, d),
+        })
+        if cfg.n_shared_experts:
+            fs = f * cfg.n_shared_experts
+            shapes.update({f'{m}.shared_experts.gate_proj.weight': (d, fs),
+                           f'{m}.shared_experts.up_proj.weight': (d, fs),
+                           f'{m}.shared_experts.down_proj.weight': (fs, d)})
+    shapes['model.norm.weight'] = (d,)
+    return shapes
+
+
+def param_count(cfg: TrunkConfig) -> int:
+    return sum(math.prod(s) for s in param_shapes(cfg).values())
+
+
+def init_params(cfg: TrunkConfig, seed: int = 0) -> Dict[str, np.ndarray]:
+    """Seeded random parameters (tests, ``allow_random_weights`` runs):
+    matrices N(0, 1/fan_in) over the contracted axis, the embedding N(0, 1),
+    norm gains near 1, a small router bias."""
+    rng = np.random.default_rng(seed)
+    out: Dict[str, np.ndarray] = {}
+    for name, shape in param_shapes(cfg).items():
+        if name.endswith('layernorm.weight') or name == 'model.norm.weight':
+            w = 0.9 + 0.2 * rng.random(shape, dtype=np.float32)
+        elif name.endswith('e_score_correction_bias'):
+            w = 0.05 * rng.standard_normal(shape, dtype=np.float32)
+        elif name == 'model.embed_tokens.weight':
+            w = rng.standard_normal(shape, dtype=np.float32)
+        else:
+            w = rng.standard_normal(shape, dtype=np.float32)
+            w *= np.float32(1.0 / math.sqrt(shape[-2]))
+        out[name] = w
+    return out
+
+
+# -- blocks -------------------------------------------------------------------
+
+def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * lax.rsqrt(var + eps) * gain).astype(x.dtype)
+
+
+def swiglu(x: jax.Array, p: Params, prefix: str) -> jax.Array:
+    gate = jnp.dot(x, p[f'{prefix}.gate_proj.weight'])
+    up = jnp.dot(x, p[f'{prefix}.up_proj.weight'])
+    return jnp.dot(jax.nn.silu(gate) * up, p[f'{prefix}.down_proj.weight'])
+
+
+def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
+              attn_block: int = 1024) -> jax.Array:
+    """Latent attention over one window: (S, D) → (S, D), causal, positions
+    0…S-1."""
+    with jax.named_scope('mla'):
+        s = x.shape[0]
+        h, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+        eps = cfg.rms_norm_eps
+        c_q = rms_norm(jnp.dot(x, p[f'{prefix}.q_a_proj.weight']),
+                       p[f'{prefix}.q_a_layernorm.weight'], eps)
+        q = jnp.dot(c_q, p[f'{prefix}.q_b_proj.weight']).reshape(s, h, dn + dr)
+        kv_a = jnp.dot(x, p[f'{prefix}.kv_a_proj_with_mqa.weight'])
+        c_kv = rms_norm(kv_a[:, :cfg.kv_lora_rank],
+                        p[f'{prefix}.kv_a_layernorm.weight'], eps)
+        k_rope = kv_a[:, cfg.kv_lora_rank:].reshape(s, 1, dr)
+        kv = jnp.dot(c_kv, p[f'{prefix}.kv_b_proj.weight']
+                     ).reshape(s, h, dn + dv)
+        positions = jnp.arange(s)
+        q_rope = rotary_interleaved(q[..., dn:], positions, cfg.rope_theta)
+        k_rope = rotary_interleaved(k_rope, positions, cfg.rope_theta)
+        q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_rope, (s, h, dr))], axis=-1)
+        out = blockwise_attention(q[None], k[None], kv[None, ..., dn:],
+                                  block_size=min(attn_block, s),
+                                  causal=True)[0]
+        return jnp.dot(out.reshape(s, h * dv), p[f'{prefix}.o_proj.weight'])
+
+
+def expert_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
+                 moe_block: int = 256) -> Tuple[jax.Array, jax.Array]:
+    """The expert layer's feed-forward over (T, D) tokens: the held
+    experts' share of the routed sum plus the shared expert. Returns the
+    output and the (held,) assignment counts."""
+    with jax.named_scope('moe'):
+        experts, weights = moe.route(
+            x, p[f'{prefix}.gate.weight'],
+            p[f'{prefix}.gate.e_score_correction_bias'],
+            top_k=cfg.num_experts_per_tok,
+            scaling=cfg.routed_scaling_factor,
+            normalise=cfg.norm_topk_prob)
+        y, counts = moe.moe_share(
+            x, experts, weights,
+            p[f'{prefix}.experts.gate_proj.weight'],
+            p[f'{prefix}.experts.up_proj.weight'],
+            p[f'{prefix}.experts.down_proj.weight'],
+            first=cfg.first_expert, block=moe_block)
+        if cfg.n_shared_experts:
+            y = y + swiglu(x, p, f'{prefix}.shared_experts')
+        return y, counts
+
+
+def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
+                  attn_block: int = 1024, moe_block: int = 256
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """(B, S) int32 ids → ``(final-norm hidden states (B, S, D), counts)``.
+
+    ``counts`` is (expert layers, held) int32: the batch's assignments on
+    each held expert, layer by layer (zero rows when no layer has experts).
+    Attention runs a window at a time (its tiles are the memory that
+    matters); the feed-forward takes all B·S tokens at once, so an expert
+    sees the whole batch's assignments in one grouped product."""
+    b, s = ids.shape
+    d = cfg.hidden_size
+    eps = cfg.rms_norm_eps
+    x = params['model.embed_tokens.weight'][ids]            # (B, S, D)
+    counts = []
+    for i in range(cfg.num_hidden_layers):
+        p = f'model.layers.{i}'
+        normed = rms_norm(x, params[f'{p}.input_layernorm.weight'], eps)
+        x = x + lax.map(
+            lambda w: mla_block(params, f'{p}.self_attn', w, cfg, attn_block),
+            normed)
+        normed = rms_norm(x, params[f'{p}.post_attention_layernorm.weight'],
+                          eps).reshape(b * s, d)
+        if cfg.is_dense(i):
+            with jax.named_scope('dense_mlp'):
+                y = swiglu(normed, params, f'{p}.mlp')
+        else:
+            y, c = expert_block(params, f'{p}.mlp', normed, cfg, moe_block)
+            counts.append(c)
+        x = x + y.reshape(b, s, d)
+    counts = (jnp.stack(counts) if counts
+              else jnp.zeros((0, cfg.n_experts_held), jnp.int32))
+    return rms_norm(x, params['model.norm.weight'], eps), counts
+
+
+def forward(params: Params, ids: jax.Array, cfg: TrunkConfig,
+            attn_block: int = 1024, moe_block: int = 256
+            ) -> Tuple[jax.Array, jax.Array]:
+    """(B, S) int32 ids → ``(features (B, D) float32, counts)``: the mean
+    of the window's final-norm hidden states (:func:`hidden_states`)."""
+    x, counts = hidden_states(params, ids, cfg, attn_block, moe_block)
+    return x.astype(jnp.float32).mean(axis=1), counts

@@ -9,6 +9,12 @@ sharded over a mesh axis and attended with **ring attention** — KV shards
 rotate around the ring via ``lax.ppermute`` (ICI neighbor exchange, no
 all-gather) while each device accumulates its queries' online softmax.
 
+Causal language trunks (``models/latent_moe.py``) take the same blockwise
+path with ``causal=True``: queries are tiled too, a query tile scans only the
+key tiles at or before it, and the value head may be narrower than the
+query/key head (latent attention: 192-wide q/k, 128-wide v).
+:func:`rotary_interleaved` is their position code.
+
 All three paths compute bit-comparable results (same online-softmax math,
 f32 accumulation):
 
@@ -44,8 +50,9 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def _online_block(q, m, l, o, kb, vb, scale, valid=None):
     """One online-softmax accumulation step against KV block (kb, vb).
 
-    ``valid`` (block_size,) bool masks padded keys out of the softmax
-    (scores → -inf ⇒ p → 0); fully-padded blocks leave the carry unchanged
+    ``valid`` masks keys out of the softmax (scores → -inf ⇒ p → 0):
+    (block_size,) bool for padded keys, or (sq, 1, block_size) for the
+    causal diagonal tile; fully-masked blocks leave the carry unchanged
     because m_new falls back to the running max.
     """
     s = jnp.einsum('bqhd,bkhd->bqhk', q, kb).astype(jnp.float32) * scale
@@ -65,24 +72,30 @@ def _online_block(q, m, l, o, kb, vb, scale, valid=None):
     return m_new, l_new, o_new
 
 
-def _online_init(q):
+def _online_init(q, v_dim: Optional[int] = None):
     b, sq, h, d = q.shape
     m = jnp.full((b, sq, h, 1), -jnp.inf, jnp.float32)
     l = jnp.zeros((b, sq, h, 1), jnp.float32)
-    o = jnp.zeros((b, sq, h, d), jnp.float32)
+    o = jnp.zeros((b, sq, h, d if v_dim is None else v_dim), jnp.float32)
     return m, l, o
 
 
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         block_size: int = 512,
-                        scale: Optional[float] = None) -> jax.Array:
+                        scale: Optional[float] = None,
+                        causal: bool = False) -> jax.Array:
     """Memory-efficient attention: scan over KV blocks, O(S·block) memory.
 
     Ragged S is handled by zero-padding KV to a block multiple and masking
     the padded keys out of the online softmax — a ViT token count
     (grid² + 1 cls) is never block-aligned, and this is the production path
     for high-resolution inputs past BLOCKWISE_THRESHOLD tokens.
+
+    ``causal=True`` (self-attention, S a block multiple): position i sees
+    keys 0…i. ``v`` may have another head width than ``q``/``k``.
     """
+    if causal:
+        return _causal_blockwise(q, k, v, block_size, _scale(q, scale))
     b, sk, h, d = k.shape
     block_size = min(block_size, sk)
     pad = (-sk) % block_size
@@ -106,8 +119,61 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return (m, l, o), None
 
     xs = (kb, vb) if valid is None else (kb, vb, valid)
-    (m, l, o), _ = lax.scan(step, _online_init(q), xs)
+    (m, l, o), _ = lax.scan(step, _online_init(q, v.shape[-1]), xs)
     return (o / l).astype(q.dtype)
+
+
+def _causal_blockwise(q, k, v, block_size: int, scale: float) -> jax.Array:
+    """Causal self-attention, tiled both ways: query tile i scans key tiles
+    0…i-1 unmasked and then its own diagonal tile under the triangle, so
+    the tiles above the diagonal cost nothing (a scan over all keys with a
+    mask would compute, and throw away, half of S²)."""
+    b, s, h, _ = q.shape
+    if k.shape[1] != s:
+        raise ValueError(f'causal attention is self-attention: q has {s} '
+                         f'positions, k has {k.shape[1]}')
+    block_size = min(block_size, s)
+    if s % block_size:
+        raise ValueError(f'causal attention needs the sequence ({s}) to be '
+                         f'a multiple of block_size ({block_size})')
+    n_blocks = s // block_size
+    kb = k.reshape(b, n_blocks, block_size, h, -1).swapaxes(0, 1)
+    vb = v.reshape(b, n_blocks, block_size, h, -1).swapaxes(0, 1)
+    pos = jnp.arange(block_size)
+    triangle = (pos[:, None] >= pos[None, :])[:, None, :]   # (q, 1, k)
+
+    out = []
+    for i in range(n_blocks):
+        qi = q[:, i * block_size:(i + 1) * block_size]
+
+        def step(carry, blk, qi=qi):
+            return _online_block(qi, *carry, *blk, scale), None
+
+        carry = _online_init(qi, v.shape[-1])
+        if i:
+            carry, _ = lax.scan(step, carry, (kb[:i], vb[:i]))
+        _, l, o = _online_block(qi, *carry, kb[i], vb[i], scale,
+                                valid=triangle)
+        out.append(o / l)
+    return jnp.concatenate(out, axis=1).astype(q.dtype)
+
+
+def rotary_interleaved(x: jax.Array, positions: jax.Array,
+                       theta: float) -> jax.Array:
+    """Rotary position code on interleaved pairs: ``(x[2i], x[2i+1])`` is
+    the complex number rotated by ``positions · theta^(-2i/d)``. ``x`` is
+    (..., S, H, d) with d even, ``positions`` (S,); the pairs stay where
+    they are (no half-split re-layout), so q and k rotated alike keep their
+    dot product's meaning."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq   # (S, d/2)
+    cos = jnp.cos(angle)[:, None, :]
+    sin = jnp.sin(angle)[:, None, :]
+    pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+    re, im = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([re * cos - im * sin, re * sin + im * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,

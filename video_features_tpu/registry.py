@@ -19,6 +19,7 @@ EXTRACTORS: Dict[str, Tuple[str, str]] = {
     'raft': ('video_features_tpu.extract.raft', 'ExtractRAFT'),
     'clip': ('video_features_tpu.extract.clip', 'ExtractCLIP'),
     'timm': ('video_features_tpu.extract.timm', 'ExtractTIMM'),
+    'lm': ('video_features_tpu.extract.lm', 'ExtractLM'),
 }
 
 # feature types whose extractor implements in-graph data parallelism
@@ -35,7 +36,7 @@ DATA_PARALLEL_FEATURES = frozenset(
 # extractor must opt in here AND set supports_packing, or sanity_check
 # degrades the knob to the per-video loop with a warning.
 PACKED_FEATURES = frozenset(
-    {'i3d', 'r21d', 's3d', 'resnet', 'clip', 'timm'})
+    {'i3d', 'r21d', 's3d', 'resnet', 'clip', 'timm', 'lm'})
 
 # feature types whose extractor accepts the bf16 fast lane
 # (compute_dtype=bfloat16 — params cast bf16 at transplant, bf16

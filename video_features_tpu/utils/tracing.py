@@ -45,6 +45,7 @@ from typing import Dict, Iterable, Iterator, List, Optional
 STAGES = (
     'decode',             # raw decode (stack families without preprocess)
     'decode+preprocess',  # decode + host transform on the prefetch thread
+    'tokenise',           # lm: decoded frames → token ids, on the host
     'audio_dsp',          # vggish: host-side mel/log-mel DSP on the wav
     'queue_idle',         # serve: blocking waits on an idle request feed
     'pack',               # batch assembly copies (window and batch np.stack)
@@ -56,6 +57,10 @@ STAGES = (
     'save',               # output materialization (.npy/.pkl writes)
     'cache_lookup',       # content-addressed cache consult
     'cache_publish',      # content-addressed cache publish
+    # counters only (add_occupancy; no time): the lm step's routing, from
+    # the per-expert counts the step itself returns
+    'moe_route',          # mean ÷ largest load on one held expert
+    'moe_held',           # assignments on held experts ÷ all assignments
 )
 
 
