@@ -24,7 +24,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from video_features_tpu.ops.nn import avg_pool, batch_norm, conv, relu
+from video_features_tpu.ops.nn import (
+    avg_pool, batch_norm, conv, conv_space_to_depth, relu,
+)
 
 Params = Dict[str, Any]
 
@@ -55,7 +57,7 @@ def tf_same_pads(kernel: Tuple[int, ...], stride: Tuple[int, ...]):
 
 def unit3d(p: Params, x: jax.Array, kernel: Tuple[int, int, int],
            stride: Tuple[int, int, int] = (1, 1, 1), use_bn: bool = True,
-           activation: bool = True) -> jax.Array:
+           activation: bool = True, conv=conv) -> jax.Array:
     """Unit3Dpy: SAME conv (+ bias) → BN → ReLU (reference i3d_net.py:37-105)."""
     x = conv(x, p['conv3d']['weight'], stride=stride,
              padding=tf_same_pads(kernel, stride),
@@ -97,7 +99,12 @@ def mixed(p: Params, x: jax.Array) -> jax.Array:
 
 def forward(params: Params, x: jax.Array, features: bool = True):
     """(B, T, 224, 224, C) → (B, 1024) features, or (softmax, logits)."""
-    x = unit3d(params['conv3d_1a_7x7'], x, (7, 7, 7), (2, 2, 2))
+    # 3 (rgb) or 2 (flow) input channels leave the MXU's contraction lanes
+    # empty: the strided stem runs at stride 1 over its taps folded into
+    # channels; the scope names its ops in the compiled HLO and the trace
+    with jax.named_scope('i3d_stem'):
+        x = unit3d(params['conv3d_1a_7x7'], x, (7, 7, 7), (2, 2, 2),
+                   conv=conv_space_to_depth)
     x = max_pool_tf(x, (1, 3, 3), (1, 2, 2))
     x = unit3d(params['conv3d_2b_1x1'], x, (1, 1, 1))
     x = unit3d(params['conv3d_2c_3x3'], x, (3, 3, 3))

@@ -10,6 +10,11 @@ MXU without relayout.
 Numerics parity notes (vs torch, for checkpoint-transplant fidelity):
   * conv: torch symmetric int padding → explicit (lo, hi) pairs here; TF-SAME
     asymmetric padding (I3D) is also expressible per-edge.
+  * conv_space_to_depth: a strided stem over 2-3 channels leaves the MXU's
+    contraction lanes empty, so I3D's first convolution folds its strided
+    taps into channels and runs at stride 1 — the same products and sums.
+    ``conv`` is not the place for that test: a branch inside it would
+    re-lower every family's program, so a model opts in at the call site.
   * batch norm is inference-only: y = (x - mean) / sqrt(var + eps) * γ + β
     with running statistics — matches torch .eval() semantics.
   * max pool with ceil_mode / TF-SAME is built from explicit -inf padding.
@@ -80,6 +85,78 @@ def conv(x: Array, kernel: Array, stride: IntOrTuple = 1,
     if bias is not None:
         out = out + bias.astype(out.dtype)
     return out
+
+
+def conv_space_to_depth(x: Array, kernel: Array, stride: IntOrTuple = 1,
+                        padding: Union[IntOrTuple,
+                                       Sequence[Tuple[int, int]]] = 0,
+                        bias: Optional[Array] = None) -> Array:
+    """``conv`` of a strided convolution, computed at stride 1 over an input
+    whose strided taps are folded into channels: the same products and the
+    same sums, the order of summation aside.
+
+    The MXU contracts over channels, so a stem with 3 input channels fills 3
+    of its 128 lanes. Two folds, both derived from the arguments:
+
+    * the last spatial dimension, if strided: its ``k`` taps become channels
+      (``k`` strided slices side by side, ``k·C`` channels, one tap left);
+    * every other strided dimension, space-to-depth: a tap ``t = s·a + r`` is
+      block ``a`` of ``ceil(k/s)`` and offset ``r`` inside the block; the
+      ``r`` of all dimensions become channels (the padded input reshaped
+      into blocks of ``s``, the kernel padded with zero taps to
+      ``s·ceil(k/s)`` and reshaped the same way), which leaves
+      ``out[o] = Σ_a x2[o + a] · w2[a]``.
+
+    I3D's 7×7×7 stride-2 stem over 3 channels becomes 4×4×1 over 84. The last
+    dimension is not folded into blocks as well because that de-interleaves
+    the array's minor dimension, which costs the TPU more than the
+    convolution it saves, and carries a zero tap. Stride 1 folds nothing and
+    falls through to ``conv``.
+    """
+    n = kernel.ndim - 2
+    strides = _tuple(stride, n)
+    if all(s == 1 for s in strides):
+        return conv(x, kernel, stride, padding, bias=bias)
+    pads = _pad_pairs(padding, n)
+    zero = jnp.zeros((), x.dtype)
+    if strides[-1] > 1:
+        k, s = kernel.shape[n - 1], strides[-1]
+        x = lax.pad(x, zero, [(0, 0, 0)] * n + [(*pads[-1], 0), (0, 0, 0)])
+        span = (x.shape[n] - k) // s * s + 1
+        x = jnp.concatenate(
+            [lax.slice_in_dim(x, t, t + span, stride=s, axis=n)
+             for t in range(k)], axis=-1)
+        kernel = kernel.reshape(*kernel.shape[:n - 1], 1, -1,
+                                kernel.shape[-1])
+        strides, pads = strides[:-1] + (1,), pads[:-1] + [(0, 0)]
+    sizes = kernel.shape[:n]
+    taps = [-(-k // s) for k, s in zip(sizes, strides)]
+    blocks, edges = [], []
+    for size, k, s, t, (lo, hi) in zip(x.shape[1:-1], sizes, strides, taps,
+                                       pads):
+        blocks.append((size + lo + hi - k) // s + t)
+        # the blocks the outputs read, no more: the high edge grows by the
+        # zero taps' reach or loses what no output window covers
+        edges.append((lo, s * blocks[-1] - size - lo, 0))
+    def phases_to_channels(a: Array, lead: int, outer) -> Array:
+        """(*lead, o_1·s_1, …, o_n·s_n, C, *rest) → (*lead, o_1, …, o_n,
+        s_1·…·s_n·C, *rest)."""
+        rest = a.shape[lead + n + 1:]
+        a = a.reshape(*a.shape[:lead],
+                      *(v for os in zip(outer, strides) for v in os), -1,
+                      *rest)
+        split = lead + 2 * n
+        a = a.transpose(*range(lead), *range(lead, split, 2),
+                        *range(lead + 1, split, 2), *range(split, a.ndim))
+        return a.reshape(*a.shape[:lead + n], -1, *rest)
+
+    # padded apart from W's: one pad before the slices measured slower
+    x = lax.pad(x, zero, [(0, 0, 0)] + edges + [(0, 0, 0)])
+    x = phases_to_channels(x, 1, blocks)
+    kernel = jnp.pad(kernel, [(0, t * s - k) for t, s, k in
+                              zip(taps, strides, sizes)] + [(0, 0), (0, 0)])
+    kernel = phases_to_channels(kernel, 0, taps)
+    return conv(x, kernel, 1, 'VALID', bias=bias)
 
 
 def batch_norm(x: Array, p: Dict[str, Array], eps: float = 1e-5) -> Array:
