@@ -130,9 +130,7 @@ def phase_kernel(clips, work, platform):
     from video_features_tpu.models import raft
     from video_features_tpu.registry import create_extractor
 
-    impl = raft._lookup_impl()
-    if impl == 'auto':
-        impl = raft._resolve_auto_lookup(RAFT_H // 8, RAFT_W // 8, platform)
+    impl = raft.resolve_lookup(RAFT_H // 8, RAFT_W // 8, platform)
     if impl != 'lanes':
         raise AssertionError(f'CLI geometry resolves to lookup {impl!r}, '
                              "not the compiled 'lanes' kernel")

@@ -357,15 +357,6 @@ def test_serve_boot_compile_free_against_published_store(
     assert _npy_bytes(td / 'out') == cold_run['out']
 
 
-def test_bench_diff_boot_rung_direction():
-    """The zero-cold-start rungs are latency-direction
-    (lower-is-better); the program hit rate gates like a throughput."""
-    import tools.bench_diff as bd
-    assert bd.lower_is_better('serve_boot_first_feature_s')
-    assert bd.lower_is_better('serve_boot_first_feature_cold_s')
-    assert not bd.lower_is_better('aot_hit_rate')
-
-
 # -- slow lane: multi-family store coverage -----------------------------------
 
 

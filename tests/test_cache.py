@@ -279,6 +279,36 @@ def test_cli_path_hit_is_byte_identical_and_skips_compute(
     _assert_identical_outputs(ex1.output_path, ex2.output_path, cache_clips)
 
 
+def test_cli_path_corrupt_entry_is_reextracted_not_served(
+        cache_clips, tmp_path):
+    """A stored object truncated on disk: the next consult evicts it,
+    that video falls back to a cold extraction (the model runs again) and
+    re-publishes — and what it saves is what the cold pass saved, never
+    the bad bytes."""
+    cache_dir = str(tmp_path / 'fc')
+
+    def run_pass(tag):
+        ex = _extractor(cache_clips, tmp_path / tag, tmp_path / 'tmp',
+                        cache_enabled=True, cache_dir=cache_dir,
+                        profile=True)
+        ex.tracer.reset = lambda: None   # accumulate stages across videos
+        for p in cache_clips:
+            ex._extract(p)
+        return ex, ex.tracer.report()
+
+    ex1, _ = run_pass('cold')
+    victim = next((Path(cache_dir) / 'objects').glob('*/*/resnet.npy'))
+    victim.write_bytes(victim.read_bytes()[:16])
+
+    ex2, rep2 = run_pass('corrupt')
+    st = ex2.cache.stats()
+    assert st['corrupt_evicted'] == 1, st
+    assert st['hits'] == len(cache_clips) - 1, st
+    assert st['puts'] == len(cache_clips) + 1, st   # the victim re-published
+    assert rep2['model']['count'] > 0           # the victim re-extracted
+    _assert_identical_outputs(ex1.output_path, ex2.output_path, cache_clips)
+
+
 def test_cache_disabled_reproduces_legacy_behavior(cache_clips, tmp_path):
     """Without cache_enabled nothing consults or populates a cache and no
     cache stages appear — today's behavior exactly."""

@@ -315,6 +315,41 @@ def test_farm_worker_crash_fails_one_video_and_respawns(tmp_path):
     assert st['videos_failed'] == 1
 
 
+def test_farm_worker_death_leaves_a_valid_postmortem_bundle(tmp_path):
+    """A worker SIGKILLed mid-video dumps ONE black-box bundle naming the
+    death (worker, victim video, requeued count), valid by the bundle's
+    own validator and inside the byte cap — the crash path's evidence,
+    written after the recovery and never instead of it."""
+    import json
+
+    from video_features_tpu.farm import DecodeFarm
+    from video_features_tpu.obs.blackbox import BlackBox, validate_bundle
+    from video_features_tpu.obs.spans import SpanRecorder
+    from video_features_tpu.utils.tracing import Tracer
+
+    paths = [tmp_path / 'a.bin', tmp_path / 'CRASH.bin', tmp_path / 'b.bin']
+    tasks = _tasks(paths)
+    rec = SpanRecorder(capacity=4096)
+    max_bytes = 8 * (1 << 20)
+    pm = tmp_path / 'postmortem'
+    farm = DecodeFarm(CrashRecipe(n_windows=4), workers=2,
+                      ring_bytes=1 << 20,
+                      tracer=Tracer(enabled=True, recorder=rec),
+                      blackbox=BlackBox(str(pm), max_bytes=max_bytes,
+                                        recorders=lambda: [rec]))
+    _drain_farm(farm, tasks)
+    assert farm.stats()['videos_failed'] == 1
+
+    bundles = sorted(pm.iterdir())
+    assert len(bundles) == 1, bundles
+    assert validate_bundle(str(bundles[0])) == []
+    meta = json.loads((bundles[0] / 'meta.json').read_text())
+    assert meta['reason'] == 'farm_worker_death', meta
+    assert meta['extra']['victim'] == str(tmp_path / 'CRASH.bin')
+    total = sum(f.stat().st_size for f in pm.rglob('*') if f.is_file())
+    assert 0 < total <= max_bytes
+
+
 def test_farm_worker_spans_land_under_worker_pid_calibrated(tmp_path):
     """vft-flight cross-process span round-trip: decode spans are
     MEASURED in the worker and shipped on the result channel; the

@@ -18,6 +18,7 @@ Three layers, cheapest first:
     ``builds_compiled == 0``).
 """
 import os
+import re
 import socket
 import threading
 import time
@@ -29,6 +30,7 @@ from video_features_tpu.fleet.ring import HashRing
 from video_features_tpu.serve import protocol
 
 from tools.make_sample_video import write_noise_clip as _write_clip  # noqa: E402
+from tools.trace_view import validate_events  # noqa: E402
 
 
 # -- hash ring ---------------------------------------------------------------
@@ -420,8 +422,6 @@ def test_router_failover_yields_one_merged_trace():
     yields ONE trace — the router's route/failover spans plus spans
     from BOTH attempted backends, merged ts-sorted under a single
     trace_id, every event stamped with its contributing host."""
-    import re
-
     def traced(tag, captured, shed_submit=False):
         def respond(msg):
             if msg['cmd'] == protocol.CMD_PING:
@@ -553,6 +553,7 @@ def _fleet_overrides(tmp_path, host_tag, shared):
         'aot_enabled': True,
         'aot_dir': str(tmp_path / f'{host_tag}_aot'),
         'aot_l2_dir': str(shared / 'artifacts'),
+        'trace_out': str(tmp_path / f'{host_tag}_trace.json'),
     }
 
 
@@ -598,6 +599,28 @@ def test_fleet_two_backends_cache_parity_and_cold_boot(
         st = client.wait(rid, timeout_s=300)
         assert st['state'] == 'done' and st['videos'][fleet_clip] == 'saved'
         assert client.metrics()['fleet']['routed'][owner_addr] == 1
+
+        # the router is the fleet's ONE scrape target: the aggregate of
+        # two REAL backends parses line by line, host-relabeled
+        prom = client.metrics_prom()
+        assert prom.endswith('\n')
+        for needle in (f'vft_fleet_routed_total{{host="{owner_addr}"}}',
+                       f'vft_serve_queue_depth{{host="{owner_addr}"}}',
+                       'vft_fleet_requests_total{outcome="completed"}'):
+            assert needle in prom, needle
+        sample = re.compile(r'^[a-zA-Z_:][a-zA-Z0-9_:]*(\{.*\})? '
+                            r'(NaN|[+-]?Inf|[-+0-9.eE]+)$')
+        for line in prom.splitlines():
+            if line and not line.startswith('#'):
+                assert sample.match(line), f'bad sample: {line!r}'
+        # ... and the routed request's scatter-gathered trace holds the
+        # router's and the owner's spans and is a valid Chrome trace
+        trace = client.trace(rid)
+        assert trace['trace_id'] and 'router' in trace['hosts'], trace
+        assert {'router', owner_addr} <= {
+            (e.get('args') or {}).get('host') for e in trace['events']}
+        assert 'route' in {e.get('name') for e in trace['events']}
+        assert validate_events(trace['events']) == []
 
         # 2: cold boot on the survivor: its empty L1 pulls the peer's
         # executables from the shared artifact tier — zero compiles

@@ -480,6 +480,53 @@ def test_trace_route_tenant_scoped_and_traceparent_adopted(gatewayed,
     _wait_done(gateway, doc2['request_id'])
 
 
+def test_front_door_trace_spans_ingress_to_d2h_under_one_trace_id(
+        ingress_clips, tmp_path):
+    """vft-flight over the front door with the recorder ON (a server-wide
+    ``trace_out``): one HTTP request carrying a caller traceparent yields
+    ONE assembled trace whose ingress, admission, pack, model and d2h
+    spans all belong to that trace_id; the armed watchdog stays quiet on
+    the HTTP metrics surface; and the merged export written at drain
+    passes tools/trace_view.py."""
+    from tools.trace_view import main as trace_view_main
+    from video_features_tpu.ingress.gateway import IngressGateway
+    from video_features_tpu.serve.server import ExtractionServer
+
+    trace_path = tmp_path / 'flight_trace.json'
+    server = ExtractionServer(
+        base_overrides=dict(_base_overrides(tmp_path),
+                            trace_out=str(trace_path),
+                            watchdog_stall_s=3600.0),   # armed, quiet
+        queue_depth=8, pool_size=2).start()
+    gateway = IngressGateway(server, auth=_make_auth()).start()
+    caller_trace = 'f1e1d1c1' * 4
+    try:
+        st, doc = _api(gateway, 'POST', '/v1/extract', {
+            'feature_type': 'resnet', 'video_paths': [ingress_clips[1]]},
+            headers={'traceparent':
+                     f'00-{caller_trace}-00f067aa0ba902b7-01'})
+        assert st == 200 and doc['trace_id'] == caller_trace, doc
+        rid = doc['request_id']
+        assert _wait_done(gateway, rid)['state'] == 'done'
+
+        st, tr = _api(gateway, 'GET', f'/v1/requests/{rid}/trace')
+        assert st == 200 and tr['trace_id'] == caller_trace, tr
+        names = {e['name'] for e in tr['events']}
+        for stage in ('ingress', 'admission', 'pack', 'model', 'd2h'):
+            assert stage in names, (stage, sorted(names))
+        for e in tr['events']:
+            args = e.get('args') or {}
+            assert (args.get('trace_id') == caller_trace
+                    or caller_trace in (args.get('trace_ids') or ())
+                    or args.get('request_id') == rid), e
+        st, m = _api(gateway, 'GET', '/v1/metrics')
+        assert m['metrics']['watchdog']['enabled'] is True
+        assert m['metrics']['watchdog']['stalls_total'] == 0
+    finally:
+        server.drain(wait=True, grace_s=60)
+    assert trace_view_main([str(trace_path), '--quiet']) == 0
+
+
 def test_segment_decode_is_tracer_bounded_to_range(ingress_clips,
                                                    tmp_path):
     """Tracer-verified acceptance: a packed segment run records decode

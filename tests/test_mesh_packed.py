@@ -177,6 +177,7 @@ def test_cli_mesh_byte_identity_and_manifest(mesh_worklist, tmp_path,
     features to mesh_devices=1, the run manifest records the mesh shape
     with per-device occupancy, and the model/d2h spans carry the mesh
     width + per-shard valid counts."""
+    from tools.trace_view import validate_events
     from video_features_tpu.cli import main as cli_main
 
     manifest = str(tmp_path / 'mesh_manifest.json')
@@ -211,6 +212,7 @@ def test_cli_mesh_byte_identity_and_manifest(mesh_worklist, tmp_path,
         assert 0.0 <= rec['occupancy'] <= 1.0
 
     events = json.loads(Path(trace).read_text())['traceEvents']
+    assert validate_events(events) == []
     mesh_spans = [e for e in events if e['ph'] == 'X'
                   and e['name'] in ('model', 'd2h')
                   and (e.get('args') or {}).get('mesh_devices')]

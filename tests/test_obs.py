@@ -617,47 +617,6 @@ def test_serve_drain_exports_merged_trace(obs_worklist, tmp_path):
     assert any(e['args'].get('trace_id') == caller_trace for e in spans)
 
 
-# -- bench_diff --------------------------------------------------------------
-
-def _bench_rec(**rungs):
-    return {'metric': 'm', 'value': rungs.get('value', 1.0), 'unit': 'u',
-            'vs_baseline': 1.0, 'rungs': rungs}
-
-
-def test_bench_diff_detects_direction_aware_regressions(tmp_path, capsys):
-    from tools.bench_diff import main as bench_diff_main
-    old = tmp_path / 'old.json'
-    new = tmp_path / 'new.json'
-    old.write_text(json.dumps(_bench_rec(
-        e2e_mixed=10.0, serve_p99_latency_s=1.0, only_old=5.0)))
-    # throughput dropped 50% AND latency doubled: both are regressions
-    new.write_text(json.dumps(_bench_rec(
-        e2e_mixed=5.0, serve_p99_latency_s=2.0, only_new='err')))
-    assert bench_diff_main([str(old), str(new)]) == 0   # report-only mode
-    capsys.readouterr()
-    assert bench_diff_main([str(old), str(new),
-                            '--fail-on-regression', '10']) == 1
-    err = capsys.readouterr().err
-    assert 'e2e_mixed' in err and 'serve_p99_latency_s' in err
-
-    # within threshold → pass
-    new.write_text(json.dumps(_bench_rec(
-        e2e_mixed=9.8, serve_p99_latency_s=1.02)))
-    assert bench_diff_main([str(old), str(new),
-                            '--fail-on-regression', '10']) == 0
-    assert bench_diff_main([str(tmp_path / 'nope.json'), str(new)]) == 2
-
-
-def test_bench_diff_latency_improvement_is_not_regression(tmp_path):
-    from tools.bench_diff import main as bench_diff_main
-    old = tmp_path / 'o.json'
-    new = tmp_path / 'n.json'
-    old.write_text(json.dumps(_bench_rec(serve_p50_latency_s=2.0)))
-    new.write_text(json.dumps(_bench_rec(serve_p50_latency_s=0.5)))
-    assert bench_diff_main([str(old), str(new),
-                            '--fail-on-regression', '1']) == 0
-
-
 # -- schema contracts --------------------------------------------------------
 
 TRACER_RECORD_KEYS = {'count', 'total_s', 'mean_s', 'max_s', 'first_s',

@@ -441,6 +441,85 @@ def test_int8_scale_table_roundtrip(tmp_path):
     assert loaded_params['conv']['bias'].dtype == np.float32
 
 
+@pytest.fixture(scope='module')
+def _lane_worklist(tmp_path_factory):
+    """Two clips, one cache directory, and the float32 lane's packed run
+    over them — what each fast lane below is held against."""
+    from tools.make_sample_video import write_noise_clip
+    root = tmp_path_factory.mktemp('lanes')
+    clips = [str(write_noise_clip(root / f'clip{i}.mp4', n, seed=i))
+             for i, n in enumerate((9, 5))]
+
+    def build(lane):
+        from video_features_tpu.config import load_config
+        from video_features_tpu.registry import create_extractor
+        return create_extractor(load_config('resnet', overrides={
+            'video_paths': clips, 'device': 'cpu', 'model_name': 'resnet18',
+            'batch_size': 4, 'allow_random_weights': True,
+            'compute_dtype': lane, 'pack_across_videos': True,
+            'on_extraction': 'save_numpy',
+            'output_path': str(root / f'out_{lane}'),
+            'tmp_path': str(root / f'tmp_{lane}'),
+            'cache_enabled': True, 'cache_dir': str(root / 'feature_cache'),
+            'manifest_out': str(root / f'manifest_{lane}.json')}))
+
+    f32 = build('float32')
+    f32.extract_packed(clips)
+    assert f32.cache.stats()['puts'] == len(clips)
+    return clips, build, _npy_files(f32.output_path)
+
+
+def _npy_files(root):
+    from pathlib import Path
+    return {f.name: f.read_bytes() for f in sorted(Path(root).rglob('*.npy'))}
+
+
+@pytest.mark.parametrize('lane, bounds', [
+    ('bfloat16', BF16_REL_L2_BOUNDS), ('int8', INT8_REL_L2_BOUNDS)],
+    ids=['bfloat16', 'int8'])
+def test_fast_lane_run_never_shares_a_cache_key_and_names_itself(
+        lane, bounds, _lane_worklist, tmp_path):
+    """A fast lane's packed run over a cache the float32 lane just filled
+    is COLD (no cross-lane hit: the lanes' keys never collide), its
+    features differ from float32's yet land under the family's pinned
+    bound, its manifest names the lane (config, every executable, the
+    ``mesh1@<lane>`` pinned hashes), and the lane's own re-run is served
+    from the cache byte for byte."""
+    import io
+    import json
+    from pathlib import Path
+
+    from video_features_tpu.parallel.packing import VideoTask
+    clips, build, f32_files = _lane_worklist
+    ex = build(lane)
+    hits_before = ex.cache.stats()['hits']     # the store's, all lanes'
+    ex.extract_packed(clips)
+    ex.finish_obs()
+    assert ex.cache.stats()['hits'] == hits_before
+    fast_files = _npy_files(ex.output_path)
+    assert set(fast_files) == set(f32_files)
+    feats = sorted(n for n in f32_files
+                   if not n.endswith(('_fps.npy', '_timestamps_ms.npy')))
+    assert feats and any(f32_files[n] != fast_files[n] for n in feats)
+
+    def rows(files):
+        return np.concatenate([np.load(io.BytesIO(files[n])).ravel()
+                               for n in feats])
+    assert rel_l2(rows(f32_files), rows(fast_files)) <= bounds['resnet']
+
+    man = json.loads(Path(ex.manifest_out).read_text())
+    assert man['config']['compute_dtype'] == lane
+    assert man['executables'], 'packed run recorded no executables'
+    assert all(v.get('compute_dtype') == lane
+               for v in man['executables'].values()), man['executables']
+    assert f'mesh1@{lane}' in man['programs_lock']['resnet']
+
+    again = tmp_path / 'again'
+    ex.extract_packed([VideoTask(p, out_root=str(again)) for p in clips])
+    assert ex.cache.stats()['hits'] == hits_before + len(clips)
+    assert _npy_files(again) == fast_files
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize('ft', sorted(_BF16_CASES))
 def test_bf16_lane_parity_all_families(ft, tmp_path, _f32_reference):

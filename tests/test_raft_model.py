@@ -82,28 +82,6 @@ def test_coords_grid_xy_order():
     assert g[0, 2, 3, 1] == 2  # y = row
 
 
-def test_lookup_dense_matches_gather():
-    """The MXU-friendly dense lookup must equal the gather oracle, including
-    zeros-padding at out-of-map coords (reference corr.py:29-50 semantics)."""
-    import jax
-    import jax.numpy as jnp
-
-    rng = np.random.RandomState(0)
-    B, H8, W8, D = 6, 12, 9, 32
-    f1 = jnp.asarray(rng.randn(B, H8, W8, D).astype(np.float32))
-    f2 = jnp.asarray(rng.randn(B, H8, W8, D).astype(np.float32))
-    py = raft_model.build_corr_pyramid(f1, f2)
-    # coords spill past every edge to exercise the zero-weight region
-    coords = jnp.asarray(
-        (rng.rand(B, H8, W8, 2) * [W8 * 1.6, H8 * 1.6]
-         - [W8 * 0.3, H8 * 0.3]).astype(np.float32))
-    with jax.default_matmul_precision('highest'):
-        a = np.asarray(raft_model.lookup_corr(py, coords))
-        b = np.asarray(raft_model.lookup_corr_dense(py, coords))
-    assert a.shape == b.shape
-    np.testing.assert_allclose(a, b, atol=1e-5)
-
-
 def test_forward_consecutive_matches_pairwise():
     """Frame-deduplicated encoding must equal the stacked-pair forward —
     same math, each interior frame's fnet encoding computed once."""

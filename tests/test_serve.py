@@ -407,6 +407,14 @@ def test_serve_async_loop_parity_and_inflight_gauge(serve_clips, tmp_path):
             assert m['inflight_batches'] == 0   # drained back to idle
             prom = client.metrics_prom()
             assert 'vft_inflight_batches 0' in prom
+            if depth == 2:
+                # the wire's own drain command (the other server takes the
+                # in-process call below; SIGTERM has the lifecycle test)
+                client.drain()
+                deadline = time.monotonic() + 60
+                while not server.drained and time.monotonic() < deadline:
+                    time.sleep(0.05)
+                assert server.drained, 'drain over the socket never ended'
         finally:
             server.drain(wait=True, grace_s=60)
         roots[depth] = os.path.join(out_root, 'resnet', 'resnet18')

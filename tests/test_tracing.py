@@ -219,17 +219,11 @@ def test_every_family_names_its_step_program(family, name):
     assert ex.step_attrs()['program'] == f'jit_{name}'
 
 
-@pytest.mark.parametrize('lookup, prep_name, name', [
-    ('lookup_corr_lanes', 'prep_pyramid_lanes', 'raft_corr_lookup_lanes'),
-    ('lookup_corr', 'prep_pyramid', 'raft_corr_lookup'),
-], ids=['lanes', 'pallas'])
-def test_lookup_kernels_are_named_in_the_lowered_module(lookup, prep_name,
-                                                        name):
+def test_lookup_kernel_is_named_in_the_lowered_module():
     """Each Mosaic call of RAFT's correlation lookup carries the kernel's name
     (one call per pyramid level), so a trace reduction can find the lookup by
     name and not as "the step's only custom call". Lowered for the TPU from
-    the CPU: no chip, nothing runs. (Here and not in tests/test_pallas_corr.py,
-    which is in the slow lane as a whole.)"""
+    the CPU: no chip, nothing runs."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -239,12 +233,9 @@ def test_lookup_kernels_are_named_in_the_lowered_module(lookup, prep_name,
     rng = np.random.RandomState(1)
     f1, f2 = (jnp.asarray(rng.randn(2, 12, 9, 32).astype(np.float32))
               for _ in range(2))
-    pyramid = raft.build_corr_pyramid(f1, f2)
-    prepped = (pallas_corr.prep_pyramid(pyramid, 4)
-               if prep_name == 'prep_pyramid'
-               else pallas_corr.prep_pyramid_lanes(pyramid))
+    prepped = pallas_corr.prep_pyramid_lanes(raft.build_corr_pyramid(f1, f2))
     coords = jnp.zeros((2, 12, 9, 2), jnp.float32)
-    text = jax.jit(getattr(pallas_corr, lookup)).trace(
+    text = jax.jit(pallas_corr.lookup_corr_lanes).trace(
         prepped, coords).lower(lowering_platforms=('tpu',)).as_text()
-    assert text.count(f'kernel_name = "{name}"') == len(prepped)
+    assert text.count('kernel_name = "raft_corr_lookup_lanes"') == len(prepped)
     assert text.count('tpu_custom_call') == len(prepped)

@@ -96,7 +96,7 @@ def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog='calibrate-int8',
         description='pin a per-family int8 scale table + measured drift '
-                    '(ops/quant.py; docs/benchmarks.md precision ladder)')
+                    '(ops/quant.py; docs/design.md precision ladder)')
     parser.add_argument('feature_type',
                         help='an INT8_FEATURES family (resnet/clip/timm)')
     parser.add_argument('--checkpoint-path',

@@ -1,22 +1,21 @@
 #!/usr/bin/env python3
 """Precision ladder across model families: drift + in-graph rate.
 
-Generalizes tools/r21d_precision_study.py to every family with a dense
+Every family with a dense
 device step (r21d, s3d, resnet50, clip ViT-B/32, vggish): for each
 matmul precision it runs the PRODUCTION extractor step (transforms +
 network, the exact jit'd fn the extractor calls) on identical inputs +
 seeded weights and prints one JSON line per (family, precision): feature
-rel L2 vs the 'highest' baseline and the in-graph rate (bench.py
-methodology — lax.scan over distinct batches inside one jit, value
-fetch). Inputs match each step's production range as well as geometry
+rel L2 vs the 'highest' baseline and an in-graph rate (lax.scan over
+distinct batches inside one jit, value fetch: for ranking the rungs of
+one run, not a benchmark number — PERF.md has those). Inputs match each step's production range as well as geometry
 (0-255 frames for the vision families, log-mel-scaled values for
 vggish — bf16 drift depends on activation magnitude).
 
 Stack families (r21d, s3d) report clips (stacks) per second; frame-wise
 families (resnet, clip) report frames per second; vggish reports 0.96 s
 log-mel examples per second. `BENCH_STACK` overrides
-the stack length and `R21D_ARCH` the r21d variant (the knobs
-tools/r21d_precision_study.py documents).
+the stack length and `R21D_ARCH` the r21d variant.
 
     python tools/family_precision_study.py [families...]
     BENCH_PLATFORM=cpu python tools/family_precision_study.py s3d  # smoke

@@ -25,9 +25,8 @@ def write_noise_clip(path, n_frames: int, w: int = 64, h: int = 48,
                      seed: int = 0) -> str:
     """A deterministic little mp4: a noise card scrolling horizontally.
 
-    The ONE tiny-fixture clip writer shared by the packing/serve test
-    suites and the driver's ``dryrun_serve`` — a codec/fps tweak here
-    reaches every consumer at once.
+    The ONE tiny-fixture clip writer shared by the test suites — a
+    codec/fps tweak here reaches every consumer at once.
     """
     import cv2
 

@@ -10,8 +10,9 @@ same weights) and in-graph clips/sec — the data behind the 'mixed'
 precision mode's pin set (ops/precision.py:MIXED_PINS).
 
 On TPU, matmul precision maps to bf16 pass counts: default=1 pass,
-high=3 (error ~2^-21), highest=6 (~fp32). Timing methodology = bench.py's
-(in-graph lax.scan + value fetch).
+high=3 (error ~2^-21), highest=6 (~fp32). The rate it prints times an
+in-graph lax.scan + value fetch: good for ranking policies within one run,
+not a benchmark number (PERF.md has those).
 
     python tools/precision_study.py            # sweep on the default device
     BENCH_PLATFORM=cpu python tools/precision_study.py  # smoke (no drift)
@@ -32,18 +33,18 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 # highest) and down-pin (tolerant subgraphs to fast passes).
 #
 # Round-1 sweep (v5e, batch 8, stack 16, 224px, vs all_highest):
-#   all_highest       flow 0        rgb 0        14.6 clips/s
-#   all_high          flow 8.4e-04  rgb 1.3e-04  24.2
-#   all_default       flow 1.24e-02 rgb 4.1e-03  45.9
-#   enc_default       flow 1.04e-02 rgb 0        12.6   (ambient highest)
-#   enc_corr_default  flow 1.03e-02 rgb 0        15.9
-#   enc_corr_high     flow 6.6e-04  rgb 0        15.5
-#   mixed(enc dflt)   flow 1.03e-02 rgb 0        15.9
+#   all_highest       flow 0        rgb 0
+#   all_high          flow 8.4e-04  rgb 1.3e-04
+#   all_default       flow 1.24e-02 rgb 4.1e-03
+#   enc_default       flow 1.04e-02 rgb 0        (ambient highest)
+#   enc_corr_default  flow 1.03e-02 rgb 0
+#   enc_corr_high     flow 6.6e-04  rgb 0
+#   mixed(enc dflt)   flow 1.03e-02 rgb 0
 # ⇒ the fnet/cnet encoders dominate the drift (1-pass bf16 there is 1e-2 on
 #   its own); corr tolerates 1-pass; iter+i3d at 1-pass add ~7e-3. So every
 #   matmul-heavy subgraph except corr/upsample needs ≥ 'high' (3-pass).
 # Round-2 refinement sweep results (drift deterministic; timings are
-# load-noisy — calibrate with bench.py):
+# load-noisy):
 #   high_corr_default          flow 4.4e-03  (corr needs ≥ high too)
 #   high_iter_default          flow 1.3e-02  (iter needs ≥ high)
 #   high_i3d_default           flow 3.4e-03 rgb 4.1e-03 (i3d needs ≥ high)
@@ -64,7 +65,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 #   refinement iteration: every component's output feeds back through the
 #   coords→lookup loop within one iteration, so there is no "cold side" to
 #   down-pin. The precision lever is exhausted at every measured
-#   granularity (docs/benchmarks.md has the consolidated analysis).
+#   granularity (docs/design.md "Numerical parity contract" has the ladder).
 POLICIES = [
     ('all_highest', 'highest', None),                       # baseline
     ('all_high', 'high', None),                             # = 'mixed'

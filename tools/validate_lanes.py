@@ -1,16 +1,17 @@
 #!/usr/bin/env python3
-"""Validate the TPU corr-lookup kernels at FULL production depth.
+"""Validate the TPU corr-lookup kernel at FULL production depth.
 
-tests/test_pallas_corr.py compares the lanes/pallas kernels against the
-gather oracle at reduced GRU iterations (fp-noise amplifies under random
-weights — see ops/pallas_corr.py); this tool runs the three lookup
-implementations through the complete 20-iteration RAFT forward at CLI
-geometry (256×344) on real hardware and reports their mutual drift.
+tests/test_corr_lookup.py compares the lanes kernel against the gather
+oracle at reduced GRU iterations (fp-noise amplifies under random
+weights — see ops/pallas_corr.py); this tool runs the three lookups
+(dense, lanes, gather) through the complete 20-iteration RAFT forward at
+CLI geometry (256×344) on real hardware and reports their mutual drift.
 
 Automated coverage of the same property lives in
-tests/test_pallas_corr.py::test_lanes_full_depth_* — an interpret-mode
+tests/test_corr_lookup.py::test_lanes_full_depth_* — an interpret-mode
 reduced-geometry variant in the slow lane plus a `-m tpu` real-hardware
-variant that calls :func:`measure_drift` exactly like this CLI does.
+variant that calls :func:`measure_drift` exactly like this CLI does, and
+in chip_smoke.py's ``kernel`` phase.
 
 Measured on v5e (2026-07-31, precision=highest, seeded weights):
     lanes  vs dense: rel L2 3.2e-05

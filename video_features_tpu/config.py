@@ -47,7 +47,7 @@ CACHE_DEFAULTS: Dict[str, Any] = {
     'cache_l2_dir': None,
 }
 
-# -- device-loop pipelining (parallel/packing.py; docs/benchmarks.md) --------
+# -- device-loop pipelining (parallel/packing.py) -----------------------------
 # Same injection policy as CACHE_DEFAULTS: one source of truth, older
 # user YAMLs pick the knobs up automatically, CLI dotlist wins.
 PIPELINE_DEFAULTS: Dict[str, Any] = {
@@ -68,7 +68,7 @@ PIPELINE_DEFAULTS: Dict[str, Any] = {
     # knob only drives the PACKED paths (pack_across_videos / serve) —
     # the per-video loop keeps data_parallel for in-graph DP.
     'mesh_devices': 1,
-    # the precision ladder (ops/precision.py, docs/benchmarks.md
+    # the precision ladder (ops/precision.py, docs/design.md
     # "precision ladder"): 'float32' (default) is exactly today's
     # numerics; 'bfloat16' casts params to bf16 at transplant time (half
     # the HBM residency + H2D bytes) and runs bf16 activations with fp32

@@ -188,10 +188,11 @@ class BaseExtractor:
     def precision_scope(self):
         """Matmul-precision context for the device loop. ``highest`` (the
         default) keeps full float32 passes for reference parity; ``default``
-        lets the TPU run bf16 MXU passes — ~an order of magnitude faster at
-        CLI geometry; ``mixed`` = parity-grade fast mode (ops/precision.py):
-        ambient 3-pass bf16, measured ≤1e-3 feature drift on the fused path
-        at ~1.9x the 'highest' throughput; ``precision_pins`` carries any
+        lets the TPU run bf16 MXU passes — the fastest and not correct by
+        the benchmark's limits (PERF.md §6, PR 28: 13.13 against 8.177
+        clips/s in ``i3d.corpus``); ``mixed`` = parity-grade fast mode
+        (ops/precision.py): ambient 3-pass bf16, measured ≤1e-3 feature
+        drift on the fused path; ``precision_pins`` carries any
         tuned per-sub-graph overrides to extractors that support them."""
         import jax
 

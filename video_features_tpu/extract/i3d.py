@@ -187,8 +187,7 @@ class ExtractI3D(BaseExtractor):
         self.decode_backend = args.get('decode_backend', 'auto')
         # device_resize=true ships RAW decode-geometry uint8 frames and
         # runs the short-side-256 resize inside the fused graph — lifting
-        # the host's per-frame PIL work (the measured host wall,
-        # docs/benchmarks.md) onto the MXU. The in-graph resample is
+        # the host's per-frame PIL work onto the MXU. The in-graph resample is
         # bit-exact Pillow arithmetic, so the features are identical to
         # the host path's (tests/test_device_resize.py)
         self.device_resize = bool(args.get('device_resize', False))

@@ -105,8 +105,7 @@ def transfer_batches(items: Iterable[tuple], put, keep_host: bool = False,
     next batch's ``device_put`` is always already issued while the
     current batch runs, so the transfer never lands on the dispatch
     critical path even when the consumer momentarily outruns the
-    producer (h2d was a 6–11.5% share serialized before dispatch in
-    BENCH_r05). Each staged unit keeps one more input batch resident on
+    producer. Each staged unit keeps one more input batch resident on
     device; ``depth=1`` restores the minimal single-buffer overlap.
     ``keep_host=True`` carries the host array alongside (debug surfaces
     like show_pred read pixels without paying a D2H round trip). The
@@ -338,12 +337,13 @@ def stream_windows_across_videos(tasks: Iterable,
 
 # -- decode lanes: several videos of the worklist at once --------------------
 #
-# One video's decode + host preprocess is ~0.6 ms a frame on one thread and
-# bounded the packed resnet50 cell at 1,543 frames/s under a step that does
-# 4,650 (ledger, PR 25). The ctypes call into libvfdecode and Pillow's
-# resize release the GIL, so K videos on K threads scale almost linearly to
-# four (ISSUE 26's host probe); threads start in microseconds and copy
-# nothing, where the farm's processes need seconds to their first window.
+# One video's decode + host preprocess on one thread bounded the packed
+# resnet50 cell at 1,524.7 frames/s with the device idle 65 % of the window
+# (ledger, PR 26, the parent's side). The ctypes call into libvfdecode and
+# Pillow's resize release the GIL, so K videos on K threads scale almost
+# linearly to four (ISSUE 26's host probe); threads start in microseconds
+# and copy nothing, where the farm's processes need seconds to their first
+# window.
 
 MAX_LANES = 4
 # A lane hands its windows over in CHUNKS: a hand-over a frame made lock

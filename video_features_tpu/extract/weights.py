@@ -87,7 +87,7 @@ def require_checkpoint(args: Any, key: str, *, feature_type: str,
             f'(tests/benchmarks only — features will be meaningless), set '
             f'`allow_random_weights=true`.')
     # stderr: diagnostics must never pollute machine-read stdout (the CLI
-    # print path and bench.py's one-JSON-line contract)
+    # print path and the benchmark's result line)
     print(f'WARNING: {what}: no `{key}` configured — running RANDOM weights '
           f'(allow_random_weights is set). Extracted features are '
           f'meaningless for downstream use.', file=sys.stderr)

@@ -1,8 +1,7 @@
 """Decode farm: multi-process decoder workers feeding the packer.
 
-BENCH_r05 left the pipeline host-decode-bound (ingraph 9.69 clips/s vs
-4.67 e2e): the in-process decoder is capped by the GIL and one process's
-swscale. This subsystem runs N decoder worker PROCESSES — each driving
+One in-process decoder is capped by the GIL and one process's swscale.
+This subsystem runs N decoder worker PROCESSES — each driving
 the exact decode + host-transform stack the in-process path runs
 (``io/video.py`` + ``ops/host_transforms.py``) — and ships decoded
 windows to the packed scheduler through bounded shared-memory byte

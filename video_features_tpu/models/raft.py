@@ -184,9 +184,8 @@ def lookup_corr_dense(pyramid: List[jax.Array], coords: jax.Array,
     where WX/WY each have two nonzeros per row ((1-f) at the floor index, f
     at floor+1; out-of-range columns are simply never matched — exactly the
     reference's zeros padding_mode). Gathers are the one access pattern TPUs
-    do poorly — XLA lowers them to serialized HBM touches (~740 ms/lookup at
-    28×28×64 pairs, i.e. ~15 s per 20-iteration forward) — while these two
-    einsums run on the MXU in microseconds.
+    do poorly — XLA lowers them to serialized HBM touches — while these two
+    einsums run on the MXU.
     """
     B, H, W, _ = coords.shape
     r = radius
@@ -341,47 +340,36 @@ def coords_grid(B: int, H: int, W: int, dtype=jnp.float32) -> jax.Array:
 # dense rather than risk a Mosaic VMEM OOM on large frames.
 LANES_VMEM_BUDGET_MB = 8.0
 
+LOOKUPS = ('auto', 'dense', 'gather', 'lanes')
 
-def _lookup_impl() -> str:
-    """Which corr-lookup implementation to compile into the forward pass.
 
-    ``VFT_RAFT_LOOKUP`` ∈ {'auto' (default), 'dense', 'gather', 'pallas',
-    'lanes'}:
-      * auto   — 'lanes' on TPU while the kernel's level-0 VMEM block fits
-        ``VFT_RAFT_LANES_VMEM_MB`` (default 8 MiB); 'dense' otherwise
-        (including the CPU, where the Pallas kernels would run
-        interpreted);
-      * dense  — :func:`lookup_corr_dense`, gather-free batched matmuls
-        (measured ~300× faster than gather on TPU; also fastest on CPU);
-      * gather — :func:`lookup_corr`, the XLA gather lowering (reference
-        semantics oracle, kept for tests);
-      * pallas — the Pallas window-slice kernel (ops/pallas_corr.py;
-        interpret mode on the CPU only — :func:`_pallas_interpret`);
-      * lanes  — lane-packed Pallas kernel (mask-reduce window sums, 128
-        pixels per lane tile): measured 14.3 → 26.9 clips/sec/chip on the
-        fused I3D two-stream bench on v5e (the lookup dominates the GRU
-        scan's per-iteration cost), identical compile time.
-    Legacy ``VFT_RAFT_PALLAS=1`` still selects the pallas path.
+def resolve_lookup(h8: int, w8: int, platform: str) -> str:
+    """Which corr lookup the forward pass compiles at a 1/8-resolution map
+    of ``h8 × w8`` on ``platform``: 'lanes', 'dense' or 'gather'.
+
+    The code decides ('auto'): 'lanes' — the lane-packed Pallas kernel,
+    ops/pallas_corr.py — on a TPU while the kernel's level-0
+    (h8, w8, LANES) f32 block fits ``LANES_VMEM_BUDGET_MB``; 'dense'
+    (:func:`lookup_corr_dense`, gather-free batched matmuls) anywhere
+    else, the CPU included, where the kernel would run interpreted.
+    Shapes are static at trace time, so the choice compiles away.
+    ``VFT_RAFT_LOOKUP`` still overrides it for two callers: 'dense' is
+    the operator's workaround for i3d on more than one chip (jax cannot
+    partition the Mosaic call) and 'gather' (:func:`lookup_corr`, the XLA
+    gather lowering) is the oracle the tests compare against; the switch
+    goes when the kernel is wrapped in ``shard_map`` (ROADMAP R1, D13).
     """
     import os
-    if os.environ.get('VFT_RAFT_PALLAS') == '1':
-        return 'pallas'
     impl = os.environ.get('VFT_RAFT_LOOKUP', 'auto')
-    assert impl in ('auto', 'dense', 'gather', 'pallas', 'lanes'), impl
-    return impl
-
-
-def _resolve_auto_lookup(h8: int, w8: int, platform: str) -> str:
-    """'lanes' when on TPU and the level-0 (h8, w8, LANES) block fits the
-    VMEM budget; 'dense' otherwise. Shapes are static at trace time, so the
-    choice compiles away."""
-    import os
-
+    if impl not in LOOKUPS:
+        raise ValueError(
+            f'VFT_RAFT_LOOKUP={impl!r}: the lookups are '
+            + ', '.join(repr(name) for name in LOOKUPS))
+    if impl != 'auto':
+        return impl
     from video_features_tpu.ops.pallas_corr import LANES
-    budget = float(os.environ.get('VFT_RAFT_LANES_VMEM_MB',
-                                  LANES_VMEM_BUDGET_MB))
     block_mb = h8 * w8 * LANES * 4 / 2 ** 20
-    if platform == 'tpu' and block_mb <= budget:
+    if platform == 'tpu' and block_mb <= LANES_VMEM_BUDGET_MB:
         return 'lanes'
     return 'dense'
 
@@ -511,9 +499,7 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
     coords0 = coords_grid(B, H8, W8) + jnp.zeros_like(fmap1[..., :2])
     up = params['update_block']
 
-    impl = _lookup_impl()
-    if impl == 'auto':
-        impl = _resolve_auto_lookup(H8, W8, platform)
+    impl = resolve_lookup(H8, W8, platform)
     if impl == 'lanes':
         # lane-layout pyramid built straight from the fmaps: the
         # (N, h, w) detour + physical transpose was the fixed phase's
@@ -528,18 +514,8 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
     else:
         with pin_scope(pins, 'corr'):
             pyramid = build_corr_pyramid(fmap1, fmap2)
-        if impl == 'pallas':
-            from video_features_tpu.ops import pallas_corr
-            with pin_scope(pins, 'corr'):
-                prepped = pallas_corr.prep_pyramid(pyramid,
-                                                   radius=CORR_RADIUS)
-            lookup = partial(pallas_corr.lookup_corr, prepped,
-                             radius=CORR_RADIUS,
-                             interpret=_pallas_interpret(platform))
-        elif impl == 'gather':
-            lookup = partial(lookup_corr, pyramid)
-        else:
-            lookup = partial(lookup_corr_dense, pyramid)
+        lookup = partial(lookup_corr if impl == 'gather'
+                         else lookup_corr_dense, pyramid)
 
     fh, mk = up['flow_head'], up['mask']
     gru = fuse_gru_params(up['gru'])

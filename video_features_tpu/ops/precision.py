@@ -34,9 +34,9 @@ Pins = Tuple[Tuple[str, str], ...]
 # The 'mixed' policy, tuned by tools/precision_study.py on v5e (fused
 # two-stream path, drift = feature rel L2 vs all-float32 on identical
 # inputs/weights): ambient 'high' (3-pass bf16 ≈ fp32 to ~2^-21 per
-# matmul) measures 8.4e-4 flow / 1.3e-4 rgb — under the ≤1e-3 parity bar —
-# at ~1.9x the float32 rate (14.9 vs 7.9 clips/s, quiet-host bench.py at
-# stack 16 / 224px). No
+# matmul) measures 8.4e-4 flow / 1.3e-4 rgb — under the ≤1e-3 parity bar
+# (its rate against float32 on the chip: not measured, every benchmark
+# cell runs 'mixed'). No
 # sub-graph survives 1-pass: encoder-at-default alone is 1.04e-2, and
 # corr-at-default under ambient high is 4.4e-3 (the flow-quantization
 # cliff amplifies both). So 'mixed' is ambient 'high' with no down-pins;
@@ -94,9 +94,7 @@ COMPUTE_DTYPES = ('float32', 'bfloat16', 'int8')
 # float32 lane on identical inputs/weights — the same metric the repo's
 # reference-parity bar uses (BASELINE.json), PARITY.md-style pinned.
 # Measured by tests/test_precision.py (CPU XLA bf16, random weights, the
-# REAL jitted steps) and re-asserted there on every run; the bench's
-# *_bf16_* rungs record the measured error next to the speedup so a
-# committed number is checkable against its bound. Bounds carry ~3x
+# REAL jitted steps) and re-asserted there on every run. Bounds carry ~3x
 # headroom over the measured drift (max-abs error is recorded alongside
 # for absolute context, but scales with feature magnitude — rel-L2 is
 # the stable pin across weights/geometry).
@@ -116,7 +114,7 @@ BF16_REL_L2_BOUNDS: Dict[str, float] = {
 }
 
 # Families that REFUSE the knob, with the measured drift that disqualifies
-# them (docs/benchmarks.md precision ladders): the fused i3d flow path
+# them (tools/family_precision_study.py): the fused i3d flow path
 # amplifies flow error through the uint8 quantization cliff, and raft's
 # raw flow output compounds bf16 error over 20 GRU refinement iterations —
 # neither meets its parity bound under bf16 storage, so the knob fails the
@@ -125,8 +123,7 @@ BF16_REL_L2_BOUNDS: Dict[str, float] = {
 # per-output-channel symmetric weight quantization (ops/quant.py) with
 # fp32 activations — so the drift is pure weight rounding, not compounding
 # activation error, and stays in the same order as bf16 for the framewise
-# backbones the lane exists for (bandwidth-bound at 2500+ frames/s;
-# quarter-size params). Same measurement protocol and ~3x headroom as
+# backbones the lane exists for (quarter-size params). Same measurement protocol and ~3x headroom as
 # BF16_REL_L2_BOUNDS above (tests/test_precision.py, CPU XLA, random
 # weights, the REAL jitted steps); tools/calibrate_int8.py re-measures
 # against real checkpoints and pins the per-tensor scale tables.
@@ -146,28 +143,28 @@ INT8_REL_L2_BOUNDS: Dict[str, float] = {
 # bound — they fall through to the generic no-measured-bound refusal.
 INT8_REFUSALS: Dict[str, str] = {
     'i3d': ('the fused RAFT->quantize->I3D flow path already measures '
-            '1.24e-2 drift under bf16 (docs/benchmarks.md precision '
+            '1.24e-2 drift under bf16 (docs/design.md precision '
             'ladder) vs the <=1e-3 parity bound, and int8 weight '
             'rounding is a coarser perturbation through the same flow '
             'uint8-quantization cliff; use precision=mixed (8.5e-4) '
             "for i3d's fast lane instead"),
     'raft': ('raw flow output compounds weight-rounding error across 20 '
              'GRU refinement iterations (the corr/iter sub-graphs '
-             'measure >=4.4e-3 under fast passes, docs/benchmarks.md) '
+             'measure >=4.4e-3 under fast passes, docs/design.md) '
              'vs the <=1e-3 parity bound; use precision=mixed for raft '
              'instead'),
 }
 
 BF16_REFUSALS: Dict[str, str] = {
     'i3d': ('the fused RAFT->quantize->I3D flow path measures 1.24e-2 '
-            'feature drift under 1-pass bf16 (docs/benchmarks.md '
+            'feature drift under 1-pass bf16 (docs/design.md '
             'precision ladder) vs the <=1e-3 parity bound — the flow '
             'uint8-quantization cliff amplifies bf16 error; use '
             "precision=mixed (3-pass bf16 matmuls, 8.5e-4) for i3d's "
             'fast lane instead'),
     'raft': ('raw flow output compounds bf16 error across 20 GRU '
              'refinement iterations (corr/iter sub-graphs measure '
-             '>=4.4e-3 under fast passes, docs/benchmarks.md) vs the '
+             '>=4.4e-3 under fast passes, docs/design.md) vs the '
              '<=1e-3 parity bound; use precision=mixed for raft '
              'instead'),
 }
@@ -246,9 +243,9 @@ def param_np_dtype(compute_dtype: str) -> np.dtype:
 
 def rel_l2(reference: np.ndarray, candidate: np.ndarray) -> float:
     """||candidate - reference||2 / ||reference||2 — the ONE definition
-    of the parity metric the bounds above pin, shared by the tests, the
-    bench *_bf16_* error rungs, and the dryrun gate so no two consumers
-    can disagree about what "under the bound" means."""
+    of the parity metric the bounds above pin, shared by the tests and
+    the study tools so no two consumers can disagree about what "under
+    the bound" means."""
     a = np.asarray(reference, np.float64).ravel()
     b = np.asarray(candidate, np.float64).ravel()
     denom = float(np.linalg.norm(a))
