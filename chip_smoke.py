@@ -12,6 +12,11 @@ reference checkout):
   * ``cli``    ``video_features_tpu.cli.main`` over three clips;
   * ``kernel`` the RAFT lookup chosen at that geometry is the compiled
     Mosaic kernel (``lanes``) and agrees with the matmul lookup at full depth;
+  * ``attention`` the ``lm`` step at the benchmark cell's widths (1 dense + 4
+    expert layers, 64 of 256 experts, windows of 8,192 ids, ``mixed``)
+    lowers one Mosaic call named ``causal_attention`` a layer, and one
+    window through ``mla_block`` agrees between that kernel and the XLA
+    tiles it replaces;
   * ``serve``  the warm-pool ``ExtractionServer`` answers two i3d requests
     and one resnet50 request through ``ServeClient``, then drains;
   * ``mesh4``  (hosts with >= 4 chips) resnet50 sharded over four chips
@@ -156,6 +161,71 @@ def phase_kernel(clips, work, platform):
         raise AssertionError(f'lanes vs dense rel L2 {drift:.3e} >= 1e-3')
     return {'lookup': impl, 'mosaic_custom_calls': mosaic_calls,
             'lanes_vs_dense_rel_l2': drift}
+
+
+def phase_attention(clips, work, platform):
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from video_features_tpu.config import load_config
+    from video_features_tpu.extract.lm import ExtractLM
+    from video_features_tpu.models import latent_moe
+    from video_features_tpu.ops.attention import resolve_causal
+    from video_features_tpu.ops.precision import MIXED_AMBIENT, rel_l2
+
+    # the cell's trunk (benchmark/configs/joyai-llm-flash-ep4.json
+    # `overrides`) over the shipped yml's widths and window, as shapes: the
+    # 6.7 GB of parameters are not drawn for a lowering
+    args = load_config('lm', overrides=dict(
+        base_config(work), video_paths=clips, num_hidden_layers=5,
+        n_experts_held=64, output_path=str(work / 'lm_out')))
+    cfg = latent_moe.TrunkConfig.from_args(args)
+    window = int(args.stack_size) * int(args.patch_grid) ** 2
+    impl = resolve_causal(platform, window, cfg.qk_head_dim, cfg.v_head_dim,
+                          MIXED_AMBIENT)
+    if impl != 'kernel':
+        raise AssertionError(f'the cell\'s window resolves to causal path '
+                             f'{impl!r}, not the compiled kernel')
+    params = {n: jax.ShapeDtypeStruct(shape, jnp.float32)
+              for n, shape in latent_moe.param_shapes(cfg).items()}
+    ids = jax.ShapeDtypeStruct((int(args.batch_size), window), jnp.int32)
+    with jax.default_matmul_precision(MIXED_AMBIENT):
+        text = jax.jit(partial(ExtractLM._forward, cfg=cfg,
+                               platform=platform)).lower(params,
+                                                         ids).as_text()
+    named = text.count('kernel_name = "causal_attention"')
+    if named != cfg.num_hidden_layers or \
+            text.count('tpu_custom_call') != named:
+        raise AssertionError(
+            f'{named} Mosaic calls named causal_attention among '
+            f'{text.count("tpu_custom_call")} in the lowered lm step, '
+            f'expected {cfg.num_hidden_layers}')
+    # one window through one layer's attention, kernel against XLA tiles:
+    # the same three passes in another order
+    prefix = 'model.layers.1.self_attn'
+    keys = iter(jax.random.split(jax.random.PRNGKey(0), 16))
+    layer = {}
+    for name, shape in latent_moe.param_shapes(cfg).items():
+        if name.startswith(prefix):
+            w = jax.random.normal(next(keys), shape, jnp.float32)
+            layer[name] = (1.0 + 0.1 * w if len(shape) == 1
+                           else w * shape[0] ** -0.5)
+    x = jax.random.normal(next(keys), (window, cfg.hidden_size), jnp.float32)
+    out = {}
+    with jax.default_matmul_precision(MIXED_AMBIENT):
+        for path, where in (('kernel', platform), ('xla', 'cpu')):
+            out[path] = np.asarray(jax.jit(partial(
+                latent_moe.mla_block, prefix=prefix, cfg=cfg,
+                platform=where))(layer, x=x))
+    drift = rel_l2(out['xla'], out['kernel'])
+    if not drift < 1e-4 or not np.isfinite(out['kernel']).all():
+        raise AssertionError(f'kernel vs XLA tiles rel L2 {drift:.3e} '
+                             f'>= 1e-4 on one window')
+    return {'causal_attention': impl, 'mosaic_custom_calls': named,
+            'kernel_vs_xla_rel_l2': drift}
 
 
 def phase_serve(clips, work):
@@ -311,6 +381,8 @@ def main() -> int:
         timed(phases, 'cli', phase_cli, clips, work, cache_dir)
         timed(phases, 'kernel', phase_kernel, clips, work,
               device['platform'])
+        timed(phases, 'attention', phase_attention, clips, work,
+              device['platform'])
         timed(phases, 'serve', phase_serve, clips, work)
         if jax.local_device_count() >= 4:
             timed(phases, 'mesh4', phase_mesh4, clips, work)
@@ -326,7 +398,9 @@ def main() -> int:
     print(json.dumps({'report': {
         'versions': versions, 'phases': phases,
         'decode_backend': decode_backend,
-        'lookup': phases['kernel']['lookup'], 'compile_cache_dir': cache_dir,
+        'lookup': phases['kernel']['lookup'],
+        'causal_attention': phases['attention']['causal_attention'],
+        'compile_cache_dir': cache_dir,
         'compile_cache_entries': phases['cli']['cache_entries']}}))
     # the verdict: these keys and no others, and nothing after it
     print(json.dumps({'ok': True, 'device': device}), flush=True)

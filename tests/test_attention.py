@@ -87,3 +87,158 @@ def test_blockwise_ragged_matches_dense():
     ref = np.asarray(dense_attention(q, k, v))
     got = np.asarray(blockwise_attention(q, k, v, block_size=64))
     np.testing.assert_allclose(got, ref, atol=2e-5)
+
+
+# -- the fused causal kernel (ops/pallas_attention.py), interpreted -----------
+
+def _causal_reference(q, k, v, scale=None):
+    """Dense causal attention in float64: what every path is held to."""
+    q, k, v = (np.asarray(t, np.float64) for t in (q, k, v))
+    s = np.einsum('bqhd,bkhd->bhqk', q, k) * (scale or q.shape[-1] ** -0.5)
+    n = q.shape[1]
+    s = np.where(np.tril(np.ones((n, n), bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum('bhqk,bkhd->bqhd', p / p.sum(-1, keepdims=True), v)
+
+
+def _rel_l2(got, want):
+    from video_features_tpu.ops.precision import rel_l2
+    return rel_l2(want, got)
+
+
+def _latent_qkv(seed, b=2, s=64, h=3, qk=192, v=128):
+    """q/k and v at latent attention's unequal head widths, 192 and 128."""
+    rng = np.random.RandomState(seed)
+    return (jnp.asarray(rng.randn(b, s, h, qk).astype(np.float32)),
+            jnp.asarray(rng.randn(b, s, h, qk).astype(np.float32)),
+            jnp.asarray(rng.randn(b, s, h, v).astype(np.float32)))
+
+
+@pytest.mark.parametrize('block_q,block_k', [
+    (64, 64),           # one tile a side: the diagonal tile alone
+    (32, 32),           # two
+    (16, 16),           # four
+    (32, 16),           # a query tile the diagonal crosses two key tiles of
+    (16, 32),           # a key tile wider than the query tile
+])
+def test_causal_kernel_matches_dense_with_a_narrower_value_head(block_q,
+                                                                block_k):
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    q, k, v = _latent_qkv(4)
+    got = causal_attention(q, k, v, 192 ** -0.5, 3, block_q, block_k,
+                           interpret=True)
+    assert got.shape == (2, 64, 3, 128) and got.dtype == jnp.float32
+    want = _causal_reference(q, k, v)
+    assert _rel_l2(got, want) < 1e-5
+    # the first row sees one key (its output is that key's value), the last
+    # row all of them
+    np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(v[:, 0]),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got[:, -1]), want[:, -1],
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize('passes', [1, 3])
+def test_causal_kernel_takes_heads_as_column_groups(passes):
+    """Latent attention's call: q and k as (nope, rope) groups, the rotary
+    key ONE head shared by all — the same numbers as the concatenated,
+    broadcast head."""
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    q, k, v = _latent_qkv(10)
+    k = k.at[..., 128:].set(k[:, :, :1, 128:])     # one rotary key for all
+    whole = causal_attention(q, k, v, 192 ** -0.5, passes, 32, 16,
+                             interpret=True)
+    parts = causal_attention((q[..., :128], q[..., 128:]),
+                             (k[..., :128], k[:, :, :1, 128:]), v,
+                             192 ** -0.5, passes, 32, 16, interpret=True)
+    np.testing.assert_array_equal(np.asarray(parts), np.asarray(whole))
+    assert _rel_l2(parts, _causal_reference(q, k, v)) < (1e-5 if passes == 3
+                                                         else 1e-2)
+
+
+@pytest.mark.parametrize('passes,low,high', [
+    (3, 0.0, 1e-5),      # hi·hi + hi·lo + lo·hi: float32-grade
+    (1, 5e-4, 1e-2),     # the head alone: the control lane stays a control
+])
+def test_causal_kernel_makes_the_passes_it_is_asked_for(passes, low, high):
+    """Held to the XLA path at 'highest': three passes sit within 1e-5 of
+    it, one pass measurably does not — what the benchmark's control lane
+    (precision=default) relies on to stay not correct."""
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    q, k, v = _latent_qkv(5)
+    with jax.default_matmul_precision('highest'):
+        want = blockwise_attention(q, k, v, block_size=16, causal=True)
+    got = causal_attention(q, k, v, 192 ** -0.5, passes, 32, 16,
+                           interpret=True)
+    assert low <= _rel_l2(got, want) < high
+
+
+def test_causal_kernel_survives_large_scores():
+    """Scores of O(1000): the running max must carry the online softmax."""
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    q, k, v = _latent_qkv(6, b=1, s=32)
+    got = causal_attention(q * 20.0, k, v, 192 ** -0.5, 3, 16, 16,
+                           interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    assert _rel_l2(got, _causal_reference(q * 20.0, k, v)) < 1e-4
+
+
+@pytest.mark.parametrize('passes,block_q,qk,match', [
+    (6, 16, 192, '1 or 3'),             # 'highest' has no lane here
+    (3, 32, 192, 'no multiple'),        # 48 positions, tiles of 32
+    (3, 16, 96, 'multiples of 64'),     # a head width the packing cannot lay
+])
+def test_causal_kernel_refuses_what_it_has_no_lane_for(passes, block_q, qk,
+                                                       match):
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    q, k, v = _latent_qkv(7, b=1, s=48, qk=qk)
+    with pytest.raises(ValueError, match=match):
+        causal_attention(q, k, v, 1.0, passes, block_q, 16, interpret=True)
+
+
+@pytest.mark.parametrize('groups,passes,want', [
+    ((192,), 3, (640, 256)),    # [hi hi lo] of 128, then of 64 padded to 256
+    ((128, 64), 3, (640, 256)),  # latent attention's nope and rope groups
+    ((192,), 1, (192, 128)),
+    ((128,), 3, (384, 256)),
+    ((64,), 3, (256, 256)),
+])
+def test_packed_widths_are_whole_lane_chunks(groups, passes, want):
+    from video_features_tpu.ops.pallas_attention import packed_widths
+    assert packed_widths(groups, 128, passes) == want
+
+
+@pytest.mark.parametrize('platform,s,qk,v,precision,want', [
+    ('tpu', 8192, 192, 128, 'high', 'kernel'),      # the cell
+    ('tpu', 8192, 192, 128, 'default', 'kernel'),   # its control lane
+    ('tpu', 8192, 192, 128, None, 'kernel'),        # unset = one pass
+    ('tpu', 8192, 192, 128, 'highest', 'xla'),      # never under six passes
+    ('tpu', 8192, 192, 128, 'float32', 'xla'),
+    ('tpu', 8192, 192, 128, 'tensorfloat32', 'xla'),
+    ('cpu', 8192, 192, 128, 'high', 'xla'),         # tier-1's path
+    ('gpu', 8192, 192, 128, 'high', 'xla'),
+    ('tpu', 8192 + 512, 192, 128, 'high', 'xla'),   # no tile multiple
+    ('tpu', 8192, 192, 96, 'high', 'xla'),          # v not 128-wide
+    ('tpu', 8192, 200, 128, 'high', 'xla'),         # odd q/k width
+    ('tpu', 64, 192, 128, 'high', 'xla'),           # a tile under 128 lanes
+    ('tpu', 128, 128, 128, 'high', 'kernel'),       # one aligned tile
+    ('tpu', 65536, 192, 128, 'high', 'xla'),        # K/V past the VMEM budget
+])
+def test_resolve_causal_decides_from_platform_shapes_and_precision(
+        platform, s, qk, v, precision, want):
+    from video_features_tpu.ops.attention import resolve_causal
+    assert resolve_causal(platform, s, qk, v, precision) == want
+
+
+def test_causal_kernel_lowered_for_a_tpu_is_one_named_mosaic_call():
+    """One custom call named causal_attention whatever the batch, under
+    three passes and under one. Lowered from the CPU: nothing runs."""
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    q, k, v = (jax.ShapeDtypeStruct((2, 256, 2, d), jnp.float32)
+               for d in (192, 192, 128))
+    for passes in (3, 1):
+        text = jax.jit(lambda *a: causal_attention(
+            *a, 192 ** -0.5, passes, 128, 128)).trace(q, k, v).lower(
+                lowering_platforms=('tpu',)).as_text()
+        assert text.count('tpu_custom_call') == 1
+        assert text.count('kernel_name = "causal_attention"') == 1

@@ -1,0 +1,51 @@
+"""The main path's Mosaic kernels compiled at the benchmark cell's widths
+for a DESCRIBED TPU v5e — no chip, nothing runs: the TPU compiler is
+installed here and refuses what the interpreter accepts (a slice off the
+tiling, more VMEM than a kernel may use). A pass is no chip run.
+
+The topology is described inside a fixture, never at import (one process
+at a time may load the TPU's library; a worker that cannot gets a skip),
+and every such test lives in this one file so one worker loads it."""
+import os
+
+import pytest
+
+jax = pytest.importorskip('jax')
+import jax.numpy as jnp  # noqa: E402
+
+
+@pytest.fixture(scope='module')
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:      # no libtpu, or another process holds it
+        pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize('passes', [3, 1])
+def test_causal_attention_compiles_at_the_cells_widths(one_chip, passes):
+    """One window of joyai-flash.corpus: 8,192 positions, 32 heads, q/k as
+    (128 nope, 64 rope) groups with ONE rotary key, 128-wide v — under three
+    passes (precision=mixed) and one (the control lane), at the shipped
+    tiles and VMEM limit."""
+    from video_features_tpu.ops.pallas_attention import causal_attention
+
+    def sds(heads, width):
+        return jax.ShapeDtypeStruct((1, 8192, heads, width), jnp.float32,
+                                    sharding=one_chip)
+
+    def attend(q_nope, q_rope, k_nope, k_rope, v):
+        return causal_attention((q_nope, q_rope), (k_nope, k_rope), v,
+                                192 ** -0.5, passes)
+
+    compiled = jax.jit(attend).lower(
+        sds(32, 128), sds(32, 64), sds(32, 128), sds(1, 64),
+        sds(32, 128)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert '%causal_attention' in text

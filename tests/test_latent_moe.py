@@ -6,6 +6,7 @@ benchmark's (``benchmark/references/joyai-llm-flash-ep4.py``), which imports
 nothing of the program."""
 import math
 import sys
+from functools import partial
 from pathlib import Path
 
 import jax
@@ -415,3 +416,71 @@ def test_lm_is_packed_but_in_no_lane_it_has_not_earned():
         load_config('lm', overrides={'video_paths': ['x.mp4'],
                                      'device': 'cpu',
                                      'compute_dtype': 'bfloat16'})
+
+
+# -- the causal kernel behind the step (ops/pallas_attention.py) ---------------
+
+# a trunk whose window the kernel applies to: one 128-id tile, a 128-wide
+# value head, 64 + 64 query/key dims
+ALIGNED = dict(TINY_PROGRAM, num_attention_heads=2, qk_nope_head_dim=64,
+               qk_rope_head_dim=64, v_head_dim=128)
+
+
+@pytest.mark.parametrize('platform,precision,calls', [
+    ('tpu', 'high', 3),        # precision=mixed: one call a layer's lax.map
+    ('tpu', 'default', 3),     # the control lane takes the kernel too
+    ('tpu', 'highest', 0),     # the yml's default keeps the XLA path
+    ('cpu', 'high', 0),        # what tier-1 and the programs lock lower
+])
+def test_the_lm_step_lowered_for_a_tpu_holds_the_named_kernel(
+        platform, precision, calls):
+    """The step as the extractor jits it (``ExtractLM._forward`` with the
+    device's platform bound), lowered for the TPU from here: a Mosaic call
+    named causal_attention in every layer's window loop where the kernel
+    applies, none where it does not."""
+    from video_features_tpu.extract.lm import ExtractLM
+    cfg = lm.TrunkConfig(**ALIGNED)
+    params = {n: jax.ShapeDtypeStruct(s, jnp.float32)
+              for n, s in lm.param_shapes(cfg).items()}
+    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(partial(ExtractLM._forward, cfg=cfg,
+                               platform=platform)).trace(
+            params, ids).lower(lowering_platforms=('tpu',)).as_text()
+    assert text.count('kernel_name = "causal_attention"') == calls
+    assert text.count('tpu_custom_call') == calls
+
+
+def test_the_kernel_path_of_mla_block_is_the_xla_path_to_rounding(
+        monkeypatch):
+    """mla_block with the kernel forced in (interpreted: the decision says
+    'kernel' only on a TPU) against the XLA path, both at three passes."""
+    from video_features_tpu.ops import pallas_attention
+    cfg = lm.TrunkConfig(**ALIGNED)
+    params = {n: jnp.asarray(w) for n, w in lm.init_params(cfg, 3).items()
+              if '.layers.1.self_attn.' in n}
+    x = jnp.asarray(np.random.default_rng(6).standard_normal(
+        (128, 64)).astype(np.float32))
+    a = 'model.layers.1.self_attn'
+    with jax.default_matmul_precision('high'):
+        want = lm.mla_block(params, a, x, cfg, 128, 'cpu')
+        monkeypatch.setattr(lm, 'resolve_causal', lambda *args: 'kernel')
+        monkeypatch.setattr(
+            pallas_attention, 'causal_attention',
+            partial(pallas_attention.causal_attention, interpret=True))
+        got = lm.mla_block(params, a, x, cfg, 128, 'tpu')
+    assert 0 < rel_l2(got, want) < 2e-5
+
+
+def test_the_extractor_says_which_causal_path_it_compiled(tmp_path, capsys):
+    """On the CPU: 'xla', on stderr and in the manifest's kernels section."""
+    import json
+    manifest = tmp_path / 'manifest.json'
+    ex = create_extractor(load_config('lm', overrides=dict(
+        TINY_PROGRAM, **WINDOW, device='cpu', allow_random_weights=True,
+        precision='mixed', video_paths=['x.mp4'],
+        output_path=str(tmp_path / 'out'), tmp_path=str(tmp_path / 'tmp'),
+        manifest_out=str(manifest))))
+    assert ex.attention_path == 'xla'
+    assert 'causal_attention=xla' in capsys.readouterr().err
+    assert ex.manifest.document()['kernels'] == {'causal_attention': 'xla'}

@@ -655,7 +655,11 @@ MANIFEST_KEYS = {'schema', 'version', 'started_at_unix_s', 'wall_s',
                  # in-process decode lanes of packed runs
                  # (extract/streaming.py): the lane plan + per-lane
                  # counters, {} on farm-backed and per-video runs
-                 'decode'}
+                 'decode',
+                 # hand-written kernels: which path each call site
+                 # compiled to ({'causal_attention': 'kernel' | 'xla'} on
+                 # lm runs), {} where a family has no such choice
+                 'kernels'}
 
 
 CANONICAL_STAGES = {'decode', 'decode+preprocess', 'audio_dsp',

@@ -113,7 +113,9 @@ class ExtractLM(StackPackingMixin, BaseExtractor):
             f'experts a layer')
         self.params = self.load_params(args)
         self._step = jax.jit(named_step(
-            partial(self._forward, cfg=self.cfg), self.step_name))
+            partial(self._forward, cfg=self.cfg,
+                    platform=self._device.platform), self.step_name))
+        self.attention_path = self._say_attention_path()
 
     def load_params(self, args):
         """The flat ``{checkpoint name: device array}`` dict: read from the
@@ -129,9 +131,35 @@ class ExtractLM(StackPackingMixin, BaseExtractor):
         return jax.device_put(latent_moe.init_params(self.cfg),
                               self._device)
 
+    def _say_attention_path(self) -> str:
+        """Which causal path the step compiles here ('kernel' or 'xla':
+        ``ops.attention.resolve_causal``, from the device's platform, the
+        window's shapes and this run's matmul precision) — said once on
+        stderr and kept for the run manifest's ``kernels`` section. All or
+        nothing per program: it is the kernel's engagement counter."""
+        import logging
+
+        from video_features_tpu.obs.events import event
+        from video_features_tpu.ops.attention import resolve_causal
+        with self.precision_scope():
+            path = resolve_causal(
+                self._device.platform, self.window_ids, self.cfg.qk_head_dim,
+                self.cfg.v_head_dim, jax.config.jax_default_matmul_precision)
+        event(logging.INFO, 'lm: causal attention path', subsystem='lm',
+              causal_attention=path, platform=self._device.platform,
+              precision=self.precision)
+        return path
+
+    def configure_obs(self, args) -> None:
+        super().configure_obs(args)
+        if self.manifest is not None:
+            self.manifest.note_kernels(
+                {'causal_attention': self.attention_path})
+
     @staticmethod
-    def _forward(params, ids, cfg):
-        feats, counts = latent_moe.forward(params, ids, cfg)
+    def _forward(params, ids, cfg, platform=None):
+        feats, counts = latent_moe.forward(params, ids, cfg,
+                                           platform=platform)
         return {'lm': feats, COUNTS_KEY: counts}
 
     # -- the host preprocess: frames → ids ----------------------------------

@@ -39,7 +39,7 @@ from jax import lax
 
 from video_features_tpu.ops import moe
 from video_features_tpu.ops.attention import (
-    blockwise_attention, rotary_interleaved,
+    KERNEL_PASSES, blockwise_attention, resolve_causal, rotary_interleaved,
 )
 
 Params = Dict[str, jax.Array]
@@ -194,10 +194,21 @@ def swiglu(x: jax.Array, p: Params, prefix: str) -> jax.Array:
     return jnp.dot(jax.nn.silu(gate) * up, p[f'{prefix}.down_proj.weight'])
 
 
+def _head_columns(w: jax.Array, h: int, lo: int, hi: int) -> jax.Array:
+    """Columns ``lo:hi`` of every head of a (in, h·d) projection, as
+    (in, h·(hi − lo)): the product of an activation with it writes that
+    column group of all heads and nothing else."""
+    return w.reshape(w.shape[0], h, -1)[:, :, lo:hi].reshape(w.shape[0], -1)
+
+
 def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
-              attn_block: int = 1024) -> jax.Array:
+              attn_block: int = 1024,
+              platform: Optional[str] = None) -> jax.Array:
     """Latent attention over one window: (S, D) → (S, D), causal, positions
-    0…S-1."""
+    0…S-1. ``platform`` is where the graph will run (None: the default
+    backend); with the shapes and the ambient matmul precision it decides
+    the causal path (``ops.attention.resolve_causal``): the fused kernel
+    where it applies, the XLA tiles of ``blockwise_attention`` elsewhere."""
     with jax.named_scope('mla'):
         s = x.shape[0]
         h, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
@@ -205,6 +216,10 @@ def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
         eps = cfg.rms_norm_eps
         c_q = rms_norm(jnp.dot(x, p[f'{prefix}.q_a_proj.weight']),
                        p[f'{prefix}.q_a_layernorm.weight'], eps)
+        precision = jax.config.jax_default_matmul_precision
+        if resolve_causal(platform or jax.default_backend(), s, dn + dr, dv,
+                          precision) == 'kernel':
+            return _mla_kernel_path(p, prefix, x, c_q, cfg, precision)
         q = jnp.dot(c_q, p[f'{prefix}.q_b_proj.weight']).reshape(s, h, dn + dr)
         kv_a = jnp.dot(x, p[f'{prefix}.kv_a_proj_with_mqa.weight'])
         c_kv = rms_norm(kv_a[:, :cfg.kv_lora_rank],
@@ -222,6 +237,39 @@ def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
                                   block_size=min(attn_block, s),
                                   causal=True)[0]
         return jnp.dot(out.reshape(s, h * dv), p[f'{prefix}.o_proj.weight'])
+
+
+def _mla_kernel_path(p: Params, prefix: str, x: jax.Array, c_q: jax.Array,
+                     cfg: TrunkConfig, precision: Optional[str]) -> jax.Array:
+    """The same attention through ``ops/pallas_attention.py``. The kernel
+    reads a head's columns as (tile, width) slabs, heads-major, so each
+    column group it takes — q's and k's nope and rope parts, v — is written
+    by a product of its own (``q_b``'s and ``kv_b``'s columns regrouped, a
+    pass over the weights, not over 200 MB of activations), 128-wide groups
+    straight into that layout; the nope ‖ rope concatenation and the rotary
+    key's copy for every head are never written: the kernel takes the parts,
+    and one rotary key for all heads."""
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    s = x.shape[0]
+    h, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    w_q, w_kv = p[f'{prefix}.q_b_proj.weight'], p[f'{prefix}.kv_b_proj.weight']
+    kv_a = jnp.dot(x, p[f'{prefix}.kv_a_proj_with_mqa.weight'])
+    c_kv = rms_norm(kv_a[:, :cfg.kv_lora_rank],
+                    p[f'{prefix}.kv_a_layernorm.weight'], cfg.rms_norm_eps)
+    positions = jnp.arange(s)
+    q_nope = jnp.dot(c_q, _head_columns(w_q, h, 0, dn)).reshape(s, h, dn)
+    q_rope = rotary_interleaved(
+        jnp.dot(c_q, _head_columns(w_q, h, dn, dn + dr)).reshape(s, h, dr),
+        positions, cfg.rope_theta)
+    k_nope = jnp.dot(c_kv, _head_columns(w_kv, h, 0, dn)).reshape(s, h, dn)
+    k_rope = rotary_interleaved(kv_a[:, cfg.kv_lora_rank:].reshape(s, 1, dr),
+                                positions, cfg.rope_theta)
+    v = jnp.dot(c_kv, _head_columns(w_kv, h, dn, dn + dv)).reshape(s, h, dv)
+    out = causal_attention((q_nope[None], q_rope[None]),
+                           (k_nope[None], k_rope[None]), v[None],
+                           (dn + dr) ** -0.5, KERNEL_PASSES[precision])[0]
+    return jnp.dot(out.reshape(s, h * dv), p[f'{prefix}.o_proj.weight'])
 
 
 def expert_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
@@ -248,7 +296,8 @@ def expert_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
 
 
 def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
-                  attn_block: int = 1024, moe_block: int = 256
+                  attn_block: int = 1024, moe_block: int = 256,
+                  platform: Optional[str] = None
                   ) -> Tuple[jax.Array, jax.Array]:
     """(B, S) int32 ids → ``(final-norm hidden states (B, S, D), counts)``.
 
@@ -266,7 +315,8 @@ def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
         p = f'model.layers.{i}'
         normed = rms_norm(x, params[f'{p}.input_layernorm.weight'], eps)
         x = x + lax.map(
-            lambda w: mla_block(params, f'{p}.self_attn', w, cfg, attn_block),
+            lambda w: mla_block(params, f'{p}.self_attn', w, cfg, attn_block,
+                                platform),
             normed)
         normed = rms_norm(x, params[f'{p}.post_attention_layernorm.weight'],
                           eps).reshape(b * s, d)
@@ -283,9 +333,10 @@ def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
 
 
 def forward(params: Params, ids: jax.Array, cfg: TrunkConfig,
-            attn_block: int = 1024, moe_block: int = 256
-            ) -> Tuple[jax.Array, jax.Array]:
+            attn_block: int = 1024, moe_block: int = 256,
+            platform: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
     """(B, S) int32 ids → ``(features (B, D) float32, counts)``: the mean
     of the window's final-norm hidden states (:func:`hidden_states`)."""
-    x, counts = hidden_states(params, ids, cfg, attn_block, moe_block)
+    x, counts = hidden_states(params, ids, cfg, attn_block, moe_block,
+                              platform)
     return x.astype(jnp.float32).mean(axis=1), counts

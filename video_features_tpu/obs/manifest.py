@@ -125,6 +125,7 @@ class RunManifest:
         self.farm: Dict[str, Any] = {}
         self.decode: Dict[str, Any] = {}
         self.mesh: Dict[str, Any] = {}
+        self.kernels: Dict[str, Any] = {}
         self.ingress: Dict[str, Any] = {}
         self.programs_lock: Dict[str, Any] = {}
         self.aot: Dict[str, Any] = {}
@@ -260,6 +261,15 @@ class RunManifest:
         with self._lock:
             self.slo.update({k: _jsonable(v) for k, v in info.items()})
 
+    def note_kernels(self, info: Dict[str, Any]) -> None:
+        """Record which path each hand-written kernel's call site compiled
+        to in this run (``{'causal_attention': 'kernel' | 'xla'}``, from
+        ``ops.attention.resolve_causal``): a kernel is all or nothing per
+        program, so this line is its engagement counter. ``{}`` for
+        families that have no such choice to report."""
+        with self._lock:
+            self.kernels.update({k: _jsonable(v) for k, v in info.items()})
+
     def note_mesh(self, info: Dict[str, Any]) -> None:
         """Record the device mesh a mesh-sharded packed run executed on
         (``mesh_devices``, the (data, time) shape, per-device labels,
@@ -289,6 +299,7 @@ class RunManifest:
                 dict(lane) for lane in self.decode.get('per_lane', [])]) \
                 if self.decode else {}
             mesh = dict(self.mesh)
+            kernels = dict(self.kernels)
             ingress = dict(self.ingress)
             programs_lock = dict(self.programs_lock)
             aot = dict(self.aot)
@@ -319,6 +330,9 @@ class RunManifest:
             # mesh-sharded packed execution (mesh_devices > 1): the
             # device mesh the run executed on, {} single-device
             'mesh': mesh,
+            # hand-written kernels: which path each call site compiled to
+            # ('kernel' or its XLA fallback), {} where there is no choice
+            'kernels': kernels,
             # network front door (ingress/): per-tenant request/shed
             # view for runs driven through it, {} otherwise
             'ingress': ingress,

@@ -15,14 +15,26 @@ key tiles at or before it, and the value head may be narrower than the
 query/key head (latent attention: 192-wide q/k, 128-wide v).
 :func:`rotary_interleaved` is their position code.
 
-All three paths compute bit-comparable results (same online-softmax math,
-f32 accumulation):
+The three XLA paths compute bit-comparable results (same online-softmax
+math, f32 accumulation, the ambient matmul precision on every product):
 
   * :func:`dense_attention` — one fused XLA softmax(QKᵀ)V; the baseline.
   * :func:`blockwise_attention` — ``lax.scan`` over KV chunks with running
     (max, denom, out) — O(S·block) memory instead of O(S²), single device.
   * :func:`ring_attention` — blockwise over the mesh axis; memory AND
     compute sharded. Use under ``shard_map`` with the sequence axis split.
+
+A fourth path is a kernel, for the causal case alone
+(``ops/pallas_attention.py``, named ``causal_attention`` in traces): the same
+online softmax with the score tile kept in VMEM, which the XLA tiles write to
+HBM several times a tile pair. It makes its bf16 passes itself (three under
+ambient ``high``, one under ``default``; ``highest`` has no lane in it), so
+it agrees with the causal XLA path to the rounding of those passes in another
+summation order (≈ 2e-5 rel-L2 on a window of the benchmark cell, both
+within 7e-5 of ``highest``: PERF.md §6, PR 30), not to the bit.
+:func:`resolve_causal` says where it applies — a TPU, tile-aligned shapes, a
+precision it has a lane for — and the causal path's caller asks it; the XLA
+path is what runs everywhere else and what the kernel is tested against.
 
 Shapes follow (B, S, H, D) [batch, sequence, heads, head_dim].
 """
@@ -78,6 +90,44 @@ def _online_init(q, v_dim: Optional[int] = None):
     l = jnp.zeros((b, sq, h, 1), jnp.float32)
     o = jnp.zeros((b, sq, h, d if v_dim is None else v_dim), jnp.float32)
     return m, l, o
+
+
+# ambient matmul precisions (``jax.default_matmul_precision``) the causal
+# kernel has a lane for, as bf16 passes a float32 product: unset, 'default'
+# and its alias 'bfloat16' are the TPU's one pass, 'high' is three. 'highest'
+# (six) and every other name have none and keep the XLA path, so no setting
+# gets fewer passes than it asks for
+KERNEL_PASSES = {None: 1, 'default': 1, 'bfloat16': 1, 'high': 3}
+
+
+def resolve_causal(platform: str, s: int, qk_dim: int, v_dim: int,
+                   precision: Optional[str]) -> str:
+    """Which causal attention compiles for ``s`` positions on ``platform``
+    under the ambient matmul ``precision``: 'kernel' (the fused Mosaic
+    kernel, ops/pallas_attention.py) or 'xla' (:func:`blockwise_attention`
+    with ``causal=True``).
+
+    The kernel applies on a TPU, where the sequence is a whole number of its
+    tiles and a head's packed keys and values fit its VMEM budget, the value
+    head fills whole 128-lane blocks (its output block is one head's
+    columns), the query/key head is a multiple of 64, and the ambient
+    precision is one it has a lane for (``KERNEL_PASSES``). Anywhere else —
+    the CPU, where it would run interpreted; ragged or odd shapes;
+    'highest' — the XLA path runs, which is also the oracle the kernel is
+    tested against. All of it is static at trace time, so the choice
+    compiles away; there is no switch. Latent attention
+    (``models/latent_moe.py::mla_block``, the causal path's one caller) asks
+    here and hands the kernel its heads as column groups."""
+    from video_features_tpu.ops import pallas_attention as kernel
+    if platform != 'tpu' or precision not in KERNEL_PASSES:
+        return 'xla'
+    tiles = (min(kernel.BLOCK_Q, s), min(kernel.BLOCK_K, s))
+    packed = sum(kernel.packed_widths((qk_dim,), v_dim,
+                                      KERNEL_PASSES[precision]))
+    if (any(s % t or t % 128 for t in tiles) or v_dim % 128 or qk_dim % 64
+            or 2 * s * packed > kernel.KV_VMEM_BYTES):
+        return 'xla'
+    return 'kernel'
 
 
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,

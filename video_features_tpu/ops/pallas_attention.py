@@ -1,0 +1,292 @@
+"""Pallas TPU kernel for causal attention: the score tile never leaves VMEM.
+
+``ops/attention.py::_causal_blockwise`` is the same arithmetic as a chain of
+XLA fusions, and on the chip every (query tile × key tile) score tensor of
+it crosses HBM about five times (written by QKᵀ, read for the row max, read
+and written by the exponential, read by PV): at latent attention's shapes —
+8,192 positions, 32 heads, 192-wide q/k, 128-wide v — 134 MB a tile pair,
+which bounds the scan by bytes that no one needs outside the tile (PERF.md
+§6, PR 30). Here one Mosaic kernel, named ``causal_attention`` in traces,
+makes QKᵀ → online softmax → PV per (head, query tile, key tile) with the
+running max, denominator and accumulator in VMEM scratch:
+
+* the grid is (window, head, query tile, key tile), key tile innermost; key
+  tiles above the diagonal are neither computed nor fetched, and the
+  triangle mask is applied only on tiles the diagonal crosses;
+* q/k and v keep their own head widths (192 and 128 in the cell: v is not
+  padded to q's width), float32 in and out, float32 accumulation;
+* **the passes are made here.** The ambient matmul precision cannot make
+  them inside a kernel (Mosaic refuses anything but one bf16 pass a dot), so
+  the caller says how many bf16 passes a float32 product takes: 3 (ambient
+  ``high``, what ``precision=mixed`` runs) splits each operand into a bf16
+  head and a bf16 remainder and sums hi·hi + hi·lo + lo·hi in float32 —
+  XLA's own three-pass product — for QKᵀ and PV alike; 1 (ambient
+  ``default``) is the head alone. The three products of QKᵀ are ONE
+  contraction over the parts laid side by side ([hi hi lo] against
+  [hi lo hi]: 576 columns for 192, padded to 640 = five 128-lane passes
+  where three separate products take six). ``highest`` has no lane here: it
+  keeps the XLA path (``ops.attention.resolve_causal``);
+* **every element is split once, in VMEM.** A query tile is packed when its
+  first key tile arrives. A key/value tile is packed by the query tile
+  whose diagonal crosses it — its first use — into a copy of the head's
+  packed keys and values that stays in VMEM for the query tiles after it
+  (14.7 MB in the cell); the float32 block's index map waits on that tile
+  until then and never returns to it, so Q, K and V are each read from HBM
+  once and XLA writes no split, concatenated or padded copy of them;
+* q and k may come as column groups (latent attention's nope and rope
+  parts; a key group of one head is shared by all heads), so the caller
+  never writes their concatenation either.
+
+Arrays enter heads-major, (B, H, S, width) — a block is one head's (tile,
+width) slab; the caller's (B, S, H, width) is transposed here, which XLA
+folds into the producing product's output layout — and the output leaves
+as (B, S, H·v_dim), which is how ``o_proj`` reads it. CPU tests run the
+same kernel body under ``interpret=True`` (tests/test_attention.py).
+"""
+from __future__ import annotations
+
+from functools import partial
+from typing import Sequence, Tuple, Union
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
+NAME = 'causal_attention'
+# the kernel's tiles at the cell's shapes: my chip runs, PR 30 (PERF.md §6)
+BLOCK_Q = 1024
+BLOCK_K = 1024
+# a head's packed keys and values stay in VMEM for all of its query tiles
+# (8,192 × (640 + 256) bf16 = 14.7 MB in the cell), beside a (1024, 1024)
+# score tile with its exponential and the two bf16 parts of it (12 MB) and
+# the double-buffered float32 blocks (6 MB): past Mosaic's 16 MB default,
+# well inside a v5e core's 128 MB. ``resolve_causal`` keeps sequences whose
+# packed keys and values pass KV_VMEM_BYTES on the XLA path.
+VMEM_LIMIT_BYTES = 96 * 2 ** 20
+KV_VMEM_BYTES = 32 * 2 ** 20
+
+_NT = (((1,), (1,)), ((), ()))      # contract both operands' last axis
+# every product in the kernel is bf16 × bf16 into float32, said outright: the
+# ambient precision reaches a kernel's dots, and Mosaic refuses 'high'
+_ONE_PASS = dict(precision=lax.Precision.DEFAULT,
+                 preferred_element_type=jnp.float32)
+
+
+def packed_widths(qk_dims: Sequence[int], v_dim: int, passes: int
+                  ) -> Tuple[int, int]:
+    """Columns of a packed query/key row (the contraction QKᵀ runs over:
+    every column group's three parts, each group padded to whole 128-lane
+    chunks) and of a packed value row ([hi | lo] of v)."""
+    if passes == 1:
+        return sum(qk_dims), v_dim
+    return sum(3 * d + -3 * d % LANES for d in qk_dims), 2 * v_dim
+
+
+def _split(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """float32 → (head, remainder), both float32 and both exact in bf16's 8
+    bits once cast: head + remainder is x to 16 bits."""
+    head = x.astype(jnp.bfloat16).astype(jnp.float32)
+    return head, x - head
+
+
+def _arrange(a: jax.Array, b: jax.Array, c: jax.Array) -> jax.Array:
+    """Three float32 (n, D) parts of a row → the bf16 (n, C) row one
+    contraction runs over: [a b c] for each whole 128-lane chunk of D, then
+    the remainder's three side by side, zero-padded to a whole chunk. At
+    D = 192: three aligned chunks, then [a | b] and [c | 0] in 64-lane
+    halves — only the remainder's pieces change lanes."""
+    dim = a.shape[1]
+    full = dim - dim % LANES
+    cols = []
+    for j in range(0, full, LANES):
+        cols += [x[:, j:j + LANES] for x in (a, b, c)]
+    if dim > full:
+        cols += [x[:, full:] for x in (a, b, c)]
+        pad = -3 * dim % LANES
+        if pad:
+            cols.append(jnp.zeros((a.shape[0], pad), a.dtype))
+    return jnp.concatenate(cols, axis=1).astype(jnp.bfloat16)
+
+
+def _pack_qk(refs, passes: int, key: bool, scale: float = 1.0) -> jax.Array:
+    """The column groups of a query (``· scale``) or key tile, packed side
+    by side: q's parts are [hi hi lo] against k's [hi lo hi]."""
+    cols = []
+    for ref in refs:
+        x = ref[...] * scale if scale != 1.0 else ref[...]
+        if passes == 1:
+            cols.append(x.astype(jnp.bfloat16))
+            continue
+        hi, lo = _split(x)
+        cols.append(_arrange(hi, lo, hi) if key else _arrange(hi, hi, lo))
+    return jnp.concatenate(cols, axis=1)
+
+
+def _pack_v(v: jax.Array, passes: int) -> jax.Array:
+    if passes == 1:
+        return v.astype(jnp.bfloat16)
+    return jnp.concatenate(_split(v), axis=1).astype(jnp.bfloat16)
+
+
+def _first_masked_tile(qi, block_q: int, block_k: int):
+    """Index of the first key tile the diagonal crosses for a query tile:
+    the tiles before it lie wholly at or before the tile's first row."""
+    return (qi * block_q + 1) // block_k
+
+
+def _last_key_tile(qi, block_q: int, block_k: int):
+    """Index of the last key tile a query tile sees (its last row's)."""
+    return (qi * block_q + block_q - 1) // block_k
+
+
+def _kernel(*refs, groups: int, block_q: int, block_k: int, v_dim: int,
+            passes: int, scale: float):
+    q_refs, k_refs = refs[:groups], refs[groups:2 * groups]
+    v_ref, o_ref, qc_ref, kc_ref, vc_ref, m_ref, l_ref, acc_ref = \
+        refs[2 * groups:]
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    first_masked = _first_masked_tile(qi, block_q, block_k)
+    last = _last_key_tile(qi, block_q, block_k)
+    rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+
+    @pl.when(ki == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        qc_ref[...] = _pack_qk(q_refs, passes, key=False, scale=scale)
+
+    def accumulate(masked: bool):
+        s = lax.dot_general(qc_ref[...], kc_ref[rows, :], _NT, **_ONE_PASS)
+        if masked:
+            row = qi * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            col = ki * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(col <= row, s, -jnp.inf)
+        # key 0 is in the first tile and every row sees it, so m_new is
+        # finite from the first step on and no exp sees -inf - -inf
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        v = vc_ref[rows, :]
+        p_hi = p.astype(jnp.bfloat16)
+        if passes == 3:
+            p_lo = (p - p_hi.astype(jnp.float32)).astype(jnp.bfloat16)
+            # p_hi · [v_hi | v_lo] in one product, p_lo · v_hi in a second
+            both = jnp.dot(p_hi, v, **_ONE_PASS)
+            pv = (both[:, :v_dim] + both[:, v_dim:]
+                  + jnp.dot(p_lo, v[:, :v_dim], **_ONE_PASS))
+        else:
+            pv = jnp.dot(p_hi, v, **_ONE_PASS)
+        acc_ref[...] = alpha * acc_ref[...] + pv
+
+    @pl.when(ki < first_masked)
+    def _():
+        accumulate(masked=False)
+
+    # a tile the diagonal crosses holds keys no earlier query tile saw: this
+    # is its first use, so its float32 block is in (the index map held it
+    # back until now) and is packed into the head's VMEM copy here, once
+    @pl.when(jnp.logical_and(ki >= first_masked, ki <= last))
+    def _():
+        kc_ref[rows, :] = _pack_qk(k_refs, passes, key=True)
+        vc_ref[rows, :] = _pack_v(v_ref[...], passes)
+        accumulate(masked=True)
+
+    @pl.when(ki == last)
+    def _():
+        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+Parts = Union[jax.Array, Sequence[jax.Array]]
+
+
+def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
+                     passes: int, block_q: int = BLOCK_Q,
+                     block_k: int = BLOCK_K,
+                     interpret: bool = False) -> jax.Array:
+    """softmax(QKᵀ·scale + causal mask)V over (B, S, H, D) float32 tensors
+    (v may be narrower), float32 (B, S, H, v's width) out, ``passes`` (1 or
+    3) bf16 passes a product.
+
+    ``q`` and ``k`` may each come as a sequence of column groups whose
+    concatenation along D is the head — latent attention's (nope, rope) —
+    so that no one has to write the concatenation to memory; a key group
+    with ONE head is shared by all heads (its rotary key). S must be a
+    multiple of both tiles and every group's width of 64; compiled (not
+    interpreted), tiles and v's width must be multiples of 128. One trace
+    event a call, whatever B."""
+    if passes not in (1, 3):
+        raise ValueError(f'causal_attention makes 1 or 3 bf16 passes, not '
+                         f'{passes}')
+    q_parts, k_parts = (tuple(x) if isinstance(x, (tuple, list)) else (x,)
+                        for x in (q, k))
+    widths = [x.shape[-1] for x in q_parts]
+    if widths != [x.shape[-1] for x in k_parts]:
+        raise ValueError(f'causal_attention: query groups {widths} and key '
+                         f'groups {[x.shape[-1] for x in k_parts]} differ')
+    if any(w % (LANES // 2) for w in widths):
+        raise ValueError(f'causal_attention: query/key groups of {widths} '
+                         f'columns are no multiples of {LANES // 2}')
+    b, s, h, _ = q_parts[0].shape
+    v_dim = v.shape[-1]
+    block_q, block_k = min(block_q, s), min(block_k, s)
+    if s % block_q or s % block_k:
+        raise ValueError(f'causal_attention: {s} positions are no multiple '
+                         f'of the tiles ({block_q}, {block_k})')
+    # heads-major: a block is one head's (tile, width) slab
+    q_parts, k_parts, (vt,) = (
+        [jnp.asarray(x, jnp.float32).transpose(0, 2, 1, 3) for x in parts]
+        for parts in (q_parts, k_parts, (v,)))
+    c_qk, c_v = packed_widths(widths, v_dim, passes)
+
+    def kv_tile(qi, ki):
+        # a key tile is fetched for the query tile whose diagonal crosses it
+        # and never again (its packed copy stays in VMEM): before the
+        # diagonal the map waits on the first crossed tile, above it on the
+        # last, and an unchanged index fetches nothing
+        return jnp.clip(ki, _first_masked_tile(qi, block_q, block_k),
+                        _last_key_tile(qi, block_q, block_k))
+
+    def spec(x, block, tile):
+        shared = x.shape[1] == 1        # one head for all: block 0 always
+        return pl.BlockSpec(
+            (None, None, block, x.shape[-1]),
+            lambda bi, hi, qi, ki: (bi, 0 if shared else hi, tile(qi, ki), 0))
+
+    def q_spec(x):
+        return spec(x, block_q, lambda qi, ki: qi)
+
+    def kv_spec(x):
+        return spec(x, block_k, kv_tile)
+
+    out = pl.pallas_call(
+        partial(_kernel, groups=len(widths), block_q=block_q,
+                block_k=block_k, v_dim=v_dim, passes=passes, scale=scale),
+        grid=(b, h, s // block_q, s // block_k),
+        in_specs=[*map(q_spec, q_parts), *map(kv_spec, k_parts),
+                  kv_spec(vt)],
+        out_specs=pl.BlockSpec((None, block_q, v_dim),
+                               lambda bi, hi, qi, ki: (bi, qi, hi)),
+        out_shape=jax.ShapeDtypeStruct((b, s, h * v_dim), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, c_qk), jnp.bfloat16),
+                        pltpu.VMEM((s, c_qk), jnp.bfloat16),
+                        pltpu.VMEM((s, c_v), jnp.bfloat16),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, v_dim), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            # query tiles of a head run in order: each packs the key tiles
+            # its diagonal crosses for the tiles after it
+            dimension_semantics=('parallel', 'parallel', 'arbitrary',
+                                 'arbitrary'),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name=NAME,
+    )(*q_parts, *k_parts, vt)
+    return out.reshape(b, s, h, v_dim)
