@@ -1,0 +1,277 @@
+"""The cell ``brumby.corpus`` and its configuration ``brumby-14b-l4``: the
+configuration file against the published config, the FLOP count recounted,
+a whole run of the cell through ``harness.run`` at a tiny size on the CPU —
+sound, then broken underneath — and the reader of the state-scan counter.
+(The trunk, its mixer and the extractor against the plain reference:
+``tests/test_retention_trunk.py``, ``tests/test_retention.py``.)"""
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import harness
+import loader
+from _layers import Ops
+
+from .conftest import BENCH
+
+CELL = 'brumby.corpus'
+SEED = 2 ** 31 + 2031
+REF = loader.load_module('references', 'brumby-14b-l4')
+
+# https://huggingface.co/manifestai/Brumby-14B-Base/blob/main/config.json,
+# as the catalog beside the model-configs guide holds it
+PUBLISHED = {
+    'attention_bias': False, 'head_dim': 128, 'hidden_act': 'silu',
+    'hidden_size': 5120, 'intermediate_size': 17408,
+    'max_position_embeddings': 32768, 'max_window_layers': 40,
+    'model_type': 'brumby', 'num_attention_heads': 40,
+    'num_hidden_layers': 40, 'num_key_value_heads': 8, 'rms_norm_eps': 1e-06,
+    'rope_scaling': None, 'rope_theta': 1000000, 'sliding_window': None,
+    'tie_word_embeddings': False, 'use_sliding_window': False,
+    'vocab_size': 151936}
+# the keys the program's trunk is built from (models/retention_trunk.py)
+MODEL_KEYS = ('vocab_size', 'hidden_size', 'intermediate_size',
+              'num_attention_heads', 'num_key_value_heads', 'head_dim',
+              'rope_theta', 'rms_norm_eps')
+
+# the trunk at a size a test run can hold: the program's overrides, and the
+# same sizes under the reference's names
+TINY_PROGRAM = dict(
+    device='cpu', batch_size=2, vocab_size=512, hidden_size=64,
+    num_hidden_layers=3, intermediate_size=160, num_attention_heads=4,
+    num_key_value_heads=2, head_dim=8, stack_size=4, step_size=4,
+    patch_grid=4)
+TINY_REFERENCE = dict(
+    REF.CFG, vocab_size=512, hidden_size=64, layers=3, intermediate_size=160,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=8, frames=4,
+    patch_grid=4, query_block=16, row_block=32)
+TINY = dict(
+    require_tpu=False, program_overrides=TINY_PROGRAM,
+    traffic_overrides={'clips': 3, 'frames': [9, 22, 13], 'width': 96,
+                       'height': 64},
+    workload_overrides={'sample': {'videos': 3, 'rows': 4, 'block': 2}})
+ARGV = ['--workload', CELL, '--seed', str(SEED), '--seconds', '0.3',
+        '--trace', '0']
+
+
+@pytest.fixture()
+def tiny_reference(monkeypatch):
+    """The reference at the tiny sizes, and the program's scan in chunks of
+    16 (its constant is 512, no option): four chunks a 64-id window, so the
+    state is handed over in what the run saves."""
+    from video_features_tpu.models import retention_trunk
+    monkeypatch.setattr(REF, 'CFG', TINY_REFERENCE)
+    monkeypatch.setattr(retention_trunk, 'RETENTION_CHUNK', 16)
+
+
+# -- the configuration and the cell, as files -------------------------------------
+
+def test_the_cell_reports_its_end_to_end_metrics(bench_json):
+    got = harness.metrics_of({'name': CELL, 'bench': bench_json},
+                             'end_to_end')
+    assert {m['name'] for m in got} == {'clips_per_s', 'setup_s'}
+    per_layer = {m['name'] for m in harness.metrics_of(
+        {'name': CELL, 'bench': bench_json}, 'per_layer')}
+    # the whole step's share of peak stands beside the mixer's counter
+    assert per_layer == {'batch_occupancy.clips', 'decode_busy.clips',
+                         'device_idle.clips', 'step_mfu.clips',
+                         'retention_state.clips'}
+    entry = [w for w in bench_json['workloads'] if w['name'] == CELL][0]
+    assert (entry['config'], entry['traffic'], entry['chips']) == (
+        'brumby-14b-l4', 'corpus-6', 1)
+    assert loader.load_json('workloads', CELL)['driver'] == 'packed'
+
+
+def test_the_configuration_keeps_every_published_key_but_the_cut(bench_json):
+    body = loader.load_json('configs', 'brumby-14b-l4')
+    entry = [c for c in bench_json['configs']
+             if c['name'] == 'brumby-14b-l4'][0]
+    assert body['reduced'] == entry['reduced'] == ['layers']
+    assert body['source'] == ('https://huggingface.co/manifestai/'
+                              'Brumby-14B-Base/blob/main/config.json')
+    for key, value in PUBLISHED.items():
+        assert body[key] == value, key
+    assert body['layers'] == 4
+    assert body['published'] == {'layers': 40, 'num_hidden_layers': 40}
+    assert body['deployment'] and body['departures']
+    assert set(body['assumed']) >= {'power', 'gate', 'normaliser',
+                                    'qk_norm_and_rotary', 'tokeniser',
+                                    'parameters', 'weights'}
+    assert body['feature_type'] == 'lm'
+    assert body['control_overrides'] == {'precision': 'default'}
+    # what the program is handed spells every model key (the shipped yml is
+    # another model's), at the published value but for the depth
+    over = body['overrides']
+    assert over['model_type'] == 'brumby' and over['num_hidden_layers'] == 4
+    for key in MODEL_KEYS:
+        assert over[key] == PUBLISHED[key], key
+        assert REF.CFG[key] == PUBLISHED[key], key
+    assert REF.CFG['layers'] == 4
+    assert (over['device'], over['precision'], over['on_extraction'],
+            over['pack_across_videos'], over['batch_size']) == (
+        'tpu', 'mixed', 'save_numpy', True, 1)
+    assert (over['stack_size'], over['step_size'], over['patch_grid']) == (
+        32, 32, 32)
+    assert REF.window_ids() == 32 * 32 ** 2 == 32768 \
+        == PUBLISHED['max_position_embeddings']
+    from video_features_tpu.config import load_config
+    from video_features_tpu.models import retention_trunk
+    args = load_config('lm', overrides=dict(over, video_paths=['x.mp4'],
+                                            device='cpu'))
+    cfg = retention_trunk.TrunkConfig.from_args(args)
+    assert retention_trunk.param_count(cfg) == 2_099_329_056
+
+
+def test_the_cells_pass_is_10_windows_in_10_steps():
+    traffic = loader.load_json('traffic', 'corpus-6')
+    assert traffic['clips'] == len(traffic['frames']) == 6
+    rows = [REF.rows_of(n) for n in traffic['frames']]
+    assert rows == [1, 1, 1, 2, 2, 3] and sum(rows) == 10
+    driver = loader.load_module('drivers', 'packed')
+
+    class One:
+        def packed_batch_size(self):
+            return 1
+    assert driver.batch_slots(One(), rows) == 10         # no padded slot
+    # the warm-up clip compiles the cell's one shape
+    assert loader.load_json('workloads', CELL)['warm_clips'] == [0]
+    assert rows[0] >= 1
+
+
+def test_flops_per_unit_is_the_models_work_recounted():
+    """Trace the reference at the published widths (shapes only: nothing is
+    computed), take the attention form's pairs away and put the recurrent
+    form's state updates and reads in."""
+    specs = REF.param_specs()['checkpoint_path']
+    params = {'checkpoint_path': {
+        name: jax.ShapeDtypeStruct(tuple(shape), jnp.float32)
+        for name, _, shape, _ in specs}}
+    assert sum(int(np.prod(s)) for _, _, s, _ in specs) == 2_099_329_056
+    ops = Ops()
+    out = jax.eval_shape(lambda p, u: REF.forward(ops, p, u), params,
+                         jax.ShapeDtypeStruct((1, 32768), jnp.int32))
+    assert out.shape == (1, 5120)
+    s = 32768
+    outside = s * 4 * (62_955_520 + 267_386_880)
+    assert ops.macs == outside + REF.attention_form_macs()
+    assert REF.attention_form_macs() == s * s * 40 * 256 * 4
+    retention = s * 4 * 8256 * 129 * (8 + 40)
+    assert REF.recurrent_form_macs() == retention
+    assert REF.model_macs(ops.macs) == outside + retention
+    body = loader.load_json('configs', 'brumby-14b-l4')
+    assert body['flops_per_unit'] == 2 * REF.model_macs(ops.macs) \
+        == 99_998_381_375_488
+    # 13.4 % of the model's work is the mixer's state
+    assert round(100 * retention / REF.model_macs(ops.macs), 1) == 13.4
+
+
+# -- a whole run, sound and broken --------------------------------------------------
+
+def test_a_sound_tiny_run_is_correct(tiny_reference):
+    result = harness.run(ARGV, **TINY)
+    assert result['correct'] is True
+    assert result['failed'] == 0 and result['attempted'] % 3 == 0
+    assert set(result['metrics']) == {'clips_per_s', 'setup_s'}
+    # on the CPU the program computes in float32: it sits on the reference,
+    # decode, tokeniser, packing, scatter and save included
+    assert result['checks']['rel_l2']['value'] < 1e-5
+    assert result['checks']['rows_off']['value'] == 0
+    json.dumps(result)
+
+
+def _swap_two_windows(extractor):
+    step = extractor.packed_step
+
+    def bad(batch):
+        out = dict(step(batch))
+        out['lm'] = out['lm'][::-1]
+        return out
+    extractor.packed_step = bad
+
+
+def _forget_everything(extractor):
+    """A fault only this trunk can have: the forget gate's bias gone, so
+    nothing older than a few positions reaches a query and the carried
+    state holds nothing."""
+    params = dict(extractor.params)
+    for name in list(params):
+        if name.endswith('g_proj.bias'):
+            params[name] = params[name] - 12.0
+    extractor.params = params
+
+
+def _lose_the_tail(extractor):
+    result = extractor.packed_result
+
+    def bad(task):
+        return {k: v[:-1] for k, v in result(task).items()}
+    extractor.packed_result = bad
+
+
+@pytest.mark.parametrize('fault,number', [
+    (_swap_two_windows, 'row_rel_l2_max'),
+    (_forget_everything, 'rel_l2'),
+    (_lose_the_tail, 'rows_off'),
+])
+def test_a_broken_timed_path_is_not_correct(tiny_reference, fault, number):
+    result = harness.run(ARGV, before_window=fault, **TINY)
+    assert result['correct'] is False
+    check = result['checks'][number]
+    assert check['value'] > check['limit']
+
+
+def test_the_precision_control_is_not_correct(tiny_reference, tmp_path):
+    """The reference in one bfloat16 pass, saved as the program would have
+    saved it, fails ``rel_l2`` under the cell's own limits."""
+    import compare
+    import traffic_gen
+    cell = harness.load_cell(CELL)
+    ckpts = harness.make_weights(REF, SEED, tmp_path)
+    corpus = traffic_gen.generate(
+        dict(cell['traffic'], **TINY['traffic_overrides']), SEED,
+        str(tmp_path / 'corpus'))
+    items = traffic_gen.pass_paths(corpus, 'p0')
+    for item in items:
+        units = REF.load_units(item['path'],
+                               range(REF.rows_of(item['frames'])))
+        np.save(item['path'] + '.npy', compare.reference_rows(
+            REF, ckpts, units, 2, mode='bfloat16'))
+    done = compare.collect([items], lambda p: p + '.npy', REF)
+    workload = dict(cell['workload'], **TINY['workload_overrides'])
+    checks, n = compare.compare(done, REF, ckpts, workload, SEED)
+    assert n == 9                       # 2 + 4 of 5 + 3 windows
+    assert checks['rows_off']['ok'] and checks['nonfinite']['ok']
+    assert not checks['rel_l2']['ok']
+
+
+# -- the state-scan counter's reader --------------------------------------------------
+
+def test_retention_state_reads_the_counter_and_nothing_where_there_is_none(
+        bench_json):
+    reader = loader.load_module('readers', 'stage_occupancy')
+    spec = json.loads((BENCH / 'metrics' / 'retention_state.clips.json')
+                      .read_text())
+    assert (spec['reader'], spec['stage']) == ('stage_occupancy',
+                                               'retention_scan')
+    entry = [m for m in bench_json['per_layer']
+             if m['name'] == 'retention_state.clips'][0]
+    assert entry == {'name': 'retention_state.clips', 'unit': '%',
+                     'better': 'higher', 'source': 'program_counter',
+                     'layer': 'device step', 'moves': 'clips_per_s',
+                     'workloads': ['brumby.corpus']}
+    full = {'retention_scan': {'count': 0, 'total_s': 0.0,
+                               'occ_valid': 10 * 4 * 32768,
+                               'occ_capacity': 10 * 4 * 32768}}
+    assert reader.read({'metric': spec, 'stages': full,
+                        'log': print}) == 100.0
+    # three of ten windows went another way than the state scan
+    part = {'retention_scan': {'occ_valid': 7 * 4 * 32768,
+                               'occ_capacity': 10 * 4 * 32768}}
+    assert reader.read({'metric': spec, 'stages': part,
+                        'log': print}) == pytest.approx(70.0)
+    # the parent commit, or the other trunk: no such counter, no number
+    assert reader.read({'metric': spec, 'stages': {'model': {'count': 3}},
+                        'log': print}) is None

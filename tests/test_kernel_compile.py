@@ -49,3 +49,25 @@ def test_causal_attention_compiles_at_the_cells_widths(one_chip, passes):
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert '%causal_attention' in text
+
+
+def test_the_retention_scan_compiles_at_the_cells_widths(one_chip):
+    """brumby.corpus's mixer at its widths — 8 key-value heads with 5 query
+    heads each, 128-wide, chunks of 512 — over four chunks of one window,
+    under three passes: XLA only (no Mosaic call), one while loop that
+    carries the 8,256 × 128 state, and temporaries that leave the chip's
+    16 GB to the 8.4 GB of parameters (φ of a chunk's queries is 676 MB)."""
+    from video_features_tpu.ops.retention import retention_chunked
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    with jax.default_matmul_precision('high'):
+        compiled = jax.jit(
+            lambda q, k, v, g: retention_chunked(q, k, v, g, 512)).lower(
+            sds(2048, 8, 5, 128), sds(2048, 8, 128), sds(2048, 8, 128),
+            sds(2048, 8)).compile()
+    text = compiled.as_text()
+    assert 'tpu_custom_call' not in text
+    assert 'f32[8,8256,128]' in text and ' while(' in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30

@@ -481,6 +481,6 @@ def test_the_extractor_says_which_causal_path_it_compiled(tmp_path, capsys):
         precision='mixed', video_paths=['x.mp4'],
         output_path=str(tmp_path / 'out'), tmp_path=str(tmp_path / 'tmp'),
         manifest_out=str(manifest))))
-    assert ex.attention_path == 'xla'
+    assert ex.kernel_notes == {'causal_attention': 'xla'}
     assert 'causal_attention=xla' in capsys.readouterr().err
     assert ex.manifest.document()['kernels'] == {'causal_attention': 'xla'}

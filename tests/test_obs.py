@@ -656,9 +656,10 @@ MANIFEST_KEYS = {'schema', 'version', 'started_at_unix_s', 'wall_s',
                  # (extract/streaming.py): the lane plan + per-lane
                  # counters, {} on farm-backed and per-video runs
                  'decode',
-                 # hand-written kernels: which path each call site
-                 # compiled to ({'causal_attention': 'kernel' | 'xla'} on
-                 # lm runs), {} where a family has no such choice
+                 # which path each call site with a choice compiled to
+                 # ({'causal_attention': 'kernel' | 'xla'} or {'retention':
+                 # 'state', 'retention_chunk': n} on lm
+                 # runs), {} where a family has no such choice
                  'kernels'}
 
 
@@ -668,7 +669,10 @@ CANONICAL_STAGES = {'decode', 'decode+preprocess', 'audio_dsp',
                     'cache_publish',
                     # PR 27, the lm family: its tokeniser's span and the
                     # routing counters of its expert layers
-                    'tokenise', 'moe_route', 'moe_held'}
+                    'tokenise', 'moe_route', 'moe_held',
+                    # PR 31, the lm family's retention trunk: positions
+                    # mixed through the carried state
+                    'retention_scan'}
 
 
 def test_stage_vocabulary_contract():

@@ -1,14 +1,23 @@
-"""Token-trunk extractor: a sparse-expert, latent-attention language trunk
-run over ids cut from decoded frames (``feature_type=lm``).
+"""Token-trunk extractor: a language trunk run over ids cut from decoded
+frames (``feature_type=lm``).
 
 The ninth family, and the first whose device step takes integers: a window
 is ``stack_size`` consecutive decoded frames (step ``step_size``, a partial
 tail dropped as the stack families drop it), the host preprocess is a
-tokeniser, and the step runs ``models/latent_moe.py`` over
+tokeniser, and the step runs a trunk over
 ``(batch, stack_size · patch_grid²) int32`` → one ``(hidden_size,)`` row a
 window. Everything around the step is the stack families' path: decode
 lanes → windows → host preprocess → pack → H2D → step → D2H → scatter →
 save (``StackPackingMixin``, ``parallel/packing.py::run_packed``).
+
+**The trunk is chosen by ``model_type``**, the published ``config.json``'s
+key (``TRUNKS``): ``joyai_llm_flash`` — latent attention + sparse experts,
+``models/latent_moe.py``, what ``configs/lm.yml`` ships — or ``brumby`` —
+gated power retention, dense, ``models/retention_trunk.py``. A trunk module
+says what this file needs of it (``models/token_trunk.py`` lists the names):
+its config from the args, its parameters, its step's second output, what it
+notes in the manifest and which counters it fills. An unknown
+``model_type`` is refused by name.
 
 **The ids are traffic, not model.** No tokeniser ships with a trunk's
 ``config.json``, so the ids are cut from the pixels by a fixed rule: of each
@@ -18,18 +27,21 @@ patch's id is ``((sum of its bytes) · 2654435761 mod 2³²) mod vocab_size``,
 patches row-major, frames in order — integer arithmetic only, so any
 decoder that is bit-exact gives the same ids.
 
-**The share.** ``n_experts_held`` (None: all) experts from ``first_expert``
-on are held here; the router keeps its width (``ops/moe.py``). The build
-refuses, with the sizes, a trunk whose parameters exceed the device's
-memory: the shipped yml is the whole published model, 48 B parameters.
+**What fits.** The build refuses, with the sizes, a trunk whose parameters
+exceed the device's memory, and says how that trunk is held in part: fewer
+layers (further pipeline stages) for both, and for the expert trunk a share
+of each layer's experts (the shipped yml is the whole published model, 48 B
+parameters).
 
-Telemetry: the ``tokenise`` span, and two counters on the stage table
-filled from the step's own per-expert counts — ``moe_route`` (mean ÷
-largest number of assignments on one held expert, summed over layers and
-steps) and ``moe_held`` (assignments on held experts ÷ all assignments).
+Telemetry: the ``tokenise`` span; the ``kernels`` note of the run manifest
+(which causal attention path, or which form of the retention mixer and its
+chunk, the step compiled); and the trunk's counters on the stage table,
+filled from the step's second output at each readback — ``moe_route`` /
+``moe_held`` (expert trunk), ``retention_scan`` (retention trunk).
 """
 from __future__ import annotations
 
+import importlib
 from functools import partial
 from typing import Dict
 
@@ -39,13 +51,22 @@ import numpy as np
 from video_features_tpu.extract.base import (
     BaseExtractor, StackPackingMixin, named_step,
 )
-from video_features_tpu.models import latent_moe
 from video_features_tpu.utils.device import jax_device
 
 TOKEN_HASH = 2654435761
-# the step's second output: (expert layers, held) assignment counts of the
-# batch; taken off again in fetch_outputs, never scattered to a video
-COUNTS_KEY = 'moe_counts'
+# published model_type → the module of its trunk (models/token_trunk.py
+# lists what such a module offers)
+TRUNKS = {
+    'joyai_llm_flash': 'video_features_tpu.models.latent_moe',
+    'brumby': 'video_features_tpu.models.retention_trunk',
+}
+
+
+def load_trunk(model_type: str):
+    if model_type not in TRUNKS:
+        raise ValueError(f'feature_type=lm has no trunk for model_type='
+                         f'{model_type!r}; known: {", ".join(sorted(TRUNKS))}')
+    return importlib.import_module(TRUNKS[model_type])
 
 
 def tokenise_frames(frames: np.ndarray, grid: int,
@@ -65,17 +86,17 @@ def tokenise_frames(frames: np.ndarray, grid: int,
     return ids.reshape(-1).astype(np.int32)
 
 
-def check_params_fit(need_bytes: int, limit_bytes, what: str) -> None:
+def check_params_fit(need_bytes: int, limit_bytes, what: str,
+                     advice: str = '') -> None:
     """Refuse at build what would fail in the first step: parameters larger
     than the device's memory (``limit_bytes`` None or 0: not known, e.g. the
-    CPU backend — nothing to check)."""
+    CPU backend — nothing to check). ``advice`` says how the trunk named by
+    ``what`` is held in part."""
     if limit_bytes and need_bytes > limit_bytes:
         raise ValueError(
             f'{what}: {need_bytes / 1e9:.2f} GB of float32 parameters do '
-            f'not fit the device\'s {limit_bytes / 1e9:.2f} GB. Hold a '
-            f'share (n_experts_held, first_expert: the experts of a layer '
-            f'divided over chips) and run fewer layers here '
-            f'(num_hidden_layers: the rest are further pipeline stages).')
+            f'not fit the device\'s {limit_bytes / 1e9:.2f} GB. '
+            f'{advice}'.rstrip())
 
 
 class ExtractLM(StackPackingMixin, BaseExtractor):
@@ -101,66 +122,62 @@ class ExtractLM(StackPackingMixin, BaseExtractor):
         self.stack_batch = int(args.get('batch_size') or 1)
         self.decode_backend = args.get('decode_backend', 'auto')
         self.data_parallel = False       # not in DATA_PARALLEL_FEATURES
-        self.cfg = latent_moe.TrunkConfig.from_args(args)
+        self.trunk = load_trunk(args.get('model_type'))
+        self.cfg = self.trunk.TrunkConfig.from_args(args)
         self.window_ids = self.stack_size * self.patch_grid ** 2
         self.packed_feat_dim = self.cfg.hidden_size
         self._device = jax_device(self.device)
         check_params_fit(
-            latent_moe.param_count(self.cfg) * 4,
+            self.trunk.param_count(self.cfg) * 4,
             (self._device.memory_stats() or {}).get('bytes_limit'),
-            f'feature_type=lm with {self.cfg.num_hidden_layers} layers and '
-            f'{self.cfg.n_experts_held} of {self.cfg.n_routed_experts} '
-            f'experts a layer')
+            f'feature_type=lm model_type={self.cfg.model_type} with '
+            f'{self.trunk.describe(self.cfg)}', self.trunk.SHARE_ADVICE)
         self.params = self.load_params(args)
         self._step = jax.jit(named_step(
             partial(self._forward, cfg=self.cfg,
                     platform=self._device.platform), self.step_name))
-        self.attention_path = self._say_attention_path()
+        self.kernel_notes = self._say_kernels()
 
     def load_params(self, args):
         """The flat ``{checkpoint name: device array}`` dict: read from the
-        ``.npz`` one array at a time straight onto the device (a 6.7 GB
-        share must not stand on the host twice), or seeded random."""
+        ``.npz`` one array at a time straight onto the device (a share of
+        several GB must not stand on the host twice), or seeded random."""
         from video_features_tpu.extract.weights import (
             load_npz_to_device, require_checkpoint,
         )
-        shapes = latent_moe.param_shapes(self.cfg)
+        shapes = self.trunk.param_shapes(self.cfg)
         ckpt = require_checkpoint(args, 'checkpoint_path', feature_type='lm')
         if ckpt:
             return load_npz_to_device(ckpt, shapes, self._device)
-        return jax.device_put(latent_moe.init_params(self.cfg),
-                              self._device)
+        return jax.device_put(self.trunk.init_params(self.cfg), self._device)
 
-    def _say_attention_path(self) -> str:
-        """Which causal path the step compiles here ('kernel' or 'xla':
-        ``ops.attention.resolve_causal``, from the device's platform, the
-        window's shapes and this run's matmul precision) — said once on
-        stderr and kept for the run manifest's ``kernels`` section. All or
-        nothing per program: it is the kernel's engagement counter."""
+    def _say_kernels(self) -> Dict[str, object]:
+        """Which path the step compiles here (``trunk.kernels``: from the
+        device's platform, the window's shapes and this run's matmul
+        precision) — said once on stderr and kept for the run manifest's
+        ``kernels`` section."""
         import logging
 
         from video_features_tpu.obs.events import event
-        from video_features_tpu.ops.attention import resolve_causal
         with self.precision_scope():
-            path = resolve_causal(
-                self._device.platform, self.window_ids, self.cfg.qk_head_dim,
-                self.cfg.v_head_dim, jax.config.jax_default_matmul_precision)
-        event(logging.INFO, 'lm: causal attention path', subsystem='lm',
-              causal_attention=path, platform=self._device.platform,
-              precision=self.precision)
-        return path
+            notes = self.trunk.kernels(
+                self.cfg, self._device.platform, self.window_ids,
+                jax.config.jax_default_matmul_precision)
+        event(logging.INFO, 'lm: the step\'s paths', subsystem='lm',
+              model_type=self.cfg.model_type, **notes,
+              platform=self._device.platform, precision=self.precision)
+        return notes
 
     def configure_obs(self, args) -> None:
         super().configure_obs(args)
         if self.manifest is not None:
-            self.manifest.note_kernels(
-                {'causal_attention': self.attention_path})
+            self.manifest.note_kernels(self.kernel_notes)
 
     @staticmethod
     def _forward(params, ids, cfg, platform=None):
-        feats, counts = latent_moe.forward(params, ids, cfg,
-                                           platform=platform)
-        return {'lm': feats, COUNTS_KEY: counts}
+        trunk = load_trunk(cfg.model_type)
+        feats, counter = trunk.forward(params, ids, cfg, platform=platform)
+        return {'lm': feats, trunk.COUNTER: counter}
 
     # -- the host preprocess: frames → ids ----------------------------------
 
@@ -193,20 +210,13 @@ class ExtractLM(StackPackingMixin, BaseExtractor):
         return self.aot_call('step', self._step, self.params, ids)
 
     def fetch_outputs(self, out):
+        # the step's second output is the trunk's: taken off here, turned
+        # into its stage-table counters, never scattered to a video
         out = dict(super().fetch_outputs(out))
-        counts = out.pop(COUNTS_KEY, None)
-        if counts is not None and self.tracer.enabled and counts.size:
-            # per layer: the held experts' mean load against the fullest
-            # one's (the one the layer waits for), and how many of all
-            # assignments fell on experts held here
-            counts = np.asarray(counts, np.int64)
-            layers, held = counts.shape
-            assigned = (self.stack_batch * self.window_ids
-                        * self.cfg.num_experts_per_tok * layers)
-            self.tracer.add_occupancy('moe_route', int(counts.sum()),
-                                      int(counts.max(axis=1).sum()) * held)
-            self.tracer.add_occupancy('moe_held', int(counts.sum()),
-                                      assigned)
+        counter = out.pop(self.trunk.COUNTER, None)
+        if counter is not None and self.tracer.enabled:
+            self.trunk.count(self.tracer, counter, self.cfg,
+                             self.stack_batch * self.window_ids)
         return out
 
     def extract(self, video_path: str) -> Dict[str, np.ndarray]:

@@ -28,7 +28,6 @@ is (held, hidden, moe_intermediate).
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 from typing import Dict, Optional, Tuple
 
@@ -37,12 +36,22 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from video_features_tpu.models import token_trunk
+from video_features_tpu.models.token_trunk import (
+    Params, embed, final_norm, mean_features, rms_norm, swiglu,
+)
 from video_features_tpu.ops import moe
 from video_features_tpu.ops.attention import (
     KERNEL_PASSES, blockwise_attention, resolve_causal, rotary_interleaved,
 )
 
-Params = Dict[str, jax.Array]
+MODEL_TYPE = 'joyai_llm_flash'
+# the step's second output: (expert layers, held) assignment counts of the
+# batch
+COUNTER = 'moe_counts'
+SHARE_ADVICE = ('Hold a share (n_experts_held, first_expert: the experts of '
+                'a layer divided over chips) and run fewer layers here '
+                '(num_hidden_layers: the rest are further pipeline stages).')
 
 # the config keys a trunk is built from, under the names the published
 # config.json uses (configs/lm.yml ships JoyAI-LLM-Flash's values)
@@ -79,6 +88,8 @@ class TrunkConfig:
     rms_norm_eps: float
     n_experts_held: Optional[int] = None     # None: all of them
     first_expert: int = 0
+
+    model_type = MODEL_TYPE
 
     def __post_init__(self):
         held = (self.n_routed_experts if self.n_experts_held is None
@@ -157,42 +168,49 @@ def param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
 
 
 def param_count(cfg: TrunkConfig) -> int:
-    return sum(math.prod(s) for s in param_shapes(cfg).values())
+    return token_trunk.param_count(param_shapes(cfg))
 
 
 def init_params(cfg: TrunkConfig, seed: int = 0) -> Dict[str, np.ndarray]:
-    """Seeded random parameters (tests, ``allow_random_weights`` runs):
-    matrices N(0, 1/fan_in) over the contracted axis, the embedding N(0, 1),
-    norm gains near 1, a small router bias."""
-    rng = np.random.default_rng(seed)
-    out: Dict[str, np.ndarray] = {}
-    for name, shape in param_shapes(cfg).items():
-        if name.endswith('layernorm.weight') or name == 'model.norm.weight':
-            w = 0.9 + 0.2 * rng.random(shape, dtype=np.float32)
-        elif name.endswith('e_score_correction_bias'):
-            w = 0.05 * rng.standard_normal(shape, dtype=np.float32)
-        elif name == 'model.embed_tokens.weight':
-            w = rng.standard_normal(shape, dtype=np.float32)
-        else:
-            w = rng.standard_normal(shape, dtype=np.float32)
-            w *= np.float32(1.0 / math.sqrt(shape[-2]))
-        out[name] = w
-    return out
+    """Seeded random parameters (``token_trunk.draw_params``), and a small
+    router bias."""
+    def router_bias(name, shape, rng):
+        if name.endswith('e_score_correction_bias'):
+            return 0.05 * rng.standard_normal(shape, dtype=np.float32)
+        return None
+    return token_trunk.draw_params(param_shapes(cfg), seed, router_bias)
+
+
+def describe(cfg: TrunkConfig) -> str:
+    return (f'{cfg.num_hidden_layers} layers and {cfg.n_experts_held} of '
+            f'{cfg.n_routed_experts} experts a layer')
+
+
+def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
+            precision: Optional[str]) -> Dict[str, object]:
+    """Which causal path the step compiles ('kernel' or 'xla':
+    ``ops.attention.resolve_causal``, from the platform, the window's
+    shapes and the matmul precision). All or nothing per program: it is
+    the kernel's engagement counter."""
+    return {'causal_attention': resolve_causal(
+        platform, window_ids, cfg.qk_head_dim, cfg.v_head_dim, precision)}
+
+
+def count(tracer, counts: np.ndarray, cfg: TrunkConfig, tokens: int) -> None:
+    """Per layer: the held experts' mean load against the fullest one's
+    (the one the layer waits for) → ``moe_route``; how many of all
+    assignments fell on experts held here → ``moe_held``."""
+    counts = np.asarray(counts, np.int64)
+    if not counts.size:
+        return
+    layers, held = counts.shape
+    tracer.add_occupancy('moe_route', int(counts.sum()),
+                         int(counts.max(axis=1).sum()) * held)
+    tracer.add_occupancy('moe_held', int(counts.sum()),
+                         int(tokens) * cfg.num_experts_per_tok * layers)
 
 
 # -- blocks -------------------------------------------------------------------
-
-def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * lax.rsqrt(var + eps) * gain).astype(x.dtype)
-
-
-def swiglu(x: jax.Array, p: Params, prefix: str) -> jax.Array:
-    gate = jnp.dot(x, p[f'{prefix}.gate_proj.weight'])
-    up = jnp.dot(x, p[f'{prefix}.up_proj.weight'])
-    return jnp.dot(jax.nn.silu(gate) * up, p[f'{prefix}.down_proj.weight'])
-
 
 def _head_columns(w: jax.Array, h: int, lo: int, hi: int) -> jax.Array:
     """Columns ``lo:hi`` of every head of a (in, h·d) projection, as
@@ -309,7 +327,7 @@ def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
     b, s = ids.shape
     d = cfg.hidden_size
     eps = cfg.rms_norm_eps
-    x = params['model.embed_tokens.weight'][ids]            # (B, S, D)
+    x = embed(params, ids)                                  # (B, S, D)
     counts = []
     for i in range(cfg.num_hidden_layers):
         p = f'model.layers.{i}'
@@ -329,7 +347,7 @@ def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
         x = x + y.reshape(b, s, d)
     counts = (jnp.stack(counts) if counts
               else jnp.zeros((0, cfg.n_experts_held), jnp.int32))
-    return rms_norm(x, params['model.norm.weight'], eps), counts
+    return final_norm(x, params, eps), counts
 
 
 def forward(params: Params, ids: jax.Array, cfg: TrunkConfig,
@@ -339,4 +357,4 @@ def forward(params: Params, ids: jax.Array, cfg: TrunkConfig,
     of the window's final-norm hidden states (:func:`hidden_states`)."""
     x, counts = hidden_states(params, ids, cfg, attn_block, moe_block,
                               platform)
-    return x.astype(jnp.float32).mean(axis=1), counts
+    return mean_features(x), counts

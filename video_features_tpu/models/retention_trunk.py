@@ -1,0 +1,236 @@
+"""A gated power-retention token trunk (``model_type: brumby``).
+
+The decoder trunk of Manifest AI's Brumby-14B-Base as a feature extractor:
+token ids in, one hidden-state row a window out. It is Qwen3's dense block
+— pre-norm residual, RMSNorm, grouped-query heads with a per-head RMSNorm
+on q and k and the half-split rotary code, a SwiGLU, no biases — with every
+softmax attention replaced by *power retention* (``ops/retention.py``): the
+weight of position ``s`` for position ``t`` is ``(q_t·k_s)²`` times a learned
+decay, which makes the mixer a linear recurrence over a state of
+``d(d+1)/2 × (d + 1)`` numbers a key-value head, and a window's cost linear
+in its length. One layer, ``h = RMSNorm(x)``:
+
+    q = rope(rmsnorm_d(W_q h))   k = rope(rmsnorm_d(W_k h))   v = W_v h
+    γ = log σ(W_g h + b_g)       one a key-value head, ≤ 0
+    y = retention(q, k, v, γ)    query heads 5j … 5j+4 read key-value head j
+    x ← x + W_o y;   x ← x + W_down(silu(W_gate h') ⊙ W_up h'),  h' = RMSNorm(x)
+
+The published ``config.json`` names the widths; the power (2), the gate (one
+log-sigmoid a key-value head, a linear map of the layer's normed input with
+a bias) and the sum normaliser are the paper's. Prefill only: a window
+starts from an empty state and its final state is dropped. The output is
+the final RMSNorm's mean over the window's positions; the head is neither
+held nor run.
+
+Parameters are a flat ``{dotted name: array}`` dict under Qwen3's names,
+matrices as (in, out); the gate is ``self_attn.g_proj.{weight,bias}``.
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from video_features_tpu.models import token_trunk
+from video_features_tpu.models.token_trunk import (
+    Params, embed, final_norm, mean_features, rms_norm, swiglu,
+)
+from video_features_tpu.ops.attention import rotary_half
+from video_features_tpu.ops.retention import retention_chunked
+
+MODEL_TYPE = 'brumby'
+# the step's second output: (layers,) positions of the batch that each
+# layer's mixer put through the chunked state scan
+COUNTER = 'retention_scanned'
+SHARE_ADVICE = ('Run fewer layers here (num_hidden_layers: the rest are '
+                'further pipeline stages).')
+
+# the config keys the trunk is built from, under the published names
+CONFIG_KEYS = (
+    'vocab_size', 'hidden_size', 'num_hidden_layers', 'intermediate_size',
+    'num_attention_heads', 'num_key_value_heads', 'head_dim', 'rope_theta',
+    'rms_norm_eps',
+)
+# positions a chunk of the state scan: the program's, no published key and
+# no option (the scan alone reads level at 256 / 512 / 1,024 on the chip, the
+# whole step 16 % slower at 1,024: PERF.md §5)
+RETENTION_CHUNK = 512
+# rows the feed-forward walks at a time: its two intermediates stand as
+# (rows, intermediate_size) float32, 285 MB each at the published width
+MLP_ROWS = 4096
+
+
+@dataclass(frozen=True)
+class TrunkConfig:
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    intermediate_size: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    rope_theta: float
+    rms_norm_eps: float
+
+    model_type = MODEL_TYPE
+
+    def __post_init__(self):
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f'num_attention_heads={self.num_attention_heads} is no '
+                f'whole number of groups of num_key_value_heads='
+                f'{self.num_key_value_heads}')
+        if self.head_dim % 2:
+            raise ValueError(f'head_dim={self.head_dim} must be even (rotary '
+                             f'pairs)')
+
+    @classmethod
+    def from_args(cls, args) -> 'TrunkConfig':
+        values = {k: args.get(k) for k in CONFIG_KEYS}
+        missing = [k for k, v in values.items() if v is None]
+        if missing:
+            raise ValueError(f'the lm trunk model_type={MODEL_TYPE} needs '
+                             f'config keys {missing}')
+        return cls(**values)
+
+    @property
+    def group(self) -> int:
+        return self.num_attention_heads // self.num_key_value_heads
+
+
+def param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
+    """{name: shape} of every parameter held, in checkpoint order."""
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    h, g, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    shapes: Dict[str, Tuple[int, ...]] = {
+        'model.embed_tokens.weight': (cfg.vocab_size, d)}
+    for i in range(cfg.num_hidden_layers):
+        p = f'model.layers.{i}'
+        a, m = f'{p}.self_attn', f'{p}.mlp'
+        shapes.update({
+            f'{p}.input_layernorm.weight': (d,),
+            f'{a}.q_proj.weight': (d, h * hd),
+            f'{a}.k_proj.weight': (d, g * hd),
+            f'{a}.v_proj.weight': (d, g * hd),
+            f'{a}.g_proj.weight': (d, g),
+            f'{a}.g_proj.bias': (g,),
+            f'{a}.q_norm.weight': (hd,),
+            f'{a}.k_norm.weight': (hd,),
+            f'{a}.o_proj.weight': (h * hd, d),
+            f'{p}.post_attention_layernorm.weight': (d,),
+            f'{m}.gate_proj.weight': (d, f),
+            f'{m}.up_proj.weight': (d, f),
+            f'{m}.down_proj.weight': (f, d),
+        })
+    shapes['model.norm.weight'] = (d,)
+    return shapes
+
+
+def param_count(cfg: TrunkConfig) -> int:
+    return token_trunk.param_count(param_shapes(cfg))
+
+
+def init_params(cfg: TrunkConfig, seed: int = 0) -> Dict[str, np.ndarray]:
+    """Seeded random parameters (``token_trunk.draw_params``); the gate's
+    bias in [4, 8], so a position is remembered for some 50 to 3,000
+    further ones, as a trained forget gate is."""
+    def gate_bias(name, shape, rng):
+        if name.endswith('g_proj.bias'):
+            return 4.0 + 4.0 * rng.random(shape, dtype=np.float32)
+        return None
+    return token_trunk.draw_params(param_shapes(cfg), seed, gate_bias)
+
+
+def describe(cfg: TrunkConfig) -> str:
+    return (f'{cfg.num_hidden_layers} layers of gated power retention '
+            f'({cfg.num_attention_heads} query / {cfg.num_key_value_heads} '
+            f'key-value heads of {cfg.head_dim}) and a dense SwiGLU of '
+            f'{cfg.intermediate_size}')
+
+
+def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
+            precision: Optional[str]) -> Dict[str, object]:
+    """The form of the mixer the step compiles and its chunk for windows
+    of ``window_ids`` positions: the state scan, on every platform and
+    precision (XLA; there is no kernel or second form to choose yet)."""
+    return {'retention': 'state',
+            'retention_chunk': min(RETENTION_CHUNK, window_ids)}
+
+
+def count(tracer, scanned: np.ndarray, cfg: TrunkConfig, tokens: int) -> None:
+    """``retention_scan``: positions × layers of one fetched step mixed
+    through the carried state ÷ positions × layers of the step."""
+    tracer.add_occupancy('retention_scan', int(np.asarray(scanned).sum()),
+                         int(tokens) * cfg.num_hidden_layers)
+
+
+# -- blocks -------------------------------------------------------------------
+
+def retention_block(p: Params, prefix: str, x: jax.Array,
+                    cfg: TrunkConfig) -> Tuple[jax.Array, jax.Array]:
+    """The mixer over one window: (S, D) normed input → (S, D), causal,
+    positions 0…S−1, from an empty state; and how many of the positions it
+    put through the state scan (what ``retention_scan`` counts)."""
+    with jax.named_scope('retention'):
+        s = x.shape[0]
+        h, g, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim)
+        eps, theta = cfg.rms_norm_eps, cfg.rope_theta
+        positions = jnp.arange(s)
+        q = jnp.dot(x, p[f'{prefix}.q_proj.weight']).reshape(s, h, d)
+        k = jnp.dot(x, p[f'{prefix}.k_proj.weight']).reshape(s, g, d)
+        v = jnp.dot(x, p[f'{prefix}.v_proj.weight']).reshape(s, g, d)
+        q = rotary_half(rms_norm(q, p[f'{prefix}.q_norm.weight'], eps),
+                        positions, theta).reshape(s, g, cfg.group, d)
+        k = rotary_half(rms_norm(k, p[f'{prefix}.k_norm.weight'], eps),
+                        positions, theta)
+        log_gate = jax.nn.log_sigmoid(
+            (jnp.dot(x, p[f'{prefix}.g_proj.weight'])
+             + p[f'{prefix}.g_proj.bias']).astype(jnp.float32))
+        y, _ = retention_chunked(q, k, v, log_gate, RETENTION_CHUNK)
+        scanned = jnp.int32(y.shape[0])
+        return (jnp.dot(y.reshape(s, h * d), p[f'{prefix}.o_proj.weight']),
+                scanned)
+
+
+def hidden_states(params: Params, ids: jax.Array,
+                  cfg: TrunkConfig) -> Tuple[jax.Array, jax.Array]:
+    """(B, S) int32 ids → final-norm hidden states (B, S, D) and the
+    (layers,) positions each layer's mixer scanned. The mixer runs a window
+    at a time (each window has a state of its own); the feed-forward walks
+    all B·S tokens in row blocks."""
+    b, s = ids.shape
+    d = cfg.hidden_size
+    eps = cfg.rms_norm_eps
+    rows = MLP_ROWS if (b * s) % MLP_ROWS == 0 else None
+    x = embed(params, ids)
+    scanned = []
+    for i in range(cfg.num_hidden_layers):
+        p = f'model.layers.{i}'
+        normed = rms_norm(x, params[f'{p}.input_layernorm.weight'], eps)
+        mixed, n = lax.map(
+            lambda w: retention_block(params, f'{p}.self_attn', w, cfg),
+            normed)
+        x = x + mixed
+        scanned.append(n.sum())
+        normed = rms_norm(x, params[f'{p}.post_attention_layernorm.weight'],
+                          eps).reshape(b * s, d)
+        with jax.named_scope('dense_mlp'):
+            y = swiglu(normed, params, f'{p}.mlp', row_block=rows)
+        x = x + y.reshape(b, s, d)
+    return final_norm(x, params, eps), jnp.stack(scanned)
+
+
+def forward(params: Params, ids: jax.Array, cfg: TrunkConfig,
+            platform: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
+    """(B, S) int32 ids → ``(features (B, D) float32, scanned (layers,)
+    int32)``: the mean of the window's final-norm hidden states, and how
+    many of the batch's positions each layer's mixer put through the state
+    scan. ``platform`` is where the graph will run; every platform takes
+    the same XLA graph."""
+    hidden, scanned = hidden_states(params, ids, cfg)
+    return mean_features(hidden), scanned

@@ -262,11 +262,13 @@ class RunManifest:
             self.slo.update({k: _jsonable(v) for k, v in info.items()})
 
     def note_kernels(self, info: Dict[str, Any]) -> None:
-        """Record which path each hand-written kernel's call site compiled
-        to in this run (``{'causal_attention': 'kernel' | 'xla'}``, from
-        ``ops.attention.resolve_causal``): a kernel is all or nothing per
-        program, so this line is its engagement counter. ``{}`` for
-        families that have no such choice to report."""
+        """Record which path each call site with a choice compiled to in
+        this run (``{'causal_attention': 'kernel' | 'xla'}`` from
+        ``ops.attention.resolve_causal``; ``{'retention': 'state',
+        'retention_chunk': n}`` from ``models.retention_trunk.kernels``,
+        which has one form to report, and its chunk): a path is all or
+        nothing per program, so this line is its engagement counter. ``{}``
+        for families that have no such choice to report."""
         with self._lock:
             self.kernels.update({k: _jsonable(v) for k, v in info.items()})
 

@@ -13,7 +13,8 @@ Causal language trunks (``models/latent_moe.py``) take the same blockwise
 path with ``causal=True``: queries are tiled too, a query tile scans only the
 key tiles at or before it, and the value head may be narrower than the
 query/key head (latent attention: 192-wide q/k, 128-wide v).
-:func:`rotary_interleaved` is their position code.
+:func:`rotary_interleaved` is their position code; :func:`rotary_half` is
+the half-split form of the same rotation (``models/retention_trunk.py``).
 
 The three XLA paths compute bit-comparable results (same online-softmax
 math, f32 accumulation, the ambient matmul precision on every product):
@@ -224,6 +225,24 @@ def rotary_interleaved(x: jax.Array, positions: jax.Array,
     re, im = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([re * cos - im * sin, re * sin + im * cos], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rotary_half(x: jax.Array, positions: jax.Array,
+                theta: float) -> jax.Array:
+    """Rotary position code in the half-split form (GPT-NeoX, Qwen):
+    ``(x[i], x[i + d/2])`` is the complex number rotated by
+    ``positions · theta^(-2i/d)`` — the same angles as
+    :func:`rotary_interleaved`, the pair's two members half a head apart.
+    ``x`` is (..., S, H, d) with d even, ``positions`` (S,)."""
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq   # (S, d/2)
+    cos = jnp.cos(angle)[:, None, :]
+    sin = jnp.sin(angle)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    re, im = x32[..., :d // 2], x32[..., d // 2:]
+    out = jnp.concatenate([re * cos - im * sin, re * sin + im * cos], axis=-1)
+    return out.astype(x.dtype)
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,

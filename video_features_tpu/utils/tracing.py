@@ -57,10 +57,12 @@ STAGES = (
     'save',               # output materialization (.npy/.pkl writes)
     'cache_lookup',       # content-addressed cache consult
     'cache_publish',      # content-addressed cache publish
-    # counters only (add_occupancy; no time): the lm step's routing, from
-    # the per-expert counts the step itself returns
+    # counters only (add_occupancy; no time), from the second output the
+    # lm step itself returns: the expert trunk's routing ...
     'moe_route',          # mean ÷ largest load on one held expert
     'moe_held',           # assignments on held experts ÷ all assignments
+    # ... and the retention trunk's mixer
+    'retention_scan',     # positions × layers through the carried state ÷ all
 )
 
 
