@@ -51,23 +51,54 @@ def test_causal_attention_compiles_at_the_cells_widths(one_chip, passes):
     assert '%causal_attention' in text
 
 
-def test_the_retention_scan_compiles_at_the_cells_widths(one_chip):
+@pytest.fixture(scope='module')
+def compiled_scan(one_chip):
     """brumby.corpus's mixer at its widths — 8 key-value heads with 5 query
     heads each, 128-wide, chunks of 512 — over four chunks of one window,
-    under three passes: XLA only (no Mosaic call), one while loop that
-    carries the 8,256 × 128 state, and temporaries that leave the chip's
-    16 GB to the 8.4 GB of parameters (φ of a chunk's queries is 676 MB)."""
+    compiled once a form and ambient precision."""
+    from functools import cache
+
     from video_features_tpu.ops.retention import retention_chunked
 
     def sds(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
-    with jax.default_matmul_precision('high'):
-        compiled = jax.jit(
-            lambda q, k, v, g: retention_chunked(q, k, v, g, 512)).lower(
-            sds(2048, 8, 5, 128), sds(2048, 8, 128), sds(2048, 8, 128),
-            sds(2048, 8)).compile()
+    @cache
+    def compiled(precision, passes):
+        with jax.default_matmul_precision(precision):
+            return jax.jit(
+                lambda q, k, v, g: retention_chunked(
+                    q, k, v, g, 512, kernel_passes=passes)).lower(
+                sds(2048, 8, 5, 128), sds(2048, 8, 128), sds(2048, 8, 128),
+                sds(2048, 8)).compile()
+    return compiled
+
+
+@pytest.mark.parametrize('form,precision', [
+    ('state', 'high'), ('kernel', 'high'), ('kernel', 'default')])
+def test_the_retention_scan_compiles_at_the_cells_widths(compiled_scan, form,
+                                                         precision):
+    """Either form is one while loop that carries the 8,256 × 128 state.
+    XLA's ('state'): no Mosaic call, φ of a chunk's queries as a 676 MB
+    buffer, temporaries that still leave the chip's 16 GB to the 8.4 GB of
+    parameters. The kernel path, under three passes (precision=mixed) and
+    one (the control lane): the two state products as Mosaic calls by name,
+    no φ buffer, and less temporary memory than XLA's form takes."""
+    from video_features_tpu.ops.attention import KERNEL_PASSES
+    from video_features_tpu.ops.retention import resolve_retention
+    assert resolve_retention('tpu', 128, 128, 512, precision) == 'kernel'
+    xla = compiled_scan('high', None)
+    compiled = (xla if form == 'state'
+                else compiled_scan(precision, KERNEL_PASSES[precision]))
     text = compiled.as_text()
-    assert 'tpu_custom_call' not in text
     assert 'f32[8,8256,128]' in text and ' while(' in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 3 * 2 ** 30
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if form == 'state':
+        assert 'tpu_custom_call' not in text
+        assert 'f32[8,5,512,8256]' in text
+        assert temp < 3 * 2 ** 30
+    else:
+        assert text.count('custom_call_target="tpu_custom_call"') == 2
+        assert '%retention_read' in text and '%retention_update' in text
+        assert 'f32[8,5,512,8256]' not in text and '8256]' not in text
+        assert temp < xla.memory_analysis().temp_size_in_bytes // 4

@@ -658,7 +658,7 @@ MANIFEST_KEYS = {'schema', 'version', 'started_at_unix_s', 'wall_s',
                  'decode',
                  # which path each call site with a choice compiled to
                  # ({'causal_attention': 'kernel' | 'xla'} or {'retention':
-                 # 'state', 'retention_chunk': n} on lm
+                 # 'kernel' | 'state', 'retention_chunk': n} on lm
                  # runs), {} where a family has no such choice
                  'kernels'}
 
@@ -672,7 +672,9 @@ CANONICAL_STAGES = {'decode', 'decode+preprocess', 'audio_dsp',
                     'tokenise', 'moe_route', 'moe_held',
                     # PR 31, the lm family's retention trunk: positions
                     # mixed through the carried state
-                    'retention_scan'}
+                    'retention_scan',
+                    # PR 32: of those, through the state-product kernels
+                    'retention_kernel'}
 
 
 def test_stage_vocabulary_contract():

@@ -81,9 +81,11 @@ def test_retention_block_matches_the_reference(tiny, chunks_of_16):
     want = REF._retention(Ops(), params, a, jnp.asarray(x), rcfg)
     with jax.default_matmul_precision('highest'):
         got = jnp.stack([rt.retention_block(params, a, w, cfg)[0] for w in x])
-        scanned = rt.retention_block(params, a, x[0], cfg)[1]
+        counted = rt.retention_block(params, a, x[0], cfg)[1]
     assert rel_l2(got, want) < 1e-5
-    assert int(scanned) == 64 and scanned.dtype == jnp.int32
+    # 64 positions through the state scan, none of them through the
+    # kernels (the CPU takes XLA's form)
+    assert counted.tolist() == [64, 0] and counted.dtype == jnp.int32
     # the seeded gate remembers: without the state's part the block is wrong
     cut = jnp.stack([
         jnp.concatenate([rt.retention_block(params, a, w[i:i + 16], cfg)[0]
@@ -99,7 +101,7 @@ def test_trunk_matches_the_reference(tiny, chunks_of_16):
                                                                    ids)
     assert got.shape == (3, 64) and got.dtype == jnp.float32
     assert rel_l2(got, want) < 1e-5
-    assert np.asarray(scanned).tolist() == [3 * 64] * 3
+    assert np.asarray(scanned).tolist() == [[3 * 64] * 3, [0] * 3]
     # the reference in one bf16 pass reads far above the program
     control = REF.forward(Ops('bfloat16'), {'checkpoint_path': params}, ids,
                           rcfg)
@@ -121,7 +123,7 @@ def test_every_chunk_and_a_padded_window_give_the_same_rows(
     assert rel_l2(got, want) < 1e-5
     assert rt.kernels(cfg, 'cpu', 64, None) == {
         'retention': 'state', 'retention_chunk': noted}
-    assert np.asarray(scanned).tolist() == [3 * 64] * 3
+    assert np.asarray(scanned).tolist() == [[3 * 64] * 3, [0] * 3]
 
 
 def test_a_later_token_changes_no_earlier_position(tiny, chunks_of_16):
@@ -159,8 +161,13 @@ def test_published_sizes_count_as_the_issue_counts_them():
     assert rt.param_count(cfg) == 2_099_329_056          # 8.40 GB
     assert args['stack_size'] * args['patch_grid'] ** 2 == 32_768 \
         == body['max_position_embeddings']
-    assert rt.kernels(cfg, 'tpu', 32_768, 'high') == {
-        'retention': 'state', 'retention_chunk': 512}
+    # the cell's step: the kernels under precision=mixed and the control
+    # lane, XLA's form under 'highest' and off the chip
+    for precision, form in (('high', 'kernel'), ('default', 'kernel'),
+                            ('highest', 'state')):
+        assert rt.kernels(cfg, 'tpu', 32_768, precision) == {
+            'retention': form, 'retention_chunk': 512}
+    assert rt.kernels(cfg, 'cpu', 32_768, 'high')['retention'] == 'state'
     # the model's work a window in the recurrent form: 100.0 TFLOP
     macs = 32_768 * 4 * (62_955_520 + 267_386_880 + 8_256 * 129 * (8 + 40))
     assert 2 * macs == body['flops_per_unit'] == 99_998_381_375_488
@@ -272,6 +279,9 @@ def test_extract_packed_equals_the_per_video_loop(clips, tmp_path, capsys,
     assert stages['model']['occ_valid'] == 10
     scan = stages['retention_scan']
     assert scan['occ_valid'] == scan['occ_capacity'] == steps * 2 * 64 * 3
+    through = stages['retention_kernel']
+    assert through['occ_valid'] == 0
+    assert through['occ_capacity'] == scan['occ_valid']
     assert 'moe_route' not in stages
     assert doc['kernels'] == {'retention': 'state', 'retention_chunk': 16}
 

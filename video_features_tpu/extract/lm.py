@@ -37,7 +37,8 @@ Telemetry: the ``tokenise`` span; the ``kernels`` note of the run manifest
 (which causal attention path, or which form of the retention mixer and its
 chunk, the step compiled); and the trunk's counters on the stage table,
 filled from the step's second output at each readback — ``moe_route`` /
-``moe_held`` (expert trunk), ``retention_scan`` (retention trunk).
+``moe_held`` (expert trunk), ``retention_scan`` / ``retention_kernel``
+(retention trunk).
 """
 from __future__ import annotations
 

@@ -39,12 +39,15 @@ from video_features_tpu.models import token_trunk
 from video_features_tpu.models.token_trunk import (
     Params, embed, final_norm, mean_features, rms_norm, swiglu,
 )
-from video_features_tpu.ops.attention import rotary_half
-from video_features_tpu.ops.retention import retention_chunked
+from video_features_tpu.ops.attention import KERNEL_PASSES, rotary_half
+from video_features_tpu.ops.retention import (
+    resolve_retention, retention_chunked,
+)
 
 MODEL_TYPE = 'brumby'
-# the step's second output: (layers,) positions of the batch that each
-# layer's mixer put through the chunked state scan
+# the step's second output: (2, layers) positions of the batch that each
+# layer's mixer put through the chunked state scan, and how many of those
+# through the Mosaic kernels of its state products
 COUNTER = 'retention_scanned'
 SHARE_ADVICE = ('Run fewer layers here (num_hidden_layers: the rest are '
                 'further pipeline stages).')
@@ -155,26 +158,40 @@ def describe(cfg: TrunkConfig) -> str:
 def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
             precision: Optional[str]) -> Dict[str, object]:
     """The form of the mixer the step compiles and its chunk for windows
-    of ``window_ids`` positions: the state scan, on every platform and
-    precision (XLA; there is no kernel or second form to choose yet)."""
-    return {'retention': 'state',
-            'retention_chunk': min(RETENTION_CHUNK, window_ids)}
+    of ``window_ids`` positions: ``retention`` is 'kernel' where the two
+    products with φ run as Mosaic kernels (``ops.retention.
+    resolve_retention``: from the platform, the head's widths, the chunk and
+    the matmul precision) and 'state' where the whole scan is XLA's."""
+    chunk = min(RETENTION_CHUNK, window_ids)
+    return {'retention': resolve_retention(platform, cfg.head_dim,
+                                           cfg.head_dim, chunk, precision),
+            'retention_chunk': chunk}
 
 
-def count(tracer, scanned: np.ndarray, cfg: TrunkConfig, tokens: int) -> None:
-    """``retention_scan``: positions × layers of one fetched step mixed
-    through the carried state ÷ positions × layers of the step."""
-    tracer.add_occupancy('retention_scan', int(np.asarray(scanned).sum()),
+def count(tracer, counted: np.ndarray, cfg: TrunkConfig, tokens: int) -> None:
+    """The step's ``(2, layers)`` counter → the stage table. Row 0,
+    ``retention_scan``: positions × layers of one fetched step mixed through
+    the carried state ÷ positions × layers of the step. Row 1,
+    ``retention_kernel``: those of them whose state products ran through the
+    kernels ÷ those scanned."""
+    scanned, through_kernel = (int(row.sum()) for row in np.asarray(counted))
+    tracer.add_occupancy('retention_scan', scanned,
                          int(tokens) * cfg.num_hidden_layers)
+    tracer.add_occupancy('retention_kernel', through_kernel, scanned)
 
 
 # -- blocks -------------------------------------------------------------------
 
-def retention_block(p: Params, prefix: str, x: jax.Array,
-                    cfg: TrunkConfig) -> Tuple[jax.Array, jax.Array]:
+def retention_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
+                    platform: Optional[str] = None
+                    ) -> Tuple[jax.Array, jax.Array]:
     """The mixer over one window: (S, D) normed input → (S, D), causal,
-    positions 0…S−1, from an empty state; and how many of the positions it
-    put through the state scan (what ``retention_scan`` counts)."""
+    positions 0…S−1, from an empty state; and a (2,) count: how many of the
+    positions it put through the state scan (what ``retention_scan``
+    counts) and how many of those through the kernels. ``platform`` is
+    where the graph will run (None: the default backend); with the head's
+    widths, the chunk and the ambient matmul precision it decides the form
+    of the state products (``ops.retention.resolve_retention``)."""
     with jax.named_scope('retention'):
         s = x.shape[0]
         h, g, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -191,46 +208,55 @@ def retention_block(p: Params, prefix: str, x: jax.Array,
         log_gate = jax.nn.log_sigmoid(
             (jnp.dot(x, p[f'{prefix}.g_proj.weight'])
              + p[f'{prefix}.g_proj.bias']).astype(jnp.float32))
-        y, _ = retention_chunked(q, k, v, log_gate, RETENTION_CHUNK)
-        scanned = jnp.int32(y.shape[0])
+        precision = jax.config.jax_default_matmul_precision
+        kernel = resolve_retention(platform or jax.default_backend(), d, d,
+                                   min(RETENTION_CHUNK, s),
+                                   precision) == 'kernel'
+        y, _ = retention_chunked(
+            q, k, v, log_gate, RETENTION_CHUNK,
+            kernel_passes=KERNEL_PASSES[precision] if kernel else None)
+        counted = jnp.array([s, s if kernel else 0], jnp.int32)
         return (jnp.dot(y.reshape(s, h * d), p[f'{prefix}.o_proj.weight']),
-                scanned)
+                counted)
 
 
-def hidden_states(params: Params, ids: jax.Array,
-                  cfg: TrunkConfig) -> Tuple[jax.Array, jax.Array]:
+def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
+                  platform: Optional[str] = None
+                  ) -> Tuple[jax.Array, jax.Array]:
     """(B, S) int32 ids → final-norm hidden states (B, S, D) and the
-    (layers,) positions each layer's mixer scanned. The mixer runs a window
-    at a time (each window has a state of its own); the feed-forward walks
-    all B·S tokens in row blocks."""
+    (2, layers) positions each layer's mixer scanned and put through the
+    kernels. The mixer runs a window at a time (each window has a state of
+    its own); the feed-forward walks all B·S tokens in row blocks."""
     b, s = ids.shape
     d = cfg.hidden_size
     eps = cfg.rms_norm_eps
     rows = MLP_ROWS if (b * s) % MLP_ROWS == 0 else None
     x = embed(params, ids)
-    scanned = []
+    counted = []
     for i in range(cfg.num_hidden_layers):
         p = f'model.layers.{i}'
         normed = rms_norm(x, params[f'{p}.input_layernorm.weight'], eps)
         mixed, n = lax.map(
-            lambda w: retention_block(params, f'{p}.self_attn', w, cfg),
+            lambda w: retention_block(params, f'{p}.self_attn', w, cfg,
+                                      platform),
             normed)
         x = x + mixed
-        scanned.append(n.sum())
+        counted.append(n.sum(axis=0))
         normed = rms_norm(x, params[f'{p}.post_attention_layernorm.weight'],
                           eps).reshape(b * s, d)
         with jax.named_scope('dense_mlp'):
             y = swiglu(normed, params, f'{p}.mlp', row_block=rows)
         x = x + y.reshape(b, s, d)
-    return final_norm(x, params, eps), jnp.stack(scanned)
+    return final_norm(x, params, eps), jnp.stack(counted, axis=1)
 
 
 def forward(params: Params, ids: jax.Array, cfg: TrunkConfig,
             platform: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
-    """(B, S) int32 ids → ``(features (B, D) float32, scanned (layers,)
+    """(B, S) int32 ids → ``(features (B, D) float32, counted (2, layers)
     int32)``: the mean of the window's final-norm hidden states, and how
     many of the batch's positions each layer's mixer put through the state
-    scan. ``platform`` is where the graph will run; every platform takes
-    the same XLA graph."""
-    hidden, scanned = hidden_states(params, ids, cfg)
-    return mean_features(hidden), scanned
+    scan and, of those, through the kernels. ``platform`` is where the
+    graph will run (None: the default backend): it is one of the things the
+    form of the scan's state products is chosen from (:func:`kernels`)."""
+    hidden, counted = hidden_states(params, ids, cfg, platform)
+    return mean_features(hidden), counted

@@ -29,6 +29,17 @@ chunks is padded up to one with zero keys and values (their weight is 0 and
 causality keeps every earlier position as it was) and cut back. The tests
 hold it to (1)–(2) and to (3)–(4), both written out there.
 
+The two products with φ — the state read ``φ(q)·S`` and the state update
+``φ(k)ᵀv`` — have two forms, chosen by :func:`resolve_retention` from the
+platform, the shapes and the ambient precision (static at trace time, no
+switch). XLA's writes φ out (:func:`power_features`) and contracts it with
+an einsum: the CPU path and the oracle. On a TPU at 128-wide heads they are
+the Mosaic kernels of ``ops/pallas_retention.py``, which form φ a 128-lane
+block at a time in VMEM beside the MXU product that consumes it (at
+brumby.corpus's widths φ of a chunk's queries is 676 MB that XLA writes to
+HBM and reads back; PERF.md §6, PR 32). The scan over chunks, the pairs
+inside a chunk, the normaliser and the division are XLA's in both.
+
 The normaliser is held as the symmetric ``d × d`` matrix ``Z_t = e^{γ_t}
 Z_{t−1} + k_t k_tᵀ`` whose upper triangle ``z`` is (the same 8,256 numbers at
 d = 128): ``z · φ(q) = qᵀ Z q``, a ``d``-wide product where the vector form
@@ -83,16 +94,52 @@ def init_state(g: int, d: int, d_v: int) -> State:
             jnp.zeros((g, d, d), jnp.float32))
 
 
+def resolve_retention(platform: str, d: int, d_v: int, chunk: int,
+                      precision: Optional[str]) -> str:
+    """Which form of the state products :func:`retention_chunked` compiles
+    for heads ``d`` / ``d_v`` wide and chunks of ``chunk`` positions on
+    ``platform`` under the ambient matmul ``precision``: 'kernel' (φ formed
+    in VMEM by the Mosaic kernels of ops/pallas_retention.py) or 'state'
+    (XLA's: φ written out and contracted by an einsum).
+
+    The kernels apply on a TPU, where both head widths are whole 128-lane
+    blocks (a block of φ is one rotation of a head's lanes), so is the
+    chunk (the update kernel holds a chunk's positions on the lanes; the
+    read kernel's row tile is then a whole divisor of a head's rows), a
+    head's state fits the read kernel's VMEM budget (d = 128 does, d = 256
+    does not) and the ambient precision is one the kernels have a lane for
+    (``ops.attention.KERNEL_PASSES``). Anywhere else — the CPU, where they
+    would run interpreted; narrow heads; a short or ragged chunk; 'highest'
+    — XLA's form runs, which is also the oracle the kernels are tested
+    against. All of it is static at trace time; there is no switch.
+    ``models/retention_trunk.py::retention_block``, the scan's one caller,
+    asks here."""
+    from video_features_tpu.ops import pallas_retention as kernel
+    from video_features_tpu.ops.attention import KERNEL_PASSES
+    if platform != 'tpu' or precision not in KERNEL_PASSES:
+        return 'state'
+    if (d % kernel.LANES or d_v % kernel.LANES or chunk % kernel.LANES
+            or kernel.state_vmem_bytes(d, d_v, KERNEL_PASSES[precision])
+            > kernel.STATE_VMEM_BYTES):
+        return 'state'
+    return 'kernel'
+
+
 def retention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
                       log_gate: jax.Array, chunk: int,
                       state: Optional[State] = None,
-                      eps: float = EPS) -> Tuple[jax.Array, State]:
+                      eps: float = EPS, kernel_passes: Optional[int] = None
+                      ) -> Tuple[jax.Array, State]:
     """The chunked form (module doc): ``(y (S, G, R, d_v), the state after
     the last position)``. ``state`` is the one to start from (None: empty,
     the window's first position sees itself alone); handing a window's
     final state to the next call continues the sequence. A window shorter
     than ``chunk`` is one chunk; a ragged tail is padded (module doc), which
-    leaves the state as the last real position left it."""
+    leaves the state as the last real position left it.
+
+    ``kernel_passes`` (1 or 3 bf16 passes a product; None: XLA's form) sends
+    the two products with φ through ``ops/pallas_retention.py``, where
+    :func:`resolve_retention` says it applies."""
     s, g, r, d = q.shape
     d_v = v.shape[-1]
     chunk = min(chunk, s)
@@ -102,9 +149,13 @@ def retention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
             jnp.pad(a, [(0, n * chunk - s)] + [(0, 0)] * (a.ndim - 1))
             for a in (q, k, v, log_gate))
     f32 = jnp.float32
+    if kernel_passes is not None:
+        from video_features_tpu.ops import pallas_retention
     # chunk-major, heads before positions: a head's rows are one slab
     qc = q.reshape(n, chunk, g, r, d).transpose(0, 2, 3, 1, 4)
     kc = k.reshape(n, chunk, g, d).transpose(0, 2, 1, 3)
+    # the update kernel reads a chunk's keys with positions on the lanes
+    ktc = None if kernel_passes is None else kc.astype(f32).swapaxes(2, 3)
     vc = v.reshape(n, chunk, g, d_v).transpose(0, 2, 1, 3)
     # the decay from the chunk's start up to and including each position
     gc = jnp.cumsum(log_gate.astype(f32).reshape(n, chunk, g), axis=1
@@ -114,7 +165,7 @@ def retention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
 
     def step(carry, blk):
         big_s, big_z = carry
-        qi, ki, vi, gi = blk
+        qi, ki, vi, gi, kti = blk
         # inside the chunk: (1)-(2), the decay as exp of a difference that
         # is masked before it is exponentiated (above the diagonal it is > 0)
         scores = jnp.einsum('grtd,gsd->grts', qi, ki,
@@ -125,10 +176,16 @@ def retention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
         num = jnp.einsum('grts,gsv->grtv', a, vi, preferred_element_type=f32)
         den = a.sum(axis=-1)
         # every earlier position, through the state at the chunk's start
-        phi_q = power_features(qi.astype(f32))
+        if kernel_passes is None:
+            from_state = jnp.einsum(
+                'grtD,gDv->grtv', power_features(qi.astype(f32)), big_s,
+                preferred_element_type=f32)
+        else:
+            from_state = pallas_retention.state_read(
+                qi.astype(f32).reshape(g, r * chunk, d), big_s,
+                kernel_passes).reshape(g, r, chunk, d_v)
         from_start = jnp.exp(gi)[:, None, :]                # (g, 1, t)
-        num = num + from_start[..., None] * jnp.einsum(
-            'grtD,gDv->grtv', phi_q, big_s, preferred_element_type=f32)
+        num = num + from_start[..., None] * from_state
         q_z = jnp.einsum('grtd,gde->grte', qi, big_z,
                          preferred_element_type=f32)
         den = den + from_start * (q_z * qi).sum(axis=-1)
@@ -137,15 +194,20 @@ def retention_chunked(q: jax.Array, k: jax.Array, v: jax.Array,
         # the chunk's end, the old state from the start to the end
         to_end = jnp.exp(gi[:, -1:] - gi)[..., None]        # (g, s, 1)
         whole = jnp.exp(gi[:, -1])[:, None, None]
-        big_s = whole * big_s + jnp.einsum(
-            'gsD,gsv->gDv', power_features(ki.astype(f32)) * to_end, vi,
-            preferred_element_type=f32)
+        if kernel_passes is None:
+            big_s = whole * big_s + jnp.einsum(
+                'gsD,gsv->gDv', power_features(ki.astype(f32)) * to_end, vi,
+                preferred_element_type=f32)
+        else:
+            big_s = pallas_retention.state_update(
+                big_s, whole[:, 0, 0], kti, vi.astype(f32) * to_end,
+                kernel_passes)
         big_z = whole * big_z + jnp.einsum(
             'gsd,gse->gde', ki * to_end, ki, preferred_element_type=f32)
         return (big_s, big_z), y.astype(q.dtype)
 
     carry = init_state(g, d, d_v) if state is None else state
-    carry, y = lax.scan(step, carry, (qc, kc, vc, gc))
+    carry, y = lax.scan(step, carry, (qc, kc, vc, gc, ktc))
     # (n, g, r, chunk, d_v) → (S, g, r, d_v)
     return y.transpose(0, 3, 1, 2, 4).reshape(n * chunk, g, r, d_v)[:s], carry
 

@@ -63,6 +63,7 @@ STAGES = (
     'moe_held',           # assignments on held experts ÷ all assignments
     # ... and the retention trunk's mixer
     'retention_scan',     # positions × layers through the carried state ÷ all
+    'retention_kernel',   # of those, through the state-product kernels
 )
 
 
