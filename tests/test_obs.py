@@ -674,7 +674,10 @@ CANONICAL_STAGES = {'decode', 'decode+preprocess', 'audio_dsp',
                     # mixed through the carried state
                     'retention_scan',
                     # PR 32: of those, through the state-product kernels
-                    'retention_kernel'}
+                    'retention_kernel',
+                    # PR 33, the lm family's hybrid trunk: the expert
+                    # walk's held assignments over the rows it computed
+                    'moe_walk'}
 
 
 def test_stage_vocabulary_contract():

@@ -12,8 +12,10 @@ save (``StackPackingMixin``, ``parallel/packing.py::run_packed``).
 
 **The trunk is chosen by ``model_type``**, the published ``config.json``'s
 key (``TRUNKS``): ``joyai_llm_flash`` — latent attention + sparse experts,
-``models/latent_moe.py``, what ``configs/lm.yml`` ships — or ``brumby`` —
-gated power retention, dense, ``models/retention_trunk.py``. A trunk module
+``models/latent_moe.py``, what ``configs/lm.yml`` ships —, ``brumby`` —
+gated power retention, dense, ``models/retention_trunk.py`` — or
+``lfm2_moe`` — gated short convolutions among grouped-query attention
+layers, sparse experts, ``models/hybrid_trunk.py``. A trunk module
 says what this file needs of it (``models/token_trunk.py`` lists the names):
 its config from the args, its parameters, its step's second output, what it
 notes in the manifest and which counters it fills. An unknown
@@ -29,7 +31,7 @@ decoder that is bit-exact gives the same ids.
 
 **What fits.** The build refuses, with the sizes, a trunk whose parameters
 exceed the device's memory, and says how that trunk is held in part: fewer
-layers (further pipeline stages) for both, and for the expert trunk a share
+layers (further pipeline stages) for all, and for the expert trunks a share
 of each layer's experts (the shipped yml is the whole published model, 48 B
 parameters).
 
@@ -37,8 +39,8 @@ Telemetry: the ``tokenise`` span; the ``kernels`` note of the run manifest
 (which causal attention path, or which form of the retention mixer and its
 chunk, the step compiled); and the trunk's counters on the stage table,
 filled from the step's second output at each readback — ``moe_route`` /
-``moe_held`` (expert trunk), ``retention_scan`` / ``retention_kernel``
-(retention trunk).
+``moe_held`` / ``moe_walk`` (the expert trunks), ``retention_scan`` /
+``retention_kernel`` (retention trunk).
 """
 from __future__ import annotations
 
@@ -60,6 +62,7 @@ TOKEN_HASH = 2654435761
 TRUNKS = {
     'joyai_llm_flash': 'video_features_tpu.models.latent_moe',
     'brumby': 'video_features_tpu.models.retention_trunk',
+    'lfm2_moe': 'video_features_tpu.models.hybrid_trunk',
 }
 
 

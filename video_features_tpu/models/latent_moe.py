@@ -92,14 +92,8 @@ class TrunkConfig:
     model_type = MODEL_TYPE
 
     def __post_init__(self):
-        held = (self.n_routed_experts if self.n_experts_held is None
-                else int(self.n_experts_held))
-        object.__setattr__(self, 'n_experts_held', held)
-        if not 0 < held <= self.n_routed_experts - self.first_expert:
-            raise ValueError(
-                f'n_experts_held={held} from first_expert='
-                f'{self.first_expert} does not lie inside the router\'s '
-                f'{self.n_routed_experts} experts')
+        object.__setattr__(self, 'n_experts_held', token_trunk.held_experts(
+            self.n_experts_held, self.first_expert, self.n_routed_experts))
         if self.num_experts_per_tok > self.n_routed_experts:
             raise ValueError('num_experts_per_tok exceeds n_routed_experts')
 
@@ -197,17 +191,10 @@ def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
 
 
 def count(tracer, counts: np.ndarray, cfg: TrunkConfig, tokens: int) -> None:
-    """Per layer: the held experts' mean load against the fullest one's
-    (the one the layer waits for) → ``moe_route``; how many of all
-    assignments fell on experts held here → ``moe_held``."""
-    counts = np.asarray(counts, np.int64)
-    if not counts.size:
-        return
-    layers, held = counts.shape
-    tracer.add_occupancy('moe_route', int(counts.sum()),
-                         int(counts.max(axis=1).sum()) * held)
-    tracer.add_occupancy('moe_held', int(counts.sum()),
-                         int(tokens) * cfg.num_experts_per_tok * layers)
+    """The step's per-expert counts → ``moe_route``, ``moe_held`` and
+    ``moe_walk`` (``token_trunk.count_experts``)."""
+    token_trunk.count_experts(tracer, counts, cfg.num_experts_per_tok, tokens,
+                              moe.BLOCK)
 
 
 # -- blocks -------------------------------------------------------------------
@@ -291,22 +278,21 @@ def _mla_kernel_path(p: Params, prefix: str, x: jax.Array, c_q: jax.Array,
 
 
 def expert_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
-                 moe_block: int = 256) -> Tuple[jax.Array, jax.Array]:
+                 moe_block: int = moe.BLOCK) -> Tuple[jax.Array, jax.Array]:
     """The expert layer's feed-forward over (T, D) tokens: the held
-    experts' share of the routed sum plus the shared expert. Returns the
-    output and the (held,) assignment counts."""
+    experts' share of the routed sum (``ops.moe.routed_experts``, under this
+    checkpoint's names) plus the shared expert. Returns the output and the
+    (held,) assignment counts."""
     with jax.named_scope('moe'):
-        experts, weights = moe.route(
+        y, counts = moe.routed_experts(
             x, p[f'{prefix}.gate.weight'],
             p[f'{prefix}.gate.e_score_correction_bias'],
-            top_k=cfg.num_experts_per_tok,
-            scaling=cfg.routed_scaling_factor,
-            normalise=cfg.norm_topk_prob)
-        y, counts = moe.moe_share(
-            x, experts, weights,
             p[f'{prefix}.experts.gate_proj.weight'],
             p[f'{prefix}.experts.up_proj.weight'],
             p[f'{prefix}.experts.down_proj.weight'],
+            top_k=cfg.num_experts_per_tok,
+            scaling=cfg.routed_scaling_factor,
+            normalise=cfg.norm_topk_prob, eps=1e-20,
             first=cfg.first_expert, block=moe_block)
         if cfg.n_shared_experts:
             y = y + swiglu(x, p, f'{prefix}.shared_experts')
@@ -314,7 +300,7 @@ def expert_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
 
 
 def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
-                  attn_block: int = 1024, moe_block: int = 256,
+                  attn_block: int = 1024, moe_block: int = moe.BLOCK,
                   platform: Optional[str] = None
                   ) -> Tuple[jax.Array, jax.Array]:
     """(B, S) int32 ids → ``(final-norm hidden states (B, S, D), counts)``.
@@ -351,7 +337,7 @@ def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
 
 
 def forward(params: Params, ids: jax.Array, cfg: TrunkConfig,
-            attn_block: int = 1024, moe_block: int = 256,
+            attn_block: int = 1024, moe_block: int = moe.BLOCK,
             platform: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
     """(B, S) int32 ids → ``(features (B, D) float32, counts)``: the mean
     of the window's final-norm hidden states (:func:`hidden_states`)."""

@@ -37,7 +37,7 @@ from jax import lax
 
 from video_features_tpu.models import token_trunk
 from video_features_tpu.models.token_trunk import (
-    Params, embed, final_norm, mean_features, rms_norm, swiglu,
+    Params, embed, final_norm, mean_features, mlp_rows, rms_norm, swiglu,
 )
 from video_features_tpu.ops.attention import KERNEL_PASSES, rotary_half
 from video_features_tpu.ops.retention import (
@@ -62,9 +62,6 @@ CONFIG_KEYS = (
 # no option (the scan alone reads level at 256 / 512 / 1,024 on the chip, the
 # whole step 16 % slower at 1,024: PERF.md §5)
 RETENTION_CHUNK = 512
-# rows the feed-forward walks at a time: its two intermediates stand as
-# (rows, intermediate_size) float32, 285 MB each at the published width
-MLP_ROWS = 4096
 
 
 @dataclass(frozen=True)
@@ -230,7 +227,9 @@ def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
     b, s = ids.shape
     d = cfg.hidden_size
     eps = cfg.rms_norm_eps
-    rows = MLP_ROWS if (b * s) % MLP_ROWS == 0 else None
+    # in row blocks: the two intermediates stand as (rows, intermediate_size)
+    # float32, 285 MB each at the published width
+    rows = mlp_rows(b * s)
     x = embed(params, ids)
     counted = []
     for i in range(cfg.num_hidden_layers):

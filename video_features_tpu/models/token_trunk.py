@@ -1,11 +1,14 @@
 """What every token trunk of the ``lm`` family is made of.
 
 The family's trunks (``models/latent_moe.py``: latent attention + sparse
-experts; ``models/retention_trunk.py``: gated power retention, dense) are
-pre-norm residual decoders over token ids that differ in their sequence
-mixer and their feed-forward's routing. The rest is here, once: RMSNorm, the
-SwiGLU, the embedding lookup, the pooled output, and the seeded draw of a
-parameter set.
+experts; ``models/retention_trunk.py``: gated power retention, dense;
+``models/hybrid_trunk.py``: gated short convolutions among grouped-query
+attention layers, sparse experts) are pre-norm residual decoders over token
+ids that differ in their sequence mixers and their feed-forward's routing.
+The rest is here, once: RMSNorm, the SwiGLU, the embedding lookup, the
+pooled output, the seeded draw of a parameter set, and for the expert
+trunks the check of a held share and the stage-table counters of a layer's
+routing.
 
 A trunk module offers ``extract/lm.py`` a few names and nothing else:
 
@@ -31,6 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from video_features_tpu.ops.moe import walk_rows
+
 Params = Dict[str, jax.Array]
 
 
@@ -40,17 +45,27 @@ def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
     return (x32 * lax.rsqrt(var + eps) * gain).astype(x.dtype)
 
 
+# a SwiGLU's three matrices under the names most checkpoints give them
+# (gate, up, down); LFM2's are w1, w3, w2
+SWIGLU_NAMES = ('gate_proj', 'up_proj', 'down_proj')
+# rows a row-blocked feed-forward walks at a time (:func:`mlp_rows`)
+MLP_ROWS = 4096
+
+
 def swiglu(x: jax.Array, p: Params, prefix: str,
-           row_block: Optional[int] = None) -> jax.Array:
+           row_block: Optional[int] = None,
+           names: Tuple[str, str, str] = SWIGLU_NAMES) -> jax.Array:
     """``W_down(silu(W_gate x) ⊙ W_up x)`` over (T, D) tokens. With
     ``row_block`` the tokens are walked that many rows at a time (T a
     multiple of it), so the two intermediates stand as (row_block, F) and
     never as (T, F): 32,768 tokens × 17,408 wide are 2.3 GB each."""
+    gate_name, up_name, down_name = names
+
     def rows(x):
-        gate = jnp.dot(x, p[f'{prefix}.gate_proj.weight'])
-        up = jnp.dot(x, p[f'{prefix}.up_proj.weight'])
+        gate = jnp.dot(x, p[f'{prefix}.{gate_name}.weight'])
+        up = jnp.dot(x, p[f'{prefix}.{up_name}.weight'])
         return jnp.dot(jax.nn.silu(gate) * up,
-                       p[f'{prefix}.down_proj.weight'])
+                       p[f'{prefix}.{down_name}.weight'])
 
     t = x.shape[0]
     if not row_block or t <= row_block:
@@ -67,9 +82,17 @@ def embed(params: Params, ids: jax.Array) -> jax.Array:
     return params['model.embed_tokens.weight'][ids]
 
 
-def final_norm(x: jax.Array, params: Params, eps: float) -> jax.Array:
-    """(B, S, D) residual stream → the final RMSNorm's hidden states."""
-    return rms_norm(x, params['model.norm.weight'], eps)
+def mlp_rows(tokens: int) -> Optional[int]:
+    """The row block of a step's dense feed-forward: ``MLP_ROWS`` where the
+    step's tokens are a whole number of them, else None (all at once)."""
+    return MLP_ROWS if tokens % MLP_ROWS == 0 else None
+
+
+def final_norm(x: jax.Array, params: Params, eps: float,
+               name: str = 'model.norm.weight') -> jax.Array:
+    """(B, S, D) residual stream → the final RMSNorm's hidden states
+    (``name``: the gain under the checkpoint's own name)."""
+    return rms_norm(x, params[name], eps)
 
 
 def mean_features(hidden: jax.Array) -> jax.Array:
@@ -100,3 +123,36 @@ def draw_params(shapes: Dict[str, Tuple[int, ...]], seed: int,
                 w *= np.float32(1.0 / math.sqrt(shape[-2]))
         out[name] = w
     return out
+
+
+def held_experts(n_experts_held: Optional[int], first_expert: int,
+                 n_routed: int) -> int:
+    """How many of a layer's ``n_routed`` experts are held here from
+    ``first_expert`` on (``n_experts_held`` None: all of them); a share
+    that does not lie inside the router's range is refused."""
+    held = n_routed if n_experts_held is None else int(n_experts_held)
+    if not 0 < held <= n_routed - first_expert:
+        raise ValueError(
+            f'n_experts_held={held} from first_expert={first_expert} does '
+            f'not lie inside the router\'s {n_routed} experts')
+    return held
+
+
+def count_experts(tracer, counts: np.ndarray, top_k: int, tokens: int,
+                  block: int) -> None:
+    """One fetched step's ``(expert layers, held)`` assignment counts → the
+    stage table. Per layer: the held experts' mean load against the fullest
+    one's (the one the layer waits for) → ``moe_route``; how many of all
+    assignments fell on experts held here → ``moe_held``; the held
+    assignments against the rows the block walk computed for them (each
+    expert's rounded up to whole blocks of ``block``) → ``moe_walk``."""
+    counts = np.asarray(counts, np.int64)
+    if not counts.size:
+        return
+    layers, held = counts.shape
+    assigned = int(counts.sum())
+    tracer.add_occupancy('moe_route', assigned,
+                         int(counts.max(axis=1).sum()) * held)
+    tracer.add_occupancy('moe_held', assigned, int(tokens) * top_k * layers)
+    tracer.add_occupancy('moe_walk', assigned,
+                         int(walk_rows(counts, block).sum()))

@@ -9,10 +9,12 @@ sharded over a mesh axis and attended with **ring attention** — KV shards
 rotate around the ring via ``lax.ppermute`` (ICI neighbor exchange, no
 all-gather) while each device accumulates its queries' online softmax.
 
-Causal language trunks (``models/latent_moe.py``) take the same blockwise
-path with ``causal=True``: queries are tiled too, a query tile scans only the
-key tiles at or before it, and the value head may be narrower than the
-query/key head (latent attention: 192-wide q/k, 128-wide v).
+Causal language trunks (``models/latent_moe.py``, ``models/hybrid_trunk.py``)
+take the same blockwise path with ``causal=True``: queries are tiled too, a
+query tile scans only the key tiles at or before it, the value head may be
+narrower than the query/key head (latent attention: 192-wide q/k, 128-wide
+v), and keys and values may have fewer heads than the queries
+(grouped-query attention: 32 query heads reading 8 key-value heads).
 :func:`rotary_interleaved` is their position code; :func:`rotary_half` is
 the half-split form of the same rotation (``models/retention_trunk.py``).
 
@@ -117,8 +119,11 @@ def resolve_causal(platform: str, s: int, qk_dim: int, v_dim: int,
     'highest' — the XLA path runs, which is also the oracle the kernel is
     tested against. All of it is static at trace time, so the choice
     compiles away; there is no switch. Latent attention
-    (``models/latent_moe.py::mla_block``, the causal path's one caller) asks
-    here and hands the kernel its heads as column groups."""
+    (``models/latent_moe.py::mla_block``) asks here and hands the kernel its
+    heads as column groups. The kernel takes equal head counts: grouped-query
+    heads (``models/hybrid_trunk.py``: 64-wide, for which the answer is 'xla'
+    by the value head's width already) have no lane in it and run the XLA
+    tiles."""
     from video_features_tpu.ops import pallas_attention as kernel
     if platform != 'tpu' or precision not in KERNEL_PASSES:
         return 'xla'
@@ -143,7 +148,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     for high-resolution inputs past BLOCKWISE_THRESHOLD tokens.
 
     ``causal=True`` (self-attention, S a block multiple): position i sees
-    keys 0…i. ``v`` may have another head width than ``q``/``k``.
+    keys 0…i. ``v`` may have another head width than ``q``/``k``, and ``k``
+    and ``v`` a whole fraction of ``q``'s heads (grouped-query).
     """
     if causal:
         return _causal_blockwise(q, k, v, block_size, _scale(q, scale))
@@ -178,7 +184,14 @@ def _causal_blockwise(q, k, v, block_size: int, scale: float) -> jax.Array:
     """Causal self-attention, tiled both ways: query tile i scans key tiles
     0…i-1 unmasked and then its own diagonal tile under the triangle, so
     the tiles above the diagonal cost nothing (a scan over all keys with a
-    mask would compute, and throw away, half of S²)."""
+    mask would compute, and throw away, half of S²).
+
+    Grouped-query heads (``k`` and ``v`` with fewer heads than ``q``, query
+    head j reading key-value head ``j div group``) ride the query axis: a
+    position's ``group`` query heads of one key-value head are laid side by
+    side as ``group`` query rows of that head, so the tiles' products keep
+    one head count, no key or value is copied, and a key tile is read once
+    for the whole group."""
     b, s, h, _ = q.shape
     if k.shape[1] != s:
         raise ValueError(f'causal attention is self-attention: q has {s} '
@@ -187,15 +200,27 @@ def _causal_blockwise(q, k, v, block_size: int, scale: float) -> jax.Array:
     if s % block_size:
         raise ValueError(f'causal attention needs the sequence ({s}) to be '
                          f'a multiple of block_size ({block_size})')
+    kv_heads = k.shape[2]
+    if h % kv_heads:
+        raise ValueError(f'{h} query heads are no whole number of groups '
+                         f'of {kv_heads} key-value heads')
+    group = h // kv_heads
     n_blocks = s // block_size
-    kb = k.reshape(b, n_blocks, block_size, h, -1).swapaxes(0, 1)
-    vb = v.reshape(b, n_blocks, block_size, h, -1).swapaxes(0, 1)
+    kb = k.reshape(b, n_blocks, block_size, kv_heads, -1).swapaxes(0, 1)
+    vb = v.reshape(b, n_blocks, block_size, kv_heads, -1).swapaxes(0, 1)
     pos = jnp.arange(block_size)
-    triangle = (pos[:, None] >= pos[None, :])[:, None, :]   # (q, 1, k)
+    q_pos, q_rows = pos, block_size
+    if group > 1:
+        # (b, s, kv·group, d) → (b, s·group, kv, d): row p·group + r is
+        # position p's query head kv·group + r
+        q = q.reshape(b, s, kv_heads, group, -1).swapaxes(2, 3).reshape(
+            b, s * group, kv_heads, -1)
+        q_pos, q_rows = jnp.repeat(pos, group), block_size * group
+    triangle = (q_pos[:, None] >= pos[None, :])[:, None, :]   # (q, 1, k)
 
     out = []
     for i in range(n_blocks):
-        qi = q[:, i * block_size:(i + 1) * block_size]
+        qi = q[:, i * q_rows:(i + 1) * q_rows]
 
         def step(carry, blk, qi=qi):
             return _online_block(qi, *carry, *blk, scale), None
@@ -206,7 +231,11 @@ def _causal_blockwise(q, k, v, block_size: int, scale: float) -> jax.Array:
         _, l, o = _online_block(qi, *carry, kb[i], vb[i], scale,
                                 valid=triangle)
         out.append(o / l)
-    return jnp.concatenate(out, axis=1).astype(q.dtype)
+    out = jnp.concatenate(out, axis=1)
+    if group > 1:
+        out = out.reshape(b, s, group, kv_heads, -1).swapaxes(2, 3).reshape(
+            b, s, h, -1)
+    return out.astype(q.dtype)
 
 
 def rotary_interleaved(x: jax.Array, positions: jax.Array,

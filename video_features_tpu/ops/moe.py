@@ -29,6 +29,11 @@ The pieces, in the order a layer uses them:
   expert.
 * :func:`combine` — each token's weighted sum over its held assignments, by
   gather (a scatter-add of rows is the slow direction on a TPU).
+
+:func:`routed_experts` is those four in a row, what an expert trunk calls
+(``models/latent_moe.py``, ``models/hybrid_trunk.py``);
+:func:`walk_rows` says how many rows the block walk computed for given
+counts, which is what its padding costs.
 """
 from __future__ import annotations
 
@@ -38,21 +43,27 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+# rows a step of the block walk computes (:func:`grouped_swiglu`): the
+# program's, no published key and no option
+BLOCK = 256
+
 
 def route(x: jax.Array, w_router: jax.Array, bias: jax.Array, *,
-          top_k: int, scaling: float, normalise: bool = True
-          ) -> Tuple[jax.Array, jax.Array]:
+          top_k: int, scaling: float, normalise: bool = True,
+          eps: float = 1e-20) -> Tuple[jax.Array, jax.Array]:
     """(T, D) tokens → ``(experts (T, top_k) int32, weights (T, top_k))``.
 
     ``w_router`` is (D, n_routed), ``bias`` (n_routed,) the load-balancing
-    correction: it moves the choice, the weights are the raw scores."""
+    correction: it moves the choice, the weights are the raw scores, over
+    their sum + ``eps`` where normalised (the constant is the model's:
+    DeepSeek-V3's 1e-20, LFM2's 1e-6)."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
     _, experts = lax.top_k(scores + bias.astype(jnp.float32), top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if normalise:
-        weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(axis=-1, keepdims=True) + eps)
     return experts.astype(jnp.int32), weights * scaling
 
 
@@ -76,7 +87,7 @@ def dispatch(experts: jax.Array, first: int, n_held: int
 
 def grouped_swiglu(x: jax.Array, order: jax.Array, counts: jax.Array,
                    w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-                   top_k: int, block: int = 256) -> jax.Array:
+                   top_k: int, block: int = BLOCK) -> jax.Array:
     """``down_e(silu(gate_e x) * up_e x)`` for every held assignment.
 
     ``x`` (T, D); ``w_gate``/``w_up`` (E, D, F), ``w_down`` (E, F, D).
@@ -129,7 +140,7 @@ def combine(rows: jax.Array, rank: jax.Array, experts: jax.Array,
 
 def moe_share(x: jax.Array, experts: jax.Array, weights: jax.Array,
               w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
-              first: int, block: int = 256
+              first: int, block: int = BLOCK
               ) -> Tuple[jax.Array, jax.Array]:
     """The routed part of a layer's output that the held experts give:
     ``(y (T, D), counts (n_held,) int32)``. The held experts are
@@ -141,3 +152,25 @@ def moe_share(x: jax.Array, experts: jax.Array, weights: jax.Array,
                           block)
     y = combine(rows, rank, experts, weights.astype(x.dtype), first, n_held)
     return y, counts
+
+
+def routed_experts(x: jax.Array, w_router: jax.Array, bias: jax.Array,
+                   w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array, *,
+                   top_k: int, scaling: float, normalise: bool, eps: float,
+                   first: int, block: int = BLOCK
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """An expert layer's routed sum over (T, D) tokens, as far as the held
+    experts give it (:func:`route`, then :func:`moe_share`): ``(y (T, D),
+    counts (held,) int32)``. A shared expert, where a model has one, is its
+    trunk's to add."""
+    experts, weights = route(x, w_router, bias, top_k=top_k, scaling=scaling,
+                             normalise=normalise, eps=eps)
+    return moe_share(x, experts, weights, w_gate, w_up, w_down,
+                     first=first, block=block)
+
+
+def walk_rows(counts, block: int = BLOCK):
+    """Rows the block walk computes for per-expert ``counts`` (any array
+    library's integers, last axis the experts): each held expert's
+    assignments rounded up to whole blocks."""
+    return (counts + block - 1) // block * block

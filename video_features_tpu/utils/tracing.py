@@ -61,6 +61,7 @@ STAGES = (
     # lm step itself returns: the expert trunk's routing ...
     'moe_route',          # mean ÷ largest load on one held expert
     'moe_held',           # assignments on held experts ÷ all assignments
+    'moe_walk',           # held assignments ÷ rows the block walk computed
     # ... and the retention trunk's mixer
     'retention_scan',     # positions × layers through the carried state ÷ all
     'retention_kernel',   # of those, through the state-product kernels
