@@ -102,3 +102,64 @@ def test_the_retention_scan_compiles_at_the_cells_widths(compiled_scan, form,
         assert '%retention_read' in text and '%retention_update' in text
         assert 'f32[8,5,512,8256]' not in text and '8256]' not in text
         assert temp < xla.memory_analysis().temp_size_in_bytes // 4
+
+
+def _while_body(text):
+    """The lines of the module's one while body and of every computation it
+    calls (its fusions), from the compiled module's text."""
+    import re
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r'^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$', line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith('}'):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    bodies = re.findall(r' while\(.*body=%?([\w.\-]+)', text)
+    assert len(bodies) == 1, bodies
+    top = comps[bodies[0]]
+    called = [line for outer in top
+              for inner in re.findall(r'calls=%?([\w.\-]+)', outer)
+              for line in comps[inner]]
+    return top, called
+
+
+def test_rafts_update_scan_compiles_without_a_two_channel_tensor(one_chip):
+    """i3d.corpus's `_refine` — 128 pairs at 32×43, 20 updates, three passes
+    (precision=mixed): four Mosaic calls a lookup by name, and in the while
+    body no convolution over or onto an operand whose minor axis is the 2
+    flow components, no channel-minor (B, 32, 43, 2) tensor and so no copy
+    of one: the carry is planes, batch on the lanes."""
+    import re
+
+    from video_features_tpu.models import raft
+    from video_features_tpu.transplant.torch2jax import transplant
+
+    def sds(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, jnp.float32, sharding=one_chip), tree)
+
+    params = sds({'update_block': jax.eval_shape(
+        lambda: transplant(raft.init_state_dict()))['update_block']})
+    fmap = jax.ShapeDtypeStruct((128, 32, 43, 256), jnp.float32,
+                                sharding=one_chip)
+
+    def refine(p, fmap1, fmap2, cnet):
+        with jax.default_matmul_precision('high'):
+            return raft._refine(p, fmap1, fmap2, cnet, 20, 'tpu')
+
+    text = jax.jit(refine).lower(params, fmap, fmap, fmap).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert len(re.findall(r'%raft_corr_lookup_lanes[.\d]* = ', text)) == 4
+    top, called = _while_body(text)
+    convs = [line for line in top + called if ' convolution(' in line]
+    assert len(convs) == 11                 # the update block's, all found
+    for line in convs:
+        shapes = re.findall(r'f32\[([\d,]+)\]', line)
+        assert not any(s.endswith(',2') for s in shapes), line
+    assert not any('f32[128,32,43,2]{3,' in line for line in top), [
+        line for line in top if 'f32[128,32,43,2]{3,' in line]
+    assert any('f32[2,128,32,43]{1,0,3,2' in line for line in top)

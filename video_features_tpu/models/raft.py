@@ -17,7 +17,11 @@ corr.py). TPU-native design choices:
     bilinear sample with ``align_corners=True`` / zeros-padding semantics
     (utils/utils.py:58-72 wraps grid_sample the same way);
   * convex 8× upsampling (raft.py:103-115) is a softmax-weighted sum over
-    3×3 flow patches, channels-last.
+    3×3 flow patches, channels-last;
+  * inside the scan the 2 flow components are never a channel axis:
+    coordinates and flow travel as two (B, H/8, W/8) planes (see
+    :func:`_refine`), because a 2-wide minor axis fills 2 of the TPU's 128
+    lanes and a convolution over or onto 2 channels leaves the MXU empty.
 
 Params mirror the torch state_dict (fnet./cnet./update_block. prefixes).
 Instance norms are affine-less (torch default) and carry no params.
@@ -34,7 +38,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from video_features_tpu.ops.nn import avg_pool, batch_norm, conv, instance_norm, relu
+from video_features_tpu.ops.nn import (avg_pool, batch_norm, conv,
+                                       conv_from_planes, conv_to_planes,
+                                       instance_norm, relu)
 
 Params = Dict[str, Any]
 
@@ -231,12 +237,31 @@ def _conv_b(p: Params, x: jax.Array, padding=0) -> jax.Array:
 
 
 def motion_encoder(p: Params, flow: jax.Array, corr: jax.Array) -> jax.Array:
+    """BasicMotionEncoder (reference update.py:79-97). ``flow`` comes as
+    its two planes, (2, B, H, W) — x then y; ``corr`` and the result are
+    channels-last: (B, H, W, 126 + 2), the flow in the last two channels.
+
+    Neither end treats the flow as a 2-channel tensor: ``convf1`` (7×7,
+    2 → 128) folds W's taps into 14 channels (:func:`conv_from_planes`),
+    and the closing concatenation is an ADD — the last convolution is
+    padded to 128 outputs with two zero kernels (``relu(0) = 0``) and the
+    flow is added into them, exactly: with no 2-channel operand beside it
+    the compiler keeps the GRU batch-minor and needs no transposed copy of
+    the 126 channels.
+    """
     cor = relu(_conv_b(p['convc1'], corr))
     cor = relu(_conv_b(p['convc2'], cor, padding=1))
-    flo = relu(_conv_b(p['convf1'], flow, padding=3))
+    with jax.named_scope('raft_convf1'):
+        flo = relu(conv_from_planes(flow, p['convf1']['weight'],
+                                    bias=p['convf1']['bias']))
     flo = relu(_conv_b(p['convf2'], flo, padding=1))
-    out = relu(_conv_b(p['conv'], jnp.concatenate([cor, flo], -1), padding=1))
-    return jnp.concatenate([out, flow], -1)
+    c = flow.shape[0]
+    w = jnp.pad(p['conv']['weight'], [(0, 0), (0, 0), (0, 0), (0, c)])
+    b = jnp.pad(p['conv']['bias'], [(0, c)])
+    out = relu(conv(jnp.concatenate([cor, flo], -1), w, padding=1, bias=b))
+    with jax.named_scope('raft_coords'):
+        return out + jnp.pad(jnp.moveaxis(flow, 0, -1),
+                             [(0, 0), (0, 0), (0, 0), (out.shape[-1] - c, 0)])
 
 
 GRU_PADS = (('1', ((0, 0), (2, 2))), ('2', ((2, 2), (0, 0))))
@@ -333,6 +358,11 @@ def coords_grid(B: int, H: int, W: int, dtype=jnp.float32) -> jax.Array:
     y, x = jnp.meshgrid(jnp.arange(H, dtype=dtype), jnp.arange(W, dtype=dtype),
                         indexing='ij')
     return jnp.broadcast_to(jnp.stack([x, y], -1), (B, H, W, 2))
+
+
+def coords_planes(B: int, H: int, W: int, dtype=jnp.float32) -> jax.Array:
+    """:func:`coords_grid` as (2, B, H, W): the x plane, then the y plane."""
+    return jnp.moveaxis(coords_grid(B, H, W, dtype), -1, 0)
 
 
 # The lanes kernel keeps one (h, w, LANES) f32 corr block per grid step in
@@ -481,7 +511,20 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
     thread their resolved device's platform instead (a CPU-committed call
     in a TPU-default process must not get the Mosaic lanes kernel).
     ``pins`` optionally overrides matmul precision per sub-graph
-    (ops/precision.py): 'corr', 'iter', 'upsample'."""
+    (ops/precision.py): 'corr', 'iter', 'upsample'.
+
+    Through the scan the coordinates travel as PLANES, ``(2, B, H8, W8)``
+    — x then y — and ``(B, H8, W8, 2)`` appears again only after it, for
+    :func:`upsample_flow`. With the 2 components minor, the carry filled 2
+    of 128 lanes (1.4 MB held in 90 MB), was copied between three layouts
+    an update, and made ``convf1`` 49 MXU products 2 lanes wide. As planes
+    the compiler lays the carry out batch-minor, which is how the flow
+    head's last convolution writes it (:func:`conv_to_planes`); the lanes
+    lookup takes each plane, flattened, as the (1, N) vector its kernel
+    wants; :func:`motion_encoder` reads and re-emits the flow without a
+    2-channel tensor. One form at every size and on every platform; the
+    'dense' and 'gather' lookups keep their (B, H, W, 2) signature and are
+    converted to at that boundary (scope ``raft_coords``)."""
     from video_features_tpu.ops.precision import pin_scope
     platform = platform or jax.default_backend()
     if platform not in ('tpu', 'cpu', 'gpu'):
@@ -496,7 +539,7 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
     # + zeros_like keeps shard_map's varying-axes type: constant carry
     # inits must match the varying outputs of the scan body when _refine
     # runs inside a shard_map shard (the add folds away otherwise)
-    coords0 = coords_grid(B, H8, W8) + jnp.zeros_like(fmap1[..., :2])
+    coords0 = coords_planes(B, H8, W8) + jnp.zeros_like(fmap1[..., 0])
     up = params['update_block']
 
     impl = resolve_lookup(H8, W8, platform)
@@ -508,14 +551,19 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
         with pin_scope(pins, 'corr'):
             prepped = pallas_corr.prep_pyramid_lanes_fused(
                 fmap1, fmap2, levels=CORR_LEVELS)
-        lookup = partial(pallas_corr.lookup_corr_lanes, prepped,
+        lookup = partial(pallas_corr.lookup_corr_planes, prepped,
                          radius=CORR_RADIUS,
                          interpret=_pallas_interpret(platform))
     else:
         with pin_scope(pins, 'corr'):
             pyramid = build_corr_pyramid(fmap1, fmap2)
-        lookup = partial(lookup_corr if impl == 'gather'
-                         else lookup_corr_dense, pyramid)
+        by_grid = partial(lookup_corr if impl == 'gather'
+                          else lookup_corr_dense, pyramid)
+
+        def lookup(planes):
+            with jax.named_scope('raft_coords'):
+                coords = jnp.moveaxis(planes, 0, -1)
+            return by_grid(coords)
 
     fh, mk = up['flow_head'], up['mask']
     gru = fuse_gru_params(up['gru'])
@@ -543,7 +591,8 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
                         net_new = sep_conv_gru(gru, gru_terms, net, motion)
                     with pin_scope(pins, 'iter_head'):
                         t = relu(_conv_b(fh['conv1'], net_new, padding=1))
-                        delta = _conv_b(fh['conv2'], t, padding=1)
+                        delta = conv_to_planes(t, fh['conv2']['weight'],
+                                               bias=fh['conv2']['bias'])
                     coords1_new = coords1 + delta
             return (net_new, coords1_new), None
         return step
@@ -573,8 +622,10 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
     with pin_scope(pins, 'iter'):
         t_mask = relu(_conv_b(mk['0'], net, padding=1))
         mask = 0.25 * _conv_b(mk['2'], t_mask)
+    with jax.named_scope('raft_coords'):
+        flow = jnp.moveaxis(coords1 - coords0, 0, -1)
     with pin_scope(pins, 'upsample'):
-        return upsample_flow(coords1 - coords0, mask)
+        return upsample_flow(flow, mask)
 
 
 def pad_to_multiple(x: jax.Array, mode: str = 'sintel',

@@ -15,6 +15,10 @@ Numerics parity notes (vs torch, for checkpoint-transplant fidelity):
     taps into channels and runs at stride 1 — the same products and sums.
     ``conv`` is not the place for that test: a branch inside it would
     re-lower every family's program, so a model opts in at the call site.
+  * conv_from_planes / conv_to_planes: the same pathology at stride 1 —
+    RAFT's 2 flow components as a convolution's input or output channels —
+    answered by keeping the few channels as planes and folding taps into
+    channels on the way in, into output planes on the way out.
   * batch norm is inference-only: y = (x - mean) / sqrt(var + eps) * γ + β
     with running statistics — matches torch .eval() semantics.
   * max pool with ceil_mode / TF-SAME is built from explicit -inf padding.
@@ -157,6 +161,62 @@ def conv_space_to_depth(x: Array, kernel: Array, stride: IntOrTuple = 1,
                               zip(taps, strides, sizes)] + [(0, 0), (0, 0)])
     kernel = phases_to_channels(kernel, 0, taps)
     return conv(x, kernel, 1, 'VALID', bias=bias)
+
+
+def conv_from_planes(planes: Array, kernel: Array,
+                     bias: Optional[Array] = None) -> Array:
+    """``conv(x, kernel, padding=k // 2)`` at stride 1 for an input of FEW
+    channels that is held as planes — (C, B, H, W) in, (B, H, W, O) out:
+    the same products and the same sums, the order of summation aside.
+
+    As channels-last the C components would be the minor axis — C of the
+    TPU's 128 lanes, the tensor padded 128 / C times over — and each of the
+    ``kh·kw`` taps an MXU product C wide. Here W's ``kw`` taps become
+    channels (``kw`` shifted slices of every plane side by side: ``kw·C``
+    channels, built batch-minor, W's shift a stride between rows) and a
+    ``kh × 1`` convolution over H is left. RAFT's ``convf1`` — 7×7 over the
+    2 flow components → 128 — becomes 7×1 over 14. Measured beside the
+    other folds (PERF.md §6, PR 34): all 98 taps stacked for one product
+    costs twice this one's time, in the 69 MB stack; a Toeplitz product
+    along W is slower than the convolution it replaces. The kernel keeps
+    its (kh, kw, C, O) checkpoint layout and is reshaped here, inside the
+    jitted step; sizes are odd.
+    """
+    kh, kw, c, o = kernel.shape
+    assert kh % 2 and kw % 2 and planes.shape[0] == c, (kernel.shape,
+                                                         planes.shape)
+    w = planes.shape[-1]
+    x = jnp.pad(planes, [(0, 0), (0, 0), (0, 0), (kw // 2, kw // 2)])
+    taps = jnp.stack([x[ch, :, :, j:j + w] for j in range(kw)
+                      for ch in range(c)], axis=-1)          # (B, H, W, kw·C)
+    return conv(taps, kernel.reshape(kh, 1, kw * c, o),
+                padding=[(kh // 2, kh // 2), (0, 0)], bias=bias)
+
+
+def conv_to_planes(x: Array, kernel: Array,
+                   bias: Optional[Array] = None) -> Array:
+    """``conv(x, kernel, padding=k // 2)`` at stride 1 onto FEW output
+    channels, returned as planes — (B, H, W, C) in, (O, B, H, W) out: the
+    mirror of :func:`conv_from_planes`, the same products and sums.
+
+    A convolution onto O channels fills O of the MXU's 128 output columns
+    once a tap. Here ONE 1×1 product writes all ``kh·kw·O`` partial planes
+    (x is read once) and the taps are ``kh·kw`` shifted adds of planes,
+    which are lane-dense. RAFT's flow head (3×3, 256 → 2) becomes one
+    product onto 18 planes and 9 adds.
+    """
+    kh, kw, c, o = kernel.shape
+    assert kh % 2 and kw % 2, kernel.shape
+    b, h, w, _ = x.shape
+    folded = kernel.transpose(2, 0, 1, 3).reshape(c, kh * kw * o)
+    parts = jnp.einsum('bhwc,ck->kbhw', x, folded.astype(x.dtype))
+    parts = jnp.pad(parts.reshape(kh, kw, o, b, h, w),
+                    [(0, 0)] * 4 + [(kh // 2, kh // 2), (kw // 2, kw // 2)])
+    out = sum(parts[i, j, :, :, i:i + h, j:j + w]
+              for i in range(kh) for j in range(kw))
+    if bias is not None:
+        out = out + bias.astype(out.dtype)[:, None, None, None]
+    return out
 
 
 def batch_norm(x: Array, p: Dict[str, Array], eps: float = 1e-5) -> Array:

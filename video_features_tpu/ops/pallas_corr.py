@@ -133,15 +133,14 @@ def _lanes_kernel(p1: int, h: int, w: int):
     return kernel
 
 
-def _lookup_level_lanes(corr_t: jax.Array, coords: jax.Array, radius: int,
-                        interpret: bool) -> jax.Array:
-    """One (h, w, N') level + (N, 2) coords → (N, (2r+1)²)."""
-    n = coords.shape[0]
+def _lookup_level_lanes(corr_t: jax.Array, x: jax.Array, y: jax.Array,
+                        radius: int, interpret: bool) -> jax.Array:
+    """One (h, w, N') level + the (N,) x and y of its centroids →
+    (N, (2r+1)²)."""
+    n = x.shape[0]
     h, w, n_pad = corr_t.shape
     p1 = 2 * radius + 1
 
-    x = coords[:, 0]
-    y = coords[:, 1]
     x0 = jnp.floor(x)
     y0 = jnp.floor(y)
     xi = x0.astype(jnp.int32)[None, :]                   # window base (x)
@@ -171,15 +170,26 @@ def _lookup_level_lanes(corr_t: jax.Array, coords: jax.Array, radius: int,
     return out[:, :n].T                                  # (N, 81)
 
 
-def lookup_corr_lanes(prepped: Sequence[jax.Array], coords: jax.Array,
-                      radius: int = 4, interpret: bool = False) -> jax.Array:
-    """Lane-packed lookup over a :func:`prep_pyramid_lanes` pyramid.
+def lookup_corr_planes(prepped: Sequence[jax.Array], planes: jax.Array,
+                       radius: int = 4, interpret: bool = False) -> jax.Array:
+    """Lane-packed lookup over a :func:`prep_pyramid_lanes` pyramid, the
+    centroids given as (2, B, H, W) planes — x then y: flattened, a plane IS
+    the kernel's lane-dense (1, N) vector, so nothing is cut out of a
+    2-wide minor axis once a level. Returns (B, H, W, levels·(2r+1)²).
 
     Same output as models/raft.py lookup_corr (dy-major ordering, zeros
     padding): element ``i·(2r+1)+j`` samples ``(x + d[i], y + d[j])``.
     """
-    b, hh, ww, _ = coords.shape
-    flat = coords.reshape(b * hh * ww, 2)
-    out = [_lookup_level_lanes(corr_t, flat / (2.0 ** i), radius, interpret)
+    x, y = planes.reshape(2, -1)
+    out = [_lookup_level_lanes(corr_t, x / (2.0 ** i), y / (2.0 ** i),
+                               radius, interpret)
            for i, corr_t in enumerate(prepped)]
-    return jnp.concatenate(out, axis=-1).reshape(b, hh, ww, -1)
+    return jnp.concatenate(out, axis=-1).reshape(*planes.shape[1:], -1)
+
+
+def lookup_corr_lanes(prepped: Sequence[jax.Array], coords: jax.Array,
+                      radius: int = 4, interpret: bool = False) -> jax.Array:
+    """:func:`lookup_corr_planes` for (B, H, W, 2) ``(x, y)`` centroids:
+    the signature of models/raft.py's other lookups."""
+    return lookup_corr_planes(prepped, jnp.moveaxis(coords, -1, 0), radius,
+                              interpret)
