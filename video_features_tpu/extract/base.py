@@ -174,6 +174,11 @@ class BaseExtractor:
         # that joins the span timeline to the device trace
         self._steps_dispatched = 0
         self._last_step = None
+        # dispatch key -> what aot_call saw there (jitted function,
+        # abstract args, statics, matmul precision), remembered only
+        # while a manifest is kept; note_executables lowers each once,
+        # off the hot path, for the manifest's cost and scope map
+        self._dispatched: Dict[tuple, Optional[Dict[str, Any]]] = {}
         # stall-watchdog feed for farm decode workers: the serve layer
         # installs ``watchdog_pending(worker_idx, n_queued)`` and the
         # DecodeFarm mirrors each worker's backlog into it (None = no
@@ -528,9 +533,14 @@ class BaseExtractor:
         program, compiled (and republished) otherwise. Without a store
         this is EXACTLY the legacy call. Byte-identical either way
         (tests/test_aot.py pins loaded ≡ compiled ≡ jit)."""
+        key = None
+        if self.manifest is not None:
+            key = self._aot_dispatch_key(name, batch, statics)
+            if key not in self._dispatched:
+                self._remember_dispatch(key, jitted, params, batch, statics)
         if self._aot_store is None or not hasattr(jitted, 'trace'):
             return jitted(params, batch, **statics)
-        key = self._aot_dispatch_key(name, batch, statics)
+        key = key or self._aot_dispatch_key(name, batch, statics)
         prog = self._aot_programs.get(key)
         if prog is None:
             with self._aot_lock:
@@ -833,19 +843,76 @@ class BaseExtractor:
         from video_features_tpu.parallel.mesh import plan_device_batch
         return plan_device_batch(capacity, mesh)
 
-    def executable_cost(self, batch):
-        """Best-effort XLA ``cost_analysis`` (FLOPs / bytes accessed) of
-        the compiled step at ``batch``'s geometry — the run-manifest
-        ``executables`` section. Works for families that follow the
-        ``self._step = jax.jit(...)``, ``self._step(self.params, batch)``
-        convention; returns None anywhere the convention doesn't hold.
-        An optimization report, never a requirement."""
-        step = getattr(self, '_step', None)
-        params = getattr(self, 'params', None)
-        if step is None or params is None or not hasattr(step, 'lower'):
-            return None
+    def _remember_dispatch(self, key: tuple, jitted, params, batch,
+                           statics: dict) -> None:
+        """First sight of dispatch ``key`` with a manifest on: keep what a
+        lowering of THIS dispatch needs — the jitted function, the args
+        as abstract shapes (with the shardings really run; never the
+        arrays: the params must stay free-able), the statics and the
+        ambient matmul precision. No lowering here: this is the hot
+        path (``aot_call`` pays a key and a dict lookup a step)."""
+        if not hasattr(jitted, 'lower'):
+            self._dispatched[key] = None    # not a jit: nothing to lower
+            return
+        import jax
+        lane = ('' if self.compute_dtype == 'float32'
+                else f':{self.compute_dtype}')
+        self._dispatched[key] = {
+            # feature family × batch geometry × dtype (× lane when it is
+            # not the default: fp32 and bf16 entries lower different
+            # programs at the same, usually uint8, input geometry)
+            'identity': f'{self.feature_type}:{tuple(batch.shape)}:'
+                        f'{batch.dtype}{lane}',
+            'jitted': jitted,
+            'args': jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(
+                    x.shape, x.dtype, sharding=getattr(x, 'sharding', None))
+                if hasattr(x, 'shape') else x, (params, batch)),
+            'statics': dict(statics),
+            'precision': jax.config.jax_default_matmul_precision,
+            'batch': int(batch.shape[0]) if batch.shape else None}
+
+    def executable_cost(self, remembered: Dict[str, Any]):
+        """Best-effort report on the compiled step of one remembered
+        dispatch (``_remember_dispatch``): XLA's ``cost_analysis`` and the
+        instruction → scope map, from one compile under the matmul
+        precision the step was traced in (a trace-context input: another
+        precision is another program). None where the step cannot be
+        lowered. An optimization report, never a requirement."""
+        from contextlib import nullcontext
+
+        import jax
+
         from video_features_tpu.obs.manifest import xla_cost_analysis
-        return xla_cost_analysis(step, params, batch)
+        precision = remembered['precision']
+        with (jax.default_matmul_precision(precision) if precision
+              else nullcontext()):
+            return xla_cost_analysis(remembered['jitted'],
+                                     *remembered['args'],
+                                     **remembered['statics'])
+
+    def note_executables(self) -> None:
+        """Lower, once each and OFF the hot path, what ``aot_call``
+        remembered since the last call, and note every executable's cost
+        and scope map in the manifest (``executables``). The packed
+        scheduler calls it when a worklist is done, the per-video loop
+        after each video: an identity already noted is skipped, so after
+        warm-up nothing is lowered or compiled on the manifest's account
+        (with the persistent compilation cache on, the one compile is a
+        cache read). No-op without a manifest."""
+        if self.manifest is None:
+            return
+        for remembered in self._dispatched.values():
+            if remembered is None or 'jitted' not in remembered:
+                continue
+            # every executable record names its lane, so the section
+            # says which precision produced the numbers it reports
+            info: Dict[str, Any] = {'batch': remembered['batch'],
+                                    'compute_dtype': self.compute_dtype}
+            info.update(self.executable_cost(remembered) or {})
+            self.manifest.note_executable(remembered['identity'], info)
+            # noted: drop what held the jit and its shapes
+            del remembered['jitted'], remembered['args']
 
     def _video_cache_key(self, video_path: str, segment=None) -> str:
         from video_features_tpu.cache import video_cache_key
@@ -965,6 +1032,9 @@ class BaseExtractor:
                     self.tracer.reset()
             if self.manifest is not None:
                 self.manifest.video_done(video_path, outcome)
+                # the step(s) this video was the first to dispatch: cost
+                # and scope map, lowered here, between videos
+                self.note_executables()
             if recorder is not None:
                 recorder.span('video', t0_video, _time.perf_counter(),
                               video=str(video_path), outcome=outcome,

@@ -68,8 +68,9 @@ def flow_stream_input(raft_params, stacks, pads, crop_size,
                                           iters=raft_iters,
                                           constrain=constrain_pairs,
                                           platform=platform, pins=pins)
-    flow = center_crop(flow, crop_size)
-    return scale_to_pm1(flow_to_uint8_levels(flow, 20.0))
+    with jax.named_scope('flow_quantise'):
+        flow = center_crop(flow, crop_size)
+        return scale_to_pm1(flow_to_uint8_levels(flow, 20.0))
 
 
 def _pil_short_side_geometry(h, w, size):
@@ -119,13 +120,13 @@ def fused_two_stream_step(params, stacks, pads, streams, constrain_pairs=None,
     out = {}
     if 'rgb' in streams:
         rgb = rgb_stream_input(stacks, crop_size)
-        with pin_scope(pins, 'i3d'):
+        with pin_scope(pins, 'i3d'), jax.named_scope('i3d_towers'):
             out['rgb'] = i3d_model.forward(params['rgb'], rgb, features=True)
     if 'flow' in streams:
         flow = flow_stream_input(params['raft'], stacks, pads, crop_size,
                                  constrain_pairs, platform=platform,
                                  pins=pins, raft_iters=raft_iters)
-        with pin_scope(pins, 'i3d'):
+        with pin_scope(pins, 'i3d'), jax.named_scope('i3d_towers'):
             out['flow'] = i3d_model.forward(params['flow'], flow,
                                             features=True)
     return out

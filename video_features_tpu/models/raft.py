@@ -437,7 +437,7 @@ def forward(params: Params, image1: jax.Array, image2: jax.Array,
     from video_features_tpu.ops.precision import pin_scope
     image1 = _normalize_frames(image1)
     image2 = _normalize_frames(image2)
-    with pin_scope(pins, 'encoder'):
+    with pin_scope(pins, 'encoder'), jax.named_scope('raft_encoders'):
         fmap1 = basic_encoder(params['fnet'], image1, 'instance')
         fmap2 = basic_encoder(params['fnet'], image2, 'instance')
         cnet = basic_encoder(params['cnet'], image1, 'batch')
@@ -482,7 +482,7 @@ def forward_stack_pairs(params: Params, stacks: jax.Array, iters: int = ITERS,
     flat = _normalize_frames(stacks.reshape(B * S1, H, W, C))
     if constrain is not None:
         flat = constrain(flat)
-    with pin_scope(pins, 'encoder'):
+    with pin_scope(pins, 'encoder'), jax.named_scope('raft_encoders'):
         fmaps = basic_encoder(params['fnet'], flat, 'instance')
     h8, w8, c = fmaps.shape[1:]
     fmaps = fmaps.reshape(B, S1, h8, w8, c)
@@ -491,7 +491,7 @@ def forward_stack_pairs(params: Params, stacks: jax.Array, iters: int = ITERS,
     first = flat.reshape(B, S1, H, W, C)[:, :-1].reshape(B * S, H, W, C)
     if constrain is not None:
         fmap1, fmap2, first = constrain(fmap1), constrain(fmap2), constrain(first)
-    with pin_scope(pins, 'encoder'):
+    with pin_scope(pins, 'encoder'), jax.named_scope('raft_encoders'):
         cnet = basic_encoder(params['cnet'], first, 'batch')
     flow = _refine(params, fmap1, fmap2, cnet, iters, platform, pins)
     return flow.reshape(B, S, flow.shape[1], flow.shape[2], 2)
@@ -548,14 +548,14 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
         # (N, h, w) detour + physical transpose was the fixed phase's
         # single worst HBM pattern (see prep_pyramid_lanes_fused)
         from video_features_tpu.ops import pallas_corr
-        with pin_scope(pins, 'corr'):
+        with pin_scope(pins, 'corr'), jax.named_scope('raft_corr'):
             prepped = pallas_corr.prep_pyramid_lanes_fused(
                 fmap1, fmap2, levels=CORR_LEVELS)
         lookup = partial(pallas_corr.lookup_corr_planes, prepped,
                          radius=CORR_RADIUS,
                          interpret=_pallas_interpret(platform))
     else:
-        with pin_scope(pins, 'corr'):
+        with pin_scope(pins, 'corr'), jax.named_scope('raft_corr'):
             pyramid = build_corr_pyramid(fmap1, fmap2)
         by_grid = partial(lookup_corr if impl == 'gather'
                           else lookup_corr_dense, pyramid)
@@ -579,17 +579,24 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
                      if early_prec else nullcontext())
             with outer:
                 net, coords1 = carry
-                with pin_scope(pins, 'corr'):
+                # the named scopes are the device-time vocabulary
+                # (obs/scopes.py: metadata only); the pins beside them
+                # stay what they are, precision contexts
+                with pin_scope(pins, 'corr'), \
+                        jax.named_scope('raft_lookup'):
                     corr = lookup(coords1)
                 flow = coords1 - coords0
                 # finer pins nest inside 'iter': an unpinned sub-component
                 # inherits the 'iter' (or ambient) precision
                 with pin_scope(pins, 'iter'):
-                    with pin_scope(pins, 'iter_motion'):
+                    with pin_scope(pins, 'iter_motion'), \
+                            jax.named_scope('raft_motion'):
                         motion = motion_encoder(up['encoder'], flow, corr)
-                    with pin_scope(pins, 'iter_gru'):
+                    with pin_scope(pins, 'iter_gru'), \
+                            jax.named_scope('raft_gru'):
                         net_new = sep_conv_gru(gru, gru_terms, net, motion)
-                    with pin_scope(pins, 'iter_head'):
+                    with pin_scope(pins, 'iter_head'), \
+                            jax.named_scope('raft_flow_head'):
                         t = relu(_conv_b(fh['conv1'], net_new, padding=1))
                         delta = conv_to_planes(t, fh['conv2']['weight'],
                                                bias=fh['conv2']['bias'])
@@ -608,23 +615,24 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
             early_n = min(int(n or 0), iters)
 
     carry = (net, coords0)
-    if early_n:
-        carry, _ = lax.scan(make_step(early_prec), carry, None,
-                            length=early_n)
-    (net, coords1), _ = lax.scan(make_step(), carry, None,
-                                 length=iters - early_n)
+    with jax.named_scope('raft_update'):
+        if early_n:
+            carry, _ = lax.scan(make_step(early_prec), carry, None,
+                                length=early_n)
+        (net, coords1), _ = lax.scan(make_step(), carry, None,
+                                     length=iters - early_n)
     # Convex-upsample mask head, ONCE after the scan: the reference
     # computes `.25·mask(net)` every iteration (update.py:139-144) but the
     # extractor consumes only the final flow (raft.py:153-175 predictions
     # [-1]) — every non-final mask is dead code, so 19/20 of the mask
     # head's FLOPs (a 3×3 128→256 + 1×1 256→576 stack) leave the scan
     # with bit-identical output.
-    with pin_scope(pins, 'iter'):
+    with pin_scope(pins, 'iter'), jax.named_scope('raft_upsample'):
         t_mask = relu(_conv_b(mk['0'], net, padding=1))
         mask = 0.25 * _conv_b(mk['2'], t_mask)
     with jax.named_scope('raft_coords'):
         flow = jnp.moveaxis(coords1 - coords0, 0, -1)
-    with pin_scope(pins, 'upsample'):
+    with pin_scope(pins, 'upsample'), jax.named_scope('raft_upsample'):
         return upsample_flow(flow, mask)
 
 

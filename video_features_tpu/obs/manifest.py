@@ -17,9 +17,16 @@ carrying
     backend-compile duration events (the real XLA compile cost, not a
     first-call-minus-steady estimate);
   * **executables**: per executable identity (feature family × input
-    geometry × dtype), the XLA ``cost_analysis`` FLOPs / bytes-accessed
-    of the compiled step where the extractor's step function supports
-    AOT lowering — the denominator for MFU math.
+    geometry × dtype), from ONE compile of the step as it was really
+    dispatched (statics, shardings and matmul precision included): the
+    XLA ``cost_analysis`` FLOPs / bytes-accessed, and ``scopes``, the map
+    from HLO instruction to ``jax.named_scope`` path (``obs/scopes.py``)
+    that turns a device trace's op events into time by the program's own
+    names. The FLOPs are XLA's count of the optimised module with every
+    ``while`` body counted ONCE (``loops_counted_once`` says when the
+    module has one): for a scanned step (i3d's 20 RAFT updates, the lm
+    trunks' block loops) a floor, NOT the denominator of an MFU — the
+    benchmark takes model FLOPs from its plain references.
 
 Collection is push-based: the extraction loops call ``video_done`` /
 ``fold_stages`` / ``note_executable`` as they go; ``write`` publishes
@@ -79,34 +86,51 @@ def _compile_snapshot() -> Dict[str, Dict[str, float]]:
         return {k: dict(v) for k, v in _compile_events.items()}
 
 
-def xla_cost_analysis(jitted, *args, **kwargs) -> Optional[Dict[str, float]]:
-    """Best-effort FLOPs / bytes-accessed for one compiled executable.
+def xla_cost_analysis(jitted, *args, **kwargs) -> Optional[Dict[str, Any]]:
+    """Best-effort report on one compiled executable, from ONE compile:
+    ``flops`` / ``bytes_accessed`` (XLA's ``cost_analysis()`` of the
+    optimised module; a ``while`` body is counted once, and
+    ``loops_counted_once`` is set where the module has one) and
+    ``scopes``, the instruction → ``jax.named_scope`` map of the same
+    compiled object (``obs.scopes.compiled_scopes``).
 
     AOT-lowers ``jitted`` at the given abstract shapes — through the ONE
     ``jitted.lower(...)`` seam shared with the vft-programs contract
-    checker (``analysis.programs.abstract_lowering``) — and reads the
-    compiled module's ``cost_analysis()``. With the persistent
+    checker (``analysis.programs.abstract_lowering``). With the persistent
     compilation cache on (``enable_compilation_cache``) the second
     compile is a cache read, not a recompile. Returns None when the
-    backend/step doesn't support it — cost analysis is an optimization
-    report, never a requirement."""
+    backend/step doesn't support it — an optimization report, never a
+    requirement."""
     try:
         from video_features_tpu.analysis.programs import abstract_lowering
-        cost = abstract_lowering(jitted, *args,
-                                 **kwargs).compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else None
-        if not cost:
-            return None
-        out = {}
-        for key in ('flops', 'bytes accessed'):
-            if key in cost:
-                out[key.replace(' ', '_')] = float(cost[key])
-        return out or None
+        lowered = abstract_lowering(jitted, *args, **kwargs)
+        compiled = lowered.compile()
     except Exception:
         # vft-lint: ok=swallowed-exception — cost analysis is an
         # optimization report, never a requirement (docstring contract)
         return None
+    out: Dict[str, Any] = {}
+    try:
+        cost = compiled.cost_analysis()
+        if isinstance(cost, (list, tuple)):
+            cost = cost[0] if cost else None
+        for key in ('flops', 'bytes accessed'):
+            if cost and key in cost:
+                out[key.replace(' ', '_')] = float(cost[key])
+    except Exception:
+        # vft-lint: ok=swallowed-exception — as above
+        pass
+    try:
+        from video_features_tpu.obs import scopes
+        text = compiled.as_text()
+        if ' while(' in text:
+            out['loops_counted_once'] = True
+        out['scopes'] = scopes.compiled_scopes(
+            lowered.as_text(debug_info=True), text)
+    except Exception:
+        # vft-lint: ok=swallowed-exception — as above
+        pass
+    return out or None
 
 
 class RunManifest:
@@ -177,7 +201,15 @@ class RunManifest:
                         info: Dict[str, Any]) -> None:
         """Attach cost/compile info for one executable identity (feature
         family × batch geometry × dtype). Later notes for the same
-        identity merge over earlier ones."""
+        identity merge over earlier ones. ``info['scopes']`` (the
+        instruction → scope map, ``obs.scopes.compiled_scopes``) is also
+        kept for a reader in this process (``obs.scopes.noted()``)."""
+        scopes_record = info.get('scopes')
+        if scopes_record:
+            # the same-process door: a reader of the device trace (the
+            # benchmark's scope_time) asks obs.scopes.noted()
+            from video_features_tpu.obs import scopes
+            scopes.note(scopes_record.get('program'), scopes_record)
         with self._lock:
             self.executables.setdefault(identity, {}).update(
                 {k: _jsonable(v) for k, v in info.items()})

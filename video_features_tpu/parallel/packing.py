@@ -649,9 +649,6 @@ def run_packed(ex, video_paths: Iterable,
 
     recorder = getattr(ex.tracer, 'recorder', None)
     manifest = getattr(ex, 'manifest', None)
-    # executable identity → (shape, dtype) seen on the device loop;
-    # cost-analyzed after the run so telemetry never stalls a batch
-    costed: Dict[str, tuple] = {}
 
     # open_q doubles as the lazy task registry: the decode thread appends
     # each task as the source yields it (list.append is atomic; only the
@@ -953,25 +950,6 @@ def run_packed(ex, video_paths: Iterable,
                 sweep()
                 continue
             record_occupancy('model', valid)
-            if manifest is not None:
-                # record each executable identity's geometry (the unit
-                # XLA compiles per) — shape+dtype only; the expensive
-                # cost-analysis lowering runs AFTER the worklist, off
-                # the device loop's critical path
-                shape = getattr(dev, 'shape', None)
-                if shape is not None:
-                    # the identity names the LANE too when it isn't the
-                    # default: fp32 and bf16 entries lower different
-                    # programs at the same input geometry (the packed
-                    # batch itself is usually uint8 on both lanes)
-                    lane = ('' if compute_dtype == 'float32'
-                            else f':{compute_dtype}')
-                    identity = (f'{getattr(ex, "feature_type", "?")}:'
-                                f'{tuple(shape)}:'
-                                f'{getattr(dev, "dtype", "")}{lane}')
-                    if identity not in costed:
-                        costed[identity] = (tuple(shape),
-                                            getattr(dev, 'dtype', None))
             pending.append((out, prov, valid, batch_videos,
                             batch_traces, step))
             ex._inflight_now = len(pending)
@@ -982,22 +960,15 @@ def run_packed(ex, video_paths: Iterable,
     ex._inflight_now = 0
     sweep(final=True)
 
-    if manifest is not None and costed:
-        # deferred XLA cost analysis: lower the step at each recorded
-        # geometry (abstract shapes — no data needed) now that the
-        # worklist is done; with the persistent compilation cache on
-        # this is a cache read, and either way it is off the hot path
-        import jax
-        for identity, (shape, dtype) in costed.items():
-            # every executable record names its lane, so the manifest's
-            # xla_cost_analysis section says which precision produced
-            # the FLOPs/bytes it reports
-            info: Dict = {'batch': batch, 'compute_dtype': compute_dtype}
-            cost = ex.executable_cost(jax.ShapeDtypeStruct(shape, dtype)) \
-                if dtype is not None else None
-            if cost:
-                info.update(cost)
-            manifest.note_executable(identity, info)
+    if manifest is not None:
+        # deferred XLA cost analysis and scope map of each executable
+        # the dispatch seam saw (BaseExtractor.aot_call remembered the
+        # jit, the shapes, the statics and the precision really run):
+        # lowered now that the worklist is done, off the device loop,
+        # and once: an identity the manifest holds is skipped, so a
+        # second extract_packed call (a benchmark's next pass, a serve
+        # worker's next wave) lowers nothing
+        ex.note_executables()
 
     if manifest is not None and ndev > 1:
         # the run manifest names the mesh that produced these numbers:
@@ -1290,7 +1261,6 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
                           else getattr(ex, 'inflight', 1) or 1), 1)
              for fam, ex in exs.items()}
     pending: Dict[str, deque] = {fam: deque() for fam in fams}
-    costed: Dict[str, Dict[str, tuple]] = {fam: {} for fam in fams}
 
     def finalize_carrier(c: FusedTask) -> None:
         for fam, sub in c.subtasks.items():
@@ -1404,15 +1374,6 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
             sweep()
             continue
         ex.tracer.add_occupancy('model', valid, fam_batch[fam])
-        if manifests[fam] is not None:
-            shape = getattr(dev, 'shape', None)
-            if shape is not None:
-                cd = str(getattr(ex, 'compute_dtype', 'float32'))
-                lane = '' if cd == 'float32' else f':{cd}'
-                identity = (f'{fam}:{tuple(shape)}:'
-                            f'{getattr(dev, "dtype", "")}{lane}')
-                costed[fam].setdefault(
-                    identity, (tuple(shape), getattr(dev, 'dtype', None)))
         pending[fam].append((out, prov, valid, batch_videos, step))
         ex._inflight_now = len(pending[fam])
         while len(pending[fam]) >= depth[fam]:
@@ -1424,19 +1385,8 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
 
     for fam, ex in exs.items():
         manifest = manifests[fam]
-        if manifest is not None and costed[fam]:
-            import jax
-            for identity, (shape, dtype) in costed[fam].items():
-                info: Dict = {'batch': fam_batch[fam],
-                              'compute_dtype':
-                                  str(getattr(ex, 'compute_dtype',
-                                              'float32'))}
-                cost = (ex.executable_cost(
-                            jax.ShapeDtypeStruct(shape, dtype))
-                        if dtype is not None else None)
-                if cost:
-                    info.update(cost)
-                manifest.note_executable(identity, info)
+        if manifest is not None:
+            ex.note_executables()    # once per identity (run_packed)
         if farm is not None and manifest is not None:
             manifest.note_farm({'decode_workers': farm.n_workers,
                                 'ring_bytes_per_worker': farm.ring_bytes,
