@@ -94,6 +94,8 @@ def test_blockwise_ragged_matches_dense():
 def _causal_reference(q, k, v, scale=None):
     """Dense causal attention in float64: what every path is held to."""
     q, k, v = (np.asarray(t, np.float64) for t in (q, k, v))
+    group = q.shape[2] // k.shape[2]    # query head j reads head j // group
+    k, v = np.repeat(k, group, axis=2), np.repeat(v, group, axis=2)
     s = np.einsum('bqhd,bkhd->bhqk', q, k) * (scale or q.shape[-1] ** -0.5)
     n = q.shape[1]
     s = np.where(np.tril(np.ones((n, n), bool)), s, -np.inf)
@@ -106,12 +108,14 @@ def _rel_l2(got, want):
     return rel_l2(want, got)
 
 
-def _latent_qkv(seed, b=2, s=64, h=3, qk=192, v=128):
-    """q/k and v at latent attention's unequal head widths, 192 and 128."""
+def _latent_qkv(seed, b=2, s=64, h=3, qk=192, v=128, kv_heads=None):
+    """q/k and v at latent attention's unequal head widths, 192 and 128;
+    ``kv_heads`` gives k and v fewer heads than q."""
     rng = np.random.RandomState(seed)
+    g = kv_heads or h
     return (jnp.asarray(rng.randn(b, s, h, qk).astype(np.float32)),
-            jnp.asarray(rng.randn(b, s, h, qk).astype(np.float32)),
-            jnp.asarray(rng.randn(b, s, h, v).astype(np.float32)))
+            jnp.asarray(rng.randn(b, s, g, qk).astype(np.float32)),
+            jnp.asarray(rng.randn(b, s, g, v).astype(np.float32)))
 
 
 @pytest.mark.parametrize('block_q,block_k', [
@@ -196,6 +200,103 @@ def test_causal_kernel_refuses_what_it_has_no_lane_for(passes, block_q, qk,
         causal_attention(q, k, v, 1.0, passes, block_q, 16, interpret=True)
 
 
+def _grouped_qkv(seed, b=2, s=64, heads=8, kv_heads=2, d=64):
+    """Grouped-query heads at lfm2's width: 64-wide q, k and v, ``heads``
+    query heads reading ``kv_heads`` key-value heads."""
+    return _latent_qkv(seed, b, s, heads, d, d, kv_heads)
+
+
+@pytest.mark.parametrize('heads,kv_heads,block_q,block_k', [
+    (8, 2, 64, 64),     # groups of 4, one tile a side
+    (8, 2, 16, 16),     # four tiles a side
+    (8, 2, 16, 32),     # a key tile two query tiles cross: packed by the first
+    (8, 2, 32, 16),     # a query tile the diagonal crosses two key tiles of
+    (8, 2, 16, 64),     # every query tile crosses the one key tile
+    (4, 2, 16, 32),     # groups of 2: 128 columns a key-value head
+    (6, 1, 32, 32),     # one key-value head for all six query heads
+])
+def test_causal_kernel_grouped_query_lane_matches_dense_and_the_xla_tiles(
+        heads, kv_heads, block_q, block_k):
+    """A grid step is one key-value head and its query heads (batch 2): the
+    numbers of dense causal attention with the keys and values repeated,
+    and of the XLA tiles the CPU keeps, which fold the group onto the query
+    axis."""
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    q, k, v = _grouped_qkv(11, heads=heads, kv_heads=kv_heads)
+    got = causal_attention(q, k, v, 0.125, 3, block_q, block_k,
+                           interpret=True)
+    assert got.shape == (2, 64, heads, 64) and got.dtype == jnp.float32
+    want = _causal_reference(q, k, v)
+    assert _rel_l2(got, want) < 1e-5
+    group = heads // kv_heads
+    with jax.default_matmul_precision('highest'):
+        tiles = blockwise_attention(q, k, v, block_size=16, causal=True)
+        # the last row sees every key: dense attention's row, heads repeated
+        last = dense_attention(q[:, -1:], jnp.repeat(k, group, 2),
+                               jnp.repeat(v, group, 2))
+    assert _rel_l2(got, tiles) < 1e-5
+    np.testing.assert_allclose(np.asarray(got[:, -1:]), np.asarray(last),
+                               rtol=2e-5, atol=2e-5)
+    # the first row sees one key: its output is its key-value head's value
+    np.testing.assert_allclose(np.asarray(got[:, 0]),
+                               np.repeat(np.asarray(v[:, 0]), group, 1),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize('passes,low,high', [
+    (3, 0.0, 1e-5),      # hi·hi + hi·lo + lo·hi: float32-grade
+    (1, 5e-4, 1e-2),     # the head alone: the control lane stays a control
+])
+def test_causal_kernel_grouped_lane_makes_the_passes_it_is_asked_for(
+        passes, low, high):
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    q, k, v = _grouped_qkv(12)
+    with jax.default_matmul_precision('highest'):
+        want = blockwise_attention(q, k, v, block_size=16, causal=True)
+    got = causal_attention(q, k, v, 0.125, passes, 16, 32, interpret=True)
+    assert low <= _rel_l2(got, want) < high
+
+
+def test_causal_kernel_grouped_lane_is_the_equal_heads_lane_on_repeats():
+    """Group 1 is unchanged, and the grouped lane is it to the bit: the
+    same heads with keys and values repeated (128-wide values: the equal-
+    heads lane's output block) give the same numbers, head by head."""
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    rng = np.random.RandomState(13)
+    q = jnp.asarray(rng.randn(2, 64, 4, 64).astype(np.float32))
+    k = jnp.asarray(rng.randn(2, 64, 2, 64).astype(np.float32))
+    v = jnp.asarray(rng.randn(2, 64, 2, 128).astype(np.float32))
+    grouped = causal_attention(q, k, v, 0.125, 3, 16, 32, interpret=True)
+    equal = causal_attention(q, jnp.repeat(k, 2, 2), jnp.repeat(v, 2, 2),
+                             0.125, 3, 16, 32, interpret=True)
+    np.testing.assert_array_equal(np.asarray(grouped), np.asarray(equal))
+
+
+def test_causal_kernel_grouped_lane_survives_large_scores():
+    """Scores of O(1000): the running max is kept per query head of the
+    group."""
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    q, k, v = _grouped_qkv(14, b=1, s=32)
+    q = q * jnp.asarray([1., 40., 5., 20., 40., 1., 20., 5.])[:, None]
+    got = causal_attention(q, k, v, 0.125, 3, 16, 16, interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    assert _rel_l2(got, _causal_reference(q, k, v)) < 1e-4
+
+
+@pytest.mark.parametrize('heads,kv_heads,v_dim,match', [
+    (6, 4, 64, 'no whole number of groups'),
+    (6, 2, 64, 'fill no whole 128-lane blocks'),    # 3 × 64 columns
+    (4, 2, 96, 'fill no whole 128-lane blocks'),    # 2 × 96 value columns
+])
+def test_causal_kernel_refuses_groups_it_has_no_lane_for(heads, kv_heads,
+                                                         v_dim, match):
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    q, k, _ = _grouped_qkv(15, b=1, s=32, heads=heads, kv_heads=kv_heads)
+    v = jnp.zeros((1, 32, kv_heads, v_dim), jnp.float32)
+    with pytest.raises(ValueError, match=match):
+        causal_attention(q, k, v, 1.0, 3, 16, 16, interpret=True)
+
+
 @pytest.mark.parametrize('groups,passes,want', [
     ((192,), 3, (640, 256)),    # [hi hi lo] of 128, then of 64 padded to 256
     ((128, 64), 3, (640, 256)),  # latent attention's nope and rope groups
@@ -208,7 +309,9 @@ def test_packed_widths_are_whole_lane_chunks(groups, passes, want):
     assert packed_widths(groups, 128, passes) == want
 
 
-@pytest.mark.parametrize('platform,s,qk,v,precision,want', [
+# (platform, positions, q/k width, v width, ambient precision, answer), equal
+# head counts
+EQUAL_HEADS = [
     ('tpu', 8192, 192, 128, 'high', 'kernel'),      # the cell
     ('tpu', 8192, 192, 128, 'default', 'kernel'),   # its control lane
     ('tpu', 8192, 192, 128, None, 'kernel'),        # unset = one pass
@@ -223,11 +326,33 @@ def test_packed_widths_are_whole_lane_chunks(groups, passes, want):
     ('tpu', 64, 192, 128, 'high', 'xla'),           # a tile under 128 lanes
     ('tpu', 128, 128, 128, 'high', 'kernel'),       # one aligned tile
     ('tpu', 65536, 192, 128, 'high', 'xla'),        # K/V past the VMEM budget
-])
+]
+# the same with (query, key-value) head counts last
+GROUPED_HEADS = [
+    # lfm2-moe's cell
+    ('tpu', 8192, 64, 64, 'high', 'kernel', (32, 8)),
+    ('tpu', 8192, 64, 64, 'default', 'kernel', (32, 8)),    # its control
+    ('cpu', 8192, 64, 64, 'high', 'xla', (32, 8)),
+    ('tpu', 8192, 64, 64, 'highest', 'xla', (32, 8)),
+    ('tpu', 8192, 64, 64, 'high', 'xla', (32, 32)),     # one 64-wide v a step
+    ('tpu', 8192, 64, 64, 'high', 'kernel', (32, 16)),  # two fill a block
+    ('tpu', 8192, 64, 64, 'high', 'xla', (24, 8)),      # three do not
+    ('tpu', 8192, 64, 64, 'high', 'xla', (32, 12)),     # no whole groups
+    ('tpu', 8192 + 256, 64, 64, 'high', 'xla', (32, 8)),    # no tile multiple
+    ('tpu', 128, 64, 64, 'high', 'kernel', (32, 8)),    # one aligned tile
+    ('tpu', 8192, 128, 128, 'high', 'kernel', (40, 8)),  # 128-wide: any group
+]
+
+
+@pytest.mark.parametrize(
+    'platform,s,qk,v,precision,want,heads',
+    [(*row, (1, 1)) for row in EQUAL_HEADS] + GROUPED_HEADS)
 def test_resolve_causal_decides_from_platform_shapes_and_precision(
-        platform, s, qk, v, precision, want):
+        platform, s, qk, v, precision, want, heads):
     from video_features_tpu.ops.attention import resolve_causal
-    assert resolve_causal(platform, s, qk, v, precision) == want
+    assert resolve_causal(platform, s, qk, v, precision, *heads) == want
+    if heads == (1, 1):      # the head counts left out: equal
+        assert resolve_causal(platform, s, qk, v, precision) == want
 
 
 def test_causal_kernel_lowered_for_a_tpu_is_one_named_mosaic_call():

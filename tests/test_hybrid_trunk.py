@@ -345,10 +345,17 @@ def test_published_sizes_count_as_the_issue_counts_them():
         layer_types=body['layer_types']))
     assert whole.operators() == {'conv': 18, 'full_attention': 6}
     assert 8.3e9 < ht.param_count(whole) < 8.4e9
-    # at these widths the causal kernel has no lane: 64-wide value heads
+    # at these widths the causal kernel's grouped-query lane applies on a
+    # TPU — four 64-wide query heads a key-value head fill two lane blocks,
+    # one alone would not — and nowhere else, nor under 'highest'
+    assert resolve_causal('tpu', 8_192, 64, 64, 'high', 32, 8) == 'kernel'
     assert resolve_causal('tpu', 8_192, 64, 64, 'high') == 'xla'
-    assert ht.kernels(cut, 'tpu', 8_192, 'high') == {
-        'causal_attention': 'xla', 'operators': 'conv 6, full_attention 2'}
+    for platform, precision, path in [
+            ('tpu', 'high', 'kernel'), ('tpu', 'default', 'kernel'),
+            ('tpu', 'highest', 'xla'), ('cpu', 'high', 'xla')]:
+        assert ht.kernels(cut, platform, 8_192, precision) == {
+            'causal_attention': path,
+            'operators': 'conv 6, full_attention 2'}, (platform, precision)
 
 
 def test_the_trunks_share_their_blocks_and_the_expert_code():
@@ -555,4 +562,64 @@ def test_the_step_carries_the_scopes_a_trace_is_read_by():
         lowering_platforms=('tpu',)).as_text(debug_info=True)
     for scope in ('short_conv', 'attention', 'moe', 'dense_mlp'):
         assert scope in text, scope
-    assert 'tpu_custom_call' not in text         # XLA only: no kernel lane
+    assert 'tpu_custom_call' not in text     # 16-wide heads: the XLA tiles
+
+
+# a trunk whose attention the kernel's grouped lane takes: 4 query heads of
+# 64 reading 2 key-value heads, windows of 128 ids
+ALIGNED = dict(TINY_PROGRAM, hidden_size=256, num_hidden_layers=3,
+               layer_types=['conv', 'full_attention', 'full_attention'])
+
+
+@pytest.mark.parametrize('platform,precision,calls', [
+    ('tpu', 'high', 2),        # precision=mixed: one call a layer's lax.map
+    ('tpu', 'default', 2),     # the control lane takes the kernel too
+    ('tpu', 'highest', 0),     # the yml's default keeps the XLA path
+    ('cpu', 'high', 0),        # what tier-1 and the programs lock lower
+])
+def test_the_step_lowered_for_a_tpu_holds_the_named_kernel(platform,
+                                                           precision, calls):
+    """The step as the extractor jits it, lowered for the TPU from here: a
+    Mosaic call named causal_attention in each attention layer's window
+    loop where ``resolve_causal`` says 'kernel', none where it does not —
+    and ``kernels``, the engagement counter, says the same."""
+    cfg = program_cfg(**ALIGNED)
+    params = {n: jax.ShapeDtypeStruct(s, jnp.float32)
+              for n, s in ht.param_shapes(cfg).items()}
+    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    with jax.default_matmul_precision(precision):
+        text = jax.jit(partial(extract_lm.ExtractLM._forward, cfg=cfg,
+                               platform=platform)).trace(
+            params, ids).lower(lowering_platforms=('tpu',)).as_text()
+    assert text.count('kernel_name = "causal_attention"') == calls
+    assert text.count('tpu_custom_call') == calls
+    assert ht.kernels(cfg, platform, 128, precision)['causal_attention'] == (
+        'kernel' if calls else 'xla')
+
+
+def test_the_kernel_path_of_attention_block_is_the_xla_path_to_rounding(
+        monkeypatch):
+    """attention_block with the kernel forced in (interpreted: the decision
+    says 'kernel' only on a TPU) against the XLA tiles, both at three
+    passes; and the trunk's rows through it against the rows through the
+    tiles."""
+    from video_features_tpu.ops import pallas_attention
+    from video_features_tpu.ops.precision import rel_l2
+    cfg = program_cfg(**ALIGNED)
+    params = {n: jnp.asarray(w) for n, w in ht.init_params(cfg, 3).items()}
+    x = jnp.asarray(np.random.default_rng(6).standard_normal(
+        (128, 256)).astype(np.float32))
+    ids = np.random.default_rng(7).integers(0, 512, (2, 128)).astype(np.int32)
+    a = 'model.layers.1.self_attn'
+    with jax.default_matmul_precision('high'):
+        want = ht.attention_block(params, a, x, cfg, 64, 'cpu')
+        rows, _ = ht.forward(params, ids, cfg, 64, platform='cpu')
+        monkeypatch.setattr(ht, 'resolve_causal', lambda *args: 'kernel')
+        monkeypatch.setattr(
+            pallas_attention, 'causal_attention',
+            partial(pallas_attention.causal_attention, interpret=True))
+        got = ht.attention_block(params, a, x, cfg, 64, 'tpu')
+        through, _ = ht.forward(params, ids, cfg, 64, platform='tpu')
+    assert got.shape == want.shape == (128, 256)
+    assert 0 < rel_l2(got, want) < 2e-5
+    assert 0 < rel_l2(through, rows) < 1e-4

@@ -51,6 +51,33 @@ def test_causal_attention_compiles_at_the_cells_widths(one_chip, passes):
     assert '%causal_attention' in text
 
 
+@pytest.mark.parametrize('passes', [3, 1])
+def test_causal_attention_grouped_lane_compiles_at_the_cells_widths(one_chip,
+                                                                    passes):
+    """One window of lfm2-moe.corpus: 8,192 positions, 32 query heads
+    reading 8 key-value heads, all 64 wide — a grid step one key-value head
+    and its four query heads, 256 output columns — under three passes
+    (precision=mixed) and one (the control lane), at the shipped tiles and
+    VMEM limit; and that is what these shapes get on a TPU."""
+    from video_features_tpu.ops.attention import KERNEL_PASSES, resolve_causal
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    precision = {3: 'high', 1: 'default'}[passes]
+    assert KERNEL_PASSES[precision] == passes
+    assert resolve_causal('tpu', 8192, 64, 64, precision, 32, 8) == 'kernel'
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((1, 8192, heads, 64), jnp.float32,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(lambda q, k, v: causal_attention(
+        q, k, v, 64 ** -0.5, passes)).lower(sds(32), sds(8), sds(8)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert '%causal_attention' in text
+    # q enters and the output leaves as they stand: no copy of either
+    assert 'f32[1,32,8192,64]' not in text
+
+
 @pytest.fixture(scope='module')
 def compiled_scan(one_chip):
     """brumby.corpus's mixer at its widths — 8 key-value heads with 5 query

@@ -19,9 +19,11 @@ over, and which kind a layer is is static — read from the published
   reads key-value head ``j div group``) of ``hidden_size /
   num_attention_heads`` dims, an RMSNorm with a gain of its own over each
   head of q and k before the half-split rotary code, causal, scale
-  ``head_dim^-½``. Through ``ops.attention.blockwise_attention(causal=True)``,
-  the XLA tiles: the fused kernel takes equal head counts and whole 128-lane
-  value heads, so it has no lane for these (``kernels``).
+  ``head_dim^-½``. On a TPU under ``precision=mixed`` / ``default`` through
+  the fused kernel's grouped-query lane (``ops/pallas_attention.py``: one
+  key-value head and its query heads a grid step), elsewhere through
+  ``ops.attention.blockwise_attention(causal=True)``, the XLA tiles;
+  ``ops.attention.resolve_causal`` decides from the shapes (``kernels``).
 * the feed-forward — a dense SwiGLU of ``intermediate_size`` in the first
   ``num_dense_layers`` layers (in row blocks); after them ``num_experts``
   SwiGLU experts of ``moe_intermediate_size``, ``num_experts_per_tok`` a
@@ -63,7 +65,9 @@ from video_features_tpu.models.token_trunk import (
     Params, embed, final_norm, mean_features, mlp_rows, rms_norm, swiglu,
 )
 from video_features_tpu.ops import moe
-from video_features_tpu.ops.attention import blockwise_attention, rotary_half
+from video_features_tpu.ops.attention import (
+    KERNEL_PASSES, blockwise_attention, resolve_causal, rotary_half,
+)
 from video_features_tpu.ops.short_conv import gated_short_conv
 
 MODEL_TYPE = 'lfm2_moe'
@@ -231,13 +235,23 @@ def describe(cfg: TrunkConfig) -> str:
             f'expert layers')
 
 
+def _causal_path(cfg: TrunkConfig, platform: str, s: int,
+                 precision: Optional[str]) -> str:
+    """``resolve_causal``'s answer for a window of ``s`` positions at this
+    trunk's head width and head counts."""
+    return resolve_causal(platform, s, cfg.head_dim, cfg.head_dim, precision,
+                          cfg.num_attention_heads, cfg.num_key_value_heads)
+
+
 def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
             precision: Optional[str]) -> Dict[str, object]:
-    """What the step compiles: the causal attention's path — 'xla' on every
-    platform and precision, the one path this trunk's attention has
-    (grouped heads of a 64-wide value head: ``ops.attention.resolve_causal``
-    answers the same for these widths) — and the operator kinds run here."""
-    return {'causal_attention': 'xla',
+    """What the step compiles: the causal attention's path ('kernel' or
+    'xla': ``ops.attention.resolve_causal``, from the platform, the window's
+    shapes, the head counts and the matmul precision; all or nothing per
+    program: it is the kernel's engagement counter) and the operator kinds
+    run here."""
+    return {'causal_attention': _causal_path(cfg, platform, window_ids,
+                                             precision),
             'operators': ', '.join(f'{kind} {n}'
                                    for kind, n in cfg.operators().items())}
 
@@ -260,9 +274,15 @@ def conv_block(p: Params, prefix: str, x: jax.Array) -> jax.Array:
 
 
 def attention_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
-                    attn_block: int = 1024) -> jax.Array:
+                    attn_block: int = 1024,
+                    platform: Optional[str] = None) -> jax.Array:
     """Grouped-query attention over one window: (S, D) normed input →
-    (S, D), causal, positions 0…S−1."""
+    (S, D), causal, positions 0…S−1. ``platform`` is where the graph will
+    run (None: the default backend); with the shapes and the ambient matmul
+    precision it decides the causal path (``ops.attention.resolve_causal``):
+    the fused kernel where it applies — q as it stands, its heads' columns
+    side by side, k and v with their own fewer heads: nothing is repeated or
+    folded — the XLA tiles of ``blockwise_attention`` elsewhere."""
     with jax.named_scope('attention'):
         s = x.shape[0]
         h, g, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -275,9 +295,18 @@ def attention_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
                                  cfg.norm_eps), positions, cfg.rope_theta)
         k = rotary_half(rms_norm(k, p[f'{prefix}.k_layernorm.weight'],
                                  cfg.norm_eps), positions, cfg.rope_theta)
-        out = blockwise_attention(q[None], k[None], v[None],
-                                  block_size=min(attn_block, s),
-                                  causal=True)[0]
+        precision = jax.config.jax_default_matmul_precision
+        if _causal_path(cfg, platform or jax.default_backend(), s,
+                        precision) == 'kernel':
+            from video_features_tpu.ops.pallas_attention import (
+                causal_attention,
+            )
+            out = causal_attention(q[None], k[None], v[None], d ** -0.5,
+                                   KERNEL_PASSES[precision])[0]
+        else:
+            out = blockwise_attention(q[None], k[None], v[None],
+                                      block_size=min(attn_block, s),
+                                      causal=True)[0]
         return jnp.dot(out.reshape(s, h * d), p[f'{prefix}.out_proj.weight'])
 
 
@@ -301,7 +330,8 @@ def expert_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
 
 
 def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
-                  attn_block: int = 1024, moe_block: int = moe.BLOCK
+                  attn_block: int = 1024, moe_block: int = moe.BLOCK,
+                  platform: Optional[str] = None
                   ) -> Tuple[jax.Array, jax.Array]:
     """(B, S) int32 ids → ``(embedding_norm's hidden states (B, S, D),
     counts)``; ``counts`` is (expert layers, held) int32, the batch's
@@ -322,7 +352,7 @@ def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
         else:
             x = x + jax.lax.map(
                 lambda w: attention_block(params, f'{p}.self_attn', w, cfg,
-                                          attn_block),
+                                          attn_block, platform),
                 normed)
         normed = rms_norm(x, params[f'{p}.ffn_norm.weight'], eps
                           ).reshape(b * s, d)
@@ -345,9 +375,7 @@ def forward(params: Params, ids: jax.Array, cfg: TrunkConfig,
             attn_block: int = 1024, moe_block: int = moe.BLOCK,
             platform: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
     """(B, S) int32 ids → ``(features (B, D) float32, counts)``: the mean
-    of the window's final hidden states (:func:`hidden_states`).
-    ``platform`` is part of what every trunk's ``forward`` is handed; this
-    one's step is the same program on each."""
-    del platform
-    x, counts = hidden_states(params, ids, cfg, attn_block, moe_block)
+    of the window's final hidden states (:func:`hidden_states`)."""
+    x, counts = hidden_states(params, ids, cfg, attn_block, moe_block,
+                              platform)
     return mean_features(x), counts

@@ -36,8 +36,10 @@ it agrees with the causal XLA path to the rounding of those passes in another
 summation order (≈ 2e-5 rel-L2 on a window of the benchmark cell, both
 within 7e-5 of ``highest``: PERF.md §6, PR 30), not to the bit.
 :func:`resolve_causal` says where it applies — a TPU, tile-aligned shapes, a
-precision it has a lane for — and the causal path's caller asks it; the XLA
-path is what runs everywhere else and what the kernel is tested against.
+precision it has a lane for — and the causal path's callers ask it (latent
+attention with equal head counts, grouped-query attention with its key-value
+head's query heads a grid step); the XLA path is what runs everywhere else
+and what the kernel is tested against.
 
 Shapes follow (B, S, H, D) [batch, sequence, heads, head_dim].
 """
@@ -104,33 +106,39 @@ KERNEL_PASSES = {None: 1, 'default': 1, 'bfloat16': 1, 'high': 3}
 
 
 def resolve_causal(platform: str, s: int, qk_dim: int, v_dim: int,
-                   precision: Optional[str]) -> str:
+                   precision: Optional[str], heads: int = 1,
+                   kv_heads: int = 1) -> str:
     """Which causal attention compiles for ``s`` positions on ``platform``
     under the ambient matmul ``precision``: 'kernel' (the fused Mosaic
     kernel, ops/pallas_attention.py) or 'xla' (:func:`blockwise_attention`
-    with ``causal=True``).
+    with ``causal=True``). ``heads`` query heads read ``kv_heads`` key-value
+    heads; only their ratio, the group, matters (1 when left out).
 
     The kernel applies on a TPU, where the sequence is a whole number of its
-    tiles and a head's packed keys and values fit its VMEM budget, the value
-    head fills whole 128-lane blocks (its output block is one head's
-    columns), the query/key head is a multiple of 64, and the ambient
-    precision is one it has a lane for (``KERNEL_PASSES``). Anywhere else —
-    the CPU, where it would run interpreted; ragged or odd shapes;
-    'highest' — the XLA path runs, which is also the oracle the kernel is
-    tested against. All of it is static at trace time, so the choice
-    compiles away; there is no switch. Latent attention
-    (``models/latent_moe.py::mla_block``) asks here and hands the kernel its
-    heads as column groups. The kernel takes equal head counts: grouped-query
-    heads (``models/hybrid_trunk.py``: 64-wide, for which the answer is 'xla'
-    by the value head's width already) have no lane in it and run the XLA
-    tiles."""
+    tiles and a key-value head's packed keys and values fit its VMEM budget,
+    the group's value heads fill whole 128-lane blocks (its output block is
+    one key-value head's query heads' columns: 128-wide value heads alone,
+    64-wide ones from a group of two on), the query/key head is a multiple
+    of 64, and the ambient precision is one it has a lane for
+    (``KERNEL_PASSES``). Anywhere else — the CPU, where it would run
+    interpreted; ragged or odd shapes; 'highest' — the XLA path runs, which
+    is also the oracle the kernel is tested against. All of it is static at
+    trace time, so the choice compiles away; there is no switch. Latent
+    attention (``models/latent_moe.py::mla_block``) asks here and hands the
+    kernel its heads as column groups; grouped-query attention
+    (``models/hybrid_trunk.py::attention_block``) asks with its head counts
+    and hands it fewer key-value heads."""
     from video_features_tpu.ops import pallas_attention as kernel
-    if platform != 'tpu' or precision not in KERNEL_PASSES:
+    if (platform != 'tpu' or precision not in KERNEL_PASSES
+            or heads % kv_heads):
         return 'xla'
-    tiles = (min(kernel.BLOCK_Q, s), min(kernel.BLOCK_K, s))
+    group = heads // kv_heads
+    block_q, block_k = kernel.tiles(s, group)
     packed = sum(kernel.packed_widths((qk_dim,), v_dim,
                                       KERNEL_PASSES[precision]))
-    if (any(s % t or t % 128 for t in tiles) or v_dim % 128 or qk_dim % 64
+    if (s % block_q or s % block_k or group * block_q % 128 or block_k % 128
+            or group * v_dim % 128 or qk_dim % 64
+            or (group > 1 and group * qk_dim % 128)
             or 2 * s * packed > kernel.KV_VMEM_BYTES):
         return 'xla'
     return 'kernel'

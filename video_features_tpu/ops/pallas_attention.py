@@ -35,18 +35,34 @@ running max, denominator and accumulator in VMEM scratch:
   once and XLA writes no split, concatenated or padded copy of them;
 * q and k may come as column groups (latent attention's nope and rope
   parts; a key group of one head is shared by all heads), so the caller
-  never writes their concatenation either.
+  never writes their concatenation either;
+* **grouped-query heads are the same kernel on a taller tile.** Where k and
+  v have fewer heads than q (query head j reads key-value head ``j div
+  group``), a grid step is one key-value head and its ``group`` query heads:
+  they are ``group`` adjacent column blocks of (B, S, H·D) as it stands —
+  four 64-wide heads are two whole 128-lane blocks, which is what lets a
+  64-wide value head in — and ride the score tile's rows, head j at rows
+  j·block_q …, each row with its own running max, denominator and
+  accumulator. One QKᵀ and one PV serve the group, a key/value tile is
+  packed once for all its heads (8,192 × (256 + 128) bf16 = 6.3 MB a
+  key-value head in lfm2-moe's cell) and read from VMEM once a tile pair;
+  nothing is repeated, folded or padded outside the kernel. A 64-wide head
+  packs as [hi hi lo 0]: one 256-column contraction. Equal head counts
+  (latent attention) are the case ``group == 1``: every step a group adds
+  is behind a static ``group > 1``, so their module holds none of it.
 
-Arrays enter heads-major, (B, H, S, width) — a block is one head's (tile,
-width) slab; the caller's (B, S, H, width) is transposed here, which XLA
-folds into the producing product's output layout — and the output leaves
-as (B, S, H·v_dim), which is how ``o_proj`` reads it. CPU tests run the
-same kernel body under ``interpret=True`` (tests/test_attention.py).
+Keys and values enter heads-major, (B, H, S, width) — a block is one head's
+(tile, width) slab; the caller's (B, S, H, width) is transposed here, which
+XLA folds into the producing product's output layout — and so do the queries
+of equal head counts; a group's queries enter as (B, S, H·width), no copy.
+The output leaves as (B, S, H·v_dim), which is how ``o_proj`` reads it. CPU
+tests run the same kernel body under ``interpret=True``
+(tests/test_attention.py).
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Sequence, Tuple, Union
+from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -56,7 +72,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 NAME = 'causal_attention'
-# the kernel's tiles at the cell's shapes: my chip runs, PR 30 (PERF.md §6)
+# the kernel's tiles at the cells' shapes — score-tile rows (a group's heads
+# share them: ``tiles``) and keys: my chip runs, PRs 30 and 36 (PERF.md §6)
 BLOCK_Q = 1024
 BLOCK_K = 1024
 # a head's packed keys and values stay in VMEM for all of its query tiles
@@ -111,12 +128,21 @@ def _arrange(a: jax.Array, b: jax.Array, c: jax.Array) -> jax.Array:
     return jnp.concatenate(cols, axis=1).astype(jnp.bfloat16)
 
 
-def _pack_qk(refs, passes: int, key: bool, scale: float = 1.0) -> jax.Array:
+def _pack_qk(refs, passes: int, key: bool, scale: float = 1.0,
+             head: Tuple[int, int] = (0, 1)) -> jax.Array:
     """The column groups of a query (``· scale``) or key tile, packed side
-    by side: q's parts are [hi hi lo] against k's [hi lo hi]."""
+    by side: q's parts are [hi hi lo] against k's [hi lo hi]. ``head`` =
+    (j, group) packs query head j of blocks that hold a key-value head's
+    ``group`` query heads side by side."""
+    j, group = head
     cols = []
     for ref in refs:
-        x = ref[...] * scale if scale != 1.0 else ref[...]
+        if group == 1:
+            x = ref[...]
+        else:
+            width = ref.shape[-1] // group
+            x = ref[:, j * width:(j + 1) * width]
+        x = x * scale if scale != 1.0 else x
         if passes == 1:
             cols.append(x.astype(jnp.bfloat16))
             continue
@@ -142,8 +168,16 @@ def _last_key_tile(qi, block_q: int, block_k: int):
     return (qi * block_q + block_q - 1) // block_k
 
 
-def _kernel(*refs, groups: int, block_q: int, block_k: int, v_dim: int,
-            passes: int, scale: float):
+def _tile_positions(shape, block_q: int, group: int) -> jax.Array:
+    """Each score row's position in its query tile: the row index, and with
+    a group's heads stacked on the rows, block_q a head, its index within
+    its head."""
+    row = lax.broadcasted_iota(jnp.int32, shape, 0)
+    return row if group == 1 else lax.rem(row, block_q)
+
+
+def _kernel(*refs, groups: int, group: int, block_q: int, block_k: int,
+            v_dim: int, passes: int, scale: float):
     q_refs, k_refs = refs[:groups], refs[groups:2 * groups]
     v_ref, o_ref, qc_ref, kc_ref, vc_ref, m_ref, l_ref, acc_ref = \
         refs[2 * groups:]
@@ -151,18 +185,27 @@ def _kernel(*refs, groups: int, block_q: int, block_k: int, v_dim: int,
     first_masked = _first_masked_tile(qi, block_q, block_k)
     last = _last_key_tile(qi, block_q, block_k)
     rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+    # a key-value head's ``group`` query heads ride the score tile's rows:
+    # head j is rows j·block_q … of the packed queries, the running max, the
+    # denominator and the accumulator, so one QKᵀ and one PV serve them all
+    heads = [slice(j * block_q, (j + 1) * block_q) for j in range(group)]
 
     @pl.when(ki == 0)
     def _():
         m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-        qc_ref[...] = _pack_qk(q_refs, passes, key=False, scale=scale)
+        if group == 1:
+            qc_ref[...] = _pack_qk(q_refs, passes, key=False, scale=scale)
+        else:
+            for j, at in enumerate(heads):
+                qc_ref[at, :] = _pack_qk(q_refs, passes, key=False,
+                                         scale=scale, head=(j, group))
 
     def accumulate(masked: bool):
         s = lax.dot_general(qc_ref[...], kc_ref[rows, :], _NT, **_ONE_PASS)
         if masked:
-            row = qi * block_q + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            row = qi * block_q + _tile_positions(s.shape, block_q, group)
             col = ki * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(col <= row, s, -jnp.inf)
         # key 0 is in the first tile and every row sees it, so m_new is
@@ -189,26 +232,46 @@ def _kernel(*refs, groups: int, block_q: int, block_k: int, v_dim: int,
     def _():
         accumulate(masked=False)
 
+    def pack_keys():
+        kc_ref[rows, :] = _pack_qk(k_refs, passes, key=True)
+        vc_ref[rows, :] = _pack_v(v_ref[...], passes)
+
     # a tile the diagonal crosses holds keys no earlier query tile saw: this
     # is its first use, so its float32 block is in (the index map held it
     # back until now) and is packed into the head's VMEM copy here, once
     @pl.when(jnp.logical_and(ki >= first_masked, ki <= last))
     def _():
-        kc_ref[rows, :] = _pack_qk(k_refs, passes, key=True)
-        vc_ref[rows, :] = _pack_v(v_ref[...], passes)
+        if block_q % block_k == 0:
+            pack_keys()
+        else:
+            # a query tile that ends inside a key tile leaves it to be
+            # crossed again: the first to cross it packs, the rest reuse
+            pl.when(_last_key_tile(qi - 1, block_q, block_k) < ki)(pack_keys)
         accumulate(masked=True)
 
     @pl.when(ki == last)
     def _():
-        o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        out = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        # the group's heads leave side by side: query head g·group + j is
+        # column block j of key-value head g's output block
+        o_ref[...] = out if group == 1 else jnp.concatenate(
+            [out[at] for at in heads], axis=1)
 
 
 Parts = Union[jax.Array, Sequence[jax.Array]]
 
 
+def tiles(s: int, group: int = 1) -> Tuple[int, int]:
+    """The shipped (query, key) tiles for ``s`` positions: a score tile has
+    at most BLOCK_Q rows — ``group`` query heads at the largest power of
+    two of positions that leaves — and BLOCK_K keys."""
+    block_q = 1 << max(BLOCK_Q // group, 1).bit_length() - 1
+    return min(block_q, s), min(BLOCK_K, s)
+
+
 def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
-                     passes: int, block_q: int = BLOCK_Q,
-                     block_k: int = BLOCK_K,
+                     passes: int, block_q: Optional[int] = None,
+                     block_k: Optional[int] = None,
                      interpret: bool = False) -> jax.Array:
     """softmax(QKᵀ·scale + causal mask)V over (B, S, H, D) float32 tensors
     (v may be narrower), float32 (B, S, H, v's width) out, ``passes`` (1 or
@@ -217,10 +280,15 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
     ``q`` and ``k`` may each come as a sequence of column groups whose
     concatenation along D is the head — latent attention's (nope, rope) —
     so that no one has to write the concatenation to memory; a key group
-    with ONE head is shared by all heads (its rotary key). S must be a
-    multiple of both tiles and every group's width of 64; compiled (not
-    interpreted), tiles and v's width must be multiples of 128. One trace
-    event a call, whatever B."""
+    with ONE head is shared by all heads (its rotary key). ``k`` and ``v``
+    may have a whole fraction of q's heads (grouped-query: query head j
+    reads key-value head ``j div group``); a grid step is then one
+    key-value head and its ``group`` query heads, whose columns — adjacent
+    in (B, S, H·D) — must fill whole 128-lane blocks. S must be a multiple
+    of both tiles (by default :func:`tiles`) and every group's width of 64;
+    compiled (not interpreted), the key tile, the score tile's rows and the
+    output block's columns (``group`` · v's width) must be multiples of
+    128. One trace event a call, whatever B."""
     if passes not in (1, 3):
         raise ValueError(f'causal_attention makes 1 or 3 bf16 passes, not '
                          f'{passes}')
@@ -234,15 +302,41 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
         raise ValueError(f'causal_attention: query/key groups of {widths} '
                          f'columns are no multiples of {LANES // 2}')
     b, s, h, _ = q_parts[0].shape
-    v_dim = v.shape[-1]
-    block_q, block_k = min(block_q, s), min(block_k, s)
+    kv_heads, v_dim = v.shape[2:]
+    if h % kv_heads:
+        raise ValueError(f'causal_attention: {h} query heads are no whole '
+                         f'number of groups of {kv_heads} key-value heads')
+    group = h // kv_heads
+    if any(x.shape[2] not in (kv_heads, 1) for x in k_parts):
+        raise ValueError(
+            f'causal_attention: key groups of '
+            f'{[x.shape[2] for x in k_parts]} heads beside {kv_heads} value '
+            f'heads (a key group has as many, or one for all)')
+    if group > 1 and any(group * w % LANES for w in (*widths, v_dim)):
+        raise ValueError(
+            f'causal_attention: {group} query heads a key-value head of '
+            f'{widths} query/key and {v_dim} value columns fill no whole '
+            f'{LANES}-lane blocks')
+    block_q, block_k = (min(given or shipped, s) for given, shipped
+                        in zip((block_q, block_k), tiles(s, group)))
     if s % block_q or s % block_k:
         raise ValueError(f'causal_attention: {s} positions are no multiple '
                          f'of the tiles ({block_q}, {block_k})')
-    # heads-major: a block is one head's (tile, width) slab
-    q_parts, k_parts, (vt,) = (
-        [jnp.asarray(x, jnp.float32).transpose(0, 2, 1, 3) for x in parts]
-        for parts in (q_parts, k_parts, (v,)))
+
+    def heads_major(parts):
+        """(B, S, H, width) → (B, H, S, width): a block is one head's (tile,
+        width) slab."""
+        return [jnp.asarray(x, jnp.float32).transpose(0, 2, 1, 3)
+                for x in parts]
+
+    if group == 1:
+        q_parts = heads_major(q_parts)
+    else:
+        # a key-value head's query heads are adjacent columns of (B, S, H·D)
+        # as it stands: a block is their (tile, group · width) slab
+        q_parts = [jnp.asarray(x, jnp.float32).reshape(b, s, -1)
+                   for x in q_parts]
+    k_parts, (vt,) = heads_major(k_parts), heads_major((v,))
     c_qk, c_v = packed_widths(widths, v_dim, passes)
 
     def kv_tile(qi, ki):
@@ -260,26 +354,30 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
             lambda bi, hi, qi, ki: (bi, 0 if shared else hi, tile(qi, ki), 0))
 
     def q_spec(x):
+        if group > 1:
+            return pl.BlockSpec((None, block_q, x.shape[-1] // kv_heads),
+                                lambda bi, hi, qi, ki: (bi, qi, hi))
         return spec(x, block_q, lambda qi, ki: qi)
 
     def kv_spec(x):
         return spec(x, block_k, kv_tile)
 
+    rows = group * block_q
     out = pl.pallas_call(
-        partial(_kernel, groups=len(widths), block_q=block_q,
+        partial(_kernel, groups=len(widths), group=group, block_q=block_q,
                 block_k=block_k, v_dim=v_dim, passes=passes, scale=scale),
-        grid=(b, h, s // block_q, s // block_k),
+        grid=(b, kv_heads, s // block_q, s // block_k),
         in_specs=[*map(q_spec, q_parts), *map(kv_spec, k_parts),
                   kv_spec(vt)],
-        out_specs=pl.BlockSpec((None, block_q, v_dim),
+        out_specs=pl.BlockSpec((None, block_q, group * v_dim),
                                lambda bi, hi, qi, ki: (bi, qi, hi)),
         out_shape=jax.ShapeDtypeStruct((b, s, h * v_dim), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, c_qk), jnp.bfloat16),
+        scratch_shapes=[pltpu.VMEM((rows, c_qk), jnp.bfloat16),
                         pltpu.VMEM((s, c_qk), jnp.bfloat16),
                         pltpu.VMEM((s, c_v), jnp.bfloat16),
-                        pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32),
-                        pltpu.VMEM((block_q, v_dim), jnp.float32)],
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, 1), jnp.float32),
+                        pltpu.VMEM((rows, v_dim), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             # query tiles of a head run in order: each packs the key tiles
             # its diagonal crosses for the tiles after it
