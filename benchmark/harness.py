@@ -371,6 +371,11 @@ def per_layer_metrics(cell: Dict, ctx: Dict, trace_dir: str):
                                  reduced=reduced))
         if value is not None:
             out[m['name']] = {'value': float(value), 'unit': m['unit']}
+    # the breakdown's device time by the program's scope paths where it
+    # exports its map (names a recompile keeps), by HLO name where not
+    by_scope = loader.load_module('readers', 'scope_time').device_ops(trace)
+    if by_scope:
+        reduced = dict(reduced, device_ops=by_scope)
     return out, reduced
 
 

@@ -34,13 +34,9 @@ the kernel (``%retention_read.<n> = … custom-call(…)``), as it names
 ``%causal_attention.<n>``; the state update beside it is
 ``%retention_update.<n>`` and does not match.
 
-**No metric reads this file yet.** ``retention_read_roofline`` (reader
-``kernel_roofline``, ``match`` = ``EVENT_MATCH``, ``events_per_call`` =
-``EVENTS_PER_CALL``, ``moves`` ``clips_per_s``, ``workloads``
-``["brumby.corpus"]``) needs its entry in ``BENCHMARK.json`` and its file
-under ``metrics/``, and ``tests/bench/test_brumby.py`` pins the cell's
-per-layer metrics as a set: a file only a ``benchmark`` PR may edit (PERF.md
-§7, PR 32).
+``metrics/retention_read_roofline.json`` (PR 37, ``brumby.corpus``) reads
+this file through ``readers/kernel_roofline.py`` with ``match`` =
+``EVENT_MATCH`` and ``events_per_call`` = ``EVENTS_PER_CALL``.
 
 The chunk is the program's constant (``models/retention_trunk.py::
 RETENTION_CHUNK``; a window shorter than it is one chunk), read from there;

@@ -24,7 +24,8 @@ compiled step was traced under and says so, once per executable
 The metric's ``scope`` names the row; its value is seconds under the scope ×
 1000 ÷ units saved in the window: a cost, not a share, so one part getting
 faster does not make the others read worse. The metrics of one trace share one
-table, logged whole.
+table, logged whole; ``device_ops`` hands the harness the same table's rows by
+scope path for ``breakdown.device_ops``, names a recompile keeps.
 
 No number, never a guess, when: no map is noted for a program that ran (a
 parent commit, a synthetic trace); a map's ``missing`` is not empty (the
@@ -46,21 +47,44 @@ UNMAPPED = '(instruction not in the map)'
 MAX_UNMAPPED = 0.01
 MAX_SUM_ERROR = 0.01
 
-_memo = {'trace': None, 'table': None}     # the metrics share one table
+_memo: Dict = {}     # a trace's join, and the table its metrics share
 
 
 def read(ctx) -> Optional[float]:
-    if _memo['trace'] is not ctx['trace']:
-        _memo['trace'], _memo['table'] = ctx['trace'], _table(ctx)
-    table = _memo['table']
+    memo = _joined(ctx['trace'])
+    if 'table' not in memo:
+        memo['table'] = _table(ctx, memo['result'])
+    table = memo['table']
     if table is None or ctx['metric']['scope'] not in table:
         return None
     return 1e3 * table[ctx['metric']['scope']] / ctx['units']
 
 
-def _table(ctx) -> Optional[Dict[str, float]]:
+def device_ops(trace: Dict, top: int = 10) -> Optional[List[List]]:
+    """The ``top`` rows of the table by scope path, ``[[path, seconds], ...]``
+    with the leftover rows among them, or ``None`` where ``attribute``
+    refuses or the programs that ran open no scope (then the harness keeps
+    the HLO names, which say more than one ``(unscoped)`` row)."""
+    result = _joined(trace)['result']
+    if 'refused' in result or not result['paths']:
+        return None
+    rows = dict(result['paths'])
+    rows.update({UNSCOPED: result['scopes'][UNSCOPED],
+                 NO_OP_NAME: result['no_op_name_s'],
+                 UNMAPPED: result['unmapped_s']})
+    ranked = sorted(rows.items(), key=lambda kv: -kv[1])[:top]
+    return [[name, seconds] for name, seconds in ranked if seconds > 0]
+
+
+def _joined(trace: Dict) -> Dict:
+    if _memo.get('trace') is not trace:
+        _memo.clear()
+        _memo.update(trace=trace, result=attribute(trace, _noted()))
+    return _memo
+
+
+def _table(ctx, result: Dict) -> Optional[Dict[str, float]]:
     log, units = ctx['log'], ctx['units']
-    result = attribute(ctx['trace'], _noted())
     if 'refused' not in result and not units:
         result = {'refused': 'no unit was saved in the window'}
     if 'refused' in result:

@@ -1,5 +1,9 @@
-"""BENCHMARK.json and the files under benchmark/ agree, and every name and
-unit uses only the characters the contract allows."""
+"""BENCHMARK.json and the files under benchmark/ agree, every name and unit
+uses only the characters the contract allows, and a later PR's appends fit:
+no test under tests/bench depends on an entry's place or on how many there
+are."""
+import ast
+import copy
 import json
 import re
 
@@ -185,3 +189,186 @@ def test_cells_report_their_end_to_end_metrics(bench_json, cell,
     got = harness.metrics_of({'name': cell, 'bench': bench_json},
                              'end_to_end')
     assert {m['name'] for m in got} == metric_names
+
+
+# -- the gate: a later PR appends, and nothing that is there may mind --------
+
+LISTS = ('configs', 'workloads', 'per_layer')
+
+
+def test_an_appended_configuration_cell_and_metric_change_no_other_cell(
+        bench_json):
+    """What a program PR may do to BENCHMARK.json: append a configuration, a
+    one-chip cell that reports ``clips_per_s`` and a per-layer entry that
+    lists only that cell. Every cell that is there keeps its metrics, name
+    for name; the new cell gets the list-less ``.clips`` metrics and its
+    own."""
+    import harness
+
+    def names(bench, cell, group):
+        return [m['name'] for m in harness.metrics_of(
+            {'name': cell, 'bench': bench}, group)]
+
+    cells = [w['name'] for w in bench_json['workloads']]
+    before = {(c, g): names(bench_json, c, g)
+              for c in cells for g in ('end_to_end', 'per_layer')}
+    grown = copy.deepcopy(bench_json)
+    grown['configs'].append({
+        'name': 'appended-config', 'source': 'https://example.org/config.json',
+        'file': 'benchmark/configs/appended-config.json', 'reduced': [],
+        'why': 'a configuration a later PR appends'})
+    grown['workloads'].append({
+        'name': 'appended.corpus', 'config': 'appended-config',
+        'traffic': 'corpus-8', 'chips': 1, 'why': 'a cell a later PR appends'})
+    for m in grown['end_to_end']:
+        if m['name'] == 'clips_per_s':
+            m['workloads'].append('appended.corpus')
+    grown['per_layer'].append({
+        'name': 'appended_ms.clips', 'unit': 'ms/clip', 'better': 'lower',
+        'source': 'device_trace', 'layer': 'device step',
+        'moves': 'clips_per_s', 'workloads': ['appended.corpus']})
+    for (cell, group), was in before.items():
+        assert names(grown, cell, group) == was, (cell, group)
+    assert names(grown, 'appended.corpus', 'end_to_end') == [
+        'clips_per_s', 'setup_s']
+    listless = [m['name'] for m in bench_json['per_layer']
+                if 'workloads' not in m and m['moves'] == 'clips_per_s']
+    assert listless and names(grown, 'appended.corpus', 'per_layer') == \
+        listless + ['appended_ms.clips']
+    # a per-layer entry appended for a cell that is there reaches that cell
+    # and no other
+    grown['per_layer'].append({
+        'name': 'appended_too.clips', 'unit': '%', 'better': 'higher',
+        'source': 'program_counter', 'layer': 'device step',
+        'moves': 'clips_per_s', 'workloads': [cells[0]]})
+    for (cell, group), was in before.items():
+        more = ['appended_too.clips'] if (cell, group) == (
+            cells[0], 'per_layer') else []
+        assert names(grown, cell, group) == was + more, (cell, group)
+
+
+def pinned_places(source: str):
+    """Where a test's source holds an entry of ``bench_json['configs' |
+    'workloads' | 'per_layer']`` to a place, or the list to a length:
+    ``(line, what)`` for an integer index of the list, a ``len(`` of it as a
+    side of a comparison other than the contract's own range check
+    (``1 <= len(x) <= limit``), and an ``==`` between a comprehension over
+    the whole list and a list written out or multiplied out. The list is
+    ``bench_json[key]`` itself or, within one function, a name it was
+    assigned to."""
+    tree = ast.parse(source)
+    functions = [n for n in tree.body if isinstance(n, ast.FunctionDef)]
+    found = _pinned_in([n for n in tree.body if n not in functions])
+    for function in functions:
+        found |= _pinned_in([function])
+    return sorted(found)
+
+
+def _pinned_in(statements):
+    aliases = set()
+
+    def is_list(node):
+        if isinstance(node, ast.Name):
+            return node.id in aliases
+        return (isinstance(node, ast.Subscript)
+                and isinstance(node.value, ast.Name)
+                and node.value.id.startswith('bench')
+                and isinstance(node.slice, ast.Constant)
+                and node.slice.value in LISTS)
+
+    def is_len(node):
+        return (isinstance(node, ast.Call) and isinstance(node.func, ast.Name)
+                and node.func.id == 'len' and len(node.args) == 1
+                and is_list(node.args[0]))
+
+    def whole(node):
+        return (isinstance(node, (ast.ListComp, ast.GeneratorExp, ast.SetComp))
+                and len(node.generators) == 1
+                and is_list(node.generators[0].iter)
+                and not node.generators[0].ifs)
+
+    def written_out(node):
+        return isinstance(node, (ast.List, ast.Tuple)) or (
+            isinstance(node, ast.BinOp) and isinstance(node.op, ast.Mult))
+
+    nodes = [n for statement in statements for n in ast.walk(statement)]
+    for node in nodes:
+        if isinstance(node, ast.Assign) and is_list(node.value):
+            aliases.update(t.id for t in node.targets
+                           if isinstance(t, ast.Name))
+    found = set()
+    for node in nodes:
+        if isinstance(node, ast.Subscript) and is_list(node.value):
+            index = node.slice
+            if isinstance(index, ast.UnaryOp):
+                index = index.operand
+            if isinstance(index, ast.Constant) and isinstance(index.value,
+                                                              int):
+                found.add((node.lineno, 'an index'))
+        if not isinstance(node, ast.Compare):
+            continue
+        sides = [node.left] + node.comparators
+        the_range = (len(sides) == 3 and is_len(sides[1])
+                     and all(isinstance(op, ast.LtE) for op in node.ops)
+                     and isinstance(sides[0], ast.Constant)
+                     and isinstance(sides[2], ast.Constant))
+        if any(is_len(x) for x in sides) and not the_range:
+            found.add((node.lineno, 'a len('))
+        if any(isinstance(op, ast.Eq) for op in node.ops) \
+                and any(whole(x) for x in sides) \
+                and any(written_out(x) for x in sides):
+            found.add((node.lineno, 'a literal list length'))
+    return found
+
+
+PINS = {
+    'the last cell': ("assert bench_json['workloads'][-1]['name'] == CELL",
+                      'an index'),
+    'the last configuration': (
+        "assert bench_json['configs'][-1]['name'] == CONFIG", 'an index'),
+    'the last metric': ("assert bench_json['per_layer'][-1] == entry",
+                        'an index'),
+    'the first, through a name': (
+        "metrics = bench_json['per_layer']\nassert metrics[0] == entry",
+        'an index'),
+    'five cells': ("assert [w['chips'] for w in bench_json['workloads']] "
+                   "== [1] * 5", 'a literal list length'),
+    'names written out': (
+        "cells = bench_json['workloads']\n"
+        "assert [w['name'] for w in cells] == ['a', 'b']",
+        'a literal list length'),
+    'a count': ("assert len(bench_json['configs']) == 5", 'a len('),
+    'a count through a name': (
+        "cells = bench_json['workloads']\nassert len(cells) > 4", 'a len('),
+}
+NO_PINS = {
+    'presence': "entry = [w for w in bench_json['workloads'] "
+                "if w['name'] == CELL][0]",
+    "the contract's range": "configs = bench_json['configs']\n"
+                            "assert 1 <= len(configs) <= 24",
+    'a set of names': "assert {w['name'] for w in bench_json['workloads']} "
+                      ">= {'i3d.corpus'}",
+    "another list's index": "assert bench_json['paths'][0] == 'benchmark'",
+    'a filtered count': "assert len([w for w in bench_json['workloads'] "
+                        "if w['chips'] == 4]) <= 1",
+}
+
+
+@pytest.mark.parametrize('case', sorted(PINS))
+def test_the_scan_finds_a_pinned_place_or_count(case):
+    source, what = PINS[case]
+    assert [w for _, w in pinned_places(source)] == [what]
+
+
+@pytest.mark.parametrize('case', sorted(NO_PINS))
+def test_the_scan_lets_presence_and_the_contracts_limits_be(case):
+    assert pinned_places(NO_PINS[case]) == []
+
+
+def test_no_test_of_the_benchmark_pins_an_entrys_place_or_a_count():
+    """Entries are appended; a test may say an entry is present and right,
+    never that it is last or that there are N (benchmark/README.md)."""
+    files = sorted((REPO / 'tests' / 'bench').glob('*.py'))
+    assert files
+    pinned = {f.name: pinned_places(f.read_text()) for f in files}
+    assert {k: v for k, v in pinned.items() if v} == {}

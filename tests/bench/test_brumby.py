@@ -1,7 +1,8 @@
 """The cell ``brumby.corpus`` and its configuration ``brumby-14b-l4``: the
 configuration file against the published config, the FLOP count recounted,
 a whole run of the cell through ``harness.run`` at a tiny size on the CPU —
-sound, then broken underneath — and the reader of the state-scan counter.
+sound, then broken underneath. (The state read's roofline:
+``tests/bench/test_retention_read.py``.)
 (The trunk, its mixer and the extractor against the plain reference:
 ``tests/test_retention_trunk.py``, ``tests/test_retention.py``.)"""
 import json
@@ -15,7 +16,6 @@ import harness
 import loader
 from _layers import Ops
 
-from .conftest import BENCH
 
 CELL = 'brumby.corpus'
 SEED = 2 ** 31 + 2031
@@ -75,10 +75,16 @@ def test_the_cell_reports_its_end_to_end_metrics(bench_json):
     assert {m['name'] for m in got} == {'clips_per_s', 'setup_s'}
     per_layer = {m['name'] for m in harness.metrics_of(
         {'name': CELL, 'bench': bench_json}, 'per_layer')}
-    # the whole step's share of peak stands beside the mixer's counter
-    assert per_layer == {'batch_occupancy.clips', 'decode_busy.clips',
-                         'device_idle.clips', 'step_mfu.clips',
-                         'retention_state.clips'}
+    # the whole step's share of peak stands beside the state read's
+    # roofline and the mixer's device time (PR 37; retention_state.clips, a
+    # constant 100, went with them); a later PR may list more for the cell
+    assert per_layer >= {
+        'batch_occupancy.clips', 'decode_busy.clips', 'device_idle.clips',
+        'step_mfu.clips', 'tokenise_busy.clips', 'device_wait.clips',
+        'input_wait.clips', 'idle_decode.clips', 'idle_unexplained.clips',
+        'retention_ms.clips', 'dense_mlp_ms.clips', 'unscoped_ms.clips',
+        'retention_read_roofline'}
+    assert 'retention_state.clips' not in per_layer
     entry = [w for w in bench_json['workloads'] if w['name'] == CELL][0]
     assert (entry['config'], entry['traffic'], entry['chips']) == (
         'brumby-14b-l4', 'corpus-6', 1)
@@ -245,33 +251,3 @@ def test_the_precision_control_is_not_correct(tiny_reference, tmp_path):
     assert n == 9                       # 2 + 4 of 5 + 3 windows
     assert checks['rows_off']['ok'] and checks['nonfinite']['ok']
     assert not checks['rel_l2']['ok']
-
-
-# -- the state-scan counter's reader --------------------------------------------------
-
-def test_retention_state_reads_the_counter_and_nothing_where_there_is_none(
-        bench_json):
-    reader = loader.load_module('readers', 'stage_occupancy')
-    spec = json.loads((BENCH / 'metrics' / 'retention_state.clips.json')
-                      .read_text())
-    assert (spec['reader'], spec['stage']) == ('stage_occupancy',
-                                               'retention_scan')
-    entry = [m for m in bench_json['per_layer']
-             if m['name'] == 'retention_state.clips'][0]
-    assert entry == {'name': 'retention_state.clips', 'unit': '%',
-                     'better': 'higher', 'source': 'program_counter',
-                     'layer': 'device step', 'moves': 'clips_per_s',
-                     'workloads': ['brumby.corpus']}
-    full = {'retention_scan': {'count': 0, 'total_s': 0.0,
-                               'occ_valid': 10 * 4 * 32768,
-                               'occ_capacity': 10 * 4 * 32768}}
-    assert reader.read({'metric': spec, 'stages': full,
-                        'log': print}) == 100.0
-    # three of ten windows went another way than the state scan
-    part = {'retention_scan': {'occ_valid': 7 * 4 * 32768,
-                               'occ_capacity': 10 * 4 * 32768}}
-    assert reader.read({'metric': spec, 'stages': part,
-                        'log': print}) == pytest.approx(70.0)
-    # the parent commit, or the other trunk: no such counter, no number
-    assert reader.read({'metric': spec, 'stages': {'model': {'count': 3}},
-                        'log': print}) is None

@@ -1,6 +1,7 @@
 """The causal-attention kernel's yardstick: operations and bytes by hand at
-the cell's shapes, the event pattern against the name the lowered step
-emits, and the roofline reader over the kernel file."""
+the two cells' shapes (latent attention's equal heads, grouped queries), the
+event pattern against the name the lowered step emits, and the roofline
+reader over the kernel file through the listed metric's own file."""
 import json
 import re
 
@@ -8,7 +9,10 @@ import pytest
 
 import loader
 
-CELL = {'positions': 8192, 'heads': 32, 'qk_dim': 192, 'v_dim': 128}
+CELL = {'positions': 8192, 'heads': 32, 'kv_heads': 32, 'qk_dim': 192,
+        'v_dim': 128}
+GQA_CELL = {'positions': 8192, 'heads': 32, 'kv_heads': 8, 'qk_dim': 64,
+            'v_dim': 64}
 
 
 @pytest.fixture(scope='module')
@@ -24,9 +28,15 @@ def v5e():
 
 @pytest.mark.parametrize('shape,pairs', [
     # 4 positions: 1 + 2 + 3 + 4 visible pairs
-    ({'positions': 4, 'heads': 1, 'qk_dim': 3, 'v_dim': 2}, 10),
-    ({'positions': 4, 'heads': 5, 'qk_dim': 3, 'v_dim': 2}, 50),
+    ({'positions': 4, 'heads': 1, 'kv_heads': 1, 'qk_dim': 3, 'v_dim': 2},
+     10),
+    ({'positions': 4, 'heads': 5, 'kv_heads': 5, 'qk_dim': 3, 'v_dim': 2},
+     50),
+    # grouped queries: every query head meets every visible key
+    ({'positions': 4, 'heads': 6, 'kv_heads': 2, 'qk_dim': 3, 'v_dim': 2},
+     60),
     (CELL, 8192 * 8193 // 2 * 32),
+    (GQA_CELL, 8192 * 8193 // 2 * 32),
 ])
 def test_flops_are_two_a_visible_pair_and_column(kernel, shape, pairs):
     assert kernel.flops(**shape) == 2 * pairs * (shape['qk_dim']
@@ -46,20 +56,60 @@ def test_the_cells_window_layer_by_hand(kernel, v5e):
         == pytest.approx(0.82, abs=0.005)
 
 
-def test_shapes_come_from_the_configuration_and_the_shipped_yml(kernel):
-    cfg = loader.load_json('configs', 'joyai-llm-flash-ep4')
-    assert kernel.window_positions() == 32 * 16 ** 2
+def test_the_grouped_query_cells_window_layer_by_hand(kernel, v5e):
+    # 33,558,528 pairs a head x 32 query heads x (64 + 64) columns x 2
+    assert kernel.flops(**GQA_CELL) == 274_911_461_376
+    assert kernel.flops(**GQA_CELL) / 1e9 == pytest.approx(274.9, abs=0.05)
+    # Q read and O written a query head, K and V read a key-value head:
+    # 8192 x (32 + 8) x 128 x 4
+    assert kernel.bytes_moved(**GQA_CELL) == 167_772_160
+    least, bound = kernel.min_seconds(v5e, **GQA_CELL)
+    assert bound == 'flops'
+    assert least * 1e3 == pytest.approx(1.395, abs=0.005)
+    assert kernel.bytes_moved(**GQA_CELL) / v5e['hbm_bytes_per_s'] * 1e3 \
+        == pytest.approx(0.205, abs=0.005)
+
+
+@pytest.mark.parametrize('config,shape', [
+    ('joyai-llm-flash-ep4', CELL),
+    ('lfm2-8b-a1b-l8', GQA_CELL),
+])
+def test_shapes_come_from_the_configuration_and_the_shipped_yml(
+        kernel, config, shape):
+    cfg = loader.load_json('configs', config)
+    # neither cell spells a window: the program's shipped configs/lm.yml
+    assert not {'stack_size', 'patch_grid'} & set(cfg['overrides'])
+    assert kernel.window_positions(cfg) == 32 * 16 ** 2
     # one event is one window of one layer: the batch does not enter
-    assert kernel.shapes(cfg, 4) == kernel.shapes(cfg, 1) == CELL
+    assert kernel.shapes(cfg, 4) == kernel.shapes(cfg, 1) == shape
+
+
+def test_a_configuration_that_spells_its_window_is_read_at_that_window(
+        kernel):
+    # brumby's cell runs windows of 32 x 32^2 ids; a grouped-query trunk at
+    # its widths would be sized there, not at the yml's 8,192
+    cfg = loader.load_json('configs', 'brumby-14b-l4')
+    assert kernel.window_positions(cfg) == 32768
+    assert kernel.shapes(cfg, 1) == {
+        'positions': 32768, 'heads': 40, 'kv_heads': 8, 'qk_dim': 128,
+        'v_dim': 128}
+    # the head's width where the configuration spells none: hidden / heads
+    lfm2 = loader.load_json('configs', 'lfm2-8b-a1b-l8')
+    assert 'head_dim' not in lfm2
+    assert lfm2['hidden_size'] // lfm2['num_attention_heads'] == 64
+    assert lfm2['assumed']['head_dim'].startswith(
+        'hidden_size / num_attention_heads = 64')
 
 
 def _spec(kernel):
-    """The metric file a benchmark PR will add for this kernel, from the
-    kernel file's own constants (no metric reads the file yet: its
-    docstring says why)."""
-    return {'name': 'causal_attention_roofline', 'kernel': 'causal_attention',
-            'match': kernel.EVENT_MATCH,
-            'events_per_call': kernel.EVENTS_PER_CALL}
+    """The metric's own file: its pattern and count are the kernel file's."""
+    spec = loader.load_json('metrics', 'causal_attention_roofline')
+    assert (spec['reader'], spec['kernel'], spec['match'],
+            spec['events_per_call']) == (
+        'kernel_roofline', 'causal_attention', kernel.EVENT_MATCH,
+        kernel.EVENTS_PER_CALL)
+    assert spec['workloads'] == ['joyai-flash.corpus', 'lfm2-moe.corpus']
+    return spec
 
 
 def test_the_event_pattern_matches_the_lowered_kernel_and_nothing_else(
@@ -85,11 +135,16 @@ def test_the_event_pattern_matches_the_lowered_kernel_and_nothing_else(
     assert kernel.EVENTS_PER_CALL == 1
 
 
-def test_the_roofline_reader_counts_one_call_an_event(kernel, v5e):
+@pytest.mark.parametrize('config,shape', [
+    ('joyai-llm-flash-ep4', CELL),
+    ('lfm2-8b-a1b-l8', GQA_CELL),
+])
+def test_the_roofline_reader_counts_one_call_an_event(kernel, v5e, config,
+                                                      shape):
     import trace_reduce
     roof = loader.load_module('readers', 'kernel_roofline')
-    cfg = loader.load_json('configs', 'joyai-llm-flash-ep4')
-    least, _ = kernel.min_seconds(v5e, **CELL)
+    cfg = loader.load_json('configs', config)
+    least, _ = kernel.min_seconds(v5e, **shape)
     name = ('%causal_attention.{} = f32[1,8192,4096]{{2,1,0:T(8,128)}} '
             'custom-call(f32[1,32,8192,128]{{3,2,1,0}} %q), '
             'custom_call_target="tpu_custom_call"')

@@ -129,17 +129,27 @@ def test_kernel_roofline_counts_a_call_per_four_level_events():
     k = loader.load_module('kernels', 'raft_lookup')
     least, _ = k.min_seconds(peaks['devices']['TPU v5 lite'],
                              **k.shapes(cfg, 8))
-    name = ('%closed_call.{} = f32[81,176128]{{1,0:T(8,128)}} custom-call('
-            's32[1,176128]{{1,0}} %a), custom_call_target="tpu_custom_call"')
+    name = ('%raft_corr_lookup_lanes.{} = f32[81,176128]{{1,0:T(8,128)}} '
+            'custom-call(s32[1,176128]{{1,0}} %a), custom_call_target='
+            '"tpu_custom_call"')
     other = ('%custom-call.7 = f32[136,32,43,128]{3,0,2,1} custom-call('
              'f32[136,8,43,128]{3,0,2,1} %b), custom_call_target='
              '"ConcatBitcast"')
+    # a second Mosaic kernel in the step is not the lookup's time
+    mosaic = ('%causal_attention.1 = f32[1,8192,4096]{2,1,0} custom-call('
+              '%q), custom_call_target="tpu_custom_call"')
     # two lookups of four levels, each level taking the least time of a
     # whole call: the share is a quarter
     events = [(name.format(i % 4), 10.0 * i, least * 1e9) for i in range(8)]
-    events.append((other, 1000.0, 5e9))
+    events += [(other, 1000.0, 5e9), (mosaic, 7e9, 5e9)]
     trace = {'planes': [{'name': '/device:TPU:0', 'lines': [
         {'name': trace_reduce.OPS_LINE, 'events': events}]}]}
     ctx = {'metric': spec, 'trace': trace, 'config': cfg, 'batch_size': 8,
            'peaks': peaks['devices']['TPU v5 lite'], 'log': lambda *a: None}
     assert roof.read(ctx) == pytest.approx(25.0)
+    # the pattern is the kernel's own name, as the program gives it
+    from video_features_tpu.ops import pallas_corr
+    import inspect
+    assert "name='raft_corr_lookup_lanes'" in inspect.getsource(pallas_corr)
+    assert spec['match'].startswith('^%raft_corr_lookup_lanes')
+    assert 'match_note' not in spec

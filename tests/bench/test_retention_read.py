@@ -64,12 +64,14 @@ def test_shapes_come_from_the_configuration_and_the_programs_chunk(kernel):
 
 
 def _spec(kernel):
-    """The metric file a benchmark PR will add for this kernel, from the
-    kernel file's own constants (no metric reads the file yet: its
-    docstring says why)."""
-    return {'name': 'retention_read_roofline', 'kernel': 'retention_read',
-            'match': kernel.EVENT_MATCH,
-            'events_per_call': kernel.EVENTS_PER_CALL}
+    """The metric's own file: its pattern and count are the kernel file's."""
+    spec = loader.load_json('metrics', 'retention_read_roofline')
+    assert (spec['reader'], spec['kernel'], spec['match'],
+            spec['events_per_call']) == (
+        'kernel_roofline', 'retention_read', kernel.EVENT_MATCH,
+        kernel.EVENTS_PER_CALL)
+    assert spec['workloads'] == ['brumby.corpus']
+    return spec
 
 
 def test_the_event_pattern_matches_the_read_and_nothing_else(kernel):

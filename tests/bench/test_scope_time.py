@@ -149,7 +149,7 @@ def test_refusals_give_no_number_and_say_why(case, monkeypatch):
     synthetic, maps, units, why = REFUSALS[case]
     logged = []
     monkeypatch.setattr(st, '_noted', lambda: maps)
-    monkeypatch.setattr(st, '_memo', {'trace': None, 'table': None})
+    monkeypatch.setattr(st, '_memo', {})
     ctx = {'trace': synthetic, 'units': units, 'metric': {'scope': 'raft_gru'},
            'reduced': {'modules_total_s': 12.0},
            'log': lambda *a: logged.append(' '.join(map(str, a)))}
@@ -172,31 +172,18 @@ def test_a_program_without_the_module_gives_nothing_and_does_not_raise(
     assert st._noted() == {}
 
 
-def spec(name, layer, scope, what):
-    return {'name': name, 'layer': layer, 'unit': 'ms/clip', 'better': 'lower',
-            'source': 'device_trace', 'moves': 'clips_per_s',
-            'workloads': ['i3d.corpus'], 'reader': 'scope_time',
-            'scope': scope, 'what': what + ' / stacks saved'}
-
-
-SPECS = {s['name']: s for s in (
-    spec('raft_update_ms.clips', 'device step', 'raft_update',
-         "device time of RAFT's refinement scans (both lax.scan calls of "
-         'models/raft.py::_refine: lookup, motion encoder, GRU, flow head, '
-         '20 updates a pair)'),
-    spec('raft_lookup_ms.clips', 'kernels', 'raft_lookup',
-         "device time of the update's correlation lookup: the four Mosaic "
-         'calls and the concatenate / transpose / reshape that deliver '
-         '(128, 32, 43, 324)'),
-    spec('raft_gru_ms.clips', 'device step', 'raft_gru',
-         "device time of the update's separable GRU (models/raft.py::"
-         'sep_conv_gru)'),
-    spec('i3d_towers_ms.clips', 'device step', 'i3d_towers',
-         'device time of both I3D towers (models/i3d.py::forward, the '
-         'folded stem included)'),
-    spec('unscoped_ms.clips', 'device', st.UNSCOPED,
-         "device time of op events whose instruction the program's map "
-         'holds under no scope: what the tracing cannot name yet'))}
+FIVE = ('raft_update_ms.clips', 'raft_lookup_ms.clips', 'raft_gru_ms.clips',
+        'i3d_towers_ms.clips', 'unscoped_ms.clips')
+SPECS = {name: loader.load_json('metrics', name) for name in FIVE}
+LM_SCOPES = {
+    'mla_ms.clips': ('mla', {'joyai-flash.corpus'}),
+    'moe_ms.clips': ('moe', {'joyai-flash.corpus', 'lfm2-moe.corpus'}),
+    'dense_mlp_ms.clips': ('dense_mlp', {'joyai-flash.corpus',
+                                         'brumby.corpus', 'lfm2-moe.corpus'}),
+    'retention_ms.clips': ('retention', {'brumby.corpus'}),
+    'attention_ms.clips': ('attention', {'lfm2-moe.corpus'}),
+    'short_conv_ms.clips': ('short_conv', {'lfm2-moe.corpus'}),
+}
 
 
 def test_the_five_metrics_share_one_table(monkeypatch):
@@ -205,7 +192,7 @@ def test_the_five_metrics_share_one_table(monkeypatch):
     monkeypatch.setattr(st, 'attribute',
                         lambda *a: calls.append(1) or real(*a))
     monkeypatch.setattr(st, '_noted', lambda: STEP_MAP)
-    monkeypatch.setattr(st, '_memo', {'trace': None, 'table': None})
+    monkeypatch.setattr(st, '_memo', {})
     ctx = {'trace': STEP, 'units': 4, 'reduced': {'modules_total_s': 12.0},
            'log': lambda *a: logged.append(' '.join(map(str, a)))}
     specs = SPECS
@@ -227,34 +214,42 @@ def test_the_five_metrics_share_one_table(monkeypatch):
     assert st.read(dict(ctx, metric={'scope': 'moe'})) is None
 
 
-def entry_of(spec):
-    return {k: spec[k] for k in ('name', 'unit', 'better', 'source', 'layer',
-                                 'moves', 'workloads')}
-
-
-def test_the_five_specs_are_entries_the_benchmark_can_take(bench_json):
-    """Each spec, cut to an entry's keys, keeps BENCHMARK.json's rules: a
-    name and a unit of the allowed characters, a layer the file already
-    names, a cell that reports the end-to-end metric it moves, and a scope
-    of the program's vocabulary."""
+def test_the_five_files_name_the_programs_scopes_and_the_cells_that_run_them(
+        bench_json):
     from video_features_tpu.obs.scopes import SCOPES
     assert {s['scope'] for s in SPECS.values()} == {
         'raft_update', 'raft_lookup', 'raft_gru', 'i3d_towers', st.UNSCOPED}
     assert {s['scope'] for s in SPECS.values()} - {st.UNSCOPED} <= set(SCOPES)
-    layers = {m['layer'] for m in bench_json['per_layer']}
-    moved = {m['name']: m for m in bench_json['end_to_end']}
-    cells = {w['name'] for w in bench_json['workloads']}
+    clips = [m for m in bench_json['end_to_end']
+             if m['name'] == 'clips_per_s'][0]
     for name, spec in SPECS.items():
-        entry = entry_of(spec)
-        assert re.fullmatch(r'[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}', name)
-        assert re.fullmatch(r'[A-Za-z0-9_/%.-]{1,16}', entry['unit'])
-        assert entry['layer'] in layers
-        assert (entry['unit'], entry['better'], entry['source']) == (
-            'ms/clip', 'lower', 'device_trace')
-        for cell in entry['workloads']:
-            assert cell in cells
-            assert cell in moved[entry['moves']].get('workloads', cells)
-        assert spec['reader'] == 'scope_time' and len(spec['what']) <= 200
+        assert (spec['unit'], spec['better'], spec['source'], spec['moves'],
+                spec['reader']) == ('ms/clip', 'lower', 'device_trace',
+                                    'clips_per_s', 'scope_time')
+        assert len(spec['what']) <= 200
+        # what is not the program's own scope is every clips cell's to report
+        assert set(spec['workloads']) == (
+            set(clips['workloads']) if name == 'unscoped_ms.clips'
+            else {'i3d.corpus'})
+
+
+@pytest.mark.parametrize('name', sorted(LM_SCOPES))
+def test_an_lm_scope_metric_is_listed_where_a_trunk_opens_the_scope(
+        name, bench_json):
+    """One file a scope, the cells whose trunk opens it in ``workloads``; the
+    entry in BENCHMARK.json says the same (test_benchmark_json holds every
+    entry to its file)."""
+    from video_features_tpu.obs.scopes import SCOPES
+    scope, cells = LM_SCOPES[name]
+    spec = loader.load_json('metrics', name)
+    assert scope in SCOPES
+    assert (spec['reader'], spec['scope'], spec['unit'], spec['better'],
+            spec['source'], spec['moves'], spec['layer']) == (
+        'scope_time', scope, 'ms/clip', 'lower', 'device_trace',
+        'clips_per_s', 'device step')
+    assert set(spec['workloads']) == cells
+    entry = [m for m in bench_json['per_layer'] if m['name'] == name][0]
+    assert set(entry['workloads']) == cells
 
 
 def test_the_cell_reads_the_five_through_the_harness(monkeypatch, bench_json):
@@ -263,26 +258,18 @@ def test_the_cell_reads_the_five_through_the_harness(monkeypatch, bench_json):
     metrics; without a map (a parent) the old set comes out alone."""
     import harness
     import trace_reduce as tr
-    lookup = ('%closed_call.1 = f32[81,176128]{1,0} custom-call(s32[1,176128]'
-              '{1,0} %a), custom_call_target="tpu_custom_call"')
+    lookup = ('%raft_corr_lookup_lanes.1 = f32[81,176128]{1,0} custom-call('
+              's32[1,176128]{1,0} %a), custom_call_target="tpu_custom_call"')
     synthetic = trace(('/device:TPU:0', {
         'XLA Ops': STEP_OPS + [(lookup, 1.5 * S, 1 * S)],
         'XLA Modules': [('jit_step(77)', 0.0, 12 * S)]}))
     maps = {'jit_step': dict(STEP_MAP['jit_step'], instructions=dict(
         STEP_MAP['jit_step']['instructions'],
-        **{'%closed_call.1': 'raft_update/raft_lookup'}))}
+        **{'%raft_corr_lookup_lanes.1': 'raft_update/raft_lookup'}))}
     monkeypatch.setattr(tr, 'find_xplane', lambda d: d)
     monkeypatch.setattr(tr, 'load_xplane', lambda p: synthetic)
-    monkeypatch.setattr(st, '_memo', {'trace': None, 'table': None})
-    # the benchmark as a `benchmark` PR would leave it: the five listed,
-    # their files found by name
-    bench = copy.deepcopy(bench_json)
-    bench['per_layer'] += [entry_of(s) for s in SPECS.values()]
-    files = loader.load_json
-    monkeypatch.setattr(loader, 'load_json', lambda kind, name: (
-        SPECS[name] if kind == 'metrics' and name in SPECS
-        else files(kind, name)))
-    cell = {'name': 'i3d.corpus', 'bench': bench}
+    monkeypatch.setattr(st, '_memo', {})
+    cell = {'name': 'i3d.corpus', 'bench': bench_json}
     config = loader.load_json('configs', 'i3d-two-stream-raft')
     ctx = {'workload': {}, 'config': config, 'window_s': 20.0, 'units': 144,
            'slots': 168, 'batch_size': 8, 'log': lambda *a: None,
@@ -291,11 +278,62 @@ def test_the_cell_reads_the_five_through_the_harness(monkeypatch, bench_json):
     old = {'batch_occupancy.clips', 'decode_busy.clips', 'device_idle.clips',
            'step_mfu.clips', 'raft_lookup_roofline'}
     monkeypatch.setattr(st, '_noted', lambda: maps)
-    metrics, _ = harness.per_layer_metrics(cell, ctx, 'unused')
+    metrics, reduced = harness.per_layer_metrics(cell, ctx, 'unused')
     assert set(metrics) == old | set(SPECS)
     assert metrics['raft_lookup_ms.clips'] == {
         'value': pytest.approx(1e3 * 3.0 / 144), 'unit': 'ms/clip'}
+    # ... and the breakdown names device time by scope path, largest first
+    assert reduced['device_ops'] == [
+        ['raft_update', 4.0], ['raft_update/raft_lookup', 3.0],
+        ['raft_update/raft_gru', 3.0], ['i3d_towers', 2.0]]
     monkeypatch.setattr(st, '_noted', lambda: {})
-    monkeypatch.setattr(st, '_memo', {'trace': None, 'table': None})
-    metrics, _ = harness.per_layer_metrics(cell, ctx, 'unused')
+    monkeypatch.setattr(st, '_memo', {})
+    metrics, reduced = harness.per_layer_metrics(cell, ctx, 'unused')
     assert set(metrics) == old
+    # no map: the HLO names, as before
+    assert reduced['device_ops'][0] == ['%while.3 while', 10.0]
+
+
+def test_device_ops_by_scope_path_ranks_the_leftovers_too_and_keeps_ten(
+        monkeypatch):
+    """``breakdown.device_ops`` where a map is noted: self times by scope
+    path with ``(unscoped)``, the compiler's copies and the unmapped rest as
+    rows among them, largest first, rows of nothing left out, at most
+    ``top``; ``None`` where the join refuses."""
+    ops = STEP_OPS + [(hlo('%fusion.77'), 12 * S, 3.5 * S),    # in the map: ''
+                      (hlo('%copy.782', 'copy'), 15.5 * S, 0.3 * S),
+                      (hlo('%copy.5', 'copy'), 15.8 * S, 0.1 * S)]  # unknown
+    synthetic = trace(('/device:TPU:0', {
+        'XLA Ops': ops, 'XLA Modules': [('jit_step(77)', 0.0, 16 * S)]}))
+    maps = {'jit_step': dict(STEP_MAP['jit_step'], no_metadata=['%copy.782'])}
+    monkeypatch.setattr(st, '_noted', lambda: maps)
+    monkeypatch.setattr(st, '_memo', {})
+    got = st.device_ops(synthetic)
+    assert [name for name, _ in got] == [
+        'raft_update', st.UNSCOPED, 'raft_update/raft_lookup',
+        'raft_update/raft_gru', 'i3d_towers', st.NO_OP_NAME, st.UNMAPPED]
+    assert [s for _, s in got] == pytest.approx(
+        [4.0, 3.5, 3.0, 3.0, 2.0, 0.3, 0.1])
+    assert st.device_ops(synthetic, top=2) == got[:2]
+    # the metrics and the breakdown share one join of the trace
+    calls = []
+    real = st.attribute
+    monkeypatch.setattr(st, 'attribute',
+                        lambda *a: calls.append(1) or real(*a))
+    monkeypatch.setattr(st, '_memo', {})
+    ctx = {'trace': synthetic, 'units': 4, 'log': lambda *a: None,
+           'reduced': {'modules_total_s': 16.0},
+           'metric': {'scope': 'raft_gru'}}
+    assert st.read(ctx) == 750.0 and st.device_ops(synthetic) == got
+    assert calls == [1]
+    # a parent, a stale cache, a lost op line: no rows, the harness keeps
+    # the HLO names; and so where the program opens no scope (resnet's step)
+    monkeypatch.setattr(st, '_noted', lambda: {})
+    monkeypatch.setattr(st, '_memo', {})
+    assert st.device_ops(synthetic) is None
+    bare = {'jit_step': {'instructions': dict.fromkeys(
+        STEP_MAP['jit_step']['instructions'], ''), 'missing': []}}
+    monkeypatch.setattr(st, '_noted', lambda: bare)
+    monkeypatch.setattr(st, '_memo', {})
+    assert st.attribute(STEP, bare)['scopes'] == {st.UNSCOPED: 12.0}
+    assert st.device_ops(STEP) is None

@@ -137,8 +137,8 @@ def test_harness_reads_every_per_layer_metric_of_a_cell(monkeypatch,
     per-layer metrics comes out once, with its unit, and none reads 0."""
     import harness
     import loader
-    lookup = ('%closed_call.1 = f32[81,176128]{1,0} custom-call(s32[1,176128]'
-              '{1,0} %a), custom_call_target="tpu_custom_call"')
+    lookup = ('%raft_corr_lookup_lanes.1 = f32[81,176128]{1,0} custom-call('
+              's32[1,176128]{1,0} %a), custom_call_target="tpu_custom_call"')
     synthetic = trace(('/device:TPU:0', {
         'XLA Ops': [('%fusion.1 = f32[8]{0} fusion(f32[8]{0} %p)', 0.0, 4e9),
                     (lookup, 4e9, 1e9)] * 1,
