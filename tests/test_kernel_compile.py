@@ -78,6 +78,48 @@ def test_causal_attention_grouped_lane_compiles_at_the_cells_widths(one_chip,
     assert 'f32[1,32,8192,64]' not in text
 
 
+@pytest.mark.parametrize('window,name,ring', [
+    (2048, 'window_attention', 5),      # the six sliding layers
+    (None, 'causal_attention', 32),     # the two full ones: 41.9 MB resident
+])
+@pytest.mark.parametrize('passes', [3, 1])
+def test_causal_attention_compiles_at_trinity_minis_widths(one_chip, passes,
+                                                           window, name,
+                                                           ring):
+    """One window of trinity-mini.corpus: 32,768 positions, 32 query heads
+    reading 4 key-value heads, all 128 wide — a grid step one key-value head
+    and its eight query heads on 128 positions. Under the window of 2,048
+    keys the call is named window_attention, its key axis the band's five
+    tiles of 512; a full layer keeps the head's whole packed keys and values
+    in VMEM, under the budget ``resolve_causal`` tests. Three passes
+    (precision=mixed) and one (the control lane), shipped tiles and VMEM
+    limit."""
+    from video_features_tpu.ops import pallas_attention as kernel
+    from video_features_tpu.ops.attention import KERNEL_PASSES, resolve_causal
+    precision = {3: 'high', 1: 'default'}[passes]
+    assert KERNEL_PASSES[precision] == passes
+    assert resolve_causal('tpu', 32768, 128, 128, precision, 32, 4,
+                          window) == 'kernel'
+    block_q, block_k = kernel.tiles(32768, 8, window)
+    assert kernel.resident_tiles(32768, block_q, block_k, window) == ring
+    packed = sum(kernel.packed_widths((128,), 128, passes))
+    assert 2 * ring * block_k * packed <= kernel.KV_VMEM_BYTES
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((1, 32768, heads, 128), jnp.float32,
+                                    sharding=one_chip)
+
+    compiled = jax.jit(lambda q, k, v: kernel.causal_attention(
+        q, k, v, 128 ** -0.5, passes, window=window)).lower(
+        sds(32), sds(4), sds(4)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    other = {'window_attention', 'causal_attention'} - {name}
+    assert f'%{name}' in text and f'%{other.pop()}' not in text
+    # q enters and the output leaves as they stand: no copy of either
+    assert 'f32[1,32,32768,128]' not in text
+
+
 @pytest.fixture(scope='module')
 def compiled_scan(one_chip):
     """brumby.corpus's mixer at its widths — 8 key-value heads with 5 query

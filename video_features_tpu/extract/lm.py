@@ -13,9 +13,11 @@ save (``StackPackingMixin``, ``parallel/packing.py::run_packed``).
 **The trunk is chosen by ``model_type``**, the published ``config.json``'s
 key (``TRUNKS``): ``joyai_llm_flash`` — latent attention + sparse experts,
 ``models/latent_moe.py``, what ``configs/lm.yml`` ships —, ``brumby`` —
-gated power retention, dense, ``models/retention_trunk.py`` — or
+gated power retention, dense, ``models/retention_trunk.py`` —,
 ``lfm2_moe`` — gated short convolutions among grouped-query attention
-layers, sparse experts, ``models/hybrid_trunk.py``. A trunk module
+layers, sparse experts, ``models/hybrid_trunk.py`` — or ``afmoe`` — sliding-
+window and full grouped-query attention layers mixed, gated, over sparse
+experts with a shared one, the same module's second dialect. A trunk module
 says what this file needs of it (``models/token_trunk.py`` lists the names):
 its config from the args, its parameters, its step's second output, what it
 notes in the manifest and which counters it fills. An unknown
@@ -63,6 +65,7 @@ TRUNKS = {
     'joyai_llm_flash': 'video_features_tpu.models.latent_moe',
     'brumby': 'video_features_tpu.models.retention_trunk',
     'lfm2_moe': 'video_features_tpu.models.hybrid_trunk',
+    'afmoe': 'video_features_tpu.models.hybrid_trunk',
 }
 
 

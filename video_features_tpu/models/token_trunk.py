@@ -2,8 +2,9 @@
 
 The family's trunks (``models/latent_moe.py``: latent attention + sparse
 experts; ``models/retention_trunk.py``: gated power retention, dense;
-``models/hybrid_trunk.py``: gated short convolutions among grouped-query
-attention layers, sparse experts) are pre-norm residual decoders over token
+``models/hybrid_trunk.py``: gated short convolutions, full and sliding-window
+grouped-query attention layers by ``layer_types``, sparse experts, in two
+dialects) are pre-norm residual decoders over token
 ids that differ in their sequence mixers and their feed-forward's routing.
 The rest is here, once: RMSNorm, the SwiGLU, the embedding lookup, the
 pooled output, the seeded draw of a parameter set, and for the expert
@@ -12,7 +13,9 @@ routing.
 
 A trunk module offers ``extract/lm.py`` a few names and nothing else:
 
-* ``MODEL_TYPE`` — the published ``config.json``'s ``model_type``;
+* ``MODEL_TYPE`` — the published ``config.json``'s ``model_type`` (a
+  module that runs several reads ``args['model_type']`` in ``from_args``
+  and keeps it on its config);
 * ``TrunkConfig.from_args(args)``, ``param_shapes(cfg)``,
   ``param_count(cfg)``, ``init_params(cfg, seed)``;
 * ``forward(params, ids, cfg, platform=...)`` → ``(features (B, D) float32,

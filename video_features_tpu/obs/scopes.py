@@ -48,6 +48,8 @@ SCOPES = (
     # hybrid_trunk.py)
     'mla',               # latent attention
     'attention',         # grouped-query attention
+    'sliding_attention',  # the same under a window (a trunk with both kinds)
+    'full_attention',    # and its full causal layers
     'retention',         # gated power retention
     'short_conv',        # gated short convolution
     'moe',               # routed (and shared) experts

@@ -14,7 +14,9 @@ take the same blockwise path with ``causal=True``: queries are tiled too, a
 query tile scans only the key tiles at or before it, the value head may be
 narrower than the query/key head (latent attention: 192-wide q/k, 128-wide
 v), and keys and values may have fewer heads than the queries
-(grouped-query attention: 32 query heads reading 8 key-value heads).
+(grouped-query attention: 32 query heads reading 8 key-value heads); under a
+``window`` a query tile starts at the tile of its oldest visible key, so the
+tiles below the band cost nothing either (sliding-window layers).
 :func:`rotary_interleaved` is their position code; :func:`rotary_half` is
 the half-split form of the same rotation (``models/retention_trunk.py``).
 
@@ -28,7 +30,8 @@ math, f32 accumulation, the ambient matmul precision on every product):
     compute sharded. Use under ``shard_map`` with the sequence axis split.
 
 A fourth path is a kernel, for the causal case alone
-(``ops/pallas_attention.py``, named ``causal_attention`` in traces): the same
+(``ops/pallas_attention.py``, named ``causal_attention`` in traces,
+``window_attention`` under a window): the same
 online softmax with the score tile kept in VMEM, which the XLA tiles write to
 HBM several times a tile pair. It makes its bf16 passes itself (three under
 ambient ``high``, one under ``default``; ``highest`` has no lane in it), so
@@ -45,6 +48,7 @@ Shapes follow (B, S, H, D) [batch, sequence, heads, head_dim].
 """
 from __future__ import annotations
 
+from functools import cache
 from typing import Optional
 
 import jax
@@ -107,12 +111,15 @@ KERNEL_PASSES = {None: 1, 'default': 1, 'bfloat16': 1, 'high': 3}
 
 def resolve_causal(platform: str, s: int, qk_dim: int, v_dim: int,
                    precision: Optional[str], heads: int = 1,
-                   kv_heads: int = 1) -> str:
+                   kv_heads: int = 1, window: Optional[int] = None) -> str:
     """Which causal attention compiles for ``s`` positions on ``platform``
     under the ambient matmul ``precision``: 'kernel' (the fused Mosaic
     kernel, ops/pallas_attention.py) or 'xla' (:func:`blockwise_attention`
     with ``causal=True``). ``heads`` query heads read ``kv_heads`` key-value
-    heads; only their ratio, the group, matters (1 when left out).
+    heads; only their ratio, the group, matters (1 when left out). Under a
+    ``window`` (a query sees its own key and the ``window - 1`` before it)
+    the kernel keeps only the band's key tiles resident, so its VMEM test
+    is over those and not over the sequence.
 
     The kernel applies on a TPU, where the sequence is a whole number of its
     tiles and a key-value head's packed keys and values fit its VMEM budget,
@@ -133,13 +140,16 @@ def resolve_causal(platform: str, s: int, qk_dim: int, v_dim: int,
             or heads % kv_heads):
         return 'xla'
     group = heads // kv_heads
-    block_q, block_k = kernel.tiles(s, group)
+    block_q, block_k = kernel.tiles(s, group, window)
+    if s % block_q or s % block_k:
+        return 'xla'
     packed = sum(kernel.packed_widths((qk_dim,), v_dim,
                                       KERNEL_PASSES[precision]))
-    if (s % block_q or s % block_k or group * block_q % 128 or block_k % 128
+    resident = block_k * kernel.resident_tiles(s, block_q, block_k, window)
+    if (group * block_q % 128 or block_k % 128
             or group * v_dim % 128 or qk_dim % 64
             or (group > 1 and group * qk_dim % 128)
-            or 2 * s * packed > kernel.KV_VMEM_BYTES):
+            or 2 * resident * packed > kernel.KV_VMEM_BYTES):
         return 'xla'
     return 'kernel'
 
@@ -147,7 +157,8 @@ def resolve_causal(platform: str, s: int, qk_dim: int, v_dim: int,
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         block_size: int = 512,
                         scale: Optional[float] = None,
-                        causal: bool = False) -> jax.Array:
+                        causal: bool = False,
+                        window: Optional[int] = None) -> jax.Array:
     """Memory-efficient attention: scan over KV blocks, O(S·block) memory.
 
     Ragged S is handled by zero-padding KV to a block multiple and masking
@@ -157,10 +168,14 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     ``causal=True`` (self-attention, S a block multiple): position i sees
     keys 0…i. ``v`` may have another head width than ``q``/``k``, and ``k``
-    and ``v`` a whole fraction of ``q``'s heads (grouped-query).
+    and ``v`` a whole fraction of ``q``'s heads (grouped-query). With a
+    ``window`` (causal only) position i sees keys i − window + 1 … i.
     """
+    if window is not None and not causal:
+        raise ValueError('a window is a causal layer\'s: causal=True')
     if causal:
-        return _causal_blockwise(q, k, v, block_size, _scale(q, scale))
+        return _causal_blockwise(q, k, v, block_size, _scale(q, scale),
+                                 window)
     b, sk, h, d = k.shape
     block_size = min(block_size, sk)
     pad = (-sk) % block_size
@@ -188,11 +203,19 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return (o / l).astype(q.dtype)
 
 
-def _causal_blockwise(q, k, v, block_size: int, scale: float) -> jax.Array:
+def _causal_blockwise(q, k, v, block_size: int, scale: float,
+                      window: Optional[int] = None) -> jax.Array:
     """Causal self-attention, tiled both ways: query tile i scans key tiles
     0…i-1 unmasked and then its own diagonal tile under the triangle, so
     the tiles above the diagonal cost nothing (a scan over all keys with a
     mask would compute, and throw away, half of S²).
+
+    Under a ``window`` (position p sees keys p − window + 1 … p) the tiles
+    below the band cost nothing either: query tile i starts at the tile
+    that holds its first row's oldest key, takes the one or two tiles the
+    band's lower edge crosses under that edge's mask, scans the whole ones
+    between and ends on its diagonal tile. ``window`` None or ≥ S is the
+    plain triangle, the same program as without the argument.
 
     Grouped-query heads (``k`` and ``v`` with fewer heads than ``q``, query
     head j reading key-value head ``j div group``) ride the query axis: a
@@ -225,6 +248,21 @@ def _causal_blockwise(q, k, v, block_size: int, scale: float) -> jax.Array:
             b, s * group, kv_heads, -1)
         q_pos, q_rows = jnp.repeat(pos, group), block_size * group
     triangle = (q_pos[:, None] >= pos[None, :])[:, None, :]   # (q, 1, k)
+    if window is not None and window < 1:
+        raise ValueError(f'a window of {window} keys sees nothing')
+    if window is not None and window >= s:
+        window = None
+    if window is not None and window < block_size:
+        # the band's lower edge crosses the diagonal tile too
+        triangle &= (q_pos[:, None] - pos[None, :] < window)[:, None, :]
+
+    @cache
+    def band(tiles_back: int):
+        """(q, 1, k): which keys of the tile ``tiles_back`` before the
+        query tile's own are no more than window − 1 positions back (one
+        mask a distance: every query tile meets the same one or two)."""
+        return (q_pos[:, None] + tiles_back * block_size - pos[None, :]
+                < window)[:, None, :]
 
     out = []
     for i in range(n_blocks):
@@ -234,8 +272,18 @@ def _causal_blockwise(q, k, v, block_size: int, scale: float) -> jax.Array:
             return _online_block(qi, *carry, *blk, scale), None
 
         carry = _online_init(qi, v.shape[-1])
-        if i:
-            carry, _ = lax.scan(step, carry, (kb[:i], vb[:i]))
+        first = whole = 0
+        if window is not None:
+            # the tile of the first row's oldest key, and the first tile
+            # whose every key the last row still sees
+            first = max(i * block_size - window + 1, 0) // block_size
+            whole = min(max(-((window - (i + 1) * block_size) // block_size),
+                            first), i)
+        for j in range(first, whole):
+            carry = _online_block(qi, *carry, kb[j], vb[j], scale,
+                                  valid=band(i - j))
+        if i > whole:
+            carry, _ = lax.scan(step, carry, (kb[whole:i], vb[whole:i]))
         _, l, o = _online_block(qi, *carry, kb[i], vb[i], scale,
                                 valid=triangle)
         out.append(o / l)

@@ -51,13 +51,30 @@ running max, denominator and accumulator in VMEM scratch:
   (latent attention) are the case ``group == 1``: every step a group adds
   is behind a static ``group > 1``, so their module holds none of it.
 
+* **a window is the same kernel on a shorter key axis.** Where query i sees
+  keys i − W + 1 … i (``window=W``, a sliding layer), the grid's key axis
+  counts from the first tile that holds a key the query tile sees and has as
+  many steps as the widest such band has tiles (``resident_tiles``: five
+  tiles of 512 keys for 128 positions under 2,048; steps past the diagonal
+  tile do nothing), the head's resident packed keys and values are a *ring*
+  of as many slots (tile t in slot ``t mod ring``, packed by the first query
+  tile that reaches it over the tile ``ring`` before it, which no one sees
+  any more: 3.3 MB, whatever the sequence), the float32 block's index map
+  moves only onto a tile no earlier query tile reached, and the mask falls
+  on the tiles the diagonal or the band's lower edge crosses; a row that
+  sees no key of such an edge tile passes through it unchanged. That call is
+  named ``window_attention`` in traces. ``window=None`` and ``window ≥ S``
+  are the plain triangle, every windowed step behind a static ``window is
+  not None``. A group of eight 128-wide query heads (trinity-mini's: 128
+  positions × 8 heads a score tile) is the grouped lane's second shape.
+
 Keys and values enter heads-major, (B, H, S, width) — a block is one head's
 (tile, width) slab; the caller's (B, S, H, width) is transposed here, which
 XLA folds into the producing product's output layout — and so do the queries
 of equal head counts; a group's queries enter as (B, S, H·width), no copy.
 The output leaves as (B, S, H·v_dim), which is how ``o_proj`` reads it. CPU
 tests run the same kernel body under ``interpret=True``
-(tests/test_attention.py).
+(tests/test_attention.py, tests/test_window_attention.py).
 """
 from __future__ import annotations
 
@@ -72,18 +89,28 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 NAME = 'causal_attention'
+# the same kernel under a window: the name its pallas_call carries in traces
+WINDOW_NAME = 'window_attention'
 # the kernel's tiles at the cells' shapes — score-tile rows (a group's heads
 # share them: ``tiles``) and keys: my chip runs, PRs 30 and 36 (PERF.md §6)
 BLOCK_Q = 1024
 BLOCK_K = 1024
+# under a window the key tile is half that: the band's two edges are masked
+# tiles computed whole, and at 2,048 keys a query five tiles of 512 waste
+# less than three of 1,024 (29.7 against 34.7 ms a window-layer at 32,768
+# positions, 43.9 at 2,048: my chip run, PR 38)
+WINDOW_BLOCK_K = 512
 # a head's packed keys and values stay in VMEM for all of its query tiles
-# (8,192 × (640 + 256) bf16 = 14.7 MB in the cell), beside a (1024, 1024)
-# score tile with its exponential and the two bf16 parts of it (12 MB) and
-# the double-buffered float32 blocks (6 MB): past Mosaic's 16 MB default,
-# well inside a v5e core's 128 MB. ``resolve_causal`` keeps sequences whose
-# packed keys and values pass KV_VMEM_BYTES on the XLA path.
+# (8,192 × (640 + 256) bf16 = 14.7 MB in joyai's cell; 32,768 × (384 + 256)
+# bf16 = 41.9 MB in a full layer of trinity-mini's, 170.6 ms a window-layer
+# where the XLA tiles take 315: my chip run, PR 38; under a window only the
+# band's ring), beside a (1024, 1024) score tile with its exponential and
+# the two bf16 parts of it (12 MB) and the double-buffered float32 blocks
+# (6 MB): past Mosaic's 16 MB default, inside a v5e core's 128 MB.
+# ``resolve_causal`` keeps sequences whose packed keys and values pass
+# KV_VMEM_BYTES on the XLA path.
 VMEM_LIMIT_BYTES = 96 * 2 ** 20
-KV_VMEM_BYTES = 32 * 2 ** 20
+KV_VMEM_BYTES = 48 * 2 ** 20
 
 _NT = (((1,), (1,)), ((), ()))      # contract both operands' last axis
 # every product in the kernel is bf16 × bf16 into float32, said outright: the
@@ -168,6 +195,25 @@ def _last_key_tile(qi, block_q: int, block_k: int):
     return (qi * block_q + block_q - 1) // block_k
 
 
+def _first_key_tile(qi, block_q: int, block_k: int, window: int):
+    """Index of the first key tile that holds a key a query tile sees under
+    a window: its first row's oldest, ``window - 1`` positions back."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def resident_tiles(s: int, block_q: int, block_k: int,
+                   window: Optional[int] = None) -> int:
+    """Key tiles a head's packed keys and values keep in VMEM: every tile of
+    the sequence, or under a ``window`` shorter than it the most one query
+    tile reaches (the band: the ring's slots, and the key-tile axis of the
+    windowed grid)."""
+    if window is None or window >= s:
+        return s // block_k
+    return max(int(_last_key_tile(qi, block_q, block_k))
+               - max(qi * block_q - (window - 1), 0) // block_k + 1
+               for qi in range(s // block_q))
+
+
 def _tile_positions(shape, block_q: int, group: int) -> jax.Array:
     """Each score row's position in its query tile: the row index, and with
     a group's heads stacked on the rows, block_q a head, its index within
@@ -177,20 +223,29 @@ def _tile_positions(shape, block_q: int, group: int) -> jax.Array:
 
 
 def _kernel(*refs, groups: int, group: int, block_q: int, block_k: int,
-            v_dim: int, passes: int, scale: float):
+            v_dim: int, passes: int, scale: float, window: Optional[int],
+            ring: int):
     q_refs, k_refs = refs[:groups], refs[groups:2 * groups]
     v_ref, o_ref, qc_ref, kc_ref, vc_ref, m_ref, l_ref, acc_ref = \
         refs[2 * groups:]
-    qi, ki = pl.program_id(2), pl.program_id(3)
+    qi, kj = pl.program_id(2), pl.program_id(3)
     first_masked = _first_masked_tile(qi, block_q, block_k)
     last = _last_key_tile(qi, block_q, block_k)
-    rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+    if window is None:
+        ki = kj
+        rows = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+    else:
+        # the grid's key axis counts from the query tile's first visible
+        # tile, and a tile's packed copy sits in the ring's slot ki mod ring
+        ki = kj + _first_key_tile(qi, block_q, block_k, window)
+        rows = pl.ds(pl.multiple_of(lax.rem(ki, ring) * block_k, block_k),
+                     block_k)
     # a key-value head's ``group`` query heads ride the score tile's rows:
     # head j is rows j·block_q … of the packed queries, the running max, the
     # denominator and the accumulator, so one QKᵀ and one PV serve them all
     heads = [slice(j * block_q, (j + 1) * block_q) for j in range(group)]
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _():
         m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
@@ -207,13 +262,22 @@ def _kernel(*refs, groups: int, group: int, block_q: int, block_k: int,
         if masked:
             row = qi * block_q + _tile_positions(s.shape, block_q, group)
             col = ki * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(col <= row, s, -jnp.inf)
+            seen = col <= row
+            if window is not None:
+                seen = jnp.logical_and(seen, col > row - window)
+            s = jnp.where(seen, s, -jnp.inf)
         # key 0 is in the first tile and every row sees it, so m_new is
         # finite from the first step on and no exp sees -inf - -inf
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
+        m_new = m_base = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        if masked and window is not None:
+            # not so under a window: a row may see no key of the tile the
+            # band's lower edge crosses (its oldest key lies in the next),
+            # and then exponentiates against a finite stand-in: p and alpha
+            # come out 0 and the row's carry passes through
+            m_base = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        p = jnp.exp(s - m_base)
+        alpha = jnp.exp(m_prev - m_base)
         l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_new
         v = vc_ref[rows, :]
@@ -228,26 +292,45 @@ def _kernel(*refs, groups: int, group: int, block_q: int, block_k: int,
             pv = jnp.dot(p_hi, v, **_ONE_PASS)
         acc_ref[...] = alpha * acc_ref[...] + pv
 
-    @pl.when(ki < first_masked)
-    def _():
-        accumulate(masked=False)
-
     def pack_keys():
         kc_ref[rows, :] = _pack_qk(k_refs, passes, key=True)
         vc_ref[rows, :] = _pack_v(v_ref[...], passes)
 
-    # a tile the diagonal crosses holds keys no earlier query tile saw: this
-    # is its first use, so its float32 block is in (the index map held it
-    # back until now) and is packed into the head's VMEM copy here, once
-    @pl.when(jnp.logical_and(ki >= first_masked, ki <= last))
-    def _():
-        if block_q % block_k == 0:
-            pack_keys()
-        else:
-            # a query tile that ends inside a key tile leaves it to be
-            # crossed again: the first to cross it packs, the rest reuse
-            pl.when(_last_key_tile(qi - 1, block_q, block_k) < ki)(pack_keys)
-        accumulate(masked=True)
+    if window is None:
+        @pl.when(ki < first_masked)
+        def _():
+            accumulate(masked=False)
+
+        # a tile the diagonal crosses holds keys no earlier query tile saw:
+        # this is its first use, so its float32 block is in (the index map
+        # held it back until now) and is packed into the head's VMEM copy
+        # here, once
+        @pl.when(jnp.logical_and(ki >= first_masked, ki <= last))
+        def _():
+            if block_q % block_k == 0:
+                pack_keys()
+            else:
+                # a query tile that ends inside a key tile leaves it to be
+                # crossed again: the first to cross it packs, the rest reuse
+                pl.when(_last_key_tile(qi - 1, block_q, block_k) < ki)(
+                    pack_keys)
+            accumulate(masked=True)
+    else:
+        # the band: tiles first … last of this query tile, ``ring`` grid
+        # steps of which those past ``last`` do nothing. A tile no earlier
+        # query tile reached is packed into its slot of the ring (over the
+        # tile ``ring`` before it, which no query tile sees any more); the
+        # mask falls on the tiles the diagonal or the band's lower edge
+        # (a key the tile's last row no longer sees) crosses
+        seen = ki <= last
+        edge = ki * block_k <= qi * block_q + block_q - 1 - window
+        masked = jnp.logical_or(ki >= first_masked, edge)
+        pl.when(jnp.logical_and(
+            seen, _last_key_tile(qi - 1, block_q, block_k) < ki))(pack_keys)
+        pl.when(jnp.logical_and(seen, jnp.logical_not(masked)))(
+            partial(accumulate, masked=False))
+        pl.when(jnp.logical_and(seen, masked))(
+            partial(accumulate, masked=True))
 
     @pl.when(ki == last)
     def _():
@@ -261,21 +344,29 @@ def _kernel(*refs, groups: int, group: int, block_q: int, block_k: int,
 Parts = Union[jax.Array, Sequence[jax.Array]]
 
 
-def tiles(s: int, group: int = 1) -> Tuple[int, int]:
+def tiles(s: int, group: int = 1, window: Optional[int] = None
+          ) -> Tuple[int, int]:
     """The shipped (query, key) tiles for ``s`` positions: a score tile has
     at most BLOCK_Q rows — ``group`` query heads at the largest power of
-    two of positions that leaves — and BLOCK_K keys."""
+    two of positions that leaves — and BLOCK_K keys, WINDOW_BLOCK_K under a
+    ``window`` shorter than the sequence."""
     block_q = 1 << max(BLOCK_Q // group, 1).bit_length() - 1
-    return min(block_q, s), min(BLOCK_K, s)
+    block_k = BLOCK_K if window is None or window >= s else WINDOW_BLOCK_K
+    return min(block_q, s), min(block_k, s)
 
 
 def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
                      passes: int, block_q: Optional[int] = None,
                      block_k: Optional[int] = None,
-                     interpret: bool = False) -> jax.Array:
+                     interpret: bool = False,
+                     window: Optional[int] = None) -> jax.Array:
     """softmax(QKᵀ·scale + causal mask)V over (B, S, H, D) float32 tensors
     (v may be narrower), float32 (B, S, H, v's width) out, ``passes`` (1 or
-    3) bf16 passes a product.
+    3) bf16 passes a product. With a ``window`` shorter than S, position i
+    sees keys i − window + 1 … i: the call is then named
+    ``window_attention``, its grid's key axis is the band's tiles and the
+    head's resident packed keys and values a ring of as many
+    (:func:`resident_tiles`); ``window`` None or ≥ S is the plain triangle.
 
     ``q`` and ``k`` may each come as a sequence of column groups whose
     concatenation along D is the head — latent attention's (nope, rope) —
@@ -318,10 +409,16 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
             f'{widths} query/key and {v_dim} value columns fill no whole '
             f'{LANES}-lane blocks')
     block_q, block_k = (min(given or shipped, s) for given, shipped
-                        in zip((block_q, block_k), tiles(s, group)))
+                        in zip((block_q, block_k), tiles(s, group, window)))
     if s % block_q or s % block_k:
         raise ValueError(f'causal_attention: {s} positions are no multiple '
                          f'of the tiles ({block_q}, {block_k})')
+    if window is not None and window < 1:
+        raise ValueError(f'causal_attention: a window of {window} keys sees '
+                         f'nothing')
+    if window is not None and window >= s:
+        window = None
+    ring = resident_tiles(s, block_q, block_k, window)
 
     def heads_major(parts):
         """(B, S, H, width) → (B, H, S, width): a block is one head's (tile,
@@ -344,8 +441,16 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
         # and never again (its packed copy stays in VMEM): before the
         # diagonal the map waits on the first crossed tile, above it on the
         # last, and an unchanged index fetches nothing
-        return jnp.clip(ki, _first_masked_tile(qi, block_q, block_k),
-                        _last_key_tile(qi, block_q, block_k))
+        if window is None:
+            return jnp.clip(ki, _first_masked_tile(qi, block_q, block_k),
+                            _last_key_tile(qi, block_q, block_k))
+        # under a window ``ki`` counts from the band's first tile, and only
+        # a tile no earlier query tile reached is new; with none new the
+        # map stays on the last
+        new = _last_key_tile(qi - 1, block_q, block_k) + 1
+        return jnp.minimum(
+            jnp.maximum(ki + _first_key_tile(qi, block_q, block_k, window),
+                        new), _last_key_tile(qi, block_q, block_k))
 
     def spec(x, block, tile):
         shared = x.shape[1] == 1        # one head for all: block 0 always
@@ -365,16 +470,17 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
     rows = group * block_q
     out = pl.pallas_call(
         partial(_kernel, groups=len(widths), group=group, block_q=block_q,
-                block_k=block_k, v_dim=v_dim, passes=passes, scale=scale),
-        grid=(b, kv_heads, s // block_q, s // block_k),
+                block_k=block_k, v_dim=v_dim, passes=passes, scale=scale,
+                window=window, ring=ring),
+        grid=(b, kv_heads, s // block_q, ring),
         in_specs=[*map(q_spec, q_parts), *map(kv_spec, k_parts),
                   kv_spec(vt)],
         out_specs=pl.BlockSpec((None, block_q, group * v_dim),
                                lambda bi, hi, qi, ki: (bi, qi, hi)),
         out_shape=jax.ShapeDtypeStruct((b, s, h * v_dim), jnp.float32),
         scratch_shapes=[pltpu.VMEM((rows, c_qk), jnp.bfloat16),
-                        pltpu.VMEM((s, c_qk), jnp.bfloat16),
-                        pltpu.VMEM((s, c_v), jnp.bfloat16),
+                        pltpu.VMEM((ring * block_k, c_qk), jnp.bfloat16),
+                        pltpu.VMEM((ring * block_k, c_v), jnp.bfloat16),
                         pltpu.VMEM((rows, 1), jnp.float32),
                         pltpu.VMEM((rows, 1), jnp.float32),
                         pltpu.VMEM((rows, v_dim), jnp.float32)],
@@ -385,6 +491,6 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
                                  'arbitrary'),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-        name=NAME,
+        name=NAME if window is None else WINDOW_NAME,
     )(*q_parts, *k_parts, vt)
     return out.reshape(b, s, h, v_dim)
