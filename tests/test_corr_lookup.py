@@ -27,42 +27,163 @@ def _random_pyramid(rng, n, h, w, levels=4):
     return pyr
 
 
-@pytest.mark.parametrize('h,w', [(12, 9), (13, 9)])
-def test_lanes_matches_gather(h, w):
-    """Lane-packed mask-reduce kernel (interpret mode) == the gather oracle
-    at even and odd sizes, a pair count off the 128-lane tile, and centroids
-    in range, fractional and far outside the map (zeros padding)."""
+# (pairs, h, w, positions a grid step may hold): even and odd sizes; a
+# level-0 w of 43 (the shipped 256×344) whose levels are 43, 21, 10 and 5
+# wide — none a multiple of 8; pixel counts off the 1,024-pixel tile
+# (2 × 12 × 9 = 216); and planes cut into chunks of rows (36 positions a
+# step: 12 × 9 in four chunks of three rows) and of one row (4 a step: a
+# row of 9 in three stretches of 3), the sums carried across grid steps
+SHAPES = [(2, 12, 9, None), (2, 13, 9, None), (1, 8, 43, None),
+          (2, 12, 9, 36), (2, 12, 9, 4)]
+
+
+def _shape_id(shape):
+    b, h, w, cap = shape
+    return f'{b}x{h}x{w}' + (f'-chunks{cap}' if cap else '')
+
+
+def _cap(monkeypatch, positions):
+    """Hold a grid step to ``positions`` of a level's plane."""
+    if positions:
+        monkeypatch.setattr(pallas_corr, 'BLOCK_BYTES',
+                            positions * pallas_corr.TILE * 4)
+
+
+@pytest.mark.parametrize('shape', SHAPES, ids=_shape_id)
+def test_lanes_matches_gather(monkeypatch, shape):
+    """The 1,024-pixel-tile kernel (interpret mode) == the gather oracle,
+    centroids in range, fractional and far outside the map (zeros
+    padding)."""
+    b, h, w, cap = shape
+    _cap(monkeypatch, cap)
     rng = np.random.RandomState(0)
-    b = 2
     pyr = _random_pyramid(rng, b * h * w, h, w)
     coords = rng.uniform(-9, max(h, w) + 9, size=(b, h, w, 2))
     coords = jnp.asarray(coords.astype(np.float32))
 
     ref = raft.lookup_corr(pyr, coords)
-    got = pallas_corr.lookup_corr_lanes(pallas_corr.prep_pyramid_lanes(pyr),
-                                        coords, interpret=True)
+    got = pallas_corr.lookup_corr_lanes(
+        pallas_corr.prep_pyramid_lanes(pyr, b), coords, interpret=True)
     assert got.shape == ref.shape
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_lanes_integer_coords_exact():
-    """Integer coords hit map values exactly (weights 0, no blending)."""
+@pytest.mark.parametrize('side', ['left', 'right', 'top', 'bottom'])
+def test_lanes_matches_gather_off_every_side(side):
+    """Centroids past one edge of the plane, at and beyond the window's
+    reach: the taps that fall off read zeros, the others the plane."""
+    rng = np.random.RandomState(3)
+    b, h, w = 2, 12, 9
+    pyr = _random_pyramid(rng, b * h * w, h, w, levels=1)
+    coords = rng.uniform(0, 1, size=(b, h, w, 2)) * [w - 1, h - 1]
+    axis, sign = {'left': (0, -1), 'right': (0, 1), 'top': (1, -1),
+                  'bottom': (1, 1)}[side]
+    extent = (w, h)[axis]
+    coords[..., axis] = (extent - 1) * (sign > 0) + sign * rng.uniform(
+        0, 7, size=(b, h, w))
+    coords = jnp.asarray(coords.astype(np.float32))
+    ref = raft.lookup_corr(pyr, coords)
+    got = pallas_corr.lookup_corr_lanes(
+        pallas_corr.prep_pyramid_lanes(pyr, b), coords, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize('shape', [(1, 8, 8, None), *SHAPES[2:]],
+                         ids=_shape_id)
+def test_lanes_integer_coords_exact(monkeypatch, shape):
+    """Integer coords hit map values exactly (weights 0, no blending), and
+    a window centre off the plane reads 0."""
+    b, h, w, cap = shape
+    _cap(monkeypatch, cap)
     rng = np.random.RandomState(1)
-    h = w = 8
-    n = h * w
+    n = b * h * w
     pyr = _random_pyramid(rng, n, h, w, levels=1)
+    shift = rng.randint(-3, 4, size=(b, h, w, 2))
     yy, xx = np.meshgrid(np.arange(h), np.arange(w), indexing='ij')
-    coords = jnp.asarray(
-        np.stack([xx, yy], -1)[None].astype(np.float32))
+    cx, cy = xx + shift[..., 0], yy + shift[..., 1]
+    coords = jnp.asarray(np.stack([cx, cy], -1).astype(np.float32))
 
     got = np.asarray(pallas_corr.lookup_corr_lanes(
-        pallas_corr.prep_pyramid_lanes(pyr), coords, interpret=True))
+        pallas_corr.prep_pyramid_lanes(pyr, b), coords, interpret=True))
     corr = np.asarray(pyr[0])[..., 0]
     # window element (i=r, j=r) — zero offset — is flat index r·9 + r
-    center = got[0].reshape(h, w, 81)[..., 4 * 9 + 4]
-    want = corr[np.arange(n).reshape(h, w), yy, xx]
+    center = got.reshape(b, h, w, 81)[..., 4 * 9 + 4]
+    inside = (cx >= 0) & (cx < w) & (cy >= 0) & (cy < h)
+    want = np.where(inside, corr[np.arange(n).reshape(b, h, w),
+                                 np.clip(cy, 0, h - 1), np.clip(cx, 0, w - 1)],
+                    0)
+    assert inside.any() and not inside.all()
     np.testing.assert_array_equal(center, want)
+
+
+def _parents_level(corr, x, y, radius=4):
+    """The parent's per-level formula (PR 38's ``_lanes_kernel``), written
+    out: masked sums over w for each x tap, the x blend, masked sums over h
+    for each y tap, the y blend. corr (N, h, w); x, y (N,) → (N, 81)."""
+    p1 = 2 * radius + 1
+    x0, y0 = np.floor(x), np.floor(y)
+    xi, yi = x0.astype(np.int64), y0.astype(np.int64)
+    fx, fy = (x - x0).astype(np.float32), (y - y0).astype(np.float32)
+    n, h, w = corr.shape
+    s = [np.sum(corr * (np.arange(w)[None, None, :]
+                        == (xi + k - radius)[:, None, None]), axis=2)
+         for k in range(p1 + 1)]                                 # (N, h)
+    rows = [(1 - fx)[:, None] * s[i] + fx[:, None] * s[i + 1]
+            for i in range(p1)]
+    out = []
+    for i in range(p1):
+        v = [np.sum(rows[i] * (np.arange(h)[None, :]
+                               == (yi + k - radius)[:, None]), axis=1)
+             for k in range(p1 + 1)]
+        out += [(1 - fy) * v[j] + fy * v[j + 1] for j in range(p1)]
+    return np.stack(out, -1).astype(np.float32)
+
+
+@pytest.mark.parametrize('shape', [SHAPES[2], SHAPES[4]], ids=_shape_id)
+def test_lanes_is_bit_identical_to_the_parents_formula(monkeypatch, shape):
+    """The kernel picks each window value as ONE element of the level where
+    the parent summed it under 0/1 masks, and blends as the parent did: the
+    same bits. Values and fractions on a dyadic grid (eighths) make every
+    product and sum exact, so the comparison is bit for bit whatever order
+    or contraction a compiler gives the blends (on the chip: equal to the
+    parent's compiled kernel on random floats, PERF.md §6, PR 39)."""
+    b, h, w, cap = shape
+    _cap(monkeypatch, cap)
+    rng = np.random.RandomState(5)
+    pyr = [jnp.asarray(rng.randint(-64, 65, size=(b * h * w, h >> i, w >> i,
+                                                  1)).astype(np.float32) / 8)
+           for i in range(4)]
+    coords = (rng.randint(-12 * 8, (max(h, w) + 12) * 8, size=(b, h, w, 2))
+              / 8).astype(np.float32)
+    got = np.asarray(pallas_corr.lookup_corr_lanes(
+        pallas_corr.prep_pyramid_lanes(pyr, b), jnp.asarray(coords),
+        interpret=True)).reshape(-1, 4, 81)
+    for level, corr in enumerate(pyr):
+        want = _parents_level(np.asarray(corr)[..., 0],
+                              coords[..., 0].reshape(-1) / 2 ** level,
+                              coords[..., 1].reshape(-1) / 2 ** level)
+        np.testing.assert_array_equal(got[:, level], want,
+                                      err_msg=f'level {level}')
+
+
+def test_lanes_buffer_feeds_convc1_as_channels_last_would():
+    """``conv_from_lanes`` over the lookup's (324, rows, 128) buffer ==
+    the 1×1 convolution over its channels-last form: the same products."""
+    from video_features_tpu.ops.nn import conv
+    rng = np.random.RandomState(2)
+    b, h, w, c, o = 3, 5, 7, 324, 16
+    buf = jnp.asarray(rng.randn(c, pallas_corr.pixel_rows(b * h * w),
+                                128).astype(np.float32))
+    kernel = jnp.asarray(rng.randn(1, 1, c, o).astype(np.float32))
+    bias = jnp.asarray(rng.randn(o).astype(np.float32))
+    with jax.default_matmul_precision('highest'):
+        got = pallas_corr.conv_from_lanes(buf, kernel, bias, (b, h, w))
+        want = conv(pallas_corr.unpack(buf, (b, h, w)), kernel, bias=bias)
+    assert got.shape == want.shape == (b, h, w, o)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
 
 
 def test_dense_matches_gather():
@@ -94,7 +215,8 @@ def test_prep_fused_matches_two_step():
     B, H, W, D = 3, 8, 11, 16     # odd W exercises the valid-pool crop
     f1 = jnp.asarray(0.1 * rng.randn(B, H, W, D).astype(np.float32))
     f2 = jnp.asarray(0.1 * rng.randn(B, H, W, D).astype(np.float32))
-    two_step = pallas_corr.prep_pyramid_lanes(raft.build_corr_pyramid(f1, f2))
+    two_step = pallas_corr.prep_pyramid_lanes(raft.build_corr_pyramid(f1, f2),
+                                              B)
     fused = pallas_corr.prep_pyramid_lanes_fused(f1, f2)
     assert len(two_step) == len(fused)
     for i, (a, b) in enumerate(zip(two_step, fused)):
@@ -107,12 +229,52 @@ def test_prep_fused_matches_two_step():
     (28, 28, 'tpu', 'lanes'),     # a 224² crop
     (32, 43, 'tpu', 'lanes'),     # the shipped i3d geometry, 256×344
     (28, 28, 'cpu', 'dense'),     # off the TPU the kernel would interpret
-    (135, 240, 'tpu', 'dense'),   # 1080p: level 0 is over the VMEM budget
+    (135, 240, 'tpu', 'dense'),   # 1080p: level 0 is over the plane budget
 ])
 def test_resolve_lookup_decides_from_shape_and_platform(
         monkeypatch, h8, w8, platform, want):
     monkeypatch.delenv('VFT_RAFT_LOOKUP', raising=False)
     assert raft.resolve_lookup(h8, w8, platform) == want
+
+
+@pytest.mark.parametrize('h8', [1, 2, 3, 5, 7, 8, 9, 16, 31, 32, 43, 64, 90,
+                                127, 128, 135, 255, 1000, 4096, 16384])
+def test_resolve_lookup_keeps_every_plane_the_128_pixel_kernel_took(
+        monkeypatch, h8):
+    """PR 38's rule took 'lanes' on a TPU wherever the (h8, w8, 128) f32
+    block fit 8 MiB (h8·w8 ≤ 16,384) and 'dense' past it; the plane test
+    answers the same at both edges, and every plane it takes is streamed
+    in chunks that divide it and fit ``BLOCK_BYTES`` a grid step."""
+    monkeypatch.delenv('VFT_RAFT_LOOKUP', raising=False)
+    widest = 16384 // h8
+    assert raft.resolve_lookup(h8, widest, 'tpu') == 'lanes'
+    assert raft.resolve_lookup(h8, widest + 1, 'tpu') == 'dense'
+    for w8 in {1, 43, widest}:
+        hc, wc = pallas_corr.chunks(h8, w8)
+        assert h8 % hc == 0 and w8 % wc == 0, (h8, w8, hc, wc)
+        assert hc * wc * pallas_corr.TILE * 4 <= pallas_corr.BLOCK_BYTES
+
+
+@pytest.mark.parametrize('frame, note', [
+    # i3d's shipped geometry, 256×344: the whole plane a grid step
+    ((256, 344), {'raft_lookup': 'lanes', 'raft_lookup_pixels': 1024,
+                  'raft_lookup_h_chunks': 1, 'raft_lookup_w_chunks': 1}),
+    # feature_type=raft at 720p: 90 × 160 positions, nine chunks of ten rows
+    ((720, 1280), {'raft_lookup': 'lanes', 'raft_lookup_pixels': 1024,
+                   'raft_lookup_h_chunks': 9, 'raft_lookup_w_chunks': 1}),
+    ((1080, 1920), {'raft_lookup': 'dense'}),
+])
+def test_the_manifest_note_names_the_lookup_and_its_tile(monkeypatch, frame,
+                                                         note):
+    """The run manifest's ``kernels`` note at a frame size (padded to /8 as
+    the extractors pad it): the lookup, the pixels a grid step and the
+    chunks its level-0 plane is streamed in."""
+    monkeypatch.delenv('VFT_RAFT_LOOKUP', raising=False)
+    _, pads = raft.pad_to_multiple(np.zeros((1, *frame, 1), np.float32))
+    t, b, l, r = pads
+    h8, w8 = (frame[0] + t + b) // 8, (frame[1] + l + r) // 8
+    assert raft.lookup_note(h8, w8, 'tpu') == note
+    assert raft.lookup_note(h8, w8, 'cpu') == {'raft_lookup': 'dense'}
 
 
 def test_lookup_switch_dense_is_honoured_on_tpu(monkeypatch):

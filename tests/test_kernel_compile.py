@@ -196,14 +196,10 @@ def _while_body(text):
     return top, called
 
 
-def test_rafts_update_scan_compiles_without_a_two_channel_tensor(one_chip):
+@pytest.fixture(scope='module')
+def refine_text(one_chip):
     """i3d.corpus's `_refine` — 128 pairs at 32×43, 20 updates, three passes
-    (precision=mixed): four Mosaic calls a lookup by name, and in the while
-    body no convolution over or onto an operand whose minor axis is the 2
-    flow components, no channel-minor (B, 32, 43, 2) tensor and so no copy
-    of one: the carry is planes, batch on the lanes."""
-    import re
-
+    (precision=mixed) — compiled once; the module's text."""
     from video_features_tpu.models import raft
     from video_features_tpu.transplant.torch2jax import transplant
 
@@ -220,7 +216,16 @@ def test_rafts_update_scan_compiles_without_a_two_channel_tensor(one_chip):
         with jax.default_matmul_precision('high'):
             return raft._refine(p, fmap1, fmap2, cnet, 20, 'tpu')
 
-    text = jax.jit(refine).lower(params, fmap, fmap, fmap).compile().as_text()
+    return jax.jit(refine).lower(params, fmap, fmap, fmap).compile().as_text()
+
+
+def test_rafts_update_scan_compiles_without_a_two_channel_tensor(refine_text):
+    """Four Mosaic calls a lookup by name, and in the while body no
+    convolution over or onto an operand whose minor axis is the 2 flow
+    components, no channel-minor (B, 32, 43, 2) tensor and so no copy of
+    one: the carry is planes, batch on the lanes."""
+    import re
+    text = refine_text
     assert text.count('custom_call_target="tpu_custom_call"') == 4
     assert len(re.findall(r'%raft_corr_lookup_lanes[.\d]* = ', text)) == 4
     top, called = _while_body(text)
@@ -232,3 +237,59 @@ def test_rafts_update_scan_compiles_without_a_two_channel_tensor(one_chip):
     assert not any('f32[128,32,43,2]{3,' in line for line in top), [
         line for line in top if 'f32[128,32,43,2]{3,' in line]
     assert any('f32[2,128,32,43]{1,0,3,2' in line for line in top)
+
+
+def test_rafts_lookup_writes_what_convc1_reads(refine_text):
+    """The four level calls write ONE (324, rows, 128) buffer in place —
+    each later call takes the one before it as its aliased operand — and
+    the last call's result is the operand of the fusion that holds
+    ``convc1``'s product: no concatenate, transpose, copy or re-tiling
+    reshape of the lookup's result stands between (the parent's
+    ``%pad_maximum_fusion``, ``%copy`` and ``%reshape`` did). The level-0
+    call's blocks fit the VMEM limit the call asks for."""
+    import re
+
+    from video_features_tpu.ops import pallas_corr
+    text = refine_text
+    top, _ = _while_body(text)
+    calls = [line for line in top if ' custom-call(' in line
+             and 'raft_corr_lookup_lanes' in line.split(' = ')[0]]
+    assert len(calls) == 4
+    names = [re.match(r'\s*(%[\w.\-]+) = ', line).group(1) for line in calls]
+    for prev, line in zip(names, calls[1:]):
+        assert re.search(rf'{re.escape(prev)}\)', line), line
+    assert all(' = f32[324,1376,128]' in line for line in calls)
+    users = [line for line in top if re.search(rf'{re.escape(names[-1])}[,)]',
+                                               line)]
+    assert len(users) == 1 and ' fusion(' in users[0], users
+    called = re.search(r'calls=%?([\w.\-]+)', users[0]).group(1)
+    body = _computation(text, called)
+    assert any(' convolution(' in line and 'f32[1376,128,256]' in line
+               for line in body), body
+    for line in body:
+        assert not re.search(r' (concatenate|transpose|copy)\(', line), line
+    # the level-0 call: the limit it asks for, and what Mosaic used of it
+    limit = int(re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                          calls[0]).group(1))
+    used = int(re.search(
+        r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+        calls[0]).group(1))
+    assert pallas_corr.chunks(32, 43) == (32, 43)
+    assert limit == pallas_corr.vmem_bytes(32, 43, 9)
+    block = 32 * 43 * pallas_corr.TILE * 4
+    assert 2 * block <= used <= limit, (block, used, limit)
+
+
+def _computation(text, name):
+    """The lines of one computation of the compiled module's text."""
+    import re
+    lines, inside = [], False
+    for line in text.splitlines():
+        head = re.match(r'^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$', line)
+        if head:
+            inside = head.group(1) == name
+        elif line.startswith('}'):
+            inside = False
+        elif inside:
+            lines.append(line)
+    return lines

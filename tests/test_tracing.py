@@ -233,7 +233,8 @@ def test_lookup_kernel_is_named_in_the_lowered_module():
     rng = np.random.RandomState(1)
     f1, f2 = (jnp.asarray(rng.randn(2, 12, 9, 32).astype(np.float32))
               for _ in range(2))
-    prepped = pallas_corr.prep_pyramid_lanes(raft.build_corr_pyramid(f1, f2))
+    prepped = pallas_corr.prep_pyramid_lanes(raft.build_corr_pyramid(f1, f2),
+                                             2)
     coords = jnp.zeros((2, 12, 9, 2), jnp.float32)
     text = jax.jit(pallas_corr.lookup_corr_lanes).trace(
         prepped, coords).lower(lowering_platforms=('tpu',)).as_text()

@@ -189,6 +189,18 @@ class BaseExtractor:
         # run_packed installs it when decode_workers > 1 takes the
         # multi-process input path
         self._farm = None
+        self._kernels_said = None
+
+    def say_kernels(self, subsystem: str, notes: Dict[str, Any]) -> None:
+        """Which path a hand-written kernel's call site compiles to: said on
+        stderr when the answer is new, and kept for the run manifest's
+        ``kernels`` section (``obs/manifest.py::note_kernels``)."""
+        if notes != self._kernels_said:
+            self._kernels_said = notes
+            event(_logging.INFO, f'{subsystem}: the kernels',
+                  subsystem=subsystem, **notes)
+        if self.manifest is not None:
+            self.manifest.note_kernels(notes)
 
     def precision_scope(self):
         """Matmul-precision context for the device loop. ``highest`` (the

@@ -326,6 +326,11 @@ class ExtractI3D(BaseExtractor):
             pads = tuple(raft_model.pad_to_multiple(
                 np.zeros((1, gh, gw, 1), np.float32))[1])
             geom = self._geom_cache[(h, w)] = (pads, resize_to)
+            if 'flow' in self.streams:
+                t, b, l, r = pads
+                self.say_kernels('raft', raft_model.lookup_note(
+                    (gh + t + b) // 8, (gw + l + r) // 8,
+                    self._device.platform))
         return geom
 
     def extract(self, video_path: str) -> Dict[str, np.ndarray]:

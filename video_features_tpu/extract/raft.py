@@ -207,6 +207,9 @@ class ExtractRAFT(BaseExtractor):
                 padded, pads = raft_model.pad_to_multiple(
                     batch, mode=self.finetuned_on,
                     multiple=self.bucket_multiple)
+                self.say_kernels('raft', raft_model.lookup_note(
+                    padded.shape[1] // 8, padded.shape[2] // 8,
+                    self._device.platform))
                 yield padded, pads, valid, ts
 
         def put(padded):

@@ -238,8 +238,12 @@ def _conv_b(p: Params, x: jax.Array, padding=0) -> jax.Array:
 
 def motion_encoder(p: Params, flow: jax.Array, corr: jax.Array) -> jax.Array:
     """BasicMotionEncoder (reference update.py:79-97). ``flow`` comes as
-    its two planes, (2, B, H, W) — x then y; ``corr`` and the result are
-    channels-last: (B, H, W, 126 + 2), the flow in the last two channels.
+    its two planes, (2, B, H, W) — x then y; ``corr`` is channels-last
+    (B, H, W, 324) from the 'dense' and 'gather' lookups, or the 'lanes'
+    lookup's (324, rows, 128) buffer, which ``convc1`` contracts over its
+    leading axis as it stands (``ops/pallas_corr.conv_from_lanes``); the
+    result is channels-last: (B, H, W, 126 + 2), the flow in the last two
+    channels.
 
     Neither end treats the flow as a 2-channel tensor: ``convf1`` (7×7,
     2 → 128) folds W's taps into 14 channels (:func:`conv_from_planes`),
@@ -249,7 +253,12 @@ def motion_encoder(p: Params, flow: jax.Array, corr: jax.Array) -> jax.Array:
     the compiler keeps the GRU batch-minor and needs no transposed copy of
     the 126 channels.
     """
-    cor = relu(_conv_b(p['convc1'], corr))
+    if corr.ndim == 3:          # the lanes lookup's buffer, channels leading
+        from video_features_tpu.ops.pallas_corr import conv_from_lanes
+        cor = relu(conv_from_lanes(corr, p['convc1']['weight'],
+                                   p['convc1']['bias'], flow.shape[1:]))
+    else:
+        cor = relu(_conv_b(p['convc1'], corr))
     cor = relu(_conv_b(p['convc2'], cor, padding=1))
     with jax.named_scope('raft_convf1'):
         flo = relu(conv_from_planes(flow, p['convf1']['weight'],
@@ -365,10 +374,12 @@ def coords_planes(B: int, H: int, W: int, dtype=jnp.float32) -> jax.Array:
     return jnp.moveaxis(coords_grid(B, H, W, dtype), -1, 0)
 
 
-# The lanes kernel keeps one (h, w, LANES) f32 corr block per grid step in
-# VMEM; past this budget (level-0 block, MiB) auto-dispatch falls back to
-# dense rather than risk a Mosaic VMEM OOM on large frames.
-LANES_VMEM_BUDGET_MB = 8.0
+# The lanes kernel streams a level's plane for each tile of 1,024 pixels
+# through VMEM, in chunks of at most ops/pallas_corr.BLOCK_BYTES
+# (``pallas_corr.chunks``); past this plane (level 0, MiB a tile: 16,384
+# positions, the frames the 128-pixel kernel took up to an 8 MiB block)
+# auto-dispatch takes dense — the kernel there is not measured.
+LANES_PLANE_MB = 64.0
 
 LOOKUPS = ('auto', 'dense', 'gather', 'lanes')
 
@@ -377,17 +388,18 @@ def resolve_lookup(h8: int, w8: int, platform: str) -> str:
     """Which corr lookup the forward pass compiles at a 1/8-resolution map
     of ``h8 × w8`` on ``platform``: 'lanes', 'dense' or 'gather'.
 
-    The code decides ('auto'): 'lanes' — the lane-packed Pallas kernel,
-    ops/pallas_corr.py — on a TPU while the kernel's level-0
-    (h8, w8, LANES) f32 block fits ``LANES_VMEM_BUDGET_MB``; 'dense'
-    (:func:`lookup_corr_dense`, gather-free batched matmuls) anywhere
-    else, the CPU included, where the kernel would run interpreted.
-    Shapes are static at trace time, so the choice compiles away.
-    ``VFT_RAFT_LOOKUP`` still overrides it for two callers: 'dense' is
-    the operator's workaround for i3d on more than one chip (jax cannot
-    partition the Mosaic call) and 'gather' (:func:`lookup_corr`, the XLA
-    gather lowering) is the oracle the tests compare against; the switch
-    goes when the kernel is wrapped in ``shard_map`` (ROADMAP R1, D13).
+    The code decides ('auto'): 'lanes' — the Pallas kernel over 1,024
+    pixels a grid step, ops/pallas_corr.py — on a TPU while the level-0
+    plane of one tile, ``h8·w8`` positions of 4 KiB, fits
+    ``LANES_PLANE_MB``; 'dense' (:func:`lookup_corr_dense`, gather-free
+    batched matmuls) anywhere else, the CPU included, where the kernel
+    would run interpreted. Shapes are static at trace time, so the choice
+    compiles away. ``VFT_RAFT_LOOKUP`` still overrides it for two callers:
+    'dense' is the operator's workaround for i3d on more than one chip (jax
+    cannot partition the Mosaic call) and 'gather' (:func:`lookup_corr`,
+    the XLA gather lowering) is the oracle the tests compare against; the
+    switch goes when the kernel is wrapped in ``shard_map`` (ROADMAP R1,
+    D13).
     """
     import os
     impl = os.environ.get('VFT_RAFT_LOOKUP', 'auto')
@@ -397,11 +409,25 @@ def resolve_lookup(h8: int, w8: int, platform: str) -> str:
             + ', '.join(repr(name) for name in LOOKUPS))
     if impl != 'auto':
         return impl
-    from video_features_tpu.ops.pallas_corr import LANES
-    block_mb = h8 * w8 * LANES * 4 / 2 ** 20
-    if platform == 'tpu' and block_mb <= LANES_VMEM_BUDGET_MB:
+    from video_features_tpu.ops.pallas_corr import TILE
+    plane_mb = h8 * w8 * TILE * 4 / 2 ** 20
+    if platform == 'tpu' and plane_mb <= LANES_PLANE_MB:
         return 'lanes'
     return 'dense'
+
+
+def lookup_note(h8: int, w8: int, platform: str) -> Dict[str, Any]:
+    """The run manifest's ``kernels`` note for RAFT at an ``h8 × w8`` map:
+    the lookup :func:`resolve_lookup` picks and, for 'lanes', the pixels a
+    grid step and the chunks its level-0 plane is streamed in."""
+    impl = resolve_lookup(h8, w8, platform)
+    if impl != 'lanes':
+        return {'raft_lookup': impl}
+    from video_features_tpu.ops import pallas_corr
+    hc, wc = pallas_corr.chunks(h8, w8)
+    return {'raft_lookup': impl, 'raft_lookup_pixels': pallas_corr.TILE,
+            'raft_lookup_h_chunks': h8 // hc,
+            'raft_lookup_w_chunks': w8 // wc}
 
 
 def _pallas_interpret(platform: str) -> bool:
@@ -520,9 +546,10 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
     an update, and made ``convf1`` 49 MXU products 2 lanes wide. As planes
     the compiler lays the carry out batch-minor, which is how the flow
     head's last convolution writes it (:func:`conv_to_planes`); the lanes
-    lookup takes each plane, flattened, as the (1, N) vector its kernel
-    wants; :func:`motion_encoder` reads and re-emits the flow without a
-    2-channel tensor. One form at every size and on every platform; the
+    lookup takes the planes in that (h, w, b) order as (8, 128) tiles of
+    pixels, and its four calls write the one buffer ``convc1`` contracts;
+    :func:`motion_encoder` reads and re-emits the flow without a 2-channel
+    tensor. One form at every size and on every platform; the
     'dense' and 'gather' lookups keep their (B, H, W, 2) signature and are
     converted to at that boundary (scope ``raft_coords``)."""
     from video_features_tpu.ops.precision import pin_scope
@@ -544,7 +571,7 @@ def _refine(params: Params, fmap1: jax.Array, fmap2: jax.Array,
 
     impl = resolve_lookup(H8, W8, platform)
     if impl == 'lanes':
-        # lane-layout pyramid built straight from the fmaps: the
+        # the kernel's pyramid built straight from the fmaps: the
         # (N, h, w) detour + physical transpose was the fixed phase's
         # single worst HBM pattern (see prep_pyramid_lanes_fused)
         from video_features_tpu.ops import pallas_corr

@@ -298,9 +298,12 @@ class RunManifest:
         this run (``{'causal_attention': 'kernel' | 'xla'}`` from
         ``ops.attention.resolve_causal``; ``{'retention': 'state',
         'retention_chunk': n}`` from ``models.retention_trunk.kernels``,
-        which has one form to report, and its chunk): a path is all or
-        nothing per program, so this line is its engagement counter. ``{}``
-        for families that have no such choice to report."""
+        which has one form to report, and its chunk; ``{'raft_lookup':
+        'lanes', 'raft_lookup_pixels': 1024, 'raft_lookup_h_chunks': n,
+        'raft_lookup_w_chunks': m}`` from ``models.raft.lookup_note``, a
+        note a RAFT geometry): a path is all or nothing per program, so this
+        line is its engagement counter. ``{}`` for families that have no
+        such choice to report."""
         with self._lock:
             self.kernels.update({k: _jsonable(v) for k, v in info.items()})
 
