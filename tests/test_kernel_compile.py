@@ -120,6 +120,53 @@ def test_causal_attention_compiles_at_trinity_minis_widths(one_chip, passes,
     assert 'f32[1,32,32768,128]' not in text
 
 
+@pytest.mark.parametrize('kind', ['sparse', 'window'])
+@pytest.mark.parametrize('passes', [3, 1])
+def test_causal_attention_compiles_at_dots3_notes_widths(one_chip, passes,
+                                                         kind):
+    """One window of dots3-note.corpus: 8,192 positions of latent attention
+    as column groups with ONE rotary key. A full layer — 128 heads of (128,
+    64) / 128 — under the indexer's selection as (8,192, 256) packed words:
+    the keep lane, named sparse_attention, a key tile of 1,024 eight 128-lane
+    bit planes of its group. A sliding layer — 64 heads of (192, 64) / 128 —
+    under a window of 513: the windowed lane with column groups, a score
+    tile of 512 positions over the two key tiles of 512 its band crosses.
+    Three passes (precision=mixed) and one (the control lane)."""
+    from video_features_tpu.ops import pallas_attention as kernel
+    from video_features_tpu.ops.attention import KERNEL_PASSES, resolve_causal
+    precision = {3: 'high', 1: 'default'}[passes]
+    heads, nope = (128, 128) if kind == 'sparse' else (64, 192)
+    window = None if kind == 'sparse' else 513
+    assert resolve_causal('tpu', 8192, nope + 64, 128, precision, 1, 1,
+                          window, kind == 'sparse') == 'kernel'
+    assert KERNEL_PASSES[precision] == passes
+    if window:
+        assert kernel.tiles(8192, 1, window) == (512, 512)
+        assert kernel.resident_tiles(8192, 512, 512, window) == 2
+
+    def sds(heads, width):
+        return jax.ShapeDtypeStruct((1, 8192, heads, width), jnp.float32,
+                                    sharding=one_chip)
+
+    extra = ()
+    if kind == 'sparse':
+        extra = (jax.ShapeDtypeStruct((1, 8192, 256), jnp.int32,
+                                      sharding=one_chip),)
+
+    def attend(q_nope, q_rope, k_nope, k_rope, v, *keep):
+        return kernel.causal_attention(
+            (q_nope, q_rope), (k_nope, k_rope), v, (nope + 64) ** -0.5,
+            passes, window=window, keep=keep[0] if keep else None)
+
+    compiled = jax.jit(attend).lower(
+        sds(heads, nope), sds(heads, 64), sds(heads, nope), sds(1, 64),
+        sds(heads, 128), *extra).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    name = 'sparse_attention' if kind == 'sparse' else 'window_attention'
+    assert f'%{name}' in text and '%causal_attention' not in text
+
+
 @pytest.fixture(scope='module')
 def compiled_scan(one_chip):
     """brumby.corpus's mixer at its widths — 8 key-value heads with 5 query

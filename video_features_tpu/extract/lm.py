@@ -17,7 +17,10 @@ gated power retention, dense, ``models/retention_trunk.py`` —,
 ``lfm2_moe`` — gated short convolutions among grouped-query attention
 layers, sparse experts, ``models/hybrid_trunk.py`` — or ``afmoe`` — sliding-
 window and full grouped-query attention layers mixed, gated, over sparse
-experts with a shared one, the same module's second dialect. A trunk module
+experts with a shared one, the same module's second dialect —, or
+``dots3_note`` — latent attention under a learned selection of keys and
+under a window, gated, over sparse experts with a shared one,
+``models/latent_moe.py``'s second dialect. A trunk module
 says what this file needs of it (``models/token_trunk.py`` lists the names):
 its config from the args, its parameters, its step's second output, what it
 notes in the manifest and which counters it fills. An unknown
@@ -66,6 +69,7 @@ TRUNKS = {
     'brumby': 'video_features_tpu.models.retention_trunk',
     'lfm2_moe': 'video_features_tpu.models.hybrid_trunk',
     'afmoe': 'video_features_tpu.models.hybrid_trunk',
+    'dots3_note': 'video_features_tpu.models.latent_moe',
 }
 
 

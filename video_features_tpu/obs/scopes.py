@@ -47,6 +47,9 @@ SCOPES = (
     # the lm family's trunks (models/latent_moe.py, retention_trunk.py,
     # hybrid_trunk.py)
     'mla',               # latent attention
+    'sparse_mla',        # the same under a learned selection of keys
+    'mla_indexer',       # in sparse_mla: the indexer that selects them
+    'window_mla',        # latent attention under a window
     'attention',         # grouped-query attention
     'sliding_attention',  # the same under a window (a trunk with both kinds)
     'full_attention',    # and its full causal layers

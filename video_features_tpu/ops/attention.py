@@ -29,9 +29,14 @@ math, f32 accumulation, the ambient matmul precision on every product):
   * :func:`ring_attention` — blockwise over the mesh axis; memory AND
     compute sharded. Use under ``shard_map`` with the sequence axis split.
 
+A selection of keys (learned sparse attention: ``ops/sparse_index.py`` picks
+a query's keys) reaches both causal paths as bits, 32 keys a word
+(:func:`pack_keep`); a query then sees the selected keys at or before it.
+
 A fourth path is a kernel, for the causal case alone
 (``ops/pallas_attention.py``, named ``causal_attention`` in traces,
-``window_attention`` under a window): the same
+``window_attention`` under a window, ``sparse_attention`` under a selection
+of keys): the same
 online softmax with the score tile kept in VMEM, which the XLA tiles write to
 HBM several times a tile pair. It makes its bf16 passes itself (three under
 ambient ``high``, one under ``default``; ``highest`` has no lane in it), so
@@ -48,7 +53,7 @@ Shapes follow (B, S, H, D) [batch, sequence, heads, head_dim].
 """
 from __future__ import annotations
 
-from functools import cache
+from functools import cache, partial
 from typing import Optional
 
 import jax
@@ -111,7 +116,8 @@ KERNEL_PASSES = {None: 1, 'default': 1, 'bfloat16': 1, 'high': 3}
 
 def resolve_causal(platform: str, s: int, qk_dim: int, v_dim: int,
                    precision: Optional[str], heads: int = 1,
-                   kv_heads: int = 1, window: Optional[int] = None) -> str:
+                   kv_heads: int = 1, window: Optional[int] = None,
+                   keep: bool = False) -> str:
     """Which causal attention compiles for ``s`` positions on ``platform``
     under the ambient matmul ``precision``: 'kernel' (the fused Mosaic
     kernel, ops/pallas_attention.py) or 'xla' (:func:`blockwise_attention`
@@ -119,7 +125,10 @@ def resolve_causal(platform: str, s: int, qk_dim: int, v_dim: int,
     heads; only their ratio, the group, matters (1 when left out). Under a
     ``window`` (a query sees its own key and the ``window - 1`` before it)
     the kernel keeps only the band's key tiles resident, so its VMEM test
-    is over those and not over the sequence.
+    is over those and not over the sequence. ``keep`` asks for the kernel's
+    keep lane (a selection of keys as packed bits, :func:`pack_keep`): equal
+    head counts, no window, and key tiles that lie inside one group of the
+    packing.
 
     The kernel applies on a TPU, where the sequence is a whole number of its
     tiles and a key-value head's packed keys and values fit its VMEM budget,
@@ -151,14 +160,67 @@ def resolve_causal(platform: str, s: int, qk_dim: int, v_dim: int,
             or (group > 1 and group * qk_dim % 128)
             or 2 * resident * packed > kernel.KV_VMEM_BYTES):
         return 'xla'
+    if keep:
+        # a key tile's bits are whole planes of one group of words
+        lanes = keep_lanes(s) if s % KEEP_BITS == 0 else 0
+        if (group > 1 or window is not None or not lanes
+                or block_k % lanes or KEEP_BITS * lanes % block_k):
+            return 'xla'
     return 'kernel'
+
+
+# -- a selection of keys as bits (learned sparse attention) -------------------
+#
+# A query's selected keys travel as bits along the key axis, 32 to an int32
+# word: a (S, S) selection is (S, S/32) words, 8.4 MB at 8,192 positions
+# where a byte a key would be 67 MB (and the kernel re-reads it for every
+# head). The words of a row come in groups of ``lanes``: key u is bit b of
+# word l of group g where u = g · 32 · lanes + b · lanes + l — so one bit
+# plane of a group is ``lanes`` consecutive keys, and a key tile of 1,024 is
+# eight whole 128-lane planes of its group's words.
+KEEP_BITS = 32
+
+
+def keep_lanes(s: int) -> int:
+    """Words a group of the packing spans for ``s`` keys: 128 (4,096 keys a
+    group) where ``s`` is a whole number of such groups, else all of a
+    row's ``s / 32`` words as one group."""
+    if s % KEEP_BITS:
+        raise ValueError(f'a selection of keys packs 32 to a word: {s} keys '
+                         f'are no multiple of 32')
+    lanes = 128
+    return lanes if s % (KEEP_BITS * lanes) == 0 else s // KEEP_BITS
+
+
+def pack_keep(keep: jax.Array) -> jax.Array:
+    """(..., S) bool → (..., S/32) int32: the selection as bits."""
+    s = keep.shape[-1]
+    lanes = keep_lanes(s)
+    bits = keep.reshape(*keep.shape[:-1], s // (KEEP_BITS * lanes),
+                        KEEP_BITS, lanes).astype(jnp.int32)
+    shift = jnp.arange(KEEP_BITS, dtype=jnp.int32)[:, None]
+    # distinct bits: their sum is their OR, bit 31 included
+    return (bits << shift).sum(axis=-2, dtype=jnp.int32).reshape(
+        *keep.shape[:-1], s // KEEP_BITS)
+
+
+def unpack_keep(words: jax.Array, s: int) -> jax.Array:
+    """(..., S/32) int32 → (..., S) bool: :func:`pack_keep` undone."""
+    lanes = keep_lanes(s)
+    grouped = words.reshape(*words.shape[:-1], s // (KEEP_BITS * lanes), 1,
+                            lanes)
+    shift = jnp.arange(KEEP_BITS, dtype=jnp.int32)[:, None]
+    # an arithmetic shift: the bit that lands lowest is the one asked for
+    return (jnp.right_shift(grouped, shift) & 1).astype(bool).reshape(
+        *words.shape[:-1], s)
 
 
 def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                         block_size: int = 512,
                         scale: Optional[float] = None,
                         causal: bool = False,
-                        window: Optional[int] = None) -> jax.Array:
+                        window: Optional[int] = None,
+                        keep: Optional[jax.Array] = None) -> jax.Array:
     """Memory-efficient attention: scan over KV blocks, O(S·block) memory.
 
     Ragged S is handled by zero-padding KV to a block multiple and masking
@@ -169,13 +231,16 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``causal=True`` (self-attention, S a block multiple): position i sees
     keys 0…i. ``v`` may have another head width than ``q``/``k``, and ``k``
     and ``v`` a whole fraction of ``q``'s heads (grouped-query). With a
-    ``window`` (causal only) position i sees keys i − window + 1 … i.
+    ``window`` (causal only) position i sees keys i − window + 1 … i; with
+    ``keep`` ((B, S, S/32) int32, :func:`pack_keep`; causal only) only the
+    keys whose bit is set among those before it.
     """
-    if window is not None and not causal:
-        raise ValueError('a window is a causal layer\'s: causal=True')
+    if (window is not None or keep is not None) and not causal:
+        raise ValueError('a window or a selection of keys is a causal '
+                         'layer\'s: causal=True')
     if causal:
         return _causal_blockwise(q, k, v, block_size, _scale(q, scale),
-                                 window)
+                                 window, keep)
     b, sk, h, d = k.shape
     block_size = min(block_size, sk)
     pad = (-sk) % block_size
@@ -204,7 +269,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _causal_blockwise(q, k, v, block_size: int, scale: float,
-                      window: Optional[int] = None) -> jax.Array:
+                      window: Optional[int] = None,
+                      keep: Optional[jax.Array] = None) -> jax.Array:
     """Causal self-attention, tiled both ways: query tile i scans key tiles
     0…i-1 unmasked and then its own diagonal tile under the triangle, so
     the tiles above the diagonal cost nothing (a scan over all keys with a
@@ -222,7 +288,14 @@ def _causal_blockwise(q, k, v, block_size: int, scale: float,
     position's ``group`` query heads of one key-value head are laid side by
     side as ``group`` query rows of that head, so the tiles' products keep
     one head count, no key or value is copied, and a key tile is read once
-    for the whole group."""
+    for the whole group.
+
+    Under ``keep`` (a selection of keys as packed bits, equal head counts,
+    no window) every tile up to the diagonal is visited and takes the
+    selection's bits as its mask: a query tile's rows are unpacked once and
+    ride the scan beside the key tiles they mask."""
+    if keep is not None:
+        return _selected_blockwise(q, k, v, block_size, scale, keep)
     b, s, h, _ = q.shape
     if k.shape[1] != s:
         raise ValueError(f'causal attention is self-attention: q has {s} '
@@ -292,6 +365,50 @@ def _causal_blockwise(q, k, v, block_size: int, scale: float,
         out = out.reshape(b, s, group, kv_heads, -1).swapaxes(2, 3).reshape(
             b, s, h, -1)
     return out.astype(q.dtype)
+
+
+def _selected_blockwise(q, k, v, block_size: int, scale: float,
+                        keep: jax.Array) -> jax.Array:
+    """:func:`_causal_blockwise` under a selection of keys: query tile i
+    scans key tiles 0…i−1 under their bits and ends on its diagonal tile
+    under the triangle and its bits. A row that sees no key of a tile passes
+    it unchanged (``_online_block``'s finite stand-in)."""
+    b, s, h, _ = q.shape
+    if k.shape[1] != s or k.shape[2] != h or v.shape[2] != h:
+        raise ValueError('a selection of keys is self-attention over equal '
+                         'head counts')
+    if keep.shape != (b, s, s // KEEP_BITS):
+        raise ValueError(f'the selection is {keep.shape}, not '
+                         f'{(b, s, s // KEEP_BITS)} packed words')
+    block_size = min(block_size, s)
+    if s % block_size:
+        raise ValueError(f'causal attention needs the sequence ({s}) to be '
+                         f'a multiple of block_size ({block_size})')
+    n_blocks = s // block_size
+    kb = k.reshape(b, n_blocks, block_size, h, -1).swapaxes(0, 1)
+    vb = v.reshape(b, n_blocks, block_size, h, -1).swapaxes(0, 1)
+    pos = jnp.arange(block_size)
+    triangle = (pos[:, None] >= pos[None, :])[:, None, :]     # (q, 1, k)
+
+    def step(qi, carry, blk):
+        kb_j, vb_j, seen = blk
+        return _online_block(qi, *carry, kb_j, vb_j, scale, valid=seen), None
+
+    out = []
+    for i in range(n_blocks):
+        qi = q[:, i * block_size:(i + 1) * block_size]
+        # (b, rows, s) bits → per key tile (tiles, b, rows, 1, keys)
+        seen = jnp.moveaxis(unpack_keep(
+            keep[:, i * block_size:(i + 1) * block_size], s).reshape(
+                b, block_size, n_blocks, 1, block_size), 2, 0)
+        carry = _online_init(qi, v.shape[-1])
+        if i:
+            carry, _ = lax.scan(partial(step, qi), carry,
+                                (kb[:i], vb[:i], seen[:i]))
+        _, l, o = _online_block(qi, *carry, kb[i], vb[i], scale,
+                                valid=triangle & seen[i])
+        out.append(o / l)
+    return jnp.concatenate(out, axis=1).astype(q.dtype)
 
 
 def rotary_interleaved(x: jax.Array, positions: jax.Array,

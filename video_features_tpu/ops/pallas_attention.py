@@ -66,7 +66,20 @@ running max, denominator and accumulator in VMEM scratch:
   named ``window_attention`` in traces. ``window=None`` and ``window ≥ S``
   are the plain triangle, every windowed step behind a static ``window is
   not None``. A group of eight 128-wide query heads (trinity-mini's: 128
-  positions × 8 heads a score tile) is the grouped lane's second shape.
+  positions × 8 heads a score tile) is the grouped lane's second shape;
+  latent attention's column groups under a window of 513 (dots3-note's
+  sliding layers: 512 positions a score tile over the two key tiles of 512
+  its band crosses) the windowed lane's third.
+* **a selection of keys is the triangle under a second mask.** Learned
+  sparse attention (``ops/sparse_index.py``) keeps, for each query, the keys
+  its indexer scores highest; that selection arrives as bits, 32 keys an
+  int32 word (``ops.attention.pack_keep``: 8.4 MB a window-layer at 8,192
+  positions), and a grid step reads its (query tile, key tile)'s words
+  beside the causal test — a key tile of 1,024 is eight 128-lane bit planes
+  of one group of words, unpacked by shifts — so every tile up to the
+  diagonal is computed and masked. A row may see no key of a tile and
+  passes it unchanged. That call is named ``sparse_attention`` in traces;
+  every step of the lane is behind a static ``keep``.
 
 Keys and values enter heads-major, (B, H, S, width) — a block is one head's
 (tile, width) slab; the caller's (B, S, H, width) is transposed here, which
@@ -87,10 +100,14 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from video_features_tpu.ops.attention import KEEP_BITS, keep_lanes
+
 LANES = 128
 NAME = 'causal_attention'
 # the same kernel under a window: the name its pallas_call carries in traces
 WINDOW_NAME = 'window_attention'
+# and under a selection of keys
+SPARSE_NAME = 'sparse_attention'
 # the kernel's tiles at the cells' shapes — score-tile rows (a group's heads
 # share them: ``tiles``) and keys: my chip runs, PRs 30 and 36 (PERF.md §6)
 BLOCK_Q = 1024
@@ -222,12 +239,27 @@ def _tile_positions(shape, block_q: int, group: int) -> jax.Array:
     return row if group == 1 else lax.rem(row, block_q)
 
 
+def _kept(keep_ref, ki, block_k: int, lanes: int) -> jax.Array:
+    """The (rows, block_k) selection of key tile ``ki`` from its group's
+    packed words (``ops.attention.pack_keep``): the tile is ``block_k /
+    lanes`` consecutive bit planes of the group, each ``lanes`` keys wide,
+    laid side by side."""
+    words = keep_ref[...]
+    first = lax.rem(ki * block_k, KEEP_BITS * lanes) // lanes
+    planes = [lax.shift_right_logical(words, jnp.full(words.shape, first + j,
+                                                      jnp.int32)) & 1
+              for j in range(block_k // lanes)]
+    return jnp.concatenate(planes, axis=1) != 0
+
+
 def _kernel(*refs, groups: int, group: int, block_q: int, block_k: int,
             v_dim: int, passes: int, scale: float, window: Optional[int],
-            ring: int):
+            ring: int, keep: int = 0):
     q_refs, k_refs = refs[:groups], refs[groups:2 * groups]
-    v_ref, o_ref, qc_ref, kc_ref, vc_ref, m_ref, l_ref, acc_ref = \
-        refs[2 * groups:]
+    rest = list(refs[2 * groups:])
+    v_ref = rest.pop(0)
+    keep_ref = rest.pop(0) if keep else None
+    o_ref, qc_ref, kc_ref, vc_ref, m_ref, l_ref, acc_ref = rest
     qi, kj = pl.program_id(2), pl.program_id(3)
     first_masked = _first_masked_tile(qi, block_q, block_k)
     last = _last_key_tile(qi, block_q, block_k)
@@ -259,22 +291,26 @@ def _kernel(*refs, groups: int, group: int, block_q: int, block_k: int,
 
     def accumulate(masked: bool):
         s = lax.dot_general(qc_ref[...], kc_ref[rows, :], _NT, **_ONE_PASS)
+        seen = _kept(keep_ref, ki, block_k, keep) if keep else None
         if masked:
             row = qi * block_q + _tile_positions(s.shape, block_q, group)
             col = ki * block_k + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            seen = col <= row
+            causal = col <= row
             if window is not None:
-                seen = jnp.logical_and(seen, col > row - window)
+                causal = jnp.logical_and(causal, col > row - window)
+            seen = causal if seen is None else jnp.logical_and(seen, causal)
+        if seen is not None:
             s = jnp.where(seen, s, -jnp.inf)
         # key 0 is in the first tile and every row sees it, so m_new is
         # finite from the first step on and no exp sees -inf - -inf
         m_prev = m_ref[...]
         m_new = m_base = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        if masked and window is not None:
+        if (masked and window is not None) or keep:
             # not so under a window: a row may see no key of the tile the
             # band's lower edge crosses (its oldest key lies in the next),
-            # and then exponentiates against a finite stand-in: p and alpha
-            # come out 0 and the row's carry passes through
+            # nor under a selection, whose first kept key may lie tiles
+            # later: the row then exponentiates against a finite stand-in,
+            # p and alpha come out 0 and its carry passes through
             m_base = jnp.where(m_new == -jnp.inf, 0.0, m_new)
         p = jnp.exp(s - m_base)
         alpha = jnp.exp(m_prev - m_base)
@@ -348,10 +384,14 @@ def tiles(s: int, group: int = 1, window: Optional[int] = None
           ) -> Tuple[int, int]:
     """The shipped (query, key) tiles for ``s`` positions: a score tile has
     at most BLOCK_Q rows — ``group`` query heads at the largest power of
-    two of positions that leaves — and BLOCK_K keys, WINDOW_BLOCK_K under a
-    ``window`` shorter than the sequence."""
+    two of positions that leaves — and BLOCK_K keys; under a ``window``
+    shorter than the sequence WINDOW_BLOCK_K keys and no more positions
+    than that (a band of 513 keys crosses two tiles of 512 for 512
+    positions, three of 512 for 1,024)."""
     block_q = 1 << max(BLOCK_Q // group, 1).bit_length() - 1
-    block_k = BLOCK_K if window is None or window >= s else WINDOW_BLOCK_K
+    block_k = BLOCK_K
+    if window is not None and window < s:
+        block_q, block_k = min(block_q, WINDOW_BLOCK_K), WINDOW_BLOCK_K
     return min(block_q, s), min(block_k, s)
 
 
@@ -359,7 +399,8 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
                      passes: int, block_q: Optional[int] = None,
                      block_k: Optional[int] = None,
                      interpret: bool = False,
-                     window: Optional[int] = None) -> jax.Array:
+                     window: Optional[int] = None,
+                     keep: Optional[jax.Array] = None) -> jax.Array:
     """softmax(QKᵀ·scale + causal mask)V over (B, S, H, D) float32 tensors
     (v may be narrower), float32 (B, S, H, v's width) out, ``passes`` (1 or
     3) bf16 passes a product. With a ``window`` shorter than S, position i
@@ -379,7 +420,15 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
     of both tiles (by default :func:`tiles`) and every group's width of 64;
     compiled (not interpreted), the key tile, the score tile's rows and the
     output block's columns (``group`` · v's width) must be multiples of
-    128. One trace event a call, whatever B."""
+    128. One trace event a call, whatever B.
+
+    ``keep`` ((B, S, S/32) int32, ``ops.attention.pack_keep``: a selection
+    of keys a query, learned sparse attention) lets a query see only the
+    selected keys at or before it: every key tile up to the diagonal is
+    computed under the tile's bits, read beside it as (block_q, lanes)
+    words, and the call is named ``sparse_attention``. Equal head counts
+    and no window; a key tile must be whole bit planes of one group of the
+    packing."""
     if passes not in (1, 3):
         raise ValueError(f'causal_attention makes 1 or 3 bf16 passes, not '
                          f'{passes}')
@@ -418,6 +467,20 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
                          f'nothing')
     if window is not None and window >= s:
         window = None
+    lanes = 0
+    if keep is not None:
+        lanes = keep_lanes(s)
+        if group > 1 or window is not None:
+            raise ValueError('causal_attention: a selection of keys takes '
+                             'equal head counts and no window')
+        if keep.shape != (b, s, s // KEEP_BITS):
+            raise ValueError(f'causal_attention: the selection is '
+                             f'{keep.shape}, not {(b, s, s // KEEP_BITS)} '
+                             f'packed words')
+        if block_k % lanes or KEEP_BITS * lanes % block_k:
+            raise ValueError(f'causal_attention: key tiles of {block_k} are '
+                             f'no whole bit planes of a group of '
+                             f'{KEEP_BITS * lanes} keys')
     ring = resident_tiles(s, block_q, block_k, window)
 
     def heads_major(parts):
@@ -467,14 +530,26 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
     def kv_spec(x):
         return spec(x, block_k, kv_tile)
 
+    extra_in, extra_specs = (), []
+    name = NAME if window is None else WINDOW_NAME
+    if keep is not None:
+        # a query tile's words of the group that holds key tile ki (held on
+        # the last tile past the diagonal, where the steps do nothing)
+        extra_in, name = (keep,), SPARSE_NAME
+        extra_specs = [pl.BlockSpec(
+            (None, block_q, lanes),
+            lambda bi, hi, qi, ki: (bi, qi, jnp.minimum(
+                ki, _last_key_tile(qi, block_q, block_k)) * block_k
+                // (KEEP_BITS * lanes)))]
+
     rows = group * block_q
     out = pl.pallas_call(
         partial(_kernel, groups=len(widths), group=group, block_q=block_q,
                 block_k=block_k, v_dim=v_dim, passes=passes, scale=scale,
-                window=window, ring=ring),
+                window=window, ring=ring, keep=lanes),
         grid=(b, kv_heads, s // block_q, ring),
         in_specs=[*map(q_spec, q_parts), *map(kv_spec, k_parts),
-                  kv_spec(vt)],
+                  kv_spec(vt), *extra_specs],
         out_specs=pl.BlockSpec((None, block_q, group * v_dim),
                                lambda bi, hi, qi, ki: (bi, qi, hi)),
         out_shape=jax.ShapeDtypeStruct((b, s, h * v_dim), jnp.float32),
@@ -491,6 +566,6 @@ def causal_attention(q: Parts, k: Parts, v: jax.Array, scale: float,
                                  'arbitrary'),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-        name=NAME if window is None else WINDOW_NAME,
-    )(*q_parts, *k_parts, vt)
+        name=name,
+    )(*q_parts, *k_parts, vt, *extra_in)
     return out.reshape(b, s, h, v_dim)
