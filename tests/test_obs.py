@@ -677,7 +677,10 @@ CANONICAL_STAGES = {'decode', 'decode+preprocess', 'audio_dsp',
                     'retention_kernel',
                     # PR 33, the lm family's hybrid trunk: the expert
                     # walk's held assignments over the rows it computed
-                    'moe_walk'}
+                    'moe_walk',
+                    # the packed path's batches built in a recycled host
+                    # buffer
+                    'pack_recycled'}
 
 
 def test_stage_vocabulary_contract():

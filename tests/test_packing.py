@@ -902,3 +902,318 @@ def test_packed_run_names_its_lanes_in_manifest_and_header(
     ex.extract_packed(mixed_worklist, decode_workers=1)
     ex.finish_obs()
     assert '1 decode lane)' in capsys.readouterr().err
+
+
+# -- batch assembly in recycled host buffers ---------------------------------
+
+
+def _stack_packer(windows, batch, max_pool_age_s=None, family_of=None,
+                  family_batch=None, clock=time.monotonic):
+    """The plain packer ``packed_batches`` must match: windows pooled as
+    references, one ``np.stack`` a flush, the tail repeating the last."""
+    from video_features_tpu.parallel.packing import FLUSH, NUDGE
+    pools, ages = {}, {}
+
+    def cap_of(key):
+        return family_batch[key[0]] if family_batch is not None else batch
+
+    def flush(key):
+        pool, pools[key] = pools[key], []
+        ages.pop(key, None)
+        wins = [w for _, w, _ in pool]
+        wins += [wins[-1]] * (cap_of(key) - len(wins))
+        return np.stack(wins), [(t, m) for t, _, m in pool], len(pool)
+
+    for item in windows:
+        if item is FLUSH or item is NUDGE:
+            if item is FLUSH:
+                for key in list(pools):
+                    if pools[key]:
+                        yield flush(key)
+            yield None, [], 0
+            continue
+        task, window, meta = item
+        key = (window.shape, window.dtype.str)
+        if family_of is not None:
+            key = (family_of(meta),) + key
+        pool = pools.setdefault(key, [])
+        if not pool:
+            ages[key] = clock()
+        pool.append(item)
+        if len(pool) == cap_of(key):
+            yield flush(key)
+        if max_pool_age_s is not None:
+            now = clock()
+            for k in list(pools):
+                if pools[k] and now - ages[k] >= max_pool_age_s:
+                    yield flush(k)
+    for key in list(pools):
+        if pools[key]:
+            yield flush(key)
+
+
+def _win(rng, shape, dtype):
+    return (rng.integers(0, 250, size=shape)).astype(dtype)
+
+
+def _stream_mixed_geometries(rng):
+    shapes = [((2, 3), np.float32), ((4,), np.uint8), ((2, 3), np.float64)]
+    items = [(f't{i % 4}', _win(rng, *shapes[i % 3]), i) for i in range(23)]
+    return items, {'batch': 3}
+
+
+def _stream_flush_and_nudge(rng):
+    from video_features_tpu.parallel.packing import FLUSH, NUDGE
+    w = lambda i: (f't{i}', _win(rng, (3, 2), np.int32), i)   # noqa: E731
+    items = [w(0), w(1), FLUSH, FLUSH, NUDGE, w(2), w(3), w(4), w(5), NUDGE,
+             w(6), FLUSH, w(7), w(8), w(9), w(10), w(11)]
+    return items, {'batch': 4}
+
+
+def _stream_pool_aging(rng):
+    odd = lambda i: (f'o{i}', _win(rng, (3, 3), np.float32), i)  # noqa: E731
+    main = lambda i: (f'm{i}', _win(rng, (2, 2), np.float32), i)  # noqa: E731
+    items = [odd(0), ('tick', 0.06)] + [main(i) for i in range(5)]
+    items += [odd(1), main(5), ('tick', 0.02), main(6), ('tick', 0.04)]
+    items += [main(i) for i in range(7, 12)] + [odd(2)]
+    return items, {'batch': 4, 'max_pool_age_s': 0.05}
+
+
+def _stream_family_capacity(rng):
+    items = []
+    for i in range(9):                # two families on one geometry
+        items.append((f't{i}', _win(rng, (4, 4, 3), np.uint8), ('a', i)))
+        items.append((f't{i}', _win(rng, (4, 4, 3), np.uint8), ('b', i)))
+    return items, {'batch': 8, 'family_of': lambda m: m[0],
+                   'family_batch': {'a': 2, 'b': 4}}
+
+
+def _stream_short_tail(rng):
+    items = [(f't{i // 3}', _win(rng, (5,), np.int16), i) for i in range(11)]
+    return items, {'batch': 4}
+
+
+@pytest.mark.parametrize('make_stream', [
+    _stream_mixed_geometries, _stream_flush_and_nudge, _stream_pool_aging,
+    _stream_family_capacity, _stream_short_tail],
+    ids=['mixed_geometries', 'flush_and_nudge', 'pool_aging',
+         'family_capacity', 'short_tail'])
+def test_recycled_buffers_pack_the_bytes_np_stack_packs(make_stream,
+                                                        monkeypatch):
+    """``packed_batches`` copying windows into recycled buffers as they
+    arrive yields, batch for batch, the bytes, ``valid`` and provenance of
+    a packer that pools references and ``np.stack``s them at flush — with
+    every buffer given back (and scribbled over) as soon as its batch is
+    read, so recycled buffers really are refilled."""
+    import types
+
+    from video_features_tpu.parallel import packing
+    now = [0.0]
+    monkeypatch.setattr(packing, 'time', types.SimpleNamespace(
+        monotonic=lambda: now[0], perf_counter=time.perf_counter))
+    items, kw = make_stream(np.random.default_rng(7))
+
+    def stream():
+        for item in items:
+            if isinstance(item, tuple) and item[0] == 'tick':
+                now[0] += item[1]
+            else:
+                yield item
+
+    now[0] = 0.0
+    expected = list(_stack_packer(stream(), clock=lambda: now[0], **kw))
+    now[0] = 0.0
+    buffers = packing.BatchBuffers()
+    got = []
+    for stacked, prov, valid in packing.packed_batches(
+            stream(), buffers=buffers, **kw):
+        if stacked is None:
+            got.append((None, prov, valid))
+            continue
+        got.append((stacked.copy(), prov, valid))
+        stacked.view(np.uint8).fill(0xA5)         # stale bytes must not leak
+        buffers.give_back(stacked)
+    assert len(got) == len(expected) > 2
+    for (g, gp, gv), (e, ep, ev) in zip(got, expected):
+        assert (gp, gv) == (ep, ev)
+        if e is None:
+            assert g is None
+        else:
+            assert (g.shape, g.dtype) == (e.shape, e.dtype)
+            assert g.tobytes() == e.tobytes()
+    stats = buffers.stats()
+    assert sum(s['batches'] for s in stats.values()) == \
+        sum(1 for e, _, _ in expected if e is not None)
+    assert sum(s['recycled'] for s in stats.values()) > 0
+
+
+def _fake_device_loop(ex, fetch_delay_s=0.0):
+    """Device hooks that read the host batch at FETCH time: ``put`` hands
+    back the host array itself, as the CPU backend may alias it, and
+    records what was put; the fetch compares the array with that record."""
+    from collections import deque
+    put_copies, checks = deque(), []
+
+    def put(batch):
+        put_copies.append(batch.copy())
+        return batch
+
+    def fetch(out):
+        if fetch_delay_s:
+            time.sleep(fetch_delay_s)      # the packer runs on meanwhile
+        arr = out['resnet']
+        checks.append(np.array_equal(arr, put_copies.popleft()))
+        return {'resnet': arr.reshape(len(arr), -1)[:, :4].astype(np.float32)}
+
+    ex.put_input = put
+    ex.packed_step = lambda dev: {'resnet': dev}
+    ex.fetch_outputs = fetch
+    return checks
+
+
+@pytest.mark.parametrize('inflight', [2, 3])
+def test_a_buffer_is_not_refilled_while_its_step_is_in_flight(
+        mixed_worklist, tmp_path, inflight):
+    """A step aliasing its host buffer reads it only when fetched: with
+    ``inflight`` steps outstanding and 14 batches of one geometry, every
+    fetched batch is still what was put, buffers were recycled, and the
+    manifest's ``decode`` section names the buffers per geometry within
+    the pipeline's bound."""
+    import json
+    manifest = tmp_path / 'manifest.json'
+    ex = create_extractor(_resnet_args(
+        mixed_worklist, tmp_path / 'out', tmp_path / 'tmp',
+        manifest_out=str(manifest)))
+    checks = _fake_device_loop(ex, fetch_delay_s=0.005)
+    ex.extract_packed(mixed_worklist, batch_size=2, inflight=inflight)
+    ex.finish_obs()
+    assert ex.failed_videos == 0
+    assert len(checks) == 14 and all(checks)          # 27 frames, batch 2
+    doc = json.loads(manifest.read_text())
+    (geometry, st), = doc['decode']['batch_buffers'].items()
+    assert geometry == '2x224x224x3 uint8'
+    assert st['batches'] == 14 and st['recycled'] > 0
+    assert st['pack_recycled'] == st['recycled'] / 14
+    # at most the lookahead of 2 and the batch filling, the transfer
+    # thread's two staged batches and the one it places, the steps in flight
+    assert st['buffers'] + st['recycled'] == 14
+    assert st['buffers'] <= 2 + 1 + 3 + inflight
+    rec = doc['stages']['pack_recycled']
+    assert (rec['occ_valid'], rec['occ_capacity']) == (st['recycled'], 14)
+
+
+def test_a_second_run_recycles_the_first_runs_buffers_and_counts_its_own(
+        mixed_worklist, tmp_path):
+    """The extractor keeps its batch buffers from run to run: a second
+    packed run starts in the first run's buffers, and the manifest's
+    ``batch_buffers`` counts that run's batches alone (its buffers beside
+    every one the geometry was given)."""
+    import json
+    import shutil
+    manifest = tmp_path / 'manifest.json'
+    ex = create_extractor(_resnet_args(
+        mixed_worklist, tmp_path / 'out', tmp_path / 'tmp',
+        manifest_out=str(manifest)))
+    _fake_device_loop(ex)
+    ex.extract_packed(mixed_worklist, batch_size=2)
+    first = ex.batch_buffers.stats()['2x224x224x3 uint8']
+    assert first['batches'] == 14
+    shutil.rmtree(tmp_path / 'out')            # nothing skipped: run again
+    ex.extract_packed(mixed_worklist, batch_size=2)
+    ex.finish_obs()
+    assert ex.failed_videos == 0
+    st = json.loads(manifest.read_text())['decode']['batch_buffers'][
+        '2x224x224x3 uint8']
+    assert st['batches'] == 14
+    assert st['recycled'] + (st['buffers'] - first['buffers']) == 14
+    assert st['recycled'] >= first['buffers'] > 0
+    assert st['pack_recycled'] == st['recycled'] / 14
+
+
+def test_pack_and_h2d_run_on_different_threads_and_pack_times_copies_only(
+        mixed_worklist, tmp_path):
+    """In a packed run the ``pack`` spans (the copies, on the thread that
+    drains the window stream) and the ``h2d`` spans (the transfer thread)
+    sit on different threads. Under a window source that waits 20 ms a
+    window, the waits show as ``decode+preprocess`` and ``pack`` stays at
+    the copies' size: no pack span covers a wait."""
+    import json
+    manifest = tmp_path / 'manifest.json'
+    ex = create_extractor(_resnet_args(
+        mixed_worklist, tmp_path / 'out', tmp_path / 'tmp',
+        manifest_out=str(manifest)))
+    _fake_device_loop(ex)
+    real = ex.packed_windows
+
+    def slow(task):
+        for item in real(task):
+            time.sleep(0.02)
+            yield item
+
+    ex.packed_windows = slow
+    ex.extract_packed(mixed_worklist, decode_workers=1)
+    ex.finish_obs()
+    assert ex.failed_videos == 0
+    spans = [e for e in ex.tracer.recorder.snapshot() if e.get('ph') == 'X']
+    tids = {name: {e['tid'] for e in spans if e['name'] == name}
+            for name in ('pack', 'h2d', 'model', 'decode+preprocess')}
+    assert tids['pack'] and tids['h2d'] and tids['model']
+    assert not tids['pack'] & tids['h2d']
+    assert not tids['pack'] & tids['model']
+    assert tids['decode+preprocess'] == tids['pack']   # the same thread
+    stages = json.loads(manifest.read_text())['stages']
+    assert stages['decode+preprocess']['total_s'] >= 27 * 0.02 * 0.9
+    assert stages['pack']['total_s'] < 0.1 * stages['decode+preprocess'][
+        'total_s']
+    assert max(e['dur'] for e in spans if e['name'] == 'pack') < 0.02e6
+    # the copies of a batch: its 4 windows in runs, and a flush span that
+    # carries the batch's videos
+    flushes = [e for e in spans if e['name'] == 'pack'
+               and 'videos' in (e.get('args') or {})]
+    assert len(flushes) == 7                           # 27 frames, batch 4
+    assert sum(e['args']['windows'] for e in spans if e['name'] == 'pack'
+               and 'windows' in (e.get('args') or {})) == 27
+
+
+def test_batch_buffers_never_hand_one_buffer_to_two_holders():
+    """Takes and gives back from more threads than cores, switched every
+    10 us: no buffer is ever held twice at once, and the counts add up."""
+    import sys
+    import threading
+
+    from video_features_tpu.parallel.packing import BatchBuffers
+    buffers = BatchBuffers()
+    held, lock, clashes = set(), threading.Lock(), []
+    n_threads, rounds = 3 * (os.cpu_count() or 2), 300
+
+    def worker(i):
+        shape = (2, 3) if i % 2 else (4,)
+        for _ in range(rounds):
+            buf, _ = buffers.take(shape, np.uint8)
+            with lock:
+                if id(buf) in held:
+                    clashes.append(id(buf))
+                held.add(id(buf))
+            buf.fill(i % 251)
+            with lock:
+                held.discard(id(buf))
+            buffers.give_back(buf)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(i,))
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert clashes == []
+    stats = buffers.stats()
+    assert set(stats) == {'2x3 uint8', '4 uint8'}
+    assert sum(s['batches'] for s in stats.values()) == n_threads * rounds
+    assert all(s['buffers'] + s['recycled'] == s['batches']
+               and s['recycled'] > 0 for s in stats.values())

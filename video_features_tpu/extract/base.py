@@ -1067,14 +1067,21 @@ class BaseExtractor:
     # files, resume, fault isolation) is preserved by the scheduler.
 
     supports_packing = False
+    # the host batch buffers the packed runs fill and recycle
+    # (``parallel.packing.BatchBuffers``), kept from run to run
+    batch_buffers = None
 
     def packed_batch_size(self) -> int:
         """Window slots per packed device batch (the compiled batch)."""
         return int(self.batch_size)
 
     def _packed_setup(self) -> None:
-        """One-time pre-run setup (e.g. lazy data-parallel mesh build) —
-        runs before ``packed_batch_size`` is read."""
+        """Pre-run setup (e.g. lazy data-parallel mesh build) — runs
+        before ``packed_batch_size`` is read; an override calls it first.
+        Makes the batch buffers on the first run."""
+        if self.batch_buffers is None:
+            from video_features_tpu.parallel.packing import BatchBuffers
+            self.batch_buffers = BatchBuffers()
 
     def packed_windows(self, task):
         """Yield ``(window, meta)`` for one video, in window order.
@@ -1275,6 +1282,7 @@ class StackPackingMixin:
         return int(self.stack_batch)
 
     def _packed_setup(self) -> None:
+        super()._packed_setup()
         if self.data_parallel:
             self._ensure_mesh('stack_batch')
 

@@ -88,6 +88,7 @@ class BaseFrameWiseExtractor(BaseExtractor):
     supports_packing = True
 
     def _packed_setup(self) -> None:
+        super()._packed_setup()
         if self.data_parallel:
             self._ensure_mesh('batch_size')
 

@@ -579,9 +579,9 @@ class DecodeFarm:
     def stream(self, tasks: Iterable, admit: Callable) -> Iterator:
         """Yield ``(task, window, meta)`` / FLUSH / NUDGE across the
         whole task stream — the drop-in replacement for
-        ``stream_windows_across_videos`` + ``prefetch_across_videos``'s
-        producer side (windows still flow through the scheduler's
-        prefetch buffer downstream)."""
+        ``stream_windows_across_videos`` (the scheduler packs the windows
+        into batches on the thread that drains this, ahead of the
+        device)."""
         self.start()
         self._admit = admit
         dispatcher = threading.Thread(

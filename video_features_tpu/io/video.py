@@ -474,23 +474,6 @@ def _parallel_map(fn, iterable, workers: int):
             yield pending.popleft().result()
 
 
-def prefetch_across_videos(window_stream, max_windows: int):
-    """Bounded N-video decode-ahead for the packed corpus pipeline.
-
-    ``window_stream`` is a cross-video window iterator (see
-    ``extract.streaming.stream_windows_across_videos``): running it on the
-    prefetch producer thread means the decoder keeps working ACROSS video
-    boundaries — while the device finishes video k's last packed batch, the
-    host is already decoding videos k+1, k+2, … until ``max_windows``
-    windows are buffered. Memory is strictly bounded at
-    ``max_windows × window_bytes`` regardless of how many videos the
-    lookahead spans (a corpus of 1-window shorts prefetches many videos
-    deep; a long video fills the buffer by itself), which is what makes
-    corpus-scale runs safe on fixed-RAM hosts.
-    """
-    return prefetch(window_stream, depth=max(int(max_windows), 1))
-
-
 def prefetch(iterable, depth: int = 2):
     """Run ``iterable`` on a background thread, buffering ``depth`` items.
 

@@ -15,11 +15,14 @@ This module inverts the loop — batch-major over the whole worklist:
     ``decode_lane_plan``), one video after another at K = 1
     (``stream_windows_across_videos``), or the decode farm's worker
     processes (``farm/``) at an explicit ``decode_workers`` > 1;
-  * a decode-ahead thread (``io.video.prefetch_across_videos``) keeps the
-    decoder busy across video boundaries under a bounded window buffer;
   * the packer fills every device batch to capacity with
     (video, window_idx) provenance, grouping by window geometry so mixed
-    corpora still feed fixed-shape executables;
+    corpora still feed fixed-shape executables. It runs on the thread
+    that drains the window stream (``io.video.prefetch``, a lookahead of
+    ``decode_ahead`` finished batches, so the decoder keeps working
+    across video boundaries) and copies each window into a recycled host
+    batch buffer (``BatchBuffers``) as it arrives; the transfer thread
+    only places finished batches on the device;
   * the device loop is asynchronous on BOTH sides: ``packed_step`` only
     DISPATCHES (device arrays out, no forced readback), and a bounded
     in-flight queue (the ``inflight`` knob, default 2; 1 = synchronous)
@@ -52,6 +55,9 @@ from __future__ import annotations
 
 import logging as _logging
 import sys
+import threading
+import time
+from collections import OrderedDict
 from typing import Callable, Dict, Iterable, Iterator, List, Optional, Tuple
 
 import numpy as np
@@ -194,11 +200,104 @@ class FusedTask(VideoTask):
         self.farm_select = None
 
 
+def _geometry(shape: tuple, dtype) -> str:
+    """A batch buffer's geometry as the manifest names it:
+    ``'1024x224x224x3 uint8'``."""
+    return f'{"x".join(str(n) for n in shape)} {np.dtype(dtype).name}'
+
+
+class BatchBuffers:
+    """Host batch buffers of the packed path, recycled per geometry.
+
+    ``packed_batches`` takes one buffer of ``(capacity, *window_shape)``
+    for each batch it starts and copies windows into it as they arrive;
+    the device loop gives the buffer back once the step that read it has
+    been fetched — never when ``device_put`` returns: on a TPU the copy
+    is still running then, and the CPU backend may alias the host array
+    outright. A take finds a given-back buffer of its geometry, or
+    allocates a new one, so nothing ever waits here and the count per
+    geometry settles at what the pipeline holds at once (the lookahead,
+    the staged transfers, the steps in flight and the batch filling).
+
+    The extractor owns one (``BaseExtractor._packed_setup``), so idle
+    buffers outlive a run and the next run recycles them: host memory
+    stays held between runs, the count × the batch's bytes per geometry
+    (5 × 154 MB in ``resnet50.corpus``). Idle buffers are kept for the
+    ``MAX_IDLE_GEOMETRIES`` geometries taken last and dropped for older
+    ones, so a long-lived daemon that sees many geometries holds a bounded
+    number. Thread-safe: takes run on the packer's thread, gives back on
+    the dispatch thread. ``stats()`` per geometry: ``buffers`` allocated,
+    ``batches`` started, ``recycled`` of them in a given-back buffer, and
+    ``pack_recycled`` = recycled ÷ batches.
+    """
+
+    MAX_IDLE_GEOMETRIES = 4
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._idle: 'OrderedDict[tuple, List[np.ndarray]]' = OrderedDict()
+        self._stats: Dict[tuple, Dict[str, int]] = {}
+
+    def take(self, shape: tuple, dtype) -> Tuple[np.ndarray, bool]:
+        """A buffer of ``shape``/``dtype`` and whether it was recycled."""
+        key = (tuple(shape), np.dtype(dtype).str)
+        with self._lock:
+            st = self._stats.setdefault(
+                key, {'buffers': 0, 'batches': 0, 'recycled': 0})
+            st['batches'] += 1
+            idle = self._idle.setdefault(key, [])
+            self._idle.move_to_end(key)
+            while len(self._idle) > self.MAX_IDLE_GEOMETRIES:
+                self._idle.popitem(last=False)
+            if idle:
+                st['recycled'] += 1
+                return idle.pop(), True
+            st['buffers'] += 1
+        return np.empty(shape, dtype), False
+
+    def give_back(self, buf: np.ndarray) -> None:
+        """Return a buffer whose step has been fetched: nothing reads it
+        any more."""
+        key = (buf.shape, buf.dtype.str)
+        with self._lock:
+            idle = self._idle.get(key)
+            if idle is not None:
+                idle.append(buf)
+
+    def counts(self) -> Dict[tuple, Dict[str, int]]:
+        """The raw counts so far, for ``stats(since=)``."""
+        with self._lock:
+            return {key: dict(st) for key, st in self._stats.items()}
+
+    def stats(self, since: Optional[Dict] = None) -> Dict[str, Dict]:
+        """Per geometry; with ``since`` (an earlier ``counts()``), the
+        batches and recycled ones that came after it — one run's — beside
+        every buffer the geometry was given so far."""
+        since = since or {}
+        out = {}
+        for key, st in self.counts().items():
+            before = since.get(key, {})
+            st = dict(st, batches=st['batches'] - before.get('batches', 0),
+                      recycled=st['recycled'] - before.get('recycled', 0))
+            if st['batches']:
+                out[_geometry(*key)] = dict(
+                    st, pack_recycled=st['recycled'] / st['batches'])
+        return out
+
+
+# A run of copies shares one ``pack`` span while each window arrives
+# within this many seconds of the last copy's end: the packer's own
+# bookkeeping between the windows of one hand-over chunk. A longer gap is a
+# wait (for a lane's next chunk, for decode) and ends the span before it.
+PACK_SPAN_GAP_S = 50e-6
+
+
 def packed_batches(windows: Iterable[tuple], batch: int,
                    max_pool_age_s: Optional[float] = None,
                    tracer: Tracer = NULL_TRACER,
                    family_of: Optional[Callable] = None,
                    family_batch: Optional[Dict] = None,
+                   buffers: Optional[BatchBuffers] = None,
                    ) -> Iterator[Tuple[np.ndarray, list, int]]:
     """Group a cross-video ``(task, window, meta)`` stream into full
     fixed-size batches: ``(stacks, provenance, valid)`` where provenance is
@@ -238,11 +337,32 @@ def packed_batches(windows: Iterable[tuple], batch: int,
     geometry — arrives. This is what bounds a lone odd-geometry request
     under CONTINUOUS traffic, where the upstream feed is never idle (and
     so never emits FLUSH) but other geometries' windows keep flowing.
-    """
-    import time as _time
 
+    Each pool fills one host buffer of ``(capacity, *shape)`` from
+    ``buffers`` (None: a private ``BatchBuffers`` that nothing is given
+    back to, so every batch gets a new buffer): a window is copied into
+    the next free slot as it arrives, and a flush hands the buffer over
+    whole, its tail slots filled with the last window — the bytes
+    ``np.stack`` of the pooled windows would give. The copies are the
+    ``pack`` stage: one span a run of back-to-back copies (one a lane's
+    hand-over chunk; ``PACK_SPAN_GAP_S``), never the wait for the next
+    window, and one a flush (the tail fill, carrying the batch's videos
+    and trace ids). ``pack_recycled`` counts, a batch, whether its buffer
+    was a recycled one. Closing the generator closes ``windows``.
+    """
+    if buffers is None:
+        buffers = BatchBuffers()
+    timed = tracer.enabled
+    # key → [buffer or None, [(task, meta)], recycled]; a key stays once
+    # seen, so FLUSH and the drain visit geometries in first-seen order
     pools: Dict[tuple, list] = {}
     ages: Dict[tuple, float] = {}      # key → oldest pooled window's time
+    run = [0.0, 0.0, 0]                # the open pack span: t0, end, copies
+
+    def end_run() -> None:
+        if run[2]:
+            tracer.add('pack', run[1] - run[0], t0=run[0], windows=run[2])
+            run[2] = 0
 
     def cap_of(key) -> int:
         # fused pools are keyed (family, shape, dtype) and fill at that
@@ -252,70 +372,92 @@ def packed_batches(windows: Iterable[tuple], batch: int,
         return batch
 
     def flush(key):
-        pool = pools[key]
-        pools[key] = []
+        buf, prov, recycled = pools[key]
+        pools[key] = [None, [], False]
         ages.pop(key, None)
-        valid = len(pool)
-        cap = cap_of(key)
-        # the batch-assembly copy is the packer's own cost — timed as its
-        # own 'pack' stage; the span attrs (videos in the batch) are
-        # built ONLY when tracing is on, so the default hot loop stays
-        # allocation-free. getattr, not t.path: unit tests drive the
-        # packer with plain task tokens.
+        valid = len(prov)
+        # the span attrs (videos in the batch) are built ONLY when
+        # tracing is on, so the default hot loop stays allocation-free.
+        # getattr, not t.path: unit tests drive the packer with plain
+        # task tokens.
         attrs = ({'videos': sorted({str(getattr(t, 'path', t))
-                                    for t, _, _ in pool}),
-                  'valid': valid, 'capacity': cap}
-                 if tracer.enabled else {})
-        if tracer.enabled:
+                                    for t, _ in prov}),
+                  'valid': valid, 'capacity': len(buf)}
+                 if timed else {})
+        if timed:
             # batch spans serve several requests at once: carry the SET
             # of trace ids so a per-request trace filter still finds the
             # shared pack/model/d2h work it rode on
-            tids = trace_ids_of(t for t, _, _ in pool)
+            tids = trace_ids_of(t for t, _ in prov)
             if tids:
                 attrs['trace_ids'] = tids
+        end_run()
         with tracer.stage('pack', **attrs):
-            wins = [w for _, w, _ in pool]
-            while len(wins) < cap:
-                wins.append(wins[-1])
-            stacked = np.stack(wins)
-        return stacked, [(t, m) for t, _, m in pool], valid
+            buf[valid:] = buf[valid - 1]
+        tracer.add_occupancy('pack_recycled', int(recycled), 1)
+        return buf, prov, valid
 
-    for item in windows:
-        if item is FLUSH:
-            for key in list(pools):
-                if pools[key]:
-                    yield flush(key)
-            # always follow with the batchless drain marker: the source
-            # is momentarily idle, so the consumer must ALSO materialize
-            # its in-flight output queue (async device loop) — without
-            # this, a lone request's LAST dispatched batch would wait on
-            # future traffic to push it through the deferred-D2H window
-            yield None, [], 0
-            continue
-        if item is NUDGE:
-            # batchless marker: lets the consumer sweep for zero-window
-            # videos without waiting for a real batch (or stream end)
-            yield None, [], 0
-            continue
-        task, window, meta = item
-        window = np.asarray(window)
-        key = (window.shape, window.dtype.str)
-        if family_of is not None:
-            key = (family_of(meta),) + key
-        pool = pools.setdefault(key, [])
-        if not pool:
-            ages[key] = _time.monotonic()
-        pool.append((task, window, meta))
-        if len(pool) == cap_of(key):
-            yield flush(key)
-        if max_pool_age_s is not None:
-            now = _time.monotonic()
-            for k in list(pools):
-                if pools[k] and now - ages[k] >= max_pool_age_s:
-                    yield flush(k)
-    for key in list(pools):
-        if pools[key]:
-            yield flush(key)
+    try:
+        for item in windows:
+            if item is FLUSH:
+                for key in list(pools):
+                    if pools[key][1]:
+                        yield flush(key)
+                # always follow with the batchless drain marker: the
+                # source is momentarily idle, so the consumer must ALSO
+                # materialize its in-flight output queue (async device
+                # loop) — without this, a lone request's LAST dispatched
+                # batch would wait on future traffic to push it through
+                # the deferred-D2H window
+                end_run()
+                yield None, [], 0
+                continue
+            if item is NUDGE:
+                # batchless marker: lets the consumer sweep for
+                # zero-window videos without waiting for a real batch (or
+                # stream end)
+                end_run()
+                yield None, [], 0
+                continue
+            task, window, meta = item
+            window = np.asarray(window)
+            key = (window.shape, window.dtype.str)
+            if family_of is not None:
+                key = (family_of(meta),) + key
+            pool = pools.setdefault(key, [None, [], False])
+            prov = pool[1]
+            if not prov:
+                ages[key] = time.monotonic()
+                pool[0], pool[2] = buffers.take(
+                    (cap_of(key),) + window.shape, window.dtype)
+            if timed:
+                t0 = time.perf_counter()
+                pool[0][len(prov)] = window
+                t1 = time.perf_counter()
+                if run[2] and t0 - run[1] <= PACK_SPAN_GAP_S:
+                    run[1] = t1
+                    run[2] += 1
+                else:
+                    end_run()
+                    run[:] = [t0, t1, 1]
+            else:
+                pool[0][len(prov)] = window
+            prov.append((task, meta))
+            if len(prov) == cap_of(key):
+                yield flush(key)
+            if max_pool_age_s is not None:
+                now = time.monotonic()
+                for k in list(pools):
+                    if pools[k][1] and now - ages[k] >= max_pool_age_s:
+                        yield flush(k)
+        for key in list(pools):
+            if pools[key][1]:
+                yield flush(key)
+        end_run()
+    finally:
+        close = getattr(windows, 'close', None)
+        if close is not None:
+            close()         # the lane windower joins its decode threads
 
 
 def _admit_task(ex, task: VideoTask) -> bool:
@@ -478,15 +620,14 @@ def _in_process_windows(tasks: Iterator, open_windows: Callable,
         # laundered into decode time. (Under lanes this wrapper would
         # time waits on the hand-over queue and call them decode, and
         # the farm traces its workers' own timings: neither uses it.)
-        import time as _time
         it = iter(source)
         while True:
-            t0 = _time.perf_counter()
+            t0 = time.perf_counter()
             try:
                 item = next(it)
             except StopIteration:
                 return
-            dt = _time.perf_counter() - t0
+            dt = time.perf_counter() - t0
             if item is FLUSH:
                 tracer.add('queue_idle', dt, t0=t0)
             elif item is NUDGE:
@@ -534,8 +675,16 @@ def run_packed(ex, video_paths: Iterable,
         batch it shares, nor abort the worklist.
 
     ``decode_ahead`` bounds the cross-video decode lookahead at
-    ``decode_ahead × batch`` windows (see ``io.video.
-    prefetch_across_videos``).
+    ``decode_ahead`` finished batches: ``packed_batches`` runs on the
+    thread that drains the window stream (``io.video.prefetch``), copying
+    each window into a recycled host buffer (``ex.batch_buffers``) as it
+    arrives, and ``transfer_batches``' thread only places finished
+    batches on the device — the copy of batch k+1 overlaps the transfer
+    of batch k. A buffer goes back for reuse once the step that read it
+    has been fetched, so a geometry holds at most ``decode_ahead`` + 1
+    (the lookahead and the batch filling or handed over) + 3 (the
+    transfer thread's two staged batches and the one it is placing) +
+    ``inflight`` buffers; the run manifest says how many it took.
 
     ``batch_size`` (default: the extractor's ``packed_batch_size``) is
     the PER-DEVICE capacity. With ``mesh_devices > 1`` the loop is
@@ -588,7 +737,7 @@ def run_packed(ex, video_paths: Iterable,
     from video_features_tpu.extract.streaming import (
         fetch_step, transfer_batches,
     )
-    from video_features_tpu.io.video import prefetch_across_videos
+    from video_features_tpu.io.video import prefetch
 
     ex._packed_setup()
     # mesh-sharded execution (mesh_devices > 1): the device loop plans
@@ -814,7 +963,12 @@ def run_packed(ex, video_paths: Iterable,
         plan = _without_farm(plan)
         source = _in_process_windows(task_stream(), open_windows, plan,
                                      ex.tracer, decode_attrs, lane_stats)
-    ahead = prefetch_across_videos(source, decode_ahead * batch)
+    buffers = ex.batch_buffers
+    counts0 = buffers.counts()
+    ahead = prefetch(packed_batches(source, batch,
+                                    max_pool_age_s=max_pool_age_s,
+                                    tracer=ex.tracer, buffers=buffers),
+                     depth=max(int(decode_ahead), 1))
 
     # the in-flight queue: dispatched-but-unmaterialized batches, oldest
     # first. ``depth=1`` degenerates to the old synchronous loop (every
@@ -826,7 +980,8 @@ def run_packed(ex, video_paths: Iterable,
     from collections import deque
     depth = max(int(inflight if inflight is not None
                     else getattr(ex, 'inflight', 1) or 1), 1)
-    # (out_dev, prov, valid, batch_videos, batch_traces, step attrs)
+    # (out_dev, host buffer, prov, valid, batch_videos, batch_traces,
+    #  step attrs)
     pending: 'deque' = deque()
     ex._inflight_now = 0
 
@@ -857,8 +1012,9 @@ def run_packed(ex, video_paths: Iterable,
         step and the deferred D2H (``fetch_step``: the ``device_wait``
         and ``d2h`` stages — neither may launder into compute time) plus
         row scatter; asynchronously raised execution faults surface here
-        and doom only this batch's videos."""
-        out_dev, prov, valid, batch_videos, batch_traces, step = \
+        and doom only this batch's videos. Only a fetched step's input
+        buffer goes back for reuse (a failed one is dropped)."""
+        out_dev, host, prov, valid, batch_videos, batch_traces, step = \
             pending.popleft()
         ex._inflight_now = len(pending)
         try:
@@ -873,6 +1029,7 @@ def run_packed(ex, video_paths: Iterable,
             doom_batch(prov, batch_videos, valid, 'd2h')
             sweep()
             return
+        buffers.give_back(host)
         record_occupancy('d2h', valid)
         for i, (task, meta) in enumerate(prov):
             task.done += 1
@@ -906,11 +1063,11 @@ def run_packed(ex, video_paths: Iterable,
         sweep()
 
     with ex.precision_scope():
-        # batch assembly + H2D of batch k+1 overlap the device running k
-        for dev, _, prov, valid in transfer_batches(
-                packed_batches(ahead, batch, max_pool_age_s=max_pool_age_s,
-                               tracer=ex.tracer),
-                ex.put_input, tracer=ex.tracer):
+        # batch assembly (on the window stream's thread) and H2D of the
+        # next batches overlap the device running this one; the host
+        # buffer rides along until its step is fetched
+        for dev, host, prov, valid in transfer_batches(
+                ahead, ex.put_input, keep_host=True, tracer=ex.tracer):
             if dev is None:
                 # batchless drain marker (NUDGE / post-FLUSH): the source
                 # is idle or a video finished without windows — finalize
@@ -950,7 +1107,7 @@ def run_packed(ex, video_paths: Iterable,
                 sweep()
                 continue
             record_occupancy('model', valid)
-            pending.append((out, prov, valid, batch_videos,
+            pending.append((out, host, prov, valid, batch_videos,
                             batch_traces, step))
             ex._inflight_now = len(pending)
             while len(pending) >= depth:
@@ -985,16 +1142,19 @@ def run_packed(ex, video_paths: Iterable,
             # width, and the manifest must say which one ran
             'compute_dtype': compute_dtype})
 
+    # this run's host batch buffers per geometry: buffers, batches,
+    # recycled, pack_recycled
+    buffer_note = {'batch_buffers': buffers.stats(since=counts0)}
     if farm is not None and manifest is not None:
         # farm config + lifetime stats land in the run manifest (the
         # 'farm' section) so a farm-backed BENCH/run record names the
         # decode parallelism that produced it
         manifest.note_farm({'decode_workers': farm.n_workers,
                             'ring_bytes_per_worker': farm.ring_bytes,
-                            'stats': farm.stats()})
+                            'stats': farm.stats(), **buffer_note})
     elif manifest is not None:
         # ... and an in-process run its lanes (the 'decode' section)
-        manifest.note_decode(plan, lane_stats)
+        manifest.note_decode(dict(plan, **buffer_note), lane_stats)
 
     if ex.tracer.enabled and ex.tracer.report():
         if manifest is not None:
@@ -1084,10 +1244,12 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
     rather than through ``transfer_batches`` — with N families
     interleaving on one device loop there is no single "next batch" to
     overlap against (so ``input_wait`` is recorded here, on the lead
-    tracer, around the window prefetch).
+    tracer, around the batch prefetch). Batches are assembled as in
+    ``run_packed``: on the window stream's thread, in the lead's recycled
+    buffers, each given back once its family's step is fetched.
     """
     from video_features_tpu.extract.streaming import fetch_step, put_traced
-    from video_features_tpu.io.video import prefetch_across_videos
+    from video_features_tpu.io.video import prefetch
 
     if not exs:
         raise ValueError('run_packed_fused needs at least one family')
@@ -1248,12 +1410,19 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
         finally:
             src.close()       # an abandoned run joins its decode lanes
 
-    ahead = prefetch_across_videos(counted(source),
-                                   decode_ahead * max_batch)
+    # batches are assembled on the window stream's thread, in the lead's
+    # recycled buffers, as in run_packed
+    buffers = lead.batch_buffers
+    counts0 = buffers.counts()
+    ahead = prefetch(packed_batches(
+        counted(source), max_batch, max_pool_age_s=max_pool_age_s,
+        tracer=lead.tracer, family_of=lambda m: m[0],
+        family_batch=fam_batch, buffers=buffers),
+        depth=max(int(decode_ahead), 1))
     if lead.tracer.enabled:
-        # the consumer side of the decode queue: this loop packs and
-        # transfers inline (no transfer_batches), so the dispatch
-        # thread's wait for input is its next() on the window prefetch
+        # the consumer side of the batch queue: this loop transfers
+        # inline (no transfer_batches), so the dispatch thread's wait for
+        # input is its next() on the batch prefetch
         ahead = lead.tracer.wrap_iter('input_wait', ahead)
 
     from collections import deque
@@ -1311,7 +1480,8 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
 
     def sync_oldest(fam: str) -> None:
         ex = exs[fam]
-        out_dev, prov, valid, batch_videos, step = pending[fam].popleft()
+        out_dev, host, prov, valid, batch_videos, step = \
+            pending[fam].popleft()
         ex._inflight_now = len(pending[fam])
         try:
             out = fetch_step(ex.fetch_outputs, out_dev, ex.tracer, step,
@@ -1323,6 +1493,7 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
             doom(fam, prov, valid, 'd2h')
             sweep()
             return
+        buffers.give_back(host)     # its step is fetched: reuse it
         ex.tracer.add_occupancy('d2h', valid, fam_batch[fam])
         for i, (c, meta) in enumerate(prov):
             f2, t_ms = meta
@@ -1340,10 +1511,7 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
             while pending[fam]:
                 sync_oldest(fam)
 
-    for stacked, prov, valid in packed_batches(
-            ahead, max_batch, max_pool_age_s=max_pool_age_s,
-            tracer=lead.tracer, family_of=lambda m: m[0],
-            family_batch=fam_batch):
+    for stacked, prov, valid in ahead:
         if stacked is None:
             # batchless drain marker (NUDGE / post-FLUSH): materialize
             # every family's in-flight queue, then finalize
@@ -1374,7 +1542,7 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
             sweep()
             continue
         ex.tracer.add_occupancy('model', valid, fam_batch[fam])
-        pending[fam].append((out, prov, valid, batch_videos, step))
+        pending[fam].append((out, stacked, prov, valid, batch_videos, step))
         ex._inflight_now = len(pending[fam])
         while len(pending[fam]) >= depth[fam]:
             sync_oldest(fam)
@@ -1383,6 +1551,7 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
         ex._inflight_now = 0
     sweep(final=True)
 
+    buffer_note = {'batch_buffers': buffers.stats(since=counts0)}
     for fam, ex in exs.items():
         manifest = manifests[fam]
         if manifest is not None:
@@ -1391,10 +1560,10 @@ def run_packed_fused(exs: Dict, video_paths: Iterable,
             manifest.note_farm({'decode_workers': farm.n_workers,
                                 'ring_bytes_per_worker': farm.ring_bytes,
                                 'stats': farm.stats(),
-                                'fused_families': fams})
+                                'fused_families': fams, **buffer_note})
         elif manifest is not None:
-            manifest.note_decode(dict(plan, fused_families=fams),
-                                 lane_stats)
+            manifest.note_decode(dict(plan, fused_families=fams,
+                                      **buffer_note), lane_stats)
         if ex.tracer.enabled and ex.tracer.report():
             if manifest is not None:
                 manifest.fold_stages(ex.tracer.report())

@@ -49,6 +49,7 @@ STAGES = (
     'audio_dsp',          # vggish: host-side mel/log-mel DSP on the wav
     'queue_idle',         # serve: blocking waits on an idle request feed
     'pack',               # batch assembly copies (window and batch np.stack)
+    'pack_recycled',      # counter: packed batches built in a reused buffer
     'h2d',                # host→device input transfer, until it has landed
     'input_wait',         # dispatch thread blocked for the next batch
     'model',              # device-step dispatch (step=, program= attrs)
