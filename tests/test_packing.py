@@ -798,7 +798,8 @@ def test_lanes_read_no_clock_with_the_tracer_off(monkeypatch):
     assert len(out) == 160 and reads == []
     assert NULL_TRACER.report() == {}
     assert sum(s['windows'] for s in stats) == 160
-    assert all(s['busy_s'] == 0.0 == s['blocked_s'] for s in stats)
+    assert all(s['busy_s'] == 0.0 == s['blocked_s'] == s['first_chunk_s']
+               for s in stats)
 
 
 def test_lane_failure_is_the_videos_own_and_consumer_failure_stops_it():
@@ -897,6 +898,9 @@ def test_packed_run_names_its_lanes_in_manifest_and_header(
     assert sum(lane['busy_s'] for lane in dec['per_lane']) == \
         pytest.approx(doc['stages']['decode+preprocess']['total_s'],
                       abs=1e-4)
+    # each lane's one video: opening it to its first chunk, inside busy
+    assert all(0 < lane['first_chunk_s'] <= lane['busy_s']
+               for lane in dec['per_lane'])
     assert doc['farm'] == {}
     # explicit 1: the serial windower says so, and keeps no lane counters
     ex.extract_packed(mixed_worklist, decode_workers=1)

@@ -11,14 +11,14 @@ like the reference (:75-79).
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Optional
 
 import jax
 import numpy as np
 
 from video_features_tpu.extract.base import BaseExtractor
 from video_features_tpu.extract.streaming import (
-    overlap_fetch, transfer_batches,
+    CHUNK_WINDOWS, overlap_fetch, transfer_batches,
 )
 from video_features_tpu.io.video import VideoLoader
 
@@ -65,10 +65,11 @@ class BaseFrameWiseExtractor(BaseExtractor):
     def maybe_show_pred(self, feats: np.ndarray) -> None:
         pass
 
-    def _make_loader(self, video_path: str) -> VideoLoader:
+    def _make_loader(self, video_path: str,
+                     batch_size: Optional[int] = None) -> VideoLoader:
         return VideoLoader(
             video_path,
-            batch_size=self.batch_size,
+            batch_size=batch_size or self.batch_size,
             fps=self.extraction_fps,
             total=self.extraction_total,
             tmp_path=self.tmp_path,
@@ -84,6 +85,13 @@ class BaseFrameWiseExtractor(BaseExtractor):
     # fills frame batches across video boundaries — at corpus scale the
     # per-video tail batch (up to batch_size - 1 padded slots, paid per
     # video today) collapses into one tail batch per corpus.
+    #
+    # The packed loaders (in-process, farm, fused) read CHUNK_WINDOWS
+    # frames a batch, not the device batch: a loader batch is gathered
+    # whole before any frame of it is yielded, so a decode lane handed a
+    # 1,024-frame loader would decode a whole video before its first
+    # chunk. At the lanes' chunk it hands frames over as they decode; the
+    # packer fills the device batch either way.
 
     supports_packing = True
 
@@ -96,7 +104,7 @@ class BaseFrameWiseExtractor(BaseExtractor):
         from video_features_tpu.extract.streaming import (
             framewise_segment_windows, segment_frame_range,
         )
-        loader = self._make_loader(task.path)
+        loader = self._make_loader(task.path, CHUNK_WINDOWS)
         task.info['fps'] = loader.fps
         # deterministic close (segment early-stop abandons the loader
         # mid-decode; GC-timed release would strand codec contexts and
@@ -126,7 +134,7 @@ class BaseFrameWiseExtractor(BaseExtractor):
             return None
         from video_features_tpu.farm.recipes import FramewiseRecipe
         return FramewiseRecipe(
-            batch_size=self.batch_size, fps=self.extraction_fps,
+            batch_size=CHUNK_WINDOWS, fps=self.extraction_fps,
             total=self.extraction_total, tmp_path=self.tmp_path,
             keep_tmp=self.keep_tmp_files, backend=self.decode_backend,
             transform=spec)

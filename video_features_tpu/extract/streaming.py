@@ -429,9 +429,11 @@ def stream_windows_across_lanes(tasks: Iterable, open_windows: Callable,
     With the tracer on each lane records one ``decode+preprocess`` span a
     CHUNK under ``span_tid`` = lane (``span_attrs(task)`` ride on it), the
     wait that ends in a ``FLUSH`` as ``queue_idle``, and ``stats`` (one dict
-    a lane: ``videos``, ``windows``, ``chunks`` and, tracer on, ``busy_s``
-    and ``blocked_s``: seconds blocked on the full hand-over queue) is
-    filled in place. Tracer off: no clock is read.
+    a lane: ``videos``, ``windows``, ``chunks`` and, tracer on, ``busy_s``,
+    ``blocked_s``: seconds blocked on the full hand-over queue, and
+    ``first_chunk_s``: seconds from opening a video to handing over its
+    first chunk, summed over the lane's videos) is filled in place. Tracer
+    off: no clock is read.
     """
     lanes = max(int(lanes), 1)
     timed = tracer.enabled
@@ -441,8 +443,9 @@ def stream_windows_across_lanes(tasks: Iterable, open_windows: Callable,
     stop = threading.Event()
     if stats is None:
         stats = []
-    stats[:] = [{'videos': 0, 'windows': 0, 'chunks': 0,
-                 'busy_s': 0.0, 'blocked_s': 0.0} for _ in range(lanes)]
+    stats[:] = [{'videos': 0, 'windows': 0, 'chunks': 0, 'busy_s': 0.0,
+                 'blocked_s': 0.0, 'first_chunk_s': 0.0}
+                for _ in range(lanes)]
 
     def hand_over(msg) -> bool:
         """Blocking put that gives up once the stream is closed."""
@@ -493,13 +496,18 @@ def stream_windows_across_lanes(tasks: Iterable, open_windows: Callable,
         attrs = span_attrs(task) if timed and span_attrs is not None else {}
         chunk: list = []
         nbytes = 0
+        first = True
         t_chunk = t_prev = time.perf_counter() if timed else 0.0
 
         def send(ended: bool, t_end: float) -> None:
-            nonlocal chunk, nbytes, t_chunk, t_prev
+            nonlocal chunk, nbytes, t_chunk, t_prev, first
             if timed:
                 dt = t_end - t_chunk
                 st['busy_s'] += dt
+                if first:
+                    # the first chunk's span starts where the video opens
+                    st['first_chunk_s'] += dt
+                first = False
                 tracer.add('decode+preprocess', dt, t0=t_chunk,
                            span_tid=lane, lane=lane,
                            windows=len(chunk) - (chunk[-1:] == [FLUSH]),

@@ -229,9 +229,10 @@ class RunManifest:
         (``streaming.decode_lane_plan``: lanes resolved and why, cores
         seen, videos at hand) of the NEWEST ``run_packed`` call, the
         number of calls, and per lane the videos, windows, chunks, busy
-        seconds and seconds blocked on the full hand-over queue, summed
-        over calls (a benchmark calls ``extract_packed`` once a pass).
-        The section stays ``{}`` on farm-backed and per-video runs."""
+        seconds, seconds blocked on the full hand-over queue and seconds
+        from opening a video to its first chunk, summed over calls (a
+        benchmark calls ``extract_packed`` once a pass). The section
+        stays ``{}`` on farm-backed and per-video runs."""
         with self._lock:
             self.decode.update({k: _jsonable(v) for k, v in plan.items()})
             self.decode['calls'] = self.decode.get('calls', 0) + 1

@@ -1182,10 +1182,13 @@ def build_fused_recipe(exs: Dict):
     equality the caller established means every family would have built
     the identical loader — and the per-family branch transforms are each
     family's own published ``host_transform_spec()``."""
+    from video_features_tpu.extract.streaming import CHUNK_WINDOWS
     from video_features_tpu.farm.recipes import FusedRecipe
     lead = next(iter(exs.values()))
+    # the loader batch is the lanes' chunk, as every packed framewise
+    # loader's (``extract/framewise.py``): frames go over as they decode
     return FusedRecipe(
-        batch_size=lead.batch_size, fps=lead.extraction_fps,
+        batch_size=CHUNK_WINDOWS, fps=lead.extraction_fps,
         total=lead.extraction_total, tmp_path=lead.tmp_path,
         keep_tmp=lead.keep_tmp_files, backend=lead.decode_backend,
         transforms={fam: ex.host_transform_spec()
