@@ -86,7 +86,7 @@ def rel_l2(got, want):
 
 def run(params, ids, cfg):
     with jax.default_matmul_precision('highest'):
-        return jax.jit(lambda p, i: ht.forward(p, i, cfg, 8, 8))(params, ids)
+        return jax.jit(lambda p, i: token_trunk.forward(p, i, cfg, 8, 8))(params, ids)
 
 
 # -- the trunk against the plain reference ----------------------------------------
@@ -199,7 +199,7 @@ def test_the_sliding_layers_see_their_window_and_no_further(tiny):
                           layer_types=list(kinds), num_dense_layers=len(kinds))
         dense = {k: v for k, v in ht.init_params(cfg, 4).items()}
         with jax.default_matmul_precision('highest'):
-            return np.asarray(ht.hidden_states(dense, ids, cfg, 8, 8)[0])
+            return np.asarray(token_trunk.hidden_states(dense, ids, cfg, 8, 8)[0])
 
     changed = ids[:1].copy()
     changed[0, 0] = (changed[0, 0] + 1) % 512
@@ -237,12 +237,12 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
             for name in ('gate_proj', 'up_proj', 'down_proj'):
                 key = f'{m}.experts.{name}.weight'
                 share[key] = whole[key][first:first + 2]
-            y, counts = ht.expert_block(share, m, jnp.asarray(x[0]), cfg, 8)
+            y, counts = token_trunk.expert_block(share, m, jnp.asarray(x[0]), cfg, 8)
             # one share is the reference given the same share
             part = REF._experts(Ops(), share, m, jnp.asarray(x), dict(
                 rcfg, n_routed_experts=2, first_expert=first))
             assert rel_l2(y, part[0]) < TOLERANCE
-            routed, _ = ht.expert_block(share, m, jnp.asarray(x[0]),
+            routed, _ = token_trunk.expert_block(share, m, jnp.asarray(x[0]),
                                         dataclasses.replace(
                                             cfg, num_shared_experts=0), 8)
             total += np.asarray(routed)
@@ -352,7 +352,7 @@ def test_extract_packed_equals_the_per_video_loop(clips, tmp_path, capsys):
         assert a.shape == b.shape == (n, 64) and a.dtype == np.float32
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
     # the saved rows are the trunk's, on the tokeniser's ids (the reference's)
-    want, _ = ht.forward(packed.params, REF.load_units(
+    want, _ = token_trunk.forward(packed.params, REF.load_units(
         clips[2], range(5), tiny_reference_cfg()), packed.cfg)
     np.testing.assert_allclose(
         np.load(Path(packed.output_path) / 'c2_lm.npy'), want, atol=1e-5)

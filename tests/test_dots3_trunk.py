@@ -29,6 +29,7 @@ from _layers import Ops  # noqa: E402
 
 from video_features_tpu.config import load_config  # noqa: E402
 from video_features_tpu.models import latent_moe as lm  # noqa: E402
+from video_features_tpu.models import token_trunk  # noqa: E402
 from video_features_tpu.registry import create_extractor  # noqa: E402
 
 SEED = 2 ** 31 + 4001
@@ -95,7 +96,7 @@ def rel_l2(got, want):
 
 def run(params, ids, cfg, precision='highest', attn_block=8):
     with jax.default_matmul_precision(precision):
-        return lm.forward({k: jnp.asarray(v) for k, v in params.items()},
+        return token_trunk.forward({k: jnp.asarray(v) for k, v in params.items()},
                           jnp.asarray(ids), cfg, attn_block, 8)
 
 
@@ -208,16 +209,16 @@ def test_four_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
     x = jnp.asarray(np.random.default_rng(4).standard_normal((64, 64)),
                     jnp.float32)
     with jax.default_matmul_precision('highest'):
-        whole, counts = lm.expert_block(p, m, x, cfg, 8)
+        whole, counts = token_trunk.expert_block(p, m, x, cfg, 8)
         parts, shares = [], []
         for first in (0, 2, 4, 6):
             share = program_cfg(n_experts_held=2, first_expert=first)
             held = {k: (v[first:first + 2] if '.experts.' in k else v)
                     for k, v in p.items()}
-            y, c = lm.expert_block(held, m, x, share, 8)
+            y, c = token_trunk.expert_block(held, m, x, share, 8)
             parts.append(y)
             shares.append(c)
-        shared = lm.swiglu(x, p, f'{m}.shared_experts')
+        shared = token_trunk.swiglu(x, p, f'{m}.shared_experts')
     summed = sum(parts) - 3 * shared
     assert rel_l2(summed, whole) < 1e-5
     assert np.concatenate([np.asarray(c) for c in shares]).tolist() == \
@@ -233,9 +234,9 @@ def test_model_type_picks_the_second_dialect_and_the_yml_holds_its_keys():
         video_paths=['x.mp4'], device='cpu'))
     cfg = lm.TrunkConfig.from_args(args)
     assert cfg.model_type == 'dots3_note' and cfg.layer_types == tuple(KINDS)
-    assert cfg.mixer(F_) == lm.Mixer(4, 48, 32, 16, 8, 16, 8e7, None, True,
+    assert cfg.latent(F_) == lm.Latent(4, 48, 32, 16, 8, 16, 8e7, None, True,
                                      True, 4, 16, 8)
-    assert cfg.mixer(S_) == lm.Mixer(2, 48, 40, 24, 8, 16, 5e4, 5, True,
+    assert cfg.latent(S_) == lm.Latent(2, 48, 40, 24, 8, 16, 5e4, 5, True,
                                      True)
     from video_features_tpu.extract.lm import load_trunk
     assert load_trunk('dots3_note') is lm
@@ -243,7 +244,7 @@ def test_model_type_picks_the_second_dialect_and_the_yml_holds_its_keys():
     joyai = lm.TrunkConfig.from_args(load_config('lm', overrides=dict(
         video_paths=['x.mp4'], device='cpu')))
     assert joyai.model_type == 'joyai_llm_flash'
-    assert set(joyai.layer_types) == {F_} and joyai.mixer().index_topk == 0
+    assert set(joyai.layer_types) == {F_} and joyai.latent().index_topk == 0
 
 
 @pytest.mark.parametrize('changes,match', [

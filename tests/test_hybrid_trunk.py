@@ -87,7 +87,7 @@ def rel_l2(got, want):
 
 def run(params, ids, cfg):
     with jax.default_matmul_precision('highest'):
-        return jax.jit(lambda p, i: ht.forward(p, i, cfg, 16, 8))(params, ids)
+        return jax.jit(lambda p, i: token_trunk.forward(p, i, cfg, 16, 8))(params, ids)
 
 
 # -- the two ops ----------------------------------------------------------------
@@ -177,7 +177,7 @@ def test_the_expert_block_matches_the_reference_and_takes_its_constant(tiny):
     m = 'model.layers.3.feed_forward'
     want = np.asarray(REF._experts(Ops(), params, m, jnp.asarray(x), rcfg))
     with jax.default_matmul_precision('highest'):
-        got, counts = ht.expert_block(params, m, jnp.asarray(x).reshape(
+        got, counts = token_trunk.expert_block(params, m, jnp.asarray(x).reshape(
             128, 64), cfg, 8)
     assert rel_l2(got, want.reshape(128, 64)) < TOLERANCE
     assert int(np.asarray(counts).sum()) == 128 * 2       # all held
@@ -190,7 +190,7 @@ def test_the_expert_block_matches_the_reference_and_takes_its_constant(tiny):
         _, w = moe.route(ones, far, jnp.zeros((6,)), top_k=2, scaling=1.0,
                          eps=eps)
         np.testing.assert_allclose(w, np.full((5, 2), each), rtol=1e-5)
-    assert ht.ROUTE_EPS == 1e-6 == REF.CFG['route_eps']
+    assert ht.DIALECTS['lfm2_moe'].route_eps == 1e-6 == REF.CFG['route_eps']
 
 
 def test_four_shares_of_the_experts_add_up_to_the_uncut_layer(tiny):
@@ -211,7 +211,7 @@ def test_four_shares_of_the_experts_add_up_to_the_uncut_layer(tiny):
             for name in ('w1', 'w3', 'w2'):
                 key = f'{m}.experts.{name}.weight'
                 share[key] = params[key][first:first + 2]
-            y, counts = ht.expert_block(share, m, jnp.asarray(x[0]), cfg, 8)
+            y, counts = token_trunk.expert_block(share, m, jnp.asarray(x[0]), cfg, 8)
             # one share is the reference given the same share
             part = REF._experts(Ops(), share, m, jnp.asarray(x), dict(
                 rcfg, n_routed_experts=2, first_expert=first))
@@ -312,7 +312,7 @@ def test_a_bad_pattern_is_refused_by_name():
 def test_a_later_token_changes_no_earlier_position(tiny):
     cfg, _, params, ids = tiny
     with jax.default_matmul_precision('highest'):
-        hidden = jax.jit(lambda p, i: ht.hidden_states(p, i, cfg, 16, 8)[0])
+        hidden = jax.jit(lambda p, i: token_trunk.hidden_states(p, i, cfg, 16, 8)[0])
         a = hidden(params, ids[:1])
         changed = ids[:1].copy()
         changed[0, 40] = (changed[0, 40] + 1) % 512
@@ -325,7 +325,7 @@ def test_published_sizes_count_as_the_issue_counts_them():
     body = loader.load_json('configs', 'lfm2-8b-a1b-l8')
     cut = ht.TrunkConfig.from_args(body['overrides'])
     shapes = ht.param_shapes(cut)
-    assert cut.head_dim == 64 and cut.operators() == {'conv': 6,
+    assert cut.head_dim == 64 and cut.kinds() == {'conv': 6,
                                                       'full_attention': 2}
 
     def layer(i, part):
@@ -343,7 +343,7 @@ def test_published_sizes_count_as_the_issue_counts_them():
     whole = ht.TrunkConfig.from_args(dict(
         body['overrides'], num_hidden_layers=24,
         layer_types=body['layer_types']))
-    assert whole.operators() == {'conv': 18, 'full_attention': 6}
+    assert whole.kinds() == {'conv': 18, 'full_attention': 6}
     assert 8.3e9 < ht.param_count(whole) < 8.4e9
     # at these widths the causal kernel's grouped-query lane applies on a
     # TPU — four 64-wide query heads a key-value head fill two lane blocks,
@@ -359,17 +359,20 @@ def test_published_sizes_count_as_the_issue_counts_them():
 
 
 def test_the_trunks_share_their_blocks_and_the_expert_code():
-    for name in ('rms_norm', 'swiglu', 'embed', 'final_norm',
-                 'mean_features'):
-        assert getattr(ht, name) is getattr(latent_moe, name) \
-            is getattr(token_trunk, name)
-    assert ht.moe is latent_moe.moe
-    # neither trunk module routes or walks by itself
+    for trunk in (ht, latent_moe):
+        for name in ('rms_norm', 'param_shapes', 'param_count'):
+            assert getattr(trunk, name) is getattr(token_trunk, name)
+        assert trunk.count is token_trunk.count_experts
+        assert trunk.TrunkConfig.from_args.__func__ is \
+            token_trunk.BaseConfig.from_args.__func__
+    # neither trunk module routes, walks or runs a layer by itself
     for trunk in (ht, latent_moe):
         source = Path(trunk.__file__).read_text()
-        assert 'moe.routed_experts(' in source
-        assert 'moe.route(' not in source and 'moe.moe_share(' not in source
-        assert 'token_trunk.count_experts(' in source
+        for call in ('moe.routed_experts(', 'moe.route(', 'moe.moe_share('):
+            assert call not in source
+        assert 'def hidden_states' not in source
+        assert 'def expert_block' not in source
+    assert 'moe.routed_experts(' in Path(token_trunk.__file__).read_text()
 
 
 def test_the_walk_counter_is_assignments_over_rows_walked():
@@ -382,14 +385,16 @@ def test_the_walk_counter_is_assignments_over_rows_walked():
 
     counts = np.array([[256, 1, 0, 300], [64, 64, 64, 64]])
     table = Table()
-    token_trunk.count_experts(table, counts, top_k=2, tokens=500, block=256)
+    token_trunk.count_experts(table, counts, program_cfg(), tokens=500,
+                              block=256)
     assert table.rows['moe_walk'] == (813, (256 + 256 + 0 + 512) + 4 * 256)
     assert table.rows['moe_route'] == (813, (300 + 64) * 4)
     assert table.rows['moe_held'] == (813, 500 * 2 * 2)
     assert moe.walk_rows(np.array([0, 1, 256, 257])).tolist() == [
         0, 256, 256, 512]
     empty = Table()
-    token_trunk.count_experts(empty, np.zeros((0, 4)), 2, 500, 256)
+    token_trunk.count_experts(empty, np.zeros((0, 4)), program_cfg(), 500,
+                              256)
     assert not empty.rows
 
 
@@ -530,7 +535,7 @@ def test_extract_packed_equals_the_per_video_loop(clips, tmp_path, capsys):
         assert a.shape == b.shape == (n, 64) and a.dtype == np.float32
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
     # the saved rows are the trunk's, on the tokeniser's ids (the reference's)
-    want, _ = ht.forward(packed.params, REF.load_units(
+    want, _ = token_trunk.forward(packed.params, REF.load_units(
         clips[2], range(5), tiny_reference_cfg()), packed.cfg)
     np.testing.assert_allclose(
         np.load(Path(packed.output_path) / 'c2_lm.npy'), want, atol=1e-5)
@@ -614,13 +619,13 @@ def test_the_kernel_path_of_attention_block_is_the_xla_path_to_rounding(
     a = 'model.layers.1.self_attn'
     with jax.default_matmul_precision('high'):
         want = ht.attention_block(params, a, x, cfg, 64, 'cpu')
-        rows, _ = ht.forward(params, ids, cfg, 64, platform='cpu')
+        rows, _ = token_trunk.forward(params, ids, cfg, 64, platform='cpu')
         monkeypatch.setattr(ht, 'resolve_causal', lambda *args: 'kernel')
         monkeypatch.setattr(
             pallas_attention, 'causal_attention',
             partial(pallas_attention.causal_attention, interpret=True))
         got = ht.attention_block(params, a, x, cfg, 64, 'tpu')
-        through, _ = ht.forward(params, ids, cfg, 64, platform='tpu')
+        through, _ = token_trunk.forward(params, ids, cfg, 64, platform='tpu')
     assert got.shape == want.shape == (128, 256)
     assert 0 < rel_l2(got, want) < 2e-5
     assert 0 < rel_l2(through, rows) < 1e-4

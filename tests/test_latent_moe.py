@@ -25,6 +25,7 @@ from _layers import Ops  # noqa: E402
 
 from video_features_tpu.config import load_config  # noqa: E402
 from video_features_tpu.models import latent_moe as lm  # noqa: E402
+from video_features_tpu.models import token_trunk  # noqa: E402
 from video_features_tpu.ops import moe  # noqa: E402
 from video_features_tpu.ops.attention import (  # noqa: E402
     blockwise_attention, rotary_interleaved,
@@ -195,10 +196,10 @@ def test_mla_block_matches_the_reference(tiny):
 def test_a_later_token_changes_no_earlier_position(tiny):
     cfg, _, params, ids = tiny
     with jax.default_matmul_precision('highest'):
-        base, _ = lm.hidden_states(params, ids[:1], cfg, 16, 8)
+        base, _ = token_trunk.hidden_states(params, ids[:1], cfg, 16, 8)
         changed = ids[:1].copy()
         changed[0, 40] = (changed[0, 40] + 1) % 512
-        other, _ = lm.hidden_states(params, changed, cfg, 16, 8)
+        other, _ = token_trunk.hidden_states(params, changed, cfg, 16, 8)
     base, other = np.asarray(base), np.asarray(other)
     np.testing.assert_array_equal(base[0, :40], other[0, :40])
     assert np.abs(base[0, 40:] - other[0, 40:]).max() > 1e-3
@@ -211,7 +212,7 @@ def test_expert_block_matches_the_reference(tiny):
     m = 'model.layers.2.mlp'
     want = REF._experts(Ops(), params, m, jnp.asarray(x), rcfg)
     with jax.default_matmul_precision('highest'):
-        got, counts = lm.expert_block(params, m, jnp.asarray(x).reshape(
+        got, counts = token_trunk.expert_block(params, m, jnp.asarray(x).reshape(
             128, 64), cfg, 8)
     assert rel_l2(got, np.asarray(want).reshape(128, 64)) < 1e-5
     assert 0 < int(np.asarray(counts).sum()) < 128 * 4
@@ -228,7 +229,7 @@ def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_layer():
         np.float32)
     m = 'model.layers.1.mlp'
     want = np.asarray(REF._experts(Ops(), params, m, jnp.asarray(x), whole))
-    shared = np.asarray(lm.swiglu(jnp.asarray(x[0]), params,
+    shared = np.asarray(token_trunk.swiglu(jnp.asarray(x[0]), params,
                                   f'{m}.shared_experts'))
     total = np.zeros((64, 64))
     held_rows = 0
@@ -239,7 +240,7 @@ def test_the_four_shares_and_the_shared_expert_once_add_up_to_the_layer():
             for name in ('gate_proj', 'up_proj', 'down_proj'):
                 key = f'{m}.experts.{name}.weight'
                 share[key] = params[key][first:first + 4]
-            y, counts = lm.expert_block(share, m, jnp.asarray(x[0]), cfg, 8)
+            y, counts = token_trunk.expert_block(share, m, jnp.asarray(x[0]), cfg, 8)
             total += np.asarray(y) - shared
             held_rows += int(np.asarray(counts).sum())
     assert held_rows == 64 * 4          # every assignment lands on one share
@@ -251,7 +252,7 @@ def test_trunk_matches_the_reference(tiny):
     want = REF.forward(Ops(), {'checkpoint_path': params}, ids, rcfg)
     with jax.default_matmul_precision('highest'):
         got, counts = jax.jit(
-            lambda p, i: lm.forward(p, i, cfg, 16, 8))(params, ids)
+            lambda p, i: token_trunk.forward(p, i, cfg, 16, 8))(params, ids)
     assert got.shape == (3, 64) and got.dtype == jnp.float32
     assert rel_l2(got, want) < 1e-5
     assert counts.shape == (2, 4)
@@ -357,7 +358,7 @@ def test_extract_packed_equals_the_per_video_loop(clips, tmp_path):
         assert a.shape == b.shape == (n, 64) and a.dtype == np.float32
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
     # the saved rows are the trunk's, on the tokeniser's ids
-    want, _ = lm.forward(packed.params, REF.load_units(
+    want, _ = token_trunk.forward(packed.params, REF.load_units(
         clips[2], range(5), tiny_reference_cfg()), packed.cfg, 16, 8)
     np.testing.assert_allclose(
         np.load(Path(packed.output_path) / 'c2_lm.npy'), want, atol=1e-5)

@@ -97,11 +97,11 @@ def test_trunk_matches_the_reference(tiny, chunks_of_16):
     cfg, rcfg, params, ids = tiny
     want = REF.forward(Ops(), {'checkpoint_path': params}, ids, rcfg)
     with jax.default_matmul_precision('highest'):
-        got, scanned = jax.jit(lambda p, i: rt.forward(p, i, cfg))(params,
+        got, scanned = jax.jit(lambda p, i: token_trunk.forward(p, i, cfg))(params,
                                                                    ids)
     assert got.shape == (3, 64) and got.dtype == jnp.float32
     assert rel_l2(got, want) < 1e-5
-    assert np.asarray(scanned).tolist() == [[3 * 64] * 3, [0] * 3]
+    assert np.asarray(scanned).tolist() == [[3 * 64, 0]] * 3
     # the reference in one bf16 pass reads far above the program
     control = REF.forward(Ops('bfloat16'), {'checkpoint_path': params}, ids,
                           rcfg)
@@ -117,22 +117,22 @@ def test_every_chunk_and_a_padded_window_give_the_same_rows(
     cfg, _, params, ids = tiny
     with jax.default_matmul_precision('highest'):
         monkeypatch.setattr(rt, 'RETENTION_CHUNK', 16)
-        want, _ = rt.forward(params, ids, cfg)
+        want, _ = token_trunk.forward(params, ids, cfg)
         monkeypatch.setattr(rt, 'RETENTION_CHUNK', chunk)
-        got, scanned = rt.forward(params, ids, cfg)
+        got, scanned = token_trunk.forward(params, ids, cfg)
     assert rel_l2(got, want) < 1e-5
     assert rt.kernels(cfg, 'cpu', 64, None) == {
         'retention': 'state', 'retention_chunk': noted}
-    assert np.asarray(scanned).tolist() == [[3 * 64] * 3, [0] * 3]
+    assert np.asarray(scanned).tolist() == [[3 * 64, 0]] * 3
 
 
 def test_a_later_token_changes_no_earlier_position(tiny, chunks_of_16):
     cfg, _, params, ids = tiny
     with jax.default_matmul_precision('highest'):
-        base, _ = rt.hidden_states(params, ids[:1], cfg)
+        base, _ = token_trunk.hidden_states(params, ids[:1], cfg)
         changed = ids[:1].copy()
         changed[0, 40] = (changed[0, 40] + 1) % 512
-        other, _ = rt.hidden_states(params, changed, cfg)
+        other, _ = token_trunk.hidden_states(params, changed, cfg)
     base, other = np.asarray(base), np.asarray(other)
     np.testing.assert_array_equal(base[0, :40], other[0, :40])
     assert np.abs(base[0, 40:] - other[0, 40:]).max() > 1e-3
@@ -174,10 +174,14 @@ def test_published_sizes_count_as_the_issue_counts_them():
 
 
 def test_both_trunks_take_their_blocks_from_one_place():
-    for name in ('rms_norm', 'swiglu', 'embed', 'final_norm',
-                 'mean_features'):
-        assert getattr(rt, name) is getattr(latent_moe, name) \
-            is getattr(token_trunk, name)
+    for trunk in (rt, latent_moe):
+        for name in ('rms_norm', 'param_shapes', 'param_count'):
+            assert getattr(trunk, name) is getattr(token_trunk, name)
+        assert trunk.TrunkConfig.from_args.__func__ is \
+            token_trunk.BaseConfig.from_args.__func__
+        source = Path(trunk.__file__).read_text()
+        assert 'def hidden_states' not in source
+        assert 'def forward' not in source
 
 
 def test_the_row_blocked_feed_forward_is_the_feed_forward(tiny):
@@ -267,7 +271,7 @@ def test_extract_packed_equals_the_per_video_loop(clips, tmp_path, capsys,
         np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
     # the saved rows are the trunk's, on the tokeniser's ids (which are the
     # reference's: the joyai reference's rule at this grid)
-    want, _ = rt.forward(packed.params, REF.load_units(
+    want, _ = token_trunk.forward(packed.params, REF.load_units(
         clips[2], range(5), tiny_reference_cfg()), packed.cfg)
     np.testing.assert_allclose(
         np.load(Path(packed.output_path) / 'c2_lm.npy'), want, atol=1e-5)

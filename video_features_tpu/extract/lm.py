@@ -20,9 +20,10 @@ window and full grouped-query attention layers mixed, gated, over sparse
 experts with a shared one, the same module's second dialect —, or
 ``dots3_note`` — latent attention under a learned selection of keys and
 under a window, gated, over sparse experts with a shared one,
-``models/latent_moe.py``'s second dialect. A trunk module
-says what this file needs of it (``models/token_trunk.py`` lists the names):
-its config from the args, its parameters, its step's second output, what it
+``models/latent_moe.py``'s second dialect. Every one runs the one decoder of
+``models/token_trunk.py``, under its dialect's row; a trunk module says what
+this file needs of it (``models/token_trunk.py`` lists the names): its
+config from the args, its parameters, its step's second output, what it
 notes in the manifest and which counters it fills. An unknown
 ``model_type`` is refused by name.
 
@@ -59,6 +60,7 @@ import numpy as np
 from video_features_tpu.extract.base import (
     BaseExtractor, StackPackingMixin, named_step,
 )
+from video_features_tpu.models import token_trunk
 from video_features_tpu.utils.device import jax_device
 
 TOKEN_HASH = 2654435761
@@ -186,9 +188,9 @@ class ExtractLM(StackPackingMixin, BaseExtractor):
 
     @staticmethod
     def _forward(params, ids, cfg, platform=None):
-        trunk = load_trunk(cfg.model_type)
-        feats, counter = trunk.forward(params, ids, cfg, platform=platform)
-        return {'lm': feats, trunk.COUNTER: counter}
+        feats, counter = token_trunk.forward(params, ids, cfg,
+                                             platform=platform)
+        return {'lm': feats, load_trunk(cfg.model_type).COUNTER: counter}
 
     # -- the host preprocess: frames → ids ----------------------------------
 

@@ -1,7 +1,10 @@
 """A hybrid token trunk: layers that differ in kind by ``layer_types`` —
 gated short convolutions, full and sliding-window grouped-query attention —
-over sparse experts. Two published model types run through it, each a
-*dialect* of the same blocks (``DIALECTS``): ``lfm2_moe`` and ``afmoe``.
+over sparse experts. Two published model types run through it, each a row
+of ``DIALECTS`` over the one decoder of ``models/token_trunk.py``:
+``lfm2_moe`` and ``afmoe``. What is this module's own is the two mixers
+(the short convolution; grouped-query attention, full or under a window),
+the config's published fields, and the notes.
 
 Token ids in, one hidden-state row a window out. Which kind a layer is is
 static, read from the published ``layer_types`` and ``num_dense_layers``:
@@ -78,8 +81,8 @@ lane-dense row.
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
+from operator import attrgetter
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -87,11 +90,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from video_features_tpu.models import token_trunk
+# param_shapes and param_count are this trunk's too: the build and the
+# benchmark read them here
 from video_features_tpu.models.token_trunk import (
-    SWIGLU_NAMES, Params, embed, final_norm, mean_features, mlp_rows,
-    rms_norm, swiglu,
+    FULL, SLIDING, BaseConfig, Dialect, Mixer, Params, param_count,
+    param_shapes, rms_norm,
 )
-from video_features_tpu.ops import moe
 from video_features_tpu.ops.attention import (
     KERNEL_PASSES, blockwise_attention, resolve_causal, rotary_half,
 )
@@ -105,9 +109,6 @@ SHARE_ADVICE = ('Run fewer layers here (num_hidden_layers and as many '
                 'entries of layer_types: the rest are further pipeline '
                 'stages) or hold a share of each layer\'s experts '
                 '(n_experts_held, first_expert).')
-# the routing weights' normaliser: the chosen scores over their sum + this
-# (the lfm2_moe modelling code's constant; the config has no key for it)
-ROUTE_EPS = 1e-6
 
 # the config keys the lfm2_moe trunk is built from, under the published names
 CONFIG_KEYS = (
@@ -131,248 +132,42 @@ AFMOE_CONFIG_KEYS = (
 )
 
 
-@dataclass(frozen=True)
-class Dialect:
-    """What a ``model_type`` fixes beside its sizes: which operator kinds its
-    layers may be and which of them carry the rotary code, whether the
-    attention output is gated, the names of the norms over the two
-    sub-layers' outputs (none: no such norms), the normaliser's constant,
-    whether its attention mixer's scope is the layer's kind ('attention'
-    where not), the published config keys and the checkpoint's names."""
-    layer_types: Tuple[str, ...]
-    rotary: Tuple[str, ...]
-    gated: bool
-    post_norms: Tuple[str, ...]
-    route_eps: float
-    scope_by_kind: bool
-    config_keys: Tuple[str, ...]
-    renamed: Tuple[Tuple[str, str], ...]      # (field, published key)
-    operator_norm: str
-    ffn_norm: str
-    qk_norms: Tuple[str, str]
-    out_proj: str
-    ffn: str
-    ffn_names: Tuple[str, str, str]
-    router: str
-    final_norm: str
+# -- the mixers -----------------------------------------------------------------
+
+def conv_shapes(cfg: TrunkConfig, a: str, kind: str
+                ) -> Dict[str, Tuple[int, ...]]:
+    """{name: shape} of one layer's short convolution under prefix ``a``."""
+    d = cfg.hidden_size
+    return {f'{a}.in_proj.weight': (d, 3 * d),
+            f'{a}.conv.weight': (cfg.conv_L_cache, d),
+            f'{a}.out_proj.weight': (d, d)}
 
 
-DIALECTS = {
-    'lfm2_moe': Dialect(
-        layer_types=('conv', 'full_attention'), rotary=('full_attention',),
-        gated=False, post_norms=(), route_eps=ROUTE_EPS,
-        scope_by_kind=False, config_keys=CONFIG_KEYS, renamed=(),
-        operator_norm='operator_norm', ffn_norm='ffn_norm',
-        qk_norms=('q_layernorm', 'k_layernorm'), out_proj='out_proj',
-        # LFM2 names a SwiGLU's matrices w1 (gate), w3 (up), w2 (down)
-        ffn='feed_forward', ffn_names=('w1', 'w3', 'w2'), router='gate',
-        final_norm='model.embedding_norm.weight'),
-    'afmoe': Dialect(
-        layer_types=('sliding_attention', 'full_attention'),
-        rotary=('sliding_attention',), gated=True,
-        post_norms=('post_attention_layernorm', 'post_mlp_layernorm'),
-        route_eps=1e-20, scope_by_kind=True, config_keys=AFMOE_CONFIG_KEYS,
-        renamed=(('routed_scaling_factor', 'route_scale'),
-                 ('norm_topk_prob', 'route_norm'),
-                 ('norm_eps', 'rms_norm_eps'),
-                 ('embed_scale', 'mup_enabled')),
-        operator_norm='input_layernorm', ffn_norm='pre_mlp_layernorm',
-        qk_norms=('q_norm', 'k_norm'), out_proj='o_proj', ffn='mlp',
-        ffn_names=SWIGLU_NAMES, router='router.gate',
-        final_norm='model.norm.weight'),
-}
+def conv_block(p: Params, prefix: str, x: jax.Array, *_) -> jax.Array:
+    """The short-convolution operator over (B, S, D) normed windows (it has
+    one form: the loop's other arguments are not read)."""
+    with jax.named_scope('short_conv'):
+        return gated_short_conv(x, p[f'{prefix}.in_proj.weight'],
+                                p[f'{prefix}.conv.weight'],
+                                p[f'{prefix}.out_proj.weight'])
 
 
-@dataclass(frozen=True)
-class TrunkConfig:
-    vocab_size: int
-    hidden_size: int
-    num_hidden_layers: int
-    layer_types: Tuple[str, ...]
-    num_dense_layers: int
-    intermediate_size: int
-    moe_intermediate_size: int
-    num_experts: int
-    num_experts_per_tok: int
-    routed_scaling_factor: float
-    norm_topk_prob: bool
-    num_attention_heads: int
-    num_key_value_heads: int
-    rope_theta: float
-    norm_eps: float
-    use_expert_bias: bool = True
-    conv_L_cache: int = 0                    # 'conv' layers' taps
-    head_dim: Optional[int] = None           # None: hidden / heads
-    sliding_window: Optional[int] = None     # 'sliding_attention' layers'
-    num_shared_experts: int = 0
-    embed_scale: bool = False                # embedding · sqrt(hidden)
-    n_experts_held: Optional[int] = None     # None: all of them
-    first_expert: int = 0
-    model_type: str = MODEL_TYPE
-
-    def __post_init__(self):
-        object.__setattr__(self, 'layer_types', tuple(self.layer_types))
-        if len(self.layer_types) != self.num_hidden_layers:
-            raise ValueError(
-                f'layer_types names {len(self.layer_types)} layers, '
-                f'num_hidden_layers={self.num_hidden_layers}: give one entry '
-                f'a layer run here')
-        known = self.dialect.layer_types
-        for i, kind in enumerate(self.layer_types):
-            if kind not in known:
-                raise ValueError(
-                    f'layer_types[{i}]={kind!r} is no operator of the '
-                    f'model_type={self.model_type} trunk; known: '
-                    f'{", ".join(known)}')
-        object.__setattr__(self, 'n_experts_held', token_trunk.held_experts(
-            self.n_experts_held, self.first_expert, self.num_experts))
-        if self.num_experts_per_tok > self.num_experts:
-            raise ValueError('num_experts_per_tok exceeds num_experts')
-        if self.num_attention_heads % self.num_key_value_heads:
-            raise ValueError(
-                f'num_attention_heads={self.num_attention_heads} is no '
-                f'whole number of groups of num_key_value_heads='
-                f'{self.num_key_value_heads}')
-        if self.head_dim is None:
-            if self.hidden_size % (2 * self.num_attention_heads):
-                raise ValueError(
-                    f'hidden_size={self.hidden_size} over '
-                    f'num_attention_heads={self.num_attention_heads} is no '
-                    f'even head width (rotary pairs)')
-            object.__setattr__(self, 'head_dim',
-                               self.hidden_size // self.num_attention_heads)
-        elif self.head_dim % 2:
-            raise ValueError(f'head_dim={self.head_dim} is no even head '
-                             f'width (rotary pairs)')
-        if 'sliding_attention' in self.layer_types and not (
-                self.sliding_window and self.sliding_window > 0):
-            raise ValueError(
-                f'sliding_attention layers need sliding_window, the keys a '
-                f'query sees; got {self.sliding_window!r}')
-
-    @classmethod
-    def from_args(cls, args) -> 'TrunkConfig':
-        model_type = args.get('model_type')
-        if model_type not in DIALECTS:
-            model_type = MODEL_TYPE
-        dialect = DIALECTS[model_type]
-        values = {k: args.get(k) for k in dialect.config_keys}
-        values['first_expert'] = values['first_expert'] or 0
-        missing = [k for k, v in values.items()
-                   if v is None and k != 'n_experts_held']
-        if missing:
-            raise ValueError(f'the lm trunk model_type={model_type} needs '
-                             f'config keys {missing}')
-        score_func = values.pop('score_func', 'sigmoid')
-        if score_func != 'sigmoid':
-            raise ValueError(
-                f'score_func={score_func!r}: the model_type={model_type} '
-                f'trunk routes on sigmoid scores (ops.moe.route) and has '
-                f'no other')
-        for field, key in dialect.renamed:
-            values[field] = values.pop(key)
-        return cls(**values, model_type=model_type)
-
-    @property
-    def dialect(self) -> Dialect:
-        return DIALECTS[self.model_type]
-
-    def is_dense(self, layer: int) -> bool:
-        return layer < self.num_dense_layers
-
-    def window_of(self, kind: str) -> Optional[int]:
-        """The keys a query of a layer of ``kind`` sees: ``sliding_window``
-        in a sliding layer, None (all before it) in the others."""
-        return self.sliding_window if kind == 'sliding_attention' else None
-
-    def operators(self) -> Dict[str, int]:
-        """{operator kind: layers of it run here}, in the dialect's order."""
-        return {kind: self.layer_types.count(kind)
-                for kind in self.dialect.layer_types}
-
-
-def param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
-    """{name: shape} of every parameter held, in checkpoint order."""
+def attention_shapes(cfg: TrunkConfig, a: str, kind: str
+                     ) -> Dict[str, Tuple[int, ...]]:
+    """{name: shape} of one layer's grouped-query attention under prefix
+    ``a``, under the dialect's names."""
     d, h, g, hd = (cfg.hidden_size, cfg.num_attention_heads,
                    cfg.num_key_value_heads, cfg.head_dim)
     names = cfg.dialect
-    post_op, post_ffn = names.post_norms or (None, None)
-    gate_name, up_name, down_name = names.ffn_names
-    shapes: Dict[str, Tuple[int, ...]] = {
-        'model.embed_tokens.weight': (cfg.vocab_size, d)}
-    for i, kind in enumerate(cfg.layer_types):
-        p = f'model.layers.{i}'
-        shapes[f'{p}.{names.operator_norm}.weight'] = (d,)
-        if kind == 'conv':
-            shapes.update({
-                f'{p}.conv.in_proj.weight': (d, 3 * d),
-                f'{p}.conv.conv.weight': (cfg.conv_L_cache, d),
-                f'{p}.conv.out_proj.weight': (d, d)})
-        else:
-            a = f'{p}.self_attn'
-            shapes.update({
-                f'{a}.q_proj.weight': (d, h * hd),
-                f'{a}.k_proj.weight': (d, g * hd),
-                f'{a}.v_proj.weight': (d, g * hd)})
-            if names.gated:
-                shapes[f'{a}.gate_proj.weight'] = (d, h * hd)
-            shapes.update({
-                f'{a}.{names.qk_norms[0]}.weight': (hd,),
-                f'{a}.{names.qk_norms[1]}.weight': (hd,),
-                f'{a}.{names.out_proj}.weight': (h * hd, d)})
-        if post_op:
-            shapes[f'{p}.{post_op}.weight'] = (d,)
-        shapes[f'{p}.{names.ffn_norm}.weight'] = (d,)
-        m = f'{p}.{names.ffn}'
-        if cfg.is_dense(i):
-            f = cfg.intermediate_size
-            shapes.update({f'{m}.{gate_name}.weight': (d, f),
-                           f'{m}.{up_name}.weight': (d, f),
-                           f'{m}.{down_name}.weight': (f, d)})
-        else:
-            f, e = cfg.moe_intermediate_size, cfg.n_experts_held
-            shapes[f'{m}.{names.router}.weight'] = (d, cfg.num_experts)
-            if cfg.use_expert_bias:
-                shapes[f'{m}.expert_bias'] = (cfg.num_experts,)
-            shapes.update({f'{m}.experts.{gate_name}.weight': (e, d, f),
-                           f'{m}.experts.{up_name}.weight': (e, d, f),
-                           f'{m}.experts.{down_name}.weight': (e, f, d)})
-            if cfg.num_shared_experts:
-                fs = f * cfg.num_shared_experts
-                shapes.update({
-                    f'{m}.shared_experts.{gate_name}.weight': (d, fs),
-                    f'{m}.shared_experts.{up_name}.weight': (d, fs),
-                    f'{m}.shared_experts.{down_name}.weight': (fs, d)})
-        if post_ffn:
-            shapes[f'{p}.{post_ffn}.weight'] = (d,)
-    shapes[names.final_norm] = (d,)
+    shapes = {f'{a}.q_proj.weight': (d, h * hd),
+              f'{a}.k_proj.weight': (d, g * hd),
+              f'{a}.v_proj.weight': (d, g * hd)}
+    if names.gated:
+        shapes[f'{a}.gate_proj.weight'] = (d, h * hd)
+    shapes.update({f'{a}.{names.qk_norms[0]}.weight': (hd,),
+                   f'{a}.{names.qk_norms[1]}.weight': (hd,),
+                   f'{a}.{names.out_proj}.weight': (h * hd, d)})
     return shapes
-
-
-def param_count(cfg: TrunkConfig) -> int:
-    return token_trunk.param_count(param_shapes(cfg))
-
-
-def init_params(cfg: TrunkConfig, seed: int = 0) -> Dict[str, np.ndarray]:
-    """Seeded random parameters (``token_trunk.draw_params``: the taps come
-    out N(0, 1/taps)), a small router bias, and where the embedding is
-    multiplied by sqrt(hidden) one drawn that much smaller."""
-    def special(name, shape, rng):
-        if name.endswith('expert_bias'):
-            return 0.05 * rng.standard_normal(shape, dtype=np.float32)
-        if cfg.embed_scale and name == 'model.embed_tokens.weight':
-            return (rng.standard_normal(shape, dtype=np.float32)
-                    * np.float32(cfg.hidden_size ** -0.5))
-        return None
-    return token_trunk.draw_params(param_shapes(cfg), seed, special)
-
-
-def describe(cfg: TrunkConfig) -> str:
-    ops = ' + '.join(f'{n} {kind}' for kind, n in cfg.operators().items())
-    return (f'{cfg.num_hidden_layers} layers ({ops}) and '
-            f'{cfg.n_experts_held} of {cfg.num_experts} experts in each of '
-            f'the {max(cfg.num_hidden_layers - cfg.num_dense_layers, 0)} '
-            f'expert layers')
 
 
 def _causal_path(cfg: TrunkConfig, platform: str, s: int,
@@ -386,73 +181,13 @@ def _causal_path(cfg: TrunkConfig, platform: str, s: int,
                           window)
 
 
-def band_note(cfg: TrunkConfig, path: str, s: int, attn_block: int) -> str:
-    """The sliding layers' tiles in words: the key tiles a query tile
-    visits under the window against those of the whole triangle, at the
-    tiles the path takes (the kernel's, or the XLA tiles' ``attn_block``)."""
-    from video_features_tpu.ops import pallas_attention as kernel
-    group = cfg.num_attention_heads // cfg.num_key_value_heads
-    w = min(cfg.sliding_window, s)
-    block_q, block_k = (kernel.tiles(s, group, w) if path == 'kernel'
-                        else (min(attn_block, s),) * 2)
-    visited = triangle = 0
-    for q0 in range(0, s - s % block_q, block_q):
-        last = (q0 + block_q - 1) // block_k
-        visited += last - max(q0 - w + 1, 0) // block_k + 1
-        triangle += last + 1
-    return (f'{visited} of the triangle\'s {triangle} (query, key) tiles of '
-            f'{block_q} x {block_k}')
-
-
-def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
-            precision: Optional[str], attn_block: int = 1024
-            ) -> Dict[str, object]:
-    """What the step compiles: the causal attention's path ('kernel' or
-    'xla': ``ops.attention.resolve_causal``, from the platform, the window's
-    shapes, the head counts and the matmul precision; all or nothing per
-    layer kind: it is the kernel's engagement counter) and the operator
-    kinds run here. A trunk with sliding layers says it per kind, with the
-    window and what it saves in tiles (:func:`band_note`)."""
-    notes: Dict[str, object] = {}
-    if cfg.sliding_window is None:
-        notes['causal_attention'] = _causal_path(cfg, platform, window_ids,
-                                                 precision)
-    else:
-        for kind in cfg.operators():
-            notes[kind] = _causal_path(cfg, platform, window_ids, precision,
-                                       cfg.window_of(kind))
-        notes['sliding_window'] = cfg.sliding_window
-        notes['window_tiles'] = band_note(cfg, notes['sliding_attention'],
-                                          window_ids, attn_block)
-    notes['operators'] = ', '.join(f'{kind} {n}'
-                                   for kind, n in cfg.operators().items())
-    return notes
-
-
-def count(tracer, counts: np.ndarray, cfg: TrunkConfig, tokens: int) -> None:
-    """The step's per-expert counts → ``moe_route``, ``moe_held`` and
-    ``moe_walk`` (``token_trunk.count_experts``)."""
-    token_trunk.count_experts(tracer, counts, cfg.num_experts_per_tok, tokens,
-                              moe.BLOCK)
-
-
-# -- blocks -------------------------------------------------------------------
-
-def conv_block(p: Params, prefix: str, x: jax.Array) -> jax.Array:
-    """The short-convolution operator over (B, S, D) normed windows."""
-    with jax.named_scope('short_conv'):
-        return gated_short_conv(x, p[f'{prefix}.in_proj.weight'],
-                                p[f'{prefix}.conv.weight'],
-                                p[f'{prefix}.out_proj.weight'])
-
-
 def mixer_scope(cfg: TrunkConfig, kind: str):
     """The scope an attention mixer opens: ``attention``, or where the
     dialect has mixers of two kinds the layer's. Each a literal:
     ``obs/scopes.py`` pins the vocabulary."""
     if not cfg.dialect.scope_by_kind:
         return jax.named_scope('attention')
-    if kind == 'sliding_attention':
+    if kind == SLIDING:
         return jax.named_scope('sliding_attention')
     return jax.named_scope('full_attention')
 
@@ -512,89 +247,173 @@ def attention_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
         return jnp.dot(out, p[f'{prefix}.{names.out_proj}.weight'])
 
 
-def expert_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
-                 moe_block: int = moe.BLOCK) -> Tuple[jax.Array, jax.Array]:
-    """The expert layer's feed-forward over (T, D) tokens: the held
-    experts' share of the routed sum (``ops.moe.routed_experts``, under this
-    checkpoint's names) plus, where the model has them, the shared experts
-    every token takes. Returns the output and the (held,) counts."""
-    names = cfg.dialect
-    gate_name, up_name, down_name = names.ffn_names
-    with jax.named_scope('moe'):
-        bias = (p[f'{prefix}.expert_bias'] if cfg.use_expert_bias
-                else jnp.zeros((cfg.num_experts,), jnp.float32))
-        y, counts = moe.routed_experts(
-            x, p[f'{prefix}.{names.router}.weight'], bias,
-            p[f'{prefix}.experts.{gate_name}.weight'],
-            p[f'{prefix}.experts.{up_name}.weight'],
-            p[f'{prefix}.experts.{down_name}.weight'],
-            top_k=cfg.num_experts_per_tok,
-            scaling=cfg.routed_scaling_factor,
-            normalise=cfg.norm_topk_prob, eps=names.route_eps,
-            first=cfg.first_expert, block=moe_block)
-        if cfg.num_shared_experts:
-            y = y + swiglu(x, p, f'{prefix}.shared_experts',
-                           names=names.ffn_names)
-        return y, counts
+# -- the dialects -----------------------------------------------------------------
+
+CONV = Mixer(conv_block, conv_shapes, prefix='conv', per_window=False)
+ATTENTION = Mixer(attention_block, attention_shapes)
+DIALECTS = {
+    'lfm2_moe': Dialect(
+        mixers={'conv': CONV, FULL: ATTENTION}, config_keys=CONFIG_KEYS,
+        # the normaliser's constant: the lfm2_moe modelling code's (the
+        # config has no key for it)
+        route_eps=1e-6,
+        operator_norm='operator_norm', ffn_norm='ffn_norm',
+        # LFM2 names a SwiGLU's matrices w1 (gate), w3 (up), w2 (down)
+        ffn='feed_forward', ffn_names=('w1', 'w3', 'w2'),
+        expert_bias='expert_bias', final_norm='model.embedding_norm.weight',
+        rotary=(FULL,), qk_norms=('q_layernorm', 'k_layernorm'),
+        out_proj='out_proj'),
+    'afmoe': Dialect(
+        mixers={SLIDING: ATTENTION, FULL: ATTENTION},
+        config_keys=AFMOE_CONFIG_KEYS,
+        renamed=(('routed_scaling_factor', 'route_scale'),
+                 ('norm_topk_prob', 'route_norm'),
+                 ('norm_eps', 'rms_norm_eps'),
+                 ('embed_scale', 'mup_enabled')),
+        ffn_norm='pre_mlp_layernorm',
+        post_norms=('post_attention_layernorm', 'post_mlp_layernorm'),
+        router='router.gate', expert_bias='expert_bias',
+        rotary=(SLIDING,), gated=True, scope_by_kind=True),
+}
 
 
-def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
-                  attn_block: int = 1024, moe_block: int = moe.BLOCK,
-                  platform: Optional[str] = None
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """(B, S) int32 ids → ``(the final norm's hidden states (B, S, D),
-    counts)``; ``counts`` is (expert layers, held) int32, the batch's
-    assignments on each held expert (zero rows when no layer has experts).
-    Attention runs a window at a time (its tiles are the memory that
-    matters); the convolution takes the batch whole, each window shifted
-    within itself; the feed-forward takes all B·S tokens at once. Where the
-    dialect has post-norms, each sub-layer's output is normed before it
-    joins the stream."""
-    b, s = ids.shape
-    d = cfg.hidden_size
-    eps = cfg.norm_eps
-    names = cfg.dialect
-    post_op, post_ffn = names.post_norms or (None, None)
-    x = embed(params, ids)                                  # (B, S, D)
-    if cfg.embed_scale:
-        x = x * math.sqrt(d)
-    counts = []
-    for i, kind in enumerate(cfg.layer_types):
-        p = f'model.layers.{i}'
-        normed = rms_norm(x, params[f'{p}.{names.operator_norm}.weight'], eps)
-        if kind == 'conv':
-            y = conv_block(params, f'{p}.conv', normed)
-        else:
-            y = jax.lax.map(
-                lambda w: attention_block(params, f'{p}.self_attn', w, cfg,
-                                          attn_block, platform, kind),
-                normed)
-        if post_op:
-            y = rms_norm(y, params[f'{p}.{post_op}.weight'], eps)
-        x = x + y
-        normed = rms_norm(x, params[f'{p}.{names.ffn_norm}.weight'], eps
-                          ).reshape(b * s, d)
-        m = f'{p}.{names.ffn}'
-        if cfg.is_dense(i):
-            with jax.named_scope('dense_mlp'):
-                y = swiglu(normed, params, m, row_block=mlp_rows(b * s),
-                           names=names.ffn_names)
-        else:
-            y, c = expert_block(params, m, normed, cfg, moe_block)
-            counts.append(c)
-        if post_ffn:
-            y = rms_norm(y, params[f'{p}.{post_ffn}.weight'], eps)
-        x = x + y.reshape(b, s, d)
-    counts = (jnp.stack(counts) if counts
-              else jnp.zeros((0, cfg.n_experts_held), jnp.int32))
-    return final_norm(x, params, eps, names.final_norm), counts
+@dataclass(frozen=True)
+class TrunkConfig(BaseConfig):
+    vocab_size: int
+    hidden_size: int
+    num_hidden_layers: int
+    layer_types: Tuple[str, ...]
+    num_dense_layers: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    num_experts: int
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool
+    num_attention_heads: int
+    num_key_value_heads: int
+    rope_theta: float
+    norm_eps: float
+    use_expert_bias: bool = True
+    conv_L_cache: int = 0                    # 'conv' layers' taps
+    head_dim: Optional[int] = None           # None: hidden / heads
+    sliding_window: Optional[int] = None     # 'sliding_attention' layers'
+    num_shared_experts: int = 0
+    embed_scale: bool = False                # embedding · sqrt(hidden)
+    score_func: str = 'sigmoid'              # afmoe's key: no other runs
+    n_experts_held: Optional[int] = None     # None: all of them
+    first_expert: int = 0
+    model_type: str = MODEL_TYPE
+
+    dialects = DIALECTS
+    window_key = 'sliding_window'
+    eps = property(attrgetter('norm_eps'))
+    routed_experts = property(attrgetter('num_experts'))
+    shared_experts = property(attrgetter('num_shared_experts'))
+
+    def __post_init__(self):
+        self.check_layers()
+        if self.score_func != 'sigmoid':
+            raise ValueError(
+                f'score_func={self.score_func!r}: the '
+                f'model_type={self.model_type} trunk routes on sigmoid '
+                f'scores (ops.moe.route) and has no other')
+        object.__setattr__(self, 'n_experts_held', token_trunk.held_experts(
+            self.n_experts_held, self.first_expert, self.num_experts))
+        if self.num_experts_per_tok > self.num_experts:
+            raise ValueError('num_experts_per_tok exceeds num_experts')
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f'num_attention_heads={self.num_attention_heads} is no '
+                f'whole number of groups of num_key_value_heads='
+                f'{self.num_key_value_heads}')
+        if self.head_dim is None:
+            if self.hidden_size % (2 * self.num_attention_heads):
+                raise ValueError(
+                    f'hidden_size={self.hidden_size} over '
+                    f'num_attention_heads={self.num_attention_heads} is no '
+                    f'even head width (rotary pairs)')
+            object.__setattr__(self, 'head_dim',
+                               self.hidden_size // self.num_attention_heads)
+        elif self.head_dim % 2:
+            raise ValueError(f'head_dim={self.head_dim} is no even head '
+                             f'width (rotary pairs)')
+
+    def is_dense(self, layer: int) -> bool:
+        return layer < self.num_dense_layers
+
+    def window_of(self, kind: str) -> Optional[int]:
+        """The keys a query of a layer of ``kind`` sees: ``sliding_window``
+        in a sliding layer, None (all before it) in the others."""
+        return self.sliding_window if kind == SLIDING else None
 
 
-def forward(params: Params, ids: jax.Array, cfg: TrunkConfig,
-            attn_block: int = 1024, moe_block: int = moe.BLOCK,
-            platform: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
-    """(B, S) int32 ids → ``(features (B, D) float32, counts)``: the mean
-    of the window's final hidden states (:func:`hidden_states`)."""
-    x, counts = hidden_states(params, ids, cfg, attn_block, moe_block,
-                              platform)
-    return mean_features(x), counts
+# -- what the build says and counts ---------------------------------------------
+
+def init_params(cfg: TrunkConfig, seed: int = 0) -> Dict[str, np.ndarray]:
+    """Seeded random parameters (``token_trunk.draw_params``: the taps come
+    out N(0, 1/taps)), a small router bias, and where the embedding is
+    multiplied by sqrt(hidden) one drawn that much smaller."""
+    def special(name, shape, rng):
+        if name.endswith('expert_bias'):
+            return 0.05 * rng.standard_normal(shape, dtype=np.float32)
+        if cfg.embed_scale and name == 'model.embed_tokens.weight':
+            return (rng.standard_normal(shape, dtype=np.float32)
+                    * np.float32(cfg.hidden_size ** -0.5))
+        return None
+    return token_trunk.draw_params(param_shapes(cfg), seed, special)
+
+
+def describe(cfg: TrunkConfig) -> str:
+    ops = ' + '.join(f'{n} {kind}' for kind, n in cfg.kinds().items())
+    return (f'{cfg.num_hidden_layers} layers ({ops}) and '
+            f'{cfg.n_experts_held} of {cfg.num_experts} experts in each of '
+            f'the {max(cfg.num_hidden_layers - cfg.num_dense_layers, 0)} '
+            f'expert layers')
+
+
+def band_note(cfg: TrunkConfig, path: str, s: int, attn_block: int) -> str:
+    """The sliding layers' tiles in words: the key tiles a query tile
+    visits under the window against those of the whole triangle, at the
+    tiles the path takes (the kernel's, or the XLA tiles' ``attn_block``)."""
+    from video_features_tpu.ops import pallas_attention as kernel
+    group = cfg.num_attention_heads // cfg.num_key_value_heads
+    w = min(cfg.sliding_window, s)
+    block_q, block_k = (kernel.tiles(s, group, w) if path == 'kernel'
+                        else (min(attn_block, s),) * 2)
+    visited = triangle = 0
+    for q0 in range(0, s - s % block_q, block_q):
+        last = (q0 + block_q - 1) // block_k
+        visited += last - max(q0 - w + 1, 0) // block_k + 1
+        triangle += last + 1
+    return (f'{visited} of the triangle\'s {triangle} (query, key) tiles of '
+            f'{block_q} x {block_k}')
+
+
+def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
+            precision: Optional[str], attn_block: int = 1024
+            ) -> Dict[str, object]:
+    """What the step compiles: the causal attention's path ('kernel' or
+    'xla': ``ops.attention.resolve_causal``, from the platform, the window's
+    shapes, the head counts and the matmul precision; all or nothing per
+    layer kind: it is the kernel's engagement counter) and the operator
+    kinds run here. A trunk with sliding layers says it per kind, with the
+    window and what it saves in tiles (:func:`band_note`)."""
+    notes: Dict[str, object] = {}
+    if cfg.sliding_window is None:
+        notes['causal_attention'] = _causal_path(cfg, platform, window_ids,
+                                                 precision)
+    else:
+        for kind in cfg.kinds():
+            notes[kind] = _causal_path(cfg, platform, window_ids, precision,
+                                       cfg.window_of(kind))
+        notes['sliding_window'] = cfg.sliding_window
+        notes['window_tiles'] = band_note(cfg, notes['sliding_attention'],
+                                          window_ids, attn_block)
+    notes['operators'] = ', '.join(f'{kind} {n}'
+                                   for kind, n in cfg.kinds().items())
+    return notes
+
+
+# the step's per-expert counts → moe_route, moe_held and moe_walk
+count = token_trunk.count_experts

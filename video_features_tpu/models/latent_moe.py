@@ -1,6 +1,9 @@
 """A sparse-expert, latent-attention token trunk (DeepSeek-V3 lineage). Two
-published model types run through it, each a *dialect* of the same blocks
-(``DIALECTS``): ``joyai_llm_flash`` and ``dots3_note``.
+published model types run through it, each a row of ``DIALECTS`` over the
+one decoder of ``models/token_trunk.py``: ``joyai_llm_flash`` and
+``dots3_note``. What is this module's own is the latent attention mixer
+(widths by layer kind, the indexer, the gate, the rescale), the config's
+published fields, and the notes.
 
 The decoder trunk of such language models as a feature extractor: token ids
 in, one hidden-state row a window out. Pre-norm residual blocks, RMSNorm,
@@ -66,18 +69,20 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from operator import attrgetter
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from video_features_tpu.models import token_trunk
+# param_shapes and param_count are this trunk's too: the build and the
+# benchmark read them here
 from video_features_tpu.models.token_trunk import (
-    Params, embed, final_norm, mean_features, mlp_rows, rms_norm, swiglu,
+    FULL, SLIDING, BaseConfig, Dialect, Mixer, Params, param_count,
+    param_shapes, rms_norm,
 )
-from video_features_tpu.ops import moe
 from video_features_tpu.ops.attention import (
     KERNEL_PASSES, blockwise_attention, resolve_causal, rotary_interleaved,
 )
@@ -90,7 +95,6 @@ COUNTER = 'moe_counts'
 SHARE_ADVICE = ('Hold a share (n_experts_held, first_expert: the experts of '
                 'a layer divided over chips) and run fewer layers here '
                 '(num_hidden_layers: the rest are further pipeline stages).')
-FULL, SLIDING = 'full_attention', 'sliding_attention'
 
 # the config keys a trunk is built from, under the names the published
 # config.json uses (configs/lm.yml ships JoyAI-LLM-Flash's values)
@@ -116,26 +120,7 @@ GATES = (None, 'headwise')
 
 
 @dataclass(frozen=True)
-class Dialect:
-    """What a ``model_type`` fixes beside its sizes: the layer kinds it may
-    have, its published config keys, and whether its dense feed-forward
-    walks the step's tokens in row blocks (``token_trunk.mlp_rows``)."""
-    layer_types: Tuple[str, ...]
-    config_keys: Tuple[str, ...]
-    row_blocked_mlp: bool
-
-
-DIALECTS = {
-    'joyai_llm_flash': Dialect(layer_types=(FULL,), config_keys=CONFIG_KEYS,
-                               row_blocked_mlp=False),
-    'dots3_note': Dialect(layer_types=(FULL, SLIDING),
-                          config_keys=DOTS3_CONFIG_KEYS,
-                          row_blocked_mlp=True),
-}
-
-
-@dataclass(frozen=True)
-class Mixer:
+class Latent:
     """One layer kind's latent attention: its widths and rotary θ, the keys
     a query sees (``window``: the last ``window`` positions, its own among
     them; ``index_topk``: the indexer's selection; neither: all before
@@ -155,8 +140,186 @@ class Mixer:
     index_topk: int = 0
 
 
+# -- the mixer ----------------------------------------------------------------
+
+def mla_shapes(cfg: TrunkConfig, a: str, kind: str
+               ) -> Dict[str, Tuple[int, ...]]:
+    """{name: shape} of one layer's latent attention under prefix ``a``."""
+    m, d = cfg.latent(kind), cfg.hidden_size
+    shapes = {
+        f'{a}.q_a_proj.weight': (d, m.q_lora_rank),
+        f'{a}.q_a_layernorm.weight': (m.q_lora_rank,),
+        f'{a}.q_b_proj.weight': (m.q_lora_rank, m.heads * (m.nope + m.rope)),
+        f'{a}.kv_a_proj_with_mqa.weight': (d, m.kv_lora_rank + m.rope),
+        f'{a}.kv_a_layernorm.weight': (m.kv_lora_rank,),
+        f'{a}.kv_b_proj.weight': (m.kv_lora_rank, m.heads * (m.nope + m.v)),
+        f'{a}.o_proj.weight': (m.heads * m.v, d),
+    }
+    if m.gated:
+        shapes[f'{a}.gate_proj.weight'] = (d, m.heads)
+    if m.index_topk:
+        i = f'{a}.indexer'
+        shapes.update({
+            f'{i}.wq_b.weight': (m.q_lora_rank, m.index_heads * m.index_dim),
+            f'{i}.wk.weight': (d, m.index_dim),
+            f'{i}.k_norm.weight': (m.index_dim,),
+            f'{i}.k_norm.bias': (m.index_dim,),
+            f'{i}.weights_proj.weight': (d, m.index_heads),
+        })
+    return shapes
+
+
+def _causal_path(cfg: TrunkConfig, kind: str, platform: str, s: int,
+                 precision: Optional[str]) -> str:
+    """``resolve_causal``'s answer for a layer of ``kind`` over ``s``
+    positions: its head widths, its window, whether it takes a selection."""
+    m = cfg.latent(kind)
+    return resolve_causal(platform, s, m.nope + m.rope, m.v, precision, 1, 1,
+                          m.window, bool(m.index_topk))
+
+
+def _head_columns(w: jax.Array, h: int, lo: int, hi: int) -> jax.Array:
+    """Columns ``lo:hi`` of every head of a (in, h·d) projection, as
+    (in, h·(hi − lo)): the product of an activation with it writes that
+    column group of all heads and nothing else."""
+    return w.reshape(w.shape[0], h, -1)[:, :, lo:hi].reshape(w.shape[0], -1)
+
+
+def mixer_scope(cfg: TrunkConfig, kind: str):
+    """The scope a latent attention mixer opens: ``mla`` (joyai), or
+    dots3_note's by kind. Each a literal: ``obs/scopes.py`` pins the
+    vocabulary."""
+    if not cfg.dialect.scope_by_kind:
+        return jax.named_scope('mla')
+    if kind == SLIDING:
+        return jax.named_scope('window_mla')
+    return jax.named_scope('sparse_mla')
+
+
+def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
+              attn_block: int = 1024,
+              platform: Optional[str] = None,
+              kind: str = FULL) -> jax.Array:
+    """Latent attention over one window: (S, D) → (S, D), causal, positions
+    0…S-1, a layer of ``kind`` (its widths, window, selection, gate and
+    rescale: :meth:`TrunkConfig.latent`). ``platform`` is where the graph
+    will run (None: the default backend); with the shapes and the ambient
+    matmul precision it decides the causal path
+    (``ops.attention.resolve_causal``): the fused kernel where it applies,
+    the XLA tiles of ``blockwise_attention`` elsewhere."""
+    m = cfg.latent(kind)
+    with mixer_scope(cfg, kind):
+        s = x.shape[0]
+        h, dn, dr, dv = m.heads, m.nope, m.rope, m.v
+        eps = cfg.rms_norm_eps
+        c_q = rms_norm(jnp.dot(x, p[f'{prefix}.q_a_proj.weight']),
+                       p[f'{prefix}.q_a_layernorm.weight'], eps)
+        keep = None
+        if m.index_topk:
+            with jax.named_scope('mla_indexer'):
+                i = f'{prefix}.indexer'
+                keep = select_keys(
+                    x, c_q, p[f'{i}.wq_b.weight'], p[f'{i}.wk.weight'],
+                    p[f'{i}.k_norm.weight'], p[f'{i}.k_norm.bias'],
+                    p[f'{i}.weights_proj.weight'], heads=m.index_heads,
+                    dim=m.index_dim, rope=dr, topk=m.index_topk,
+                    theta=m.theta)[None]
+        if m.rescale:
+            c_q = c_q * math.sqrt(cfg.hidden_size / m.q_lora_rank)
+        precision = jax.config.jax_default_matmul_precision
+        if _causal_path(cfg, kind, platform or jax.default_backend(), s,
+                        precision) == 'kernel':
+            out = _mla_kernel_path(p, prefix, x, c_q, cfg, m, precision,
+                                   keep)
+        else:
+            out = _mla_xla_path(p, prefix, x, c_q, cfg, m, attn_block, keep)
+        out = out.reshape(s, h * dv)
+        if m.gated:
+            gate = jax.nn.sigmoid(jnp.dot(x, p[f'{prefix}.gate_proj.weight']))
+            out = (out.reshape(s, h, dv) * gate[..., None]).reshape(s, h * dv)
+        return jnp.dot(out, p[f'{prefix}.o_proj.weight'])
+
+
+def _latent_kv(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
+               m: Latent) -> Tuple[jax.Array, jax.Array]:
+    """(the normed — and under the rescale, rescaled — kv latent, the
+    rotary key's columns before rotation)."""
+    kv_a = jnp.dot(x, p[f'{prefix}.kv_a_proj_with_mqa.weight'])
+    c_kv = rms_norm(kv_a[:, :m.kv_lora_rank],
+                    p[f'{prefix}.kv_a_layernorm.weight'], cfg.rms_norm_eps)
+    if m.rescale:
+        c_kv = c_kv * math.sqrt(cfg.hidden_size / m.kv_lora_rank)
+    return c_kv, kv_a[:, m.kv_lora_rank:]
+
+
+def _mla_xla_path(p: Params, prefix: str, x: jax.Array, c_q: jax.Array,
+                  cfg: TrunkConfig, m: Latent, attn_block: int,
+                  keep: Optional[jax.Array]) -> jax.Array:
+    """The heads' output (S, H, d_v) through the XLA tiles."""
+    s = x.shape[0]
+    h, dn, dr, dv = m.heads, m.nope, m.rope, m.v
+    q = jnp.dot(c_q, p[f'{prefix}.q_b_proj.weight']).reshape(s, h, dn + dr)
+    c_kv, k_rope = _latent_kv(p, prefix, x, cfg, m)
+    k_rope = k_rope.reshape(s, 1, dr)
+    kv = jnp.dot(c_kv, p[f'{prefix}.kv_b_proj.weight']).reshape(s, h, dn + dv)
+    positions = jnp.arange(s)
+    q_rope = rotary_interleaved(q[..., dn:], positions, m.theta)
+    k_rope = rotary_interleaved(k_rope, positions, m.theta)
+    q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :dn], jnp.broadcast_to(k_rope, (s, h, dr))], axis=-1)
+    return blockwise_attention(q[None], k[None], kv[None, ..., dn:],
+                               block_size=min(attn_block, s), causal=True,
+                               window=m.window, keep=keep)[0]
+
+
+def _mla_kernel_path(p: Params, prefix: str, x: jax.Array, c_q: jax.Array,
+                     cfg: TrunkConfig, m: Latent, precision: Optional[str],
+                     keep: Optional[jax.Array]) -> jax.Array:
+    """The same attention through ``ops/pallas_attention.py``. The kernel
+    reads a head's columns as (tile, width) slabs, heads-major, so each
+    column group it takes — q's and k's nope and rope parts, v — is written
+    by a product of its own (``q_b``'s and ``kv_b``'s columns regrouped, a
+    pass over the weights, not over 200 MB of activations), 128-wide groups
+    straight into that layout; the nope ‖ rope concatenation and the rotary
+    key's copy for every head are never written: the kernel takes the parts,
+    and one rotary key for all heads."""
+    from video_features_tpu.ops.pallas_attention import causal_attention
+    s = x.shape[0]
+    h, dn, dr, dv = m.heads, m.nope, m.rope, m.v
+    w_q, w_kv = p[f'{prefix}.q_b_proj.weight'], p[f'{prefix}.kv_b_proj.weight']
+    c_kv, k_rope = _latent_kv(p, prefix, x, cfg, m)
+    positions = jnp.arange(s)
+    q_nope = jnp.dot(c_q, _head_columns(w_q, h, 0, dn)).reshape(s, h, dn)
+    q_rope = rotary_interleaved(
+        jnp.dot(c_q, _head_columns(w_q, h, dn, dn + dr)).reshape(s, h, dr),
+        positions, m.theta)
+    k_nope = jnp.dot(c_kv, _head_columns(w_kv, h, 0, dn)).reshape(s, h, dn)
+    k_rope = rotary_interleaved(k_rope.reshape(s, 1, dr), positions, m.theta)
+    v = jnp.dot(c_kv, _head_columns(w_kv, h, dn, dn + dv)).reshape(s, h, dv)
+    return causal_attention((q_nope[None], q_rope[None]),
+                            (k_nope[None], k_rope[None]), v[None],
+                            (dn + dr) ** -0.5, KERNEL_PASSES[precision],
+                            window=m.window, keep=keep)[0]
+
+
+
+# -- the dialects -------------------------------------------------------------
+
+MLA = Mixer(mla_block, mla_shapes)
+DIALECTS = {
+    'joyai_llm_flash': Dialect(mixers={FULL: MLA}, config_keys=CONFIG_KEYS,
+                               row_blocked_mlp=False),
+    'dots3_note': Dialect(
+        mixers={FULL: MLA, SLIDING: MLA}, config_keys=DOTS3_CONFIG_KEYS,
+        optional=('n_experts_held', 'first_expert', 'attention_gate_type',
+                  'swa_attention_gate_type'),
+        scope_by_kind=True),
+}
+
+
 @dataclass(frozen=True)
-class TrunkConfig:
+class TrunkConfig(BaseConfig):
     vocab_size: int
     hidden_size: int
     num_hidden_layers: int
@@ -196,56 +359,23 @@ class TrunkConfig:
     apply_mla_qkv_lora_rescale: bool = False
     model_type: str = MODEL_TYPE
 
+    dialects = DIALECTS
+    window_key = 'sliding_window_size'
+    eps = property(attrgetter('rms_norm_eps'))
+    routed_experts = property(attrgetter('n_routed_experts'))
+    shared_experts = property(attrgetter('n_shared_experts'))
+
     def __post_init__(self):
         object.__setattr__(self, 'n_experts_held', token_trunk.held_experts(
             self.n_experts_held, self.first_expert, self.n_routed_experts))
         if self.num_experts_per_tok > self.n_routed_experts:
             raise ValueError('num_experts_per_tok exceeds n_routed_experts')
-        kinds = (tuple(self.layer_types) if self.layer_types is not None
-                 else (FULL,) * self.num_hidden_layers)
-        object.__setattr__(self, 'layer_types', kinds)
-        if len(kinds) != self.num_hidden_layers:
-            raise ValueError(
-                f'layer_types names {len(kinds)} layers, '
-                f'num_hidden_layers={self.num_hidden_layers}: give one entry '
-                f'a layer run here')
-        known = self.dialect.layer_types
-        for i, kind in enumerate(kinds):
-            if kind not in known:
-                raise ValueError(
-                    f'layer_types[{i}]={kind!r} is no mixer of the '
-                    f'model_type={self.model_type} trunk; known: '
-                    f'{", ".join(known)}')
+        self.check_layers()
         for key in ('attention_gate_type', 'swa_attention_gate_type'):
             if getattr(self, key) not in GATES:
                 raise ValueError(
                     f'{key}={getattr(self, key)!r}: the trunk gates a '
                     f'mixer\'s heads headwise or not at all')
-        if SLIDING in kinds and not (self.sliding_window_size
-                                     and self.sliding_window_size > 0):
-            raise ValueError(
-                f'sliding_attention layers need sliding_window_size, the '
-                f'keys a query sees; got {self.sliding_window_size!r}')
-
-    @classmethod
-    def from_args(cls, args) -> 'TrunkConfig':
-        model_type = args.get('model_type')
-        if model_type not in DIALECTS:
-            model_type = MODEL_TYPE
-        values = {k: args.get(k) for k in DIALECTS[model_type].config_keys}
-        values['first_expert'] = values['first_expert'] or 0
-        missing = [k for k, v in values.items()
-                   if v is None and k not in (
-                       'n_experts_held', 'attention_gate_type',
-                       'swa_attention_gate_type')]
-        if missing:
-            raise ValueError(f'the lm trunk model_type={model_type} needs '
-                             f'config keys {missing}')
-        return cls(**values, model_type=model_type)
-
-    @property
-    def dialect(self) -> Dialect:
-        return DIALECTS[self.model_type]
 
     @property
     def qk_head_dim(self) -> int:
@@ -254,18 +384,18 @@ class TrunkConfig:
     def is_dense(self, layer: int) -> bool:
         return layer < self.first_k_dense_replace
 
-    def mixer(self, kind: str = FULL) -> Mixer:
+    def latent(self, kind: str = FULL) -> Latent:
         """The latent attention of a layer of ``kind``."""
         rescale = bool(self.apply_mla_qkv_lora_rescale)
         if kind == SLIDING:
-            return Mixer(
+            return Latent(
                 self.swa_num_attention_heads, self.swa_q_lora_rank,
                 self.swa_kv_lora_rank, self.swa_qk_nope_head_dim,
                 self.swa_qk_rope_head_dim, self.swa_v_head_dim,
                 self.swa_rope_theta, window=self.sliding_window_size,
                 gated=self.swa_attention_gate_type == 'headwise',
                 rescale=rescale)
-        return Mixer(
+        return Latent(
             self.num_attention_heads, self.q_lora_rank, self.kv_lora_rank,
             self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
             self.rope_theta, gated=self.attention_gate_type == 'headwise',
@@ -273,76 +403,8 @@ class TrunkConfig:
             index_dim=self.index_head_dim or 0,
             index_topk=self.index_topk or 0)
 
-    def kinds(self) -> Dict[str, int]:
-        """{layer kind: layers of it run here}, in the dialect's order."""
-        return {kind: self.layer_types.count(kind)
-                for kind in self.dialect.layer_types}
 
-
-def mixer_shapes(cfg: TrunkConfig, a: str, kind: str
-                 ) -> Dict[str, Tuple[int, ...]]:
-    """{name: shape} of one layer's latent attention under prefix ``a``."""
-    m, d = cfg.mixer(kind), cfg.hidden_size
-    shapes = {
-        f'{a}.q_a_proj.weight': (d, m.q_lora_rank),
-        f'{a}.q_a_layernorm.weight': (m.q_lora_rank,),
-        f'{a}.q_b_proj.weight': (m.q_lora_rank, m.heads * (m.nope + m.rope)),
-        f'{a}.kv_a_proj_with_mqa.weight': (d, m.kv_lora_rank + m.rope),
-        f'{a}.kv_a_layernorm.weight': (m.kv_lora_rank,),
-        f'{a}.kv_b_proj.weight': (m.kv_lora_rank, m.heads * (m.nope + m.v)),
-        f'{a}.o_proj.weight': (m.heads * m.v, d),
-    }
-    if m.gated:
-        shapes[f'{a}.gate_proj.weight'] = (d, m.heads)
-    if m.index_topk:
-        i = f'{a}.indexer'
-        shapes.update({
-            f'{i}.wq_b.weight': (m.q_lora_rank, m.index_heads * m.index_dim),
-            f'{i}.wk.weight': (d, m.index_dim),
-            f'{i}.k_norm.weight': (m.index_dim,),
-            f'{i}.k_norm.bias': (m.index_dim,),
-            f'{i}.weights_proj.weight': (d, m.index_heads),
-        })
-    return shapes
-
-
-def param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
-    """{name: shape} of every parameter held, in checkpoint order."""
-    d = cfg.hidden_size
-    shapes: Dict[str, Tuple[int, ...]] = {
-        'model.embed_tokens.weight': (cfg.vocab_size, d)}
-    for i, kind in enumerate(cfg.layer_types):
-        p = f'model.layers.{i}'
-        shapes[f'{p}.input_layernorm.weight'] = (d,)
-        shapes.update(mixer_shapes(cfg, f'{p}.self_attn', kind))
-        shapes[f'{p}.post_attention_layernorm.weight'] = (d,)
-        m = f'{p}.mlp'
-        if cfg.is_dense(i):
-            f = cfg.intermediate_size
-            shapes.update({f'{m}.gate_proj.weight': (d, f),
-                           f'{m}.up_proj.weight': (d, f),
-                           f'{m}.down_proj.weight': (f, d)})
-            continue
-        f, e = cfg.moe_intermediate_size, cfg.n_experts_held
-        shapes.update({
-            f'{m}.gate.weight': (d, cfg.n_routed_experts),
-            f'{m}.gate.e_score_correction_bias': (cfg.n_routed_experts,),
-            f'{m}.experts.gate_proj.weight': (e, d, f),
-            f'{m}.experts.up_proj.weight': (e, d, f),
-            f'{m}.experts.down_proj.weight': (e, f, d),
-        })
-        if cfg.n_shared_experts:
-            fs = f * cfg.n_shared_experts
-            shapes.update({f'{m}.shared_experts.gate_proj.weight': (d, fs),
-                           f'{m}.shared_experts.up_proj.weight': (d, fs),
-                           f'{m}.shared_experts.down_proj.weight': (fs, d)})
-    shapes['model.norm.weight'] = (d,)
-    return shapes
-
-
-def param_count(cfg: TrunkConfig) -> int:
-    return token_trunk.param_count(param_shapes(cfg))
-
+# -- what the build says and counts --------------------------------------------
 
 def init_params(cfg: TrunkConfig, seed: int = 0) -> Dict[str, np.ndarray]:
     """Seeded random parameters (``token_trunk.draw_params``), and a small
@@ -361,15 +423,6 @@ def describe(cfg: TrunkConfig) -> str:
                                   for kind, n in cfg.kinds().items()) + ')'
     return (f'{cfg.num_hidden_layers} layers{kinds} and {cfg.n_experts_held} '
             f'of {cfg.n_routed_experts} experts a layer')
-
-
-def _causal_path(cfg: TrunkConfig, kind: str, platform: str, s: int,
-                 precision: Optional[str]) -> str:
-    """``resolve_causal``'s answer for a layer of ``kind`` over ``s``
-    positions: its head widths, its window, whether it takes a selection."""
-    m = cfg.mixer(kind)
-    return resolve_causal(platform, s, m.nope + m.rope, m.v, precision, 1, 1,
-                          m.window, bool(m.index_topk))
 
 
 def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
@@ -396,205 +449,5 @@ def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
     return notes
 
 
-def count(tracer, counts: np.ndarray, cfg: TrunkConfig, tokens: int) -> None:
-    """The step's per-expert counts → ``moe_route``, ``moe_held`` and
-    ``moe_walk`` (``token_trunk.count_experts``)."""
-    token_trunk.count_experts(tracer, counts, cfg.num_experts_per_tok, tokens,
-                              moe.BLOCK)
-
-
-# -- blocks -------------------------------------------------------------------
-
-def _head_columns(w: jax.Array, h: int, lo: int, hi: int) -> jax.Array:
-    """Columns ``lo:hi`` of every head of a (in, h·d) projection, as
-    (in, h·(hi − lo)): the product of an activation with it writes that
-    column group of all heads and nothing else."""
-    return w.reshape(w.shape[0], h, -1)[:, :, lo:hi].reshape(w.shape[0], -1)
-
-
-def mixer_scope(cfg: TrunkConfig, kind: str):
-    """The scope a latent attention mixer opens: ``mla`` (joyai), or
-    dots3_note's by kind. Each a literal: ``obs/scopes.py`` pins the
-    vocabulary."""
-    if cfg.model_type == MODEL_TYPE:
-        return jax.named_scope('mla')
-    if kind == SLIDING:
-        return jax.named_scope('window_mla')
-    return jax.named_scope('sparse_mla')
-
-
-def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
-              attn_block: int = 1024,
-              platform: Optional[str] = None,
-              kind: str = FULL) -> jax.Array:
-    """Latent attention over one window: (S, D) → (S, D), causal, positions
-    0…S-1, a layer of ``kind`` (its widths, window, selection, gate and
-    rescale: :meth:`TrunkConfig.mixer`). ``platform`` is where the graph
-    will run (None: the default backend); with the shapes and the ambient
-    matmul precision it decides the causal path
-    (``ops.attention.resolve_causal``): the fused kernel where it applies,
-    the XLA tiles of ``blockwise_attention`` elsewhere."""
-    m = cfg.mixer(kind)
-    with mixer_scope(cfg, kind):
-        s = x.shape[0]
-        h, dn, dr, dv = m.heads, m.nope, m.rope, m.v
-        eps = cfg.rms_norm_eps
-        c_q = rms_norm(jnp.dot(x, p[f'{prefix}.q_a_proj.weight']),
-                       p[f'{prefix}.q_a_layernorm.weight'], eps)
-        keep = None
-        if m.index_topk:
-            with jax.named_scope('mla_indexer'):
-                i = f'{prefix}.indexer'
-                keep = select_keys(
-                    x, c_q, p[f'{i}.wq_b.weight'], p[f'{i}.wk.weight'],
-                    p[f'{i}.k_norm.weight'], p[f'{i}.k_norm.bias'],
-                    p[f'{i}.weights_proj.weight'], heads=m.index_heads,
-                    dim=m.index_dim, rope=dr, topk=m.index_topk,
-                    theta=m.theta)[None]
-        if m.rescale:
-            c_q = c_q * math.sqrt(cfg.hidden_size / m.q_lora_rank)
-        precision = jax.config.jax_default_matmul_precision
-        if _causal_path(cfg, kind, platform or jax.default_backend(), s,
-                        precision) == 'kernel':
-            out = _mla_kernel_path(p, prefix, x, c_q, cfg, m, precision,
-                                   keep)
-        else:
-            out = _mla_xla_path(p, prefix, x, c_q, cfg, m, attn_block, keep)
-        out = out.reshape(s, h * dv)
-        if m.gated:
-            gate = jax.nn.sigmoid(jnp.dot(x, p[f'{prefix}.gate_proj.weight']))
-            out = (out.reshape(s, h, dv) * gate[..., None]).reshape(s, h * dv)
-        return jnp.dot(out, p[f'{prefix}.o_proj.weight'])
-
-
-def _latent_kv(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
-               m: Mixer) -> Tuple[jax.Array, jax.Array]:
-    """(the normed — and under the rescale, rescaled — kv latent, the
-    rotary key's columns before rotation)."""
-    kv_a = jnp.dot(x, p[f'{prefix}.kv_a_proj_with_mqa.weight'])
-    c_kv = rms_norm(kv_a[:, :m.kv_lora_rank],
-                    p[f'{prefix}.kv_a_layernorm.weight'], cfg.rms_norm_eps)
-    if m.rescale:
-        c_kv = c_kv * math.sqrt(cfg.hidden_size / m.kv_lora_rank)
-    return c_kv, kv_a[:, m.kv_lora_rank:]
-
-
-def _mla_xla_path(p: Params, prefix: str, x: jax.Array, c_q: jax.Array,
-                  cfg: TrunkConfig, m: Mixer, attn_block: int,
-                  keep: Optional[jax.Array]) -> jax.Array:
-    """The heads' output (S, H, d_v) through the XLA tiles."""
-    s = x.shape[0]
-    h, dn, dr, dv = m.heads, m.nope, m.rope, m.v
-    q = jnp.dot(c_q, p[f'{prefix}.q_b_proj.weight']).reshape(s, h, dn + dr)
-    c_kv, k_rope = _latent_kv(p, prefix, x, cfg, m)
-    k_rope = k_rope.reshape(s, 1, dr)
-    kv = jnp.dot(c_kv, p[f'{prefix}.kv_b_proj.weight']).reshape(s, h, dn + dv)
-    positions = jnp.arange(s)
-    q_rope = rotary_interleaved(q[..., dn:], positions, m.theta)
-    k_rope = rotary_interleaved(k_rope, positions, m.theta)
-    q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
-    k = jnp.concatenate(
-        [kv[..., :dn], jnp.broadcast_to(k_rope, (s, h, dr))], axis=-1)
-    return blockwise_attention(q[None], k[None], kv[None, ..., dn:],
-                               block_size=min(attn_block, s), causal=True,
-                               window=m.window, keep=keep)[0]
-
-
-def _mla_kernel_path(p: Params, prefix: str, x: jax.Array, c_q: jax.Array,
-                     cfg: TrunkConfig, m: Mixer, precision: Optional[str],
-                     keep: Optional[jax.Array]) -> jax.Array:
-    """The same attention through ``ops/pallas_attention.py``. The kernel
-    reads a head's columns as (tile, width) slabs, heads-major, so each
-    column group it takes — q's and k's nope and rope parts, v — is written
-    by a product of its own (``q_b``'s and ``kv_b``'s columns regrouped, a
-    pass over the weights, not over 200 MB of activations), 128-wide groups
-    straight into that layout; the nope ‖ rope concatenation and the rotary
-    key's copy for every head are never written: the kernel takes the parts,
-    and one rotary key for all heads."""
-    from video_features_tpu.ops.pallas_attention import causal_attention
-    s = x.shape[0]
-    h, dn, dr, dv = m.heads, m.nope, m.rope, m.v
-    w_q, w_kv = p[f'{prefix}.q_b_proj.weight'], p[f'{prefix}.kv_b_proj.weight']
-    c_kv, k_rope = _latent_kv(p, prefix, x, cfg, m)
-    positions = jnp.arange(s)
-    q_nope = jnp.dot(c_q, _head_columns(w_q, h, 0, dn)).reshape(s, h, dn)
-    q_rope = rotary_interleaved(
-        jnp.dot(c_q, _head_columns(w_q, h, dn, dn + dr)).reshape(s, h, dr),
-        positions, m.theta)
-    k_nope = jnp.dot(c_kv, _head_columns(w_kv, h, 0, dn)).reshape(s, h, dn)
-    k_rope = rotary_interleaved(k_rope.reshape(s, 1, dr), positions, m.theta)
-    v = jnp.dot(c_kv, _head_columns(w_kv, h, dn, dn + dv)).reshape(s, h, dv)
-    return causal_attention((q_nope[None], q_rope[None]),
-                            (k_nope[None], k_rope[None]), v[None],
-                            (dn + dr) ** -0.5, KERNEL_PASSES[precision],
-                            window=m.window, keep=keep)[0]
-
-
-def expert_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
-                 moe_block: int = moe.BLOCK) -> Tuple[jax.Array, jax.Array]:
-    """The expert layer's feed-forward over (T, D) tokens: the held
-    experts' share of the routed sum (``ops.moe.routed_experts``, under this
-    checkpoint's names) plus the shared expert. Returns the output and the
-    (held,) assignment counts."""
-    with jax.named_scope('moe'):
-        y, counts = moe.routed_experts(
-            x, p[f'{prefix}.gate.weight'],
-            p[f'{prefix}.gate.e_score_correction_bias'],
-            p[f'{prefix}.experts.gate_proj.weight'],
-            p[f'{prefix}.experts.up_proj.weight'],
-            p[f'{prefix}.experts.down_proj.weight'],
-            top_k=cfg.num_experts_per_tok,
-            scaling=cfg.routed_scaling_factor,
-            normalise=cfg.norm_topk_prob, eps=1e-20,
-            first=cfg.first_expert, block=moe_block)
-        if cfg.n_shared_experts:
-            y = y + swiglu(x, p, f'{prefix}.shared_experts')
-        return y, counts
-
-
-def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
-                  attn_block: int = 1024, moe_block: int = moe.BLOCK,
-                  platform: Optional[str] = None
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """(B, S) int32 ids → ``(final-norm hidden states (B, S, D), counts)``.
-
-    ``counts`` is (expert layers, held) int32: the batch's assignments on
-    each held expert, layer by layer (zero rows when no layer has experts).
-    Attention runs a window at a time (its tiles are the memory that
-    matters); the feed-forward takes all B·S tokens at once, so an expert
-    sees the whole batch's assignments in one grouped product."""
-    b, s = ids.shape
-    d = cfg.hidden_size
-    eps = cfg.rms_norm_eps
-    x = embed(params, ids)                                  # (B, S, D)
-    counts = []
-    for i, kind in enumerate(cfg.layer_types):
-        p = f'model.layers.{i}'
-        normed = rms_norm(x, params[f'{p}.input_layernorm.weight'], eps)
-        x = x + lax.map(
-            lambda w: mla_block(params, f'{p}.self_attn', w, cfg, attn_block,
-                                platform, kind),
-            normed)
-        normed = rms_norm(x, params[f'{p}.post_attention_layernorm.weight'],
-                          eps).reshape(b * s, d)
-        if cfg.is_dense(i):
-            rows = mlp_rows(b * s) if cfg.dialect.row_blocked_mlp else None
-            with jax.named_scope('dense_mlp'):
-                y = swiglu(normed, params, f'{p}.mlp', row_block=rows)
-        else:
-            y, c = expert_block(params, f'{p}.mlp', normed, cfg, moe_block)
-            counts.append(c)
-        x = x + y.reshape(b, s, d)
-    counts = (jnp.stack(counts) if counts
-              else jnp.zeros((0, cfg.n_experts_held), jnp.int32))
-    return final_norm(x, params, eps), counts
-
-
-def forward(params: Params, ids: jax.Array, cfg: TrunkConfig,
-            attn_block: int = 1024, moe_block: int = moe.BLOCK,
-            platform: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
-    """(B, S) int32 ids → ``(features (B, D) float32, counts)``: the mean
-    of the window's final-norm hidden states (:func:`hidden_states`)."""
-    x, counts = hidden_states(params, ids, cfg, attn_block, moe_block,
-                              platform)
-    return mean_features(x), counts
+# the step's per-expert counts → moe_route, moe_held and moe_walk
+count = token_trunk.count_experts

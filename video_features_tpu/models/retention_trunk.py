@@ -22,22 +22,26 @@ starts from an empty state and its final state is dropped. The output is
 the final RMSNorm's mean over the window's positions; the head is neither
 held nor run.
 
-Parameters are a flat ``{dotted name: array}`` dict under Qwen3's names,
-matrices as (in, out); the gate is ``self_attn.g_proj.{weight,bias}``.
+The decoder around the mixer is ``models/token_trunk.py``'s, under the one
+row of ``DIALECTS``. Parameters are a flat ``{dotted name: array}`` dict
+under Qwen3's names, matrices as (in, out); the gate is
+``self_attn.g_proj.{weight,bias}``.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
+from operator import attrgetter
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from video_features_tpu.models import token_trunk
+# param_shapes and param_count are this trunk's too: the build and the
+# benchmark read them here
 from video_features_tpu.models.token_trunk import (
-    Params, embed, final_norm, mean_features, mlp_rows, rms_norm, swiglu,
+    BaseConfig, Dialect, Mixer, Params, param_count, param_shapes, rms_norm,
 )
 from video_features_tpu.ops.attention import KERNEL_PASSES, rotary_half
 from video_features_tpu.ops.retention import (
@@ -45,12 +49,13 @@ from video_features_tpu.ops.retention import (
 )
 
 MODEL_TYPE = 'brumby'
-# the step's second output: (2, layers) positions of the batch that each
+# the step's second output: (layers, 2) positions of the batch that each
 # layer's mixer put through the chunked state scan, and how many of those
 # through the Mosaic kernels of its state products
 COUNTER = 'retention_scanned'
 SHARE_ADVICE = ('Run fewer layers here (num_hidden_layers: the rest are '
                 'further pipeline stages).')
+RETENTION = 'retention'
 
 # the config keys the trunk is built from, under the published names
 CONFIG_KEYS = (
@@ -64,8 +69,74 @@ CONFIG_KEYS = (
 RETENTION_CHUNK = 512
 
 
+# -- the mixer ----------------------------------------------------------------
+
+def retention_shapes(cfg: TrunkConfig, a: str, kind: str
+                     ) -> Dict[str, Tuple[int, ...]]:
+    """{name: shape} of one layer's retention mixer under prefix ``a``."""
+    d = cfg.hidden_size
+    h, g, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    return {f'{a}.q_proj.weight': (d, h * hd),
+            f'{a}.k_proj.weight': (d, g * hd),
+            f'{a}.v_proj.weight': (d, g * hd),
+            f'{a}.g_proj.weight': (d, g),
+            f'{a}.g_proj.bias': (g,),
+            f'{a}.q_norm.weight': (hd,),
+            f'{a}.k_norm.weight': (hd,),
+            f'{a}.o_proj.weight': (h * hd, d)}
+
+
+def retention_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
+                    attn_block: Optional[int] = None,
+                    platform: Optional[str] = None, kind: str = RETENTION
+                    ) -> Tuple[jax.Array, jax.Array]:
+    """The mixer over one window: (S, D) normed input → (S, D), causal,
+    positions 0…S−1, from an empty state; and a (2,) count: how many of the
+    positions it put through the state scan (what ``retention_scan``
+    counts) and how many of those through the kernels. ``platform`` is
+    where the graph will run (None: the default backend); with the head's
+    widths, the chunk and the ambient matmul precision it decides the form
+    of the state products (``ops.retention.resolve_retention``). It has no
+    attention tiles and one kind: ``attn_block`` and ``kind`` are the
+    loop's, not read."""
+    with jax.named_scope('retention'):
+        s = x.shape[0]
+        h, g, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim)
+        eps, theta = cfg.rms_norm_eps, cfg.rope_theta
+        positions = jnp.arange(s)
+        q = jnp.dot(x, p[f'{prefix}.q_proj.weight']).reshape(s, h, d)
+        k = jnp.dot(x, p[f'{prefix}.k_proj.weight']).reshape(s, g, d)
+        v = jnp.dot(x, p[f'{prefix}.v_proj.weight']).reshape(s, g, d)
+        q = rotary_half(rms_norm(q, p[f'{prefix}.q_norm.weight'], eps),
+                        positions, theta).reshape(s, g, cfg.group, d)
+        k = rotary_half(rms_norm(k, p[f'{prefix}.k_norm.weight'], eps),
+                        positions, theta)
+        log_gate = jax.nn.log_sigmoid(
+            (jnp.dot(x, p[f'{prefix}.g_proj.weight'])
+             + p[f'{prefix}.g_proj.bias']).astype(jnp.float32))
+        precision = jax.config.jax_default_matmul_precision
+        kernel = resolve_retention(platform or jax.default_backend(), d, d,
+                                   min(RETENTION_CHUNK, s),
+                                   precision) == 'kernel'
+        y, _ = retention_chunked(
+            q, k, v, log_gate, RETENTION_CHUNK,
+            kernel_passes=KERNEL_PASSES[precision] if kernel else None)
+        counted = jnp.array([s, s if kernel else 0], jnp.int32)
+        return (jnp.dot(y.reshape(s, h * d), p[f'{prefix}.o_proj.weight']),
+                counted)
+
+
+DIALECTS = {
+    MODEL_TYPE: Dialect(
+        mixers={RETENTION: Mixer(retention_block, retention_shapes,
+                                 counted=True)},
+        config_keys=CONFIG_KEYS),
+}
+
+
 @dataclass(frozen=True)
-class TrunkConfig:
+class TrunkConfig(BaseConfig):
     vocab_size: int
     hidden_size: int
     num_hidden_layers: int
@@ -75,10 +146,14 @@ class TrunkConfig:
     head_dim: int
     rope_theta: float
     rms_norm_eps: float
+    layer_types: Optional[Tuple[str, ...]] = None   # every layer retention
+    model_type: str = MODEL_TYPE
 
-    model_type = MODEL_TYPE
+    dialects = DIALECTS
+    eps = property(attrgetter('rms_norm_eps'))
 
     def __post_init__(self):
+        self.check_layers()
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError(
                 f'num_attention_heads={self.num_attention_heads} is no '
@@ -88,51 +163,15 @@ class TrunkConfig:
             raise ValueError(f'head_dim={self.head_dim} must be even (rotary '
                              f'pairs)')
 
-    @classmethod
-    def from_args(cls, args) -> 'TrunkConfig':
-        values = {k: args.get(k) for k in CONFIG_KEYS}
-        missing = [k for k, v in values.items() if v is None]
-        if missing:
-            raise ValueError(f'the lm trunk model_type={MODEL_TYPE} needs '
-                             f'config keys {missing}')
-        return cls(**values)
-
     @property
     def group(self) -> int:
         return self.num_attention_heads // self.num_key_value_heads
 
-
-def param_shapes(cfg: TrunkConfig) -> Dict[str, Tuple[int, ...]]:
-    """{name: shape} of every parameter held, in checkpoint order."""
-    d, f = cfg.hidden_size, cfg.intermediate_size
-    h, g, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    shapes: Dict[str, Tuple[int, ...]] = {
-        'model.embed_tokens.weight': (cfg.vocab_size, d)}
-    for i in range(cfg.num_hidden_layers):
-        p = f'model.layers.{i}'
-        a, m = f'{p}.self_attn', f'{p}.mlp'
-        shapes.update({
-            f'{p}.input_layernorm.weight': (d,),
-            f'{a}.q_proj.weight': (d, h * hd),
-            f'{a}.k_proj.weight': (d, g * hd),
-            f'{a}.v_proj.weight': (d, g * hd),
-            f'{a}.g_proj.weight': (d, g),
-            f'{a}.g_proj.bias': (g,),
-            f'{a}.q_norm.weight': (hd,),
-            f'{a}.k_norm.weight': (hd,),
-            f'{a}.o_proj.weight': (h * hd, d),
-            f'{p}.post_attention_layernorm.weight': (d,),
-            f'{m}.gate_proj.weight': (d, f),
-            f'{m}.up_proj.weight': (d, f),
-            f'{m}.down_proj.weight': (f, d),
-        })
-    shapes['model.norm.weight'] = (d,)
-    return shapes
+    def is_dense(self, layer: int) -> bool:
+        return True
 
 
-def param_count(cfg: TrunkConfig) -> int:
-    return token_trunk.param_count(param_shapes(cfg))
-
+# -- what the build says and counts ---------------------------------------------
 
 def init_params(cfg: TrunkConfig, seed: int = 0) -> Dict[str, np.ndarray]:
     """Seeded random parameters (``token_trunk.draw_params``); the gate's
@@ -166,96 +205,13 @@ def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
 
 
 def count(tracer, counted: np.ndarray, cfg: TrunkConfig, tokens: int) -> None:
-    """The step's ``(2, layers)`` counter → the stage table. Row 0,
+    """The step's ``(layers, 2)`` counter → the stage table. Column 0,
     ``retention_scan``: positions × layers of one fetched step mixed through
-    the carried state ÷ positions × layers of the step. Row 1,
+    the carried state ÷ positions × layers of the step. Column 1,
     ``retention_kernel``: those of them whose state products ran through the
     kernels ÷ those scanned."""
-    scanned, through_kernel = (int(row.sum()) for row in np.asarray(counted))
+    scanned, through_kernel = (int(col.sum())
+                               for col in np.asarray(counted).T)
     tracer.add_occupancy('retention_scan', scanned,
                          int(tokens) * cfg.num_hidden_layers)
     tracer.add_occupancy('retention_kernel', through_kernel, scanned)
-
-
-# -- blocks -------------------------------------------------------------------
-
-def retention_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
-                    platform: Optional[str] = None
-                    ) -> Tuple[jax.Array, jax.Array]:
-    """The mixer over one window: (S, D) normed input → (S, D), causal,
-    positions 0…S−1, from an empty state; and a (2,) count: how many of the
-    positions it put through the state scan (what ``retention_scan``
-    counts) and how many of those through the kernels. ``platform`` is
-    where the graph will run (None: the default backend); with the head's
-    widths, the chunk and the ambient matmul precision it decides the form
-    of the state products (``ops.retention.resolve_retention``)."""
-    with jax.named_scope('retention'):
-        s = x.shape[0]
-        h, g, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
-        eps, theta = cfg.rms_norm_eps, cfg.rope_theta
-        positions = jnp.arange(s)
-        q = jnp.dot(x, p[f'{prefix}.q_proj.weight']).reshape(s, h, d)
-        k = jnp.dot(x, p[f'{prefix}.k_proj.weight']).reshape(s, g, d)
-        v = jnp.dot(x, p[f'{prefix}.v_proj.weight']).reshape(s, g, d)
-        q = rotary_half(rms_norm(q, p[f'{prefix}.q_norm.weight'], eps),
-                        positions, theta).reshape(s, g, cfg.group, d)
-        k = rotary_half(rms_norm(k, p[f'{prefix}.k_norm.weight'], eps),
-                        positions, theta)
-        log_gate = jax.nn.log_sigmoid(
-            (jnp.dot(x, p[f'{prefix}.g_proj.weight'])
-             + p[f'{prefix}.g_proj.bias']).astype(jnp.float32))
-        precision = jax.config.jax_default_matmul_precision
-        kernel = resolve_retention(platform or jax.default_backend(), d, d,
-                                   min(RETENTION_CHUNK, s),
-                                   precision) == 'kernel'
-        y, _ = retention_chunked(
-            q, k, v, log_gate, RETENTION_CHUNK,
-            kernel_passes=KERNEL_PASSES[precision] if kernel else None)
-        counted = jnp.array([s, s if kernel else 0], jnp.int32)
-        return (jnp.dot(y.reshape(s, h * d), p[f'{prefix}.o_proj.weight']),
-                counted)
-
-
-def hidden_states(params: Params, ids: jax.Array, cfg: TrunkConfig,
-                  platform: Optional[str] = None
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """(B, S) int32 ids → final-norm hidden states (B, S, D) and the
-    (2, layers) positions each layer's mixer scanned and put through the
-    kernels. The mixer runs a window at a time (each window has a state of
-    its own); the feed-forward walks all B·S tokens in row blocks."""
-    b, s = ids.shape
-    d = cfg.hidden_size
-    eps = cfg.rms_norm_eps
-    # in row blocks: the two intermediates stand as (rows, intermediate_size)
-    # float32, 285 MB each at the published width
-    rows = mlp_rows(b * s)
-    x = embed(params, ids)
-    counted = []
-    for i in range(cfg.num_hidden_layers):
-        p = f'model.layers.{i}'
-        normed = rms_norm(x, params[f'{p}.input_layernorm.weight'], eps)
-        mixed, n = lax.map(
-            lambda w: retention_block(params, f'{p}.self_attn', w, cfg,
-                                      platform),
-            normed)
-        x = x + mixed
-        counted.append(n.sum(axis=0))
-        normed = rms_norm(x, params[f'{p}.post_attention_layernorm.weight'],
-                          eps).reshape(b * s, d)
-        with jax.named_scope('dense_mlp'):
-            y = swiglu(normed, params, f'{p}.mlp', row_block=rows)
-        x = x + y.reshape(b, s, d)
-    return final_norm(x, params, eps), jnp.stack(counted, axis=1)
-
-
-def forward(params: Params, ids: jax.Array, cfg: TrunkConfig,
-            platform: Optional[str] = None) -> Tuple[jax.Array, jax.Array]:
-    """(B, S) int32 ids → ``(features (B, D) float32, counted (2, layers)
-    int32)``: the mean of the window's final-norm hidden states, and how
-    many of the batch's positions each layer's mixer put through the state
-    scan and, of those, through the kernels. ``platform`` is where the
-    graph will run (None: the default backend): it is one of the things the
-    form of the scan's state products is chosen from (:func:`kernels`)."""
-    hidden, counted = hidden_states(params, ids, cfg, platform)
-    return mean_features(hidden), counted

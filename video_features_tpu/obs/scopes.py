@@ -44,8 +44,9 @@ SCOPES = (
     'flow_quantise',     # crop, clamp ±20, uint8 levels, ±1
     'i3d_towers',        # both I3D towers
     'i3d_stem',          # in i3d_towers: a tower's first convolution
-    # the lm family's trunks (models/latent_moe.py, retention_trunk.py,
-    # hybrid_trunk.py)
+    # the lm family's trunks: the mixers (models/latent_moe.py,
+    # retention_trunk.py, hybrid_trunk.py), the feed-forwards
+    # (models/token_trunk.py)
     'mla',               # latent attention
     'sparse_mla',        # the same under a learned selection of keys
     'mla_indexer',       # in sparse_mla: the indexer that selects them

@@ -30,8 +30,8 @@ The pieces, in the order a layer uses them:
 * :func:`combine` — each token's weighted sum over its held assignments, by
   gather (a scatter-add of rows is the slow direction on a TPU).
 
-:func:`routed_experts` is those four in a row, what an expert trunk calls
-(``models/latent_moe.py``, ``models/hybrid_trunk.py``);
+:func:`routed_experts` is those four in a row, what every expert trunk's
+layer calls (``models/token_trunk.py::expert_block``);
 :func:`walk_rows` says how many rows the block walk computed for given
 counts, which is what its padding costs.
 """
