@@ -278,8 +278,8 @@ def test_model_type_picks_the_second_dialect_and_the_yml_holds_its_keys():
                        r'keys \[.*\'sliding_window\'.*\'score_func\'.*\]'):
         ht.TrunkConfig.from_args(dict(yml, model_type='afmoe'))
     with pytest.raises(ValueError, match=r"no trunk for model_type='afm'; "
-                       r'known: afmoe, brumby, dots3_note, joyai_llm_flash, '
-                       r'lfm2_moe'):
+                       r'known: afmoe, brumby, dots3_note, granitemoehybrid, '
+                       r'joyai_llm_flash, lfm2_moe'):
         extract_lm.load_trunk('afm')
 
 

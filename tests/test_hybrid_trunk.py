@@ -476,8 +476,8 @@ def test_model_type_picks_the_third_trunk_and_the_yml_holds_its_keys():
                        r'keys \[.*\'layer_types\'.*\'norm_eps\'\]'):
         ht.TrunkConfig.from_args(yml)     # the shipped sizes are another model
     with pytest.raises(ValueError, match=r"no trunk for model_type='lfm2'; "
-                       r'known: afmoe, brumby, dots3_note, joyai_llm_flash, '
-                       r'lfm2_moe'):
+                       r'known: afmoe, brumby, dots3_note, granitemoehybrid, '
+                       r'joyai_llm_flash, lfm2_moe'):
         extract_lm.load_trunk('lfm2')
 
 

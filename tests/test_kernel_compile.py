@@ -340,3 +340,36 @@ def _computation(text, name):
         elif inside:
             lines.append(line)
     return lines
+
+
+@pytest.mark.parametrize('passes', [3, 1])
+def test_the_ssd_scan_compiles_at_granite_micros_widths(one_chip, passes):
+    """granite-micro.corpus's Mamba-2 scan at its widths — 64 heads of 64
+    over a state of 128 shared by every head, chunks of 256 — over eight
+    chunks of a window, through the kernel under three passes
+    (precision=mixed) and one (the control lane): one Mosaic call named
+    ssd_scan, its head groups whole 128-lane blocks, inside the VMEM limit
+    it asks for; and that is what these shapes get on a TPU."""
+    from video_features_tpu.ops import pallas_ssd
+    from video_features_tpu.ops.attention import KERNEL_PASSES
+    from video_features_tpu.ops.ssd import resolve_ssd, ssd_chunked
+    precision = {3: 'high', 1: 'default'}[passes]
+    assert KERNEL_PASSES[precision] == passes
+    assert resolve_ssd('tpu', 32768, 64, 64, 128, 256, precision) == 'kernel'
+    assert pallas_ssd.heads_per_step(64, 64) * 64 % pallas_ssd.LANES == 0
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    s = 2048
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(lambda x, dt, a, b, c, d: ssd_chunked(
+            x, dt, a, b, c, d, 256, kernel_passes=passes)).lower(
+            sds(s, 4096), sds(s, 64), sds(64), sds(s, 128), sds(s, 128),
+            sds(64)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert '%ssd_scan' in text
+    # x enters and y leaves in the layout the mixer's projections use: no
+    # copy of either
+    assert 'f32[2048,64,64]' not in text

@@ -680,7 +680,10 @@ CANONICAL_STAGES = {'decode', 'decode+preprocess', 'audio_dsp',
                     'moe_walk',
                     # the packed path's batches built in a recycled host
                     # buffer
-                    'pack_recycled'}
+                    'pack_recycled',
+                    # the hybrid trunk's Mamba-2 mixers: positions through
+                    # the SSD scan, and chunks of it through the kernel
+                    'ssd_scan', 'ssd_kernel'}
 
 
 def test_stage_vocabulary_contract():

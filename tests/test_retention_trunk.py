@@ -207,7 +207,8 @@ def test_model_type_picks_the_trunk_and_an_unknown_one_is_refused_by_name():
                                        'device': 'cpu'})
     assert yml['model_type'] == 'joyai_llm_flash'
     with pytest.raises(ValueError, match=r"no trunk for model_type='qwen3'"
-                       r'; known: afmoe, brumby, dots3_note, joyai_llm_flash'):
+                       r'; known: afmoe, brumby, dots3_note, granitemoehybrid, '
+                       r'joyai_llm_flash'):
         create_extractor(load_config('lm', overrides=dict(
             TINY_PROGRAM, **WINDOW, model_type='qwen3', device='cpu',
             video_paths=['x.mp4'], allow_random_weights=True)))

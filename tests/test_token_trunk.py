@@ -127,6 +127,23 @@ CELLS = {
               L + '4.mlp.shared_experts.up_proj.weight',
               L + '4.mlp.shared_experts.down_proj.weight',
               'model.norm.weight']),
+    'granite-4.0-h-micro-l20': dict(
+        kernels={'causal_attention': 'kernel', 'ssd': 'kernel',
+                 'ssd_chunk': 256, 'operators': 'mamba 18, attention 2'},
+        describe='20 layers (18 mamba + 2 attention) and a dense SwiGLU of '
+                 '8192',
+        first=['model.embed_tokens.weight', L + '0.input_layernorm.weight',
+               L + '0.mamba.in_proj.weight', L + '0.mamba.conv1d.weight',
+               L + '0.mamba.conv1d.bias', L + '0.mamba.dt_bias',
+               L + '0.mamba.A_log', L + '0.mamba.D',
+               L + '0.mamba.norm.weight', L + '0.mamba.out_proj.weight'],
+        last=[L + '19.mamba.conv1d.bias', L + '19.mamba.dt_bias',
+              L + '19.mamba.A_log', L + '19.mamba.D',
+              L + '19.mamba.norm.weight', L + '19.mamba.out_proj.weight',
+              L + '19.post_attention_layernorm.weight',
+              L + '19.shared_mlp.input_linear.weight',
+              L + '19.shared_mlp.output_linear.weight',
+              'model.norm.weight']),
 }
 
 

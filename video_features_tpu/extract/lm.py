@@ -20,7 +20,10 @@ window and full grouped-query attention layers mixed, gated, over sparse
 experts with a shared one, the same module's second dialect —, or
 ``dots3_note`` — latent attention under a learned selection of keys and
 under a window, gated, over sparse experts with a shared one,
-``models/latent_moe.py``'s second dialect. Every one runs the one decoder of
+``models/latent_moe.py``'s second dialect —, or ``granitemoehybrid`` —
+Mamba-2 state-space mixers among grouped-query attention layers with no
+positional code, dense, ``models/hybrid_trunk.py``'s third dialect. Every
+one runs the one decoder of
 ``models/token_trunk.py``, under its dialect's row; a trunk module says what
 this file needs of it (``models/token_trunk.py`` lists the names): its
 config from the args, its parameters, its step's second output, what it
@@ -46,7 +49,8 @@ Telemetry: the ``tokenise`` span; the ``kernels`` note of the run manifest
 chunk, the step compiled); and the trunk's counters on the stage table,
 filled from the step's second output at each readback — ``moe_route`` /
 ``moe_held`` / ``moe_walk`` (the expert trunks), ``retention_scan`` /
-``retention_kernel`` (retention trunk).
+``retention_kernel`` (retention trunk), ``ssd_scan`` / ``ssd_kernel``
+(granitemoehybrid's Mamba-2 mixers).
 """
 from __future__ import annotations
 
@@ -72,6 +76,7 @@ TRUNKS = {
     'lfm2_moe': 'video_features_tpu.models.hybrid_trunk',
     'afmoe': 'video_features_tpu.models.hybrid_trunk',
     'dots3_note': 'video_features_tpu.models.latent_moe',
+    'granitemoehybrid': 'video_features_tpu.models.hybrid_trunk',
 }
 
 
@@ -80,6 +85,13 @@ def load_trunk(model_type: str):
         raise ValueError(f'feature_type=lm has no trunk for model_type='
                          f'{model_type!r}; known: {", ".join(sorted(TRUNKS))}')
     return importlib.import_module(TRUNKS[model_type])
+
+
+def step_counter(cfg):
+    """(name, count) of the step's second output: the dialect's own where
+    it has one, else its trunk module's ``COUNTER`` and ``count``."""
+    trunk = load_trunk(cfg.model_type)
+    return cfg.dialect.counter or (trunk.COUNTER, trunk.count)
 
 
 def tokenise_frames(frames: np.ndarray, grid: int,
@@ -190,7 +202,7 @@ class ExtractLM(StackPackingMixin, BaseExtractor):
     def _forward(params, ids, cfg, platform=None):
         feats, counter = token_trunk.forward(params, ids, cfg,
                                              platform=platform)
-        return {'lm': feats, load_trunk(cfg.model_type).COUNTER: counter}
+        return {'lm': feats, step_counter(cfg)[0]: counter}
 
     # -- the host preprocess: frames → ids ----------------------------------
 
@@ -226,10 +238,11 @@ class ExtractLM(StackPackingMixin, BaseExtractor):
         # the step's second output is the trunk's: taken off here, turned
         # into its stage-table counters, never scattered to a video
         out = dict(super().fetch_outputs(out))
-        counter = out.pop(self.trunk.COUNTER, None)
+        name, count = step_counter(self.cfg)
+        counter = out.pop(name, None)
         if counter is not None and self.tracer.enabled:
-            self.trunk.count(self.tracer, counter, self.cfg,
-                             self.stack_batch * self.window_ids)
+            count(self.tracer, counter, self.cfg,
+                  self.stack_batch * self.window_ids)
         return out
 
     def extract(self, video_path: str) -> Dict[str, np.ndarray]:

@@ -1,27 +1,33 @@
 """The one decoder every token trunk of the ``lm`` family runs.
 
-The family's five published model types (``models/latent_moe.py``:
+The family's six published model types (``models/latent_moe.py``:
 joyai_llm_flash and dots3_note, latent attention over sparse experts;
 ``models/hybrid_trunk.py``: lfm2_moe and afmoe, short convolutions and
-grouped-query attention over sparse experts; ``models/retention_trunk.py``:
-brumby, gated power retention, dense) are pre-norm residual decoders over
+grouped-query attention over sparse experts, and granitemoehybrid, Mamba-2
+state-space mixers and grouped-query attention, dense;
+``models/retention_trunk.py``: brumby, gated power retention, dense) are
+pre-norm residual decoders over
 token ids. A layer is a (mixer kind, feed-forward kind) pair; a model type is
 a :class:`Dialect` row — its layer kinds and their :class:`Mixer` s, its
 published config keys, its checkpoint's names — under a config class of its
 own. Everything else is here, once.
 
 **The layer loop** (:func:`hidden_states`, :func:`forward`): the embedding
-(× √hidden where the config's ``embed_scale`` says so), then layer ``i`` of
-kind ``layer_types[i]``::
+(× √hidden where the config's ``embed_scale`` says so, × its
+``embedding_multiplier`` where it has one), then layer ``i`` of kind
+``layer_types[i]``::
 
-    h = x + post_op(mixer_kind(RMSNorm(x)))
-    x = h + post_ffn(ffn_i(RMSNorm(h)))
+    h = x + m · post_op(mixer_kind(RMSNorm(x)))
+    x = h + m · post_ffn(ffn_i(RMSNorm(h)))
 
-``post_op`` and ``post_ffn`` the dialect's post-norms where it has them, the
+``m`` the config's ``residual_multiplier`` where it has one (no multiply is
+traced where it has none), ``post_op`` and ``post_ffn`` the dialect's
+post-norms where it has them, the
 mixer a window at a time (``lax.map``) or over the whole batch as its
 :class:`Mixer` says, ``ffn_i`` a dense SwiGLU in the leading dense layers (in
-row blocks where the dialect walks them, :func:`mlp_rows`) and the expert
-layer (:func:`expert_block`) after them; then the final norm under its
+row blocks where the dialect walks them, :func:`mlp_rows`; gate and up one
+fused matrix where the dialect names no up matrix) and the expert layer
+(:func:`expert_block`) after them; then the final norm under its
 checkpoint name and the mean over a window's positions. The step's second
 output stacks what each layer counts: an expert layer's ``(held,)``
 assignments (zero rows where no layer has experts), or a counting mixer's
@@ -43,7 +49,8 @@ A trunk module offers ``extract/lm.py`` a few names and nothing else:
   seed)``;
 * ``COUNTER`` — the name the step's second output leaves the step under,
   and ``count(tracer, counter, cfg, tokens)``, the stage-table counters
-  filled from one fetched step's counter;
+  filled from one fetched step's counter (a dialect may bring its own:
+  ``Dialect.counter``);
 * ``describe(cfg)`` and ``SHARE_ADVICE`` — the trunk in a few words and how
   to hold less of it, for the build's refusal of what cannot fit;
 * ``kernels(cfg, platform, window_ids, precision)`` — which path the step
@@ -76,7 +83,8 @@ def rms_norm(x: jax.Array, gain: jax.Array, eps: float) -> jax.Array:
 
 
 # a SwiGLU's three matrices under the names most checkpoints give them
-# (gate, up, down); LFM2's are w1, w3, w2
+# (gate, up, down); LFM2's are w1, w3, w2. An up name of None: the first
+# matrix is gate and up side by side, (in, 2 · width), the gate's half first
 SWIGLU_NAMES = ('gate_proj', 'up_proj', 'down_proj')
 # rows a row-blocked feed-forward walks at a time (:func:`mlp_rows`)
 MLP_ROWS = 4096
@@ -84,16 +92,21 @@ MLP_ROWS = 4096
 
 def swiglu(x: jax.Array, p: Params, prefix: str,
            row_block: Optional[int] = None,
-           names: Tuple[str, str, str] = SWIGLU_NAMES) -> jax.Array:
-    """``W_down(silu(W_gate x) ⊙ W_up x)`` over (T, D) tokens. With
-    ``row_block`` the tokens are walked that many rows at a time (T a
-    multiple of it), so the two intermediates stand as (row_block, F) and
-    never as (T, F): 32,768 tokens × 17,408 wide are 2.3 GB each."""
+           names: Tuple[str, Optional[str], str] = SWIGLU_NAMES
+           ) -> jax.Array:
+    """``W_down(silu(W_gate x) ⊙ W_up x)`` over (T, D) tokens (``names``'
+    up None: ``[gate ‖ up] = x W_gate`` in one product). With ``row_block``
+    the tokens are walked that many rows at a time (T a multiple of it), so
+    the two intermediates stand as (row_block, F) and never as (T, F):
+    32,768 tokens × 17,408 wide are 2.3 GB each."""
     gate_name, up_name, down_name = names
 
     def rows(x):
         gate = jnp.dot(x, p[f'{prefix}.{gate_name}.weight'])
-        up = jnp.dot(x, p[f'{prefix}.{up_name}.weight'])
+        if up_name is None:
+            gate, up = jnp.split(gate, 2, axis=-1)
+        else:
+            up = jnp.dot(x, p[f'{prefix}.{up_name}.weight'])
         return jnp.dot(jax.nn.silu(gate) * up,
                        p[f'{prefix}.{down_name}.weight'])
 
@@ -141,11 +154,12 @@ class Dialect:
     may be left out, the config's default then standing), whether its dense
     feed-forward walks the step's tokens in row blocks, the router
     normaliser's constant (the chosen scores over their sum + it) and the
-    checkpoint's names. The last group is what its grouped-query attention
+    checkpoint's names. The next group is what its grouped-query attention
     mixers read (``models/hybrid_trunk.py``): the kinds that carry the
     rotary code, whether the heads' output is gated, whether the mixer's
-    scope is the layer's kind, and the names of the per-head norms and the
-    output projection."""
+    scope is the layer's kind, and the names of the per-head norms (None:
+    none) and the output projection. Last, the step's counter where the
+    dialect's is not its module's."""
     mixers: Dict[str, Mixer]
     config_keys: Tuple[str, ...]
     renamed: Tuple[Tuple[str, str], ...] = ()
@@ -156,15 +170,18 @@ class Dialect:
     ffn_norm: str = 'post_attention_layernorm'
     post_norms: Tuple[str, ...] = ()          # (after the mixer, the ffn)
     ffn: str = 'mlp'
-    ffn_names: Tuple[str, str, str] = SWIGLU_NAMES
+    ffn_names: Tuple[str, Optional[str], str] = SWIGLU_NAMES
     router: str = 'gate'
     expert_bias: str = 'gate.e_score_correction_bias'
     final_norm: str = 'model.norm.weight'
     rotary: Tuple[str, ...] = ()
     gated: bool = False
     scope_by_kind: bool = False
-    qk_norms: Tuple[str, str] = ('q_norm', 'k_norm')
+    qk_norms: Optional[Tuple[str, str]] = ('q_norm', 'k_norm')
     out_proj: str = 'o_proj'
+    # (name, count) of the step's second output where they are not the
+    # trunk module's ``COUNTER`` and ``count``
+    counter: Optional[Tuple[str, Callable]] = None
 
 
 class BaseConfig:
@@ -178,6 +195,8 @@ class BaseConfig:
     window_key: Optional[str] = None
     # what only some trunks' configs hold as fields, where they do not
     embed_scale = False                       # the embedding × √hidden
+    embedding_multiplier = None               # the embedding × this
+    residual_multiplier = None                # each sub-layer's output × this
     use_expert_bias = True                    # the router's bias is held
 
     @classmethod
@@ -250,10 +269,13 @@ def held_experts(n_experts_held: Optional[int], first_expert: int,
 
 # -- parameters --------------------------------------------------------------
 
-def _swiglu_shapes(prefix: str, names: Tuple[str, str, str], d: int, f: int,
-                   stack: Tuple[int, ...] = ()
+def _swiglu_shapes(prefix: str, names: Tuple[str, Optional[str], str],
+                   d: int, f: int, stack: Tuple[int, ...] = ()
                    ) -> Dict[str, Tuple[int, ...]]:
     gate_name, up_name, down_name = names
+    if up_name is None:
+        return {f'{prefix}.{gate_name}.weight': stack + (d, 2 * f),
+                f'{prefix}.{down_name}.weight': stack + (f, d)}
     return {f'{prefix}.{gate_name}.weight': stack + (d, f),
             f'{prefix}.{up_name}.weight': stack + (d, f),
             f'{prefix}.{down_name}.weight': stack + (f, d)}
@@ -371,6 +393,9 @@ def hidden_states(params: Params, ids: jax.Array, cfg,
     x = params['model.embed_tokens.weight'][ids]            # (B, S, D)
     if cfg.embed_scale:
         x = x * math.sqrt(d)
+    if cfg.embedding_multiplier is not None:
+        x = x * cfg.embedding_multiplier
+    residual = cfg.residual_multiplier
     counted = []
     for i, kind in enumerate(cfg.layer_types):
         p = f'model.layers.{i}'
@@ -387,6 +412,8 @@ def hidden_states(params: Params, ids: jax.Array, cfg,
             y, n = y
         if post_op:
             y = rms_norm(y, params[f'{p}.{post_op}.weight'], eps)
+        if residual is not None:
+            y = y * residual
         x = x + y
         if mixer.counted:
             counted.append(n.sum(axis=0))
@@ -403,6 +430,8 @@ def hidden_states(params: Params, ids: jax.Array, cfg,
             counted.append(c)
         if post_ffn:
             y = rms_norm(y, params[f'{p}.{post_ffn}.weight'], eps)
+        if residual is not None:
+            y = y * residual
         x = x + y.reshape(b, s, d)
     # no layer counted: an expert trunk's stage of dense layers only
     counter = (jnp.stack(counted) if counted

@@ -56,6 +56,8 @@ SCOPES = (
     'full_attention',    # and its full causal layers
     'retention',         # gated power retention
     'short_conv',        # gated short convolution
+    'mamba',             # the Mamba-2 mixer
+    'ssd',               # in mamba: step sizes, decays, the scan, D skip
     'moe',               # routed (and shared) experts
     'dense_mlp',         # a dense SwiGLU
 )

@@ -63,9 +63,12 @@ STAGES = (
     'moe_route',          # mean ÷ largest load on one held expert
     'moe_held',           # assignments on held experts ÷ all assignments
     'moe_walk',           # held assignments ÷ rows the block walk computed
-    # ... and the retention trunk's mixer
+    # ... the retention trunk's mixer
     'retention_scan',     # positions × layers through the carried state ÷ all
     'retention_kernel',   # of those, through the state-product kernels
+    # ... and the hybrid trunk's Mamba-2 mixers
+    'ssd_scan',           # positions × Mamba layers through the SSD scan ÷ all
+    'ssd_kernel',         # chunks scanned through the ssd_scan kernel ÷ all
 )
 
 
