@@ -121,8 +121,10 @@ def test_trunk_matches_the_reference_on_the_xla_tiles(tiny):
     """float32 both sides: the selection is the same and the rest rounding
     (5.6e-7 when written)."""
     cfg, rcfg, params, ids = tiny
-    got, counts = run(params, ids, cfg)
+    got, (counts, index) = run(params, ids, cfg)
     assert got.shape == (2, 64) and counts.shape == (4, 8)
+    # each full layer scores its one block of 32 in both windows, by XLA
+    assert np.asarray(index).tolist() == [[2, 0], [2, 0]]
     assert rel_l2(got, reference(params, ids, rcfg)) < 1e-5
 
 

@@ -373,3 +373,35 @@ def test_the_ssd_scan_compiles_at_granite_micros_widths(one_chip, passes):
     # x enters and y leaves in the layout the mixer's projections use: no
     # copy of either
     assert 'f32[2048,64,64]' not in text
+
+
+@pytest.mark.parametrize('passes', [3, 1])
+def test_the_index_scores_compile_at_dots3_notes_widths(one_chip, passes):
+    """dots3-note.corpus's lightning indexer at its widths — 64 heads of 128
+    over 8,192 positions, the 24 query blocks of 256 past index_topk 2,048
+    — as ONE Mosaic call named index_scores over their triangle of key
+    tiles, under three passes (precision=mixed) and one (the control lane),
+    inside the VMEM limit it asks for; and that is what these shapes get on
+    a TPU."""
+    from video_features_tpu.ops import pallas_index
+    from video_features_tpu.ops.attention import KERNEL_PASSES
+    from video_features_tpu.ops.sparse_index import (
+        resolve_index, scored_blocks,
+    )
+    precision = {3: 'high', 1: 'default'}[passes]
+    assert KERNEL_PASSES[precision] == passes
+    assert resolve_index('tpu', 8192, 64, 128, 256, precision) == 'kernel'
+    blocks = scored_blocks(8192, 2048, 256)
+    assert blocks == list(range(8, 32))
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = jax.jit(lambda q, k, w: pallas_index.index_scores(
+        q, k, w, blocks, 256, passes)).lower(
+        sds(8192, 64, 128), sds(8192, 128), sds(8192, 64)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert '%index_scores' in text
+    # the scores leave as the (rows, keys) matrix top_keys reads
+    assert 'f32[6144,8192]' in text

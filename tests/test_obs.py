@@ -683,7 +683,10 @@ CANONICAL_STAGES = {'decode', 'decode+preprocess', 'audio_dsp',
                     'pack_recycled',
                     # the hybrid trunk's Mamba-2 mixers: positions through
                     # the SSD scan, and chunks of it through the kernel
-                    'ssd_scan', 'ssd_kernel'}
+                    'ssd_scan', 'ssd_kernel',
+                    # the sparse latent trunk's indexer: query blocks scored
+                    # through its kernel
+                    'index_kernel'}
 
 
 def test_stage_vocabulary_contract():

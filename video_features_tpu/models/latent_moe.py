@@ -86,12 +86,17 @@ from video_features_tpu.models.token_trunk import (
 from video_features_tpu.ops.attention import (
     KERNEL_PASSES, blockwise_attention, resolve_causal, rotary_interleaved,
 )
-from video_features_tpu.ops.sparse_index import select_keys
+from video_features_tpu.ops.sparse_index import (
+    BLOCK, resolve_index, scored_blocks, select_keys,
+)
 
 MODEL_TYPE = 'joyai_llm_flash'
 # the step's second output: (expert layers, held) assignment counts of the
 # batch
 COUNTER = 'moe_counts'
+# dots3_note's: those, and (full layers, 2) counts of the indexer's query
+# blocks (:func:`count_selection`)
+SELECTION_COUNTER = 'moe_index_counts'
 SHARE_ADVICE = ('Hold a share (n_experts_held, first_expert: the experts of '
                 'a layer divided over chips) and run fewer layers here '
                 '(num_hidden_layers: the rest are further pipeline stages).')
@@ -178,6 +183,15 @@ def _causal_path(cfg: TrunkConfig, kind: str, platform: str, s: int,
                           m.window, bool(m.index_topk))
 
 
+def _index_path(cfg: TrunkConfig, kind: str, platform: str, s: int,
+                precision: Optional[str]) -> str:
+    """``resolve_index``'s answer for the indexer of a layer of ``kind``
+    over ``s`` positions."""
+    m = cfg.latent(kind)
+    return resolve_index(platform, s, m.index_heads, m.index_dim, BLOCK,
+                         precision)
+
+
 def _head_columns(w: jax.Array, h: int, lo: int, hi: int) -> jax.Array:
     """Columns ``lo:hi`` of every head of a (in, h·d) projection, as
     (in, h·(hi − lo)): the product of an activation with it writes that
@@ -206,8 +220,11 @@ def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
     will run (None: the default backend); with the shapes and the ambient
     matmul precision it decides the causal path
     (``ops.attention.resolve_causal``): the fused kernel where it applies,
-    the XLA tiles of ``blockwise_attention`` elsewhere."""
+    the XLA tiles of ``blockwise_attention`` elsewhere; and so the
+    indexer's scores (``ops.sparse_index.resolve_index``)."""
     m = cfg.latent(kind)
+    platform = platform or jax.default_backend()
+    precision = jax.config.jax_default_matmul_precision
     with mixer_scope(cfg, kind):
         s = x.shape[0]
         h, dn, dr, dv = m.heads, m.nope, m.rope, m.v
@@ -216,6 +233,8 @@ def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
                        p[f'{prefix}.q_a_layernorm.weight'], eps)
         keep = None
         if m.index_topk:
+            kernel = _index_path(cfg, kind, platform, s,
+                                 precision) == 'kernel'
             with jax.named_scope('mla_indexer'):
                 i = f'{prefix}.indexer'
                 keep = select_keys(
@@ -223,12 +242,12 @@ def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
                     p[f'{i}.k_norm.weight'], p[f'{i}.k_norm.bias'],
                     p[f'{i}.weights_proj.weight'], heads=m.index_heads,
                     dim=m.index_dim, rope=dr, topk=m.index_topk,
-                    theta=m.theta)[None]
+                    theta=m.theta,
+                    kernel_passes=KERNEL_PASSES[precision] if kernel
+                    else None)[None]
         if m.rescale:
             c_q = c_q * math.sqrt(cfg.hidden_size / m.q_lora_rank)
-        precision = jax.config.jax_default_matmul_precision
-        if _causal_path(cfg, kind, platform or jax.default_backend(), s,
-                        precision) == 'kernel':
+        if _causal_path(cfg, kind, platform, s, precision) == 'kernel':
             out = _mla_kernel_path(p, prefix, x, c_q, cfg, m, precision,
                                    keep)
         else:
@@ -238,6 +257,35 @@ def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
             gate = jax.nn.sigmoid(jnp.dot(x, p[f'{prefix}.gate_proj.weight']))
             out = (out.reshape(s, h, dv) * gate[..., None]).reshape(s, h * dv)
         return jnp.dot(out, p[f'{prefix}.o_proj.weight'])
+
+
+def sparse_mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
+                     attn_block: int = 1024,
+                     platform: Optional[str] = None,
+                     kind: str = FULL) -> Tuple[jax.Array, jax.Array]:
+    """:func:`mla_block` of a layer the indexer selects for, and a (2,)
+    count: the window's query blocks the indexer scored and how many of
+    them through the kernel (what ``index_kernel`` counts)."""
+    out = mla_block(p, prefix, x, cfg, attn_block, platform, kind)
+    m, s = cfg.latent(kind), x.shape[0]
+    scored = len(scored_blocks(s, m.index_topk)) if m.index_topk else 0
+    kernel = scored and _index_path(
+        cfg, kind, platform or jax.default_backend(), s,
+        jax.config.jax_default_matmul_precision) == 'kernel'
+    return out, jnp.array([scored, scored if kernel else 0], jnp.int32)
+
+
+def count_selection(tracer, counter, cfg: TrunkConfig, tokens: int) -> None:
+    """One fetched ``dots3_note`` step's counter — (the expert layers'
+    counts, the full layers' ``(layers, 2)`` indexer counts) → the stage
+    table: the expert rows (``token_trunk.count_experts``) and
+    ``index_kernel``, query blocks scored through the kernel ÷ query blocks
+    scored."""
+    experts, index = counter
+    token_trunk.count_experts(tracer, experts, cfg, tokens)
+    scored, through = (int(c) for c in
+                       np.asarray(index).reshape(-1, 2).sum(axis=0))
+    tracer.add_occupancy('index_kernel', through, scored)
 
 
 def _latent_kv(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
@@ -307,14 +355,17 @@ def _mla_kernel_path(p: Params, prefix: str, x: jax.Array, c_q: jax.Array,
 # -- the dialects -------------------------------------------------------------
 
 MLA = Mixer(mla_block, mla_shapes)
+SPARSE_MLA = Mixer(sparse_mla_block, mla_shapes, counted=True)
 DIALECTS = {
     'joyai_llm_flash': Dialect(mixers={FULL: MLA}, config_keys=CONFIG_KEYS,
                                row_blocked_mlp=False),
     'dots3_note': Dialect(
-        mixers={FULL: MLA, SLIDING: MLA}, config_keys=DOTS3_CONFIG_KEYS,
+        mixers={FULL: SPARSE_MLA, SLIDING: MLA},
+        config_keys=DOTS3_CONFIG_KEYS,
         optional=('n_experts_held', 'first_expert', 'attention_gate_type',
                   'swa_attention_gate_type'),
-        scope_by_kind=True),
+        scope_by_kind=True,
+        counter=(SELECTION_COUNTER, count_selection)),
 }
 
 
@@ -444,6 +495,9 @@ def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
                 'sparse_attention'
             notes[lane] = _causal_path(cfg, kind, platform, window_ids,
                                        precision)
+    if cfg.kinds().get(FULL) and cfg.latent(FULL).index_topk:
+        notes['index_scores'] = _index_path(cfg, FULL, platform, window_ids,
+                                            precision)
     notes['layers'] = ', '.join(f'{kind} {n}'
                                 for kind, n in cfg.kinds().items())
     return notes

@@ -31,7 +31,8 @@ fused matrix where the dialect names no up matrix) and the expert layer
 checkpoint name and the mean over a window's positions. The step's second
 output stacks what each layer counts: an expert layer's ``(held,)``
 assignments (zero rows where no layer has experts), or a counting mixer's
-per-layer count, summed over the batch.
+per-layer count, summed over the batch; a trunk with both gives the pair
+(expert layers' stack, counting mixers' stack).
 
 **The parameters** (:func:`param_shapes`): one walk over the layers in
 checkpoint order that asks each layer's mixer for its own shapes;
@@ -396,7 +397,7 @@ def hidden_states(params: Params, ids: jax.Array, cfg,
     if cfg.embedding_multiplier is not None:
         x = x * cfg.embedding_multiplier
     residual = cfg.residual_multiplier
-    counted = []
+    mixed, routed = [], []
     for i, kind in enumerate(cfg.layer_types):
         p = f'model.layers.{i}'
         mixer = names.mixers[kind]
@@ -416,7 +417,7 @@ def hidden_states(params: Params, ids: jax.Array, cfg,
             y = y * residual
         x = x + y
         if mixer.counted:
-            counted.append(n.sum(axis=0))
+            mixed.append(n.sum(axis=0))
         normed = rms_norm(x, params[f'{p}.{names.ffn_norm}.weight'], eps
                           ).reshape(b * s, d)
         m = f'{p}.{names.ffn}'
@@ -427,15 +428,18 @@ def hidden_states(params: Params, ids: jax.Array, cfg,
                            names=names.ffn_names)
         else:
             y, c = expert_block(params, m, normed, cfg, moe_block)
-            counted.append(c)
+            routed.append(c)
         if post_ffn:
             y = rms_norm(y, params[f'{p}.{post_ffn}.weight'], eps)
         if residual is not None:
             y = y * residual
         x = x + y.reshape(b, s, d)
-    # no layer counted: an expert trunk's stage of dense layers only
-    counter = (jnp.stack(counted) if counted
-               else jnp.zeros((0, cfg.n_experts_held), jnp.int32))
+    if mixed and routed:
+        counter = (jnp.stack(routed), jnp.stack(mixed))
+    elif mixed or routed:
+        counter = jnp.stack(mixed or routed)
+    else:   # no layer counted: an expert trunk's stage of dense layers only
+        counter = jnp.zeros((0, cfg.n_experts_held), jnp.int32)
     return rms_norm(x, params[names.final_norm], eps), counter
 
 

@@ -66,9 +66,11 @@ STAGES = (
     # ... the retention trunk's mixer
     'retention_scan',     # positions × layers through the carried state ÷ all
     'retention_kernel',   # of those, through the state-product kernels
-    # ... and the hybrid trunk's Mamba-2 mixers
+    # ... the hybrid trunk's Mamba-2 mixers
     'ssd_scan',           # positions × Mamba layers through the SSD scan ÷ all
     'ssd_kernel',         # chunks scanned through the ssd_scan kernel ÷ all
+    # ... and the lightning indexer of the sparse latent trunk
+    'index_kernel',       # query blocks scored by the index_scores kernel ÷ all
 )
 
 
