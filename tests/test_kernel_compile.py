@@ -405,3 +405,24 @@ def test_the_index_scores_compile_at_dots3_notes_widths(one_chip, passes):
     assert '%index_scores' in text
     # the scores leave as the (rows, keys) matrix top_keys reads
     assert 'f32[6144,8192]' in text
+
+
+def test_the_topk_threshold_compiles_at_dots3_notes_widths(one_chip):
+    """dots3-note.corpus's thresholds — the 24 scored query blocks of 256
+    over 8,192 keys, the top 2,048 of each row — as ONE Mosaic call named
+    topk_threshold on index_scores' (6,144, 8,192) table, inside the VMEM
+    limit it asks for, with no copy of the table; and that is what these
+    shapes get on a TPU."""
+    from video_features_tpu.ops import pallas_index
+    from video_features_tpu.ops.sparse_index import (
+        resolve_threshold, scored_blocks,
+    )
+    assert resolve_threshold('tpu', 8192, 256) == 'kernel'
+    blocks = scored_blocks(8192, 2048, 256)
+    table = jax.ShapeDtypeStruct((6144, 8192), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(lambda t: pallas_index.topk_threshold(
+        t, blocks, 256, 2048)).lower(table).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert '%topk_threshold' in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0

@@ -685,8 +685,9 @@ CANONICAL_STAGES = {'decode', 'decode+preprocess', 'audio_dsp',
                     # the SSD scan, and chunks of it through the kernel
                     'ssd_scan', 'ssd_kernel',
                     # the sparse latent trunk's indexer: query blocks scored
-                    # through its kernel
-                    'index_kernel'}
+                    # through its kernel, and its rows whose threshold the
+                    # tie rule settled
+                    'index_kernel', 'topk_ties'}
 
 
 def test_stage_vocabulary_contract():

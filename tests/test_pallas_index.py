@@ -138,8 +138,9 @@ def test_the_step_lowered_for_a_tpu_scores_through_the_kernel(platform,
                                                               calls):
     """A dots3_note trunk at 128-wide index heads, 256 ids a window (one
     scored block of 256 over one key tile): one index_scores call in each
-    full layer's window loop where resolve_index says 'kernel', and the
-    step's notes say so."""
+    full layer's window loop where resolve_index says 'kernel', one
+    topk_threshold call beside it on its table, and the step's notes say
+    so."""
     from tests.test_dots3_trunk import program_cfg
 
     from video_features_tpu.extract.lm import ExtractLM
@@ -152,15 +153,21 @@ def test_the_step_lowered_for_a_tpu_scores_through_the_kernel(platform,
                                platform=platform)).trace(
             params, ids).lower(lowering_platforms=('tpu',)).as_text()
     assert text.count('kernel_name = "index_scores"') == calls
+    # the thresholds beside it, from its table
+    assert text.count('kernel_name = "topk_threshold"') == calls
     notes = lm.kernels(cfg, platform, 256, precision)
     assert notes['index_scores'] == ('kernel' if calls else 'xla')
+    assert notes['topk_threshold'] == notes['index_scores']
 
 
 def test_a_full_layer_through_the_kernel_is_the_xla_layer_and_counts_it(
         monkeypatch):
     """sparse_mla_block at 32 ids (one block, scored) on the XLA form and
     through the kernel interpreted: the same layer to the passes' rounding,
-    and the count [blocks scored, of them through the kernel]."""
+    and the count [blocks scored, of them through the kernel, rows the tie
+    rule settled, rows scored]; the kernel's table thresholded by
+    ``topk_threshold`` too (interpreted) gives the same layer exactly, and
+    the same count."""
     from tests.test_dots3_trunk import program_cfg
 
     from video_features_tpu.ops.precision import rel_l2
@@ -174,21 +181,29 @@ def test_a_full_layer_through_the_kernel_is_the_xla_layer_and_counts_it(
         monkeypatch.setattr(lm, 'resolve_index', lambda *args: 'kernel')
         with pltpu.force_tpu_interpret_mode():
             got, n_kernel = lm.sparse_mla_block(params, a, x, cfg, 8, 'cpu')
+            monkeypatch.setattr(lm, 'resolve_threshold',
+                                lambda *args: 'kernel')
+            both, n_both = lm.sparse_mla_block(params, a, x, cfg, 8, 'cpu')
     assert rel_l2(got, want) < 1e-5
-    np.testing.assert_array_equal(np.asarray(n_xla), [1, 0])
-    np.testing.assert_array_equal(np.asarray(n_kernel), [1, 1])
+    np.testing.assert_array_equal(np.asarray(both), np.asarray(got))
+    ties = int(n_xla[2])
+    np.testing.assert_array_equal(np.asarray(n_xla), [1, 0, ties, 32])
+    np.testing.assert_array_equal(np.asarray(n_kernel), [1, 1, ties, 32])
+    np.testing.assert_array_equal(np.asarray(n_both), n_kernel)
 
 
 @pytest.mark.parametrize('index,valid,capacity', [
-    ([[48, 48], [48, 48]], 96, 96),     # dots3-note.corpus: 24 a window, 2
-    ([[48, 0], [48, 0]], 0, 96),        # XLA's form
-    ([[0, 0], [0, 0]], 0, 0),           # topk past the window: none scored
+    # dots3-note.corpus: 24 blocks of 256 a window, 2 windows; 3 rows tied
+    ([[48, 48, 1, 12288], [48, 48, 2, 12288]], 96, 96),
+    ([[48, 0, 0, 12288], [48, 0, 0, 12288]], 0, 96),    # XLA's form
+    ([[0, 0, 0, 0], [0, 0, 0, 0]], 0, 0),   # topk past the window: none
 ])
 def test_the_counter_adds_blocks_scored_through_the_kernel(index, valid,
                                                            capacity):
     """One fetched step's (expert counts, indexer counts) → the stage
     table: ``index_kernel`` is blocks through the kernel ÷ blocks scored
-    over the full layers, beside the expert rows."""
+    over the full layers, ``topk_ties`` rows the tie rule settled ÷ rows
+    scored, beside the expert rows."""
     from tests.test_dots3_trunk import program_cfg
     cfg = program_cfg()
     tracer = Tracer()
@@ -202,6 +217,10 @@ def test_the_counter_adds_blocks_scored_through_the_kernel(index, valid,
     row = table['index_kernel']
     assert (row.get('occ_valid', 0), row.get('occ_capacity', 0)) == (
         2 * valid, 2 * capacity)
+    ties = table['topk_ties']
+    index = np.asarray(index)
+    assert (ties.get('occ_valid', 0), ties.get('occ_capacity', 0)) == (
+        2 * int(index[:, 2].sum()), 2 * int(index[:, 3].sum()))
     assert table['moe_held']['occ_valid'] == 2 * 4 * 8 * 8
     from video_features_tpu.extract.lm import step_counter
     assert step_counter(cfg) == (lm.SELECTION_COUNTER, lm.count_selection)

@@ -241,8 +241,8 @@ def brute_selection(inp, heads, dim, rope, topk, theta):
 def test_each_row_keeps_exactly_min_t_plus_1_topk_keys(topk):
     inp = indexer_inputs(9)
     with jax.default_matmul_precision('highest'):
-        words = select_keys(**inp, heads=4, dim=16, rope=8, topk=topk,
-                            theta=8e7, block=8)
+        words, _ = select_keys(**inp, heads=4, dim=16, rope=8, topk=topk,
+                               theta=8e7, block=8)
     keep = np.asarray(unpack_keep(words, 32))
     assert not np.triu(keep, 1).any()                  # nothing ahead
     assert (keep.sum(1) == np.minimum(np.arange(32) + 1, topk)).all()
@@ -259,7 +259,7 @@ def test_ties_go_to_the_lower_index_as_top_k_takes_them():
     inp = indexer_inputs(10)
     inp['w_weights'] = jnp.zeros_like(inp['w_weights'])
     keep = np.asarray(unpack_keep(select_keys(
-        **inp, heads=4, dim=16, rope=8, topk=8, theta=8e7, block=16), 32))
+        **inp, heads=4, dim=16, rope=8, topk=8, theta=8e7, block=16)[0], 32))
     want = np.tril(np.ones((32, 32), bool)) & (np.arange(32) < 8)[None]
     assert (keep == want).all()
     scores = jnp.asarray([[0.0, 2.0, 0.0, 0.0, 1.0, 0.0]])
@@ -270,7 +270,7 @@ def test_ties_go_to_the_lower_index_as_top_k_takes_them():
 def test_blocks_before_topk_keep_their_triangle_and_score_nothing():
     inp = indexer_inputs(11)
     keep = np.asarray(unpack_keep(select_keys(
-        **inp, heads=4, dim=16, rope=8, topk=32, theta=8e7, block=8), 32))
+        **inp, heads=4, dim=16, rope=8, topk=32, theta=8e7, block=8)[0], 32))
     assert (keep == np.tril(np.ones((32, 32), bool))).all()
     for topk, scored in ((32, False), (8, True)):
         text = jax.jit(partial(select_keys, heads=4, dim=16, rope=8,

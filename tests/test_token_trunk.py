@@ -105,7 +105,7 @@ CELLS = {
               L + '7.post_mlp_layernorm.weight', 'model.norm.weight']),
     'dots3-note-prev-ep32-l5': dict(
         kernels={'sparse_attention': 'kernel', 'window_attention': 'kernel',
-                 'index_scores': 'kernel',
+                 'index_scores': 'kernel', 'topk_threshold': 'kernel',
                  'layers': 'full_attention 2, sliding_attention 3'},
         describe='5 layers (2 full_attention + 3 sliding_attention) and 8 of '
                  '256 experts a layer',

@@ -50,8 +50,8 @@ chunk, the step compiled); and the trunk's counters on the stage table,
 filled from the step's second output at each readback — ``moe_route`` /
 ``moe_held`` / ``moe_walk`` (the expert trunks), ``retention_scan`` /
 ``retention_kernel`` (retention trunk), ``ssd_scan`` / ``ssd_kernel``
-(granitemoehybrid's Mamba-2 mixers), ``index_kernel`` (dots3_note's
-lightning indexer).
+(granitemoehybrid's Mamba-2 mixers), ``index_kernel`` / ``topk_ties``
+(dots3_note's lightning indexer).
 """
 from __future__ import annotations
 

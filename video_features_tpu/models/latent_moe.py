@@ -87,15 +87,15 @@ from video_features_tpu.ops.attention import (
     KERNEL_PASSES, blockwise_attention, resolve_causal, rotary_interleaved,
 )
 from video_features_tpu.ops.sparse_index import (
-    BLOCK, resolve_index, scored_blocks, select_keys,
+    BLOCK, resolve_index, resolve_threshold, scored_blocks, select_keys,
 )
 
 MODEL_TYPE = 'joyai_llm_flash'
 # the step's second output: (expert layers, held) assignment counts of the
 # batch
 COUNTER = 'moe_counts'
-# dots3_note's: those, and (full layers, 2) counts of the indexer's query
-# blocks (:func:`count_selection`)
+# dots3_note's: those, and (full layers, 4) counts of the indexer's query
+# blocks and rows (:func:`count_selection`)
 SELECTION_COUNTER = 'moe_index_counts'
 SHARE_ADVICE = ('Hold a share (n_experts_held, first_expert: the experts of '
                 'a layer divided over chips) and run fewer layers here '
@@ -192,6 +192,16 @@ def _index_path(cfg: TrunkConfig, kind: str, platform: str, s: int,
                          precision)
 
 
+def _threshold_path(cfg: TrunkConfig, kind: str, platform: str, s: int,
+                    precision: Optional[str]) -> str:
+    """Which form finds the thresholds of that indexer: the kernel reads
+    ``index_scores``' table, so 'kernel' only beside it and where
+    ``resolve_threshold`` says so."""
+    if _index_path(cfg, kind, platform, s, precision) != 'kernel':
+        return 'xla'
+    return resolve_threshold(platform, s, BLOCK)
+
+
 def _head_columns(w: jax.Array, h: int, lo: int, hi: int) -> jax.Array:
     """Columns ``lo:hi`` of every head of a (in, h·d) projection, as
     (in, h·(hi − lo)): the product of an activation with it writes that
@@ -221,7 +231,16 @@ def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
     matmul precision it decides the causal path
     (``ops.attention.resolve_causal``): the fused kernel where it applies,
     the XLA tiles of ``blockwise_attention`` elsewhere; and so the
-    indexer's scores (``ops.sparse_index.resolve_index``)."""
+    indexer's scores (``ops.sparse_index.resolve_index``) and thresholds
+    (``resolve_threshold``)."""
+    return _mla(p, prefix, x, cfg, attn_block, platform, kind)[0]
+
+
+def _mla(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
+         attn_block: int, platform: Optional[str], kind: str
+         ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """:func:`mla_block`'s output, and where the layer's indexer selects
+    the rows whose threshold the tie rule settled (None without one)."""
     m = cfg.latent(kind)
     platform = platform or jax.default_backend()
     precision = jax.config.jax_default_matmul_precision
@@ -231,20 +250,23 @@ def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
         eps = cfg.rms_norm_eps
         c_q = rms_norm(jnp.dot(x, p[f'{prefix}.q_a_proj.weight']),
                        p[f'{prefix}.q_a_layernorm.weight'], eps)
-        keep = None
+        keep = tied = None
         if m.index_topk:
             kernel = _index_path(cfg, kind, platform, s,
                                  precision) == 'kernel'
             with jax.named_scope('mla_indexer'):
                 i = f'{prefix}.indexer'
-                keep = select_keys(
+                keep, tied = select_keys(
                     x, c_q, p[f'{i}.wq_b.weight'], p[f'{i}.wk.weight'],
                     p[f'{i}.k_norm.weight'], p[f'{i}.k_norm.bias'],
                     p[f'{i}.weights_proj.weight'], heads=m.index_heads,
                     dim=m.index_dim, rope=dr, topk=m.index_topk,
                     theta=m.theta,
                     kernel_passes=KERNEL_PASSES[precision] if kernel
-                    else None)[None]
+                    else None,
+                    threshold_kernel=_threshold_path(
+                        cfg, kind, platform, s, precision) == 'kernel')
+                keep = keep[None]
         if m.rescale:
             c_q = c_q * math.sqrt(cfg.hidden_size / m.q_lora_rank)
         if _causal_path(cfg, kind, platform, s, precision) == 'kernel':
@@ -256,36 +278,42 @@ def mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
         if m.gated:
             gate = jax.nn.sigmoid(jnp.dot(x, p[f'{prefix}.gate_proj.weight']))
             out = (out.reshape(s, h, dv) * gate[..., None]).reshape(s, h * dv)
-        return jnp.dot(out, p[f'{prefix}.o_proj.weight'])
+        return jnp.dot(out, p[f'{prefix}.o_proj.weight']), tied
 
 
 def sparse_mla_block(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
                      attn_block: int = 1024,
                      platform: Optional[str] = None,
                      kind: str = FULL) -> Tuple[jax.Array, jax.Array]:
-    """:func:`mla_block` of a layer the indexer selects for, and a (2,)
+    """:func:`mla_block` of a layer the indexer selects for, and a (4,)
     count: the window's query blocks the indexer scored and how many of
-    them through the kernel (what ``index_kernel`` counts)."""
-    out = mla_block(p, prefix, x, cfg, attn_block, platform, kind)
+    them through the kernel (what ``index_kernel`` counts); the scored rows
+    whose threshold the tie rule settled and the scored rows (what
+    ``topk_ties`` counts)."""
+    out, tied = _mla(p, prefix, x, cfg, attn_block, platform, kind)
     m, s = cfg.latent(kind), x.shape[0]
     scored = len(scored_blocks(s, m.index_topk)) if m.index_topk else 0
     kernel = scored and _index_path(
         cfg, kind, platform or jax.default_backend(), s,
         jax.config.jax_default_matmul_precision) == 'kernel'
-    return out, jnp.array([scored, scored if kernel else 0], jnp.int32)
+    count = jnp.array([scored, scored if kernel else 0, 0,
+                       scored * min(BLOCK, s)], jnp.int32)
+    return out, count if tied is None else count.at[2].set(tied)
 
 
 def count_selection(tracer, counter, cfg: TrunkConfig, tokens: int) -> None:
     """One fetched ``dots3_note`` step's counter — (the expert layers'
-    counts, the full layers' ``(layers, 2)`` indexer counts) → the stage
-    table: the expert rows (``token_trunk.count_experts``) and
+    counts, the full layers' ``(layers, 4)`` indexer counts) → the stage
+    table: the expert rows (``token_trunk.count_experts``),
     ``index_kernel``, query blocks scored through the kernel ÷ query blocks
-    scored."""
+    scored, and ``topk_ties``, scored rows whose threshold the tie rule
+    settled ÷ scored rows."""
     experts, index = counter
     token_trunk.count_experts(tracer, experts, cfg, tokens)
-    scored, through = (int(c) for c in
-                       np.asarray(index).reshape(-1, 2).sum(axis=0))
+    scored, through, tied, rows = (
+        int(c) for c in np.asarray(index).reshape(-1, 4).sum(axis=0))
     tracer.add_occupancy('index_kernel', through, scored)
+    tracer.add_occupancy('topk_ties', tied, rows)
 
 
 def _latent_kv(p: Params, prefix: str, x: jax.Array, cfg: TrunkConfig,
@@ -484,7 +512,8 @@ def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
     the dialect has two layer kinds per kind, named by the kernel's lane
     (``sparse_attention`` for the selected full layers,
     ``window_attention`` for the sliding ones): it is the kernel's
-    engagement counter."""
+    engagement counter. Beside them the indexer's two forms, where the
+    full layers select: ``index_scores`` and ``topk_threshold``."""
     if cfg.model_type == MODEL_TYPE:
         return {'causal_attention': _causal_path(cfg, FULL, platform,
                                                  window_ids, precision)}
@@ -498,6 +527,8 @@ def kernels(cfg: TrunkConfig, platform: str, window_ids: int,
     if cfg.kinds().get(FULL) and cfg.latent(FULL).index_topk:
         notes['index_scores'] = _index_path(cfg, FULL, platform, window_ids,
                                             precision)
+        notes['topk_threshold'] = _threshold_path(cfg, FULL, platform,
+                                                  window_ids, precision)
     notes['layers'] = ', '.join(f'{kind} {n}'
                                 for kind, n in cfg.kinds().items())
     return notes

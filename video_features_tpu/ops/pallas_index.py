@@ -1,4 +1,5 @@
-"""The lightning indexer's scores as one Pallas TPU kernel: ``index_scores``.
+"""The lightning indexer's scores and the thresholds of their top-k as two
+Pallas TPU kernels: ``index_scores`` and ``topk_threshold``.
 
 ``ops/sparse_index.py::select_keys``'s XLA form computes each query block's
 (rows, heads, keys) products and sums them over the heads behind a ReLU.
@@ -8,7 +9,7 @@ a window-layer, 90 % of the three-pass ceiling); compiled alone at the same
 shapes it writes the (256, 64, keys) float32 products to HBM and reads them
 back (24.3 ms). Here the products of a (query block, key tile) pair live
 only in VMEM whatever XLA decides, and what reaches HBM is the (rows, keys)
-float32 score matrix that ``top_keys`` reads (one v5e chip, PERF.md §5):
+float32 score matrix that the thresholds read (one v5e chip, PERF.md §5):
 
     I[t, u] = Σ_j w[t, j] · ReLU(q[t, j] · k[u]),   −inf where u > t
 
@@ -38,8 +39,32 @@ float32 score matrix that ``top_keys`` reads (one v5e chip, PERF.md §5):
 The caller's queries (S, heads, dim) enter heads-major with positions on
 lanes, (heads, dim, S) float32 (the fusion that applies rotary writes them
 so); keys (S, dim) float32; weights (heads, 1, S) float32, the score scale
-folded in. CPU tests run the same body interpreted
-(``pltpu.force_tpu_interpret_mode()``, ``tests/test_pallas_index.py``).
+folded in.
+
+``topk_threshold`` reads that matrix and gives each row what
+``sparse_index.thresholds`` takes from ``lax.top_k``'s full sort — ``tau``,
+the ``topk``-th largest score, and ``last``, the highest index kept on it —
+with no sort; XLA then compares and packs the bits as before:
+
+* **one call a window-layer**, one grid step a scored block: its scores
+  are copied into VMEM a (block, block) tile at a time up to its last row
+  (the columns past it are never written, and never read), the next
+  block's copy started before this one's count; each score becomes an
+  int32 key in float order (−0.0 as +0.0), the diagonal tile's keys past
+  a row the lowest key;
+* **a group of 32 rows at a time** bisects on the keys' bits: 32 rounds
+  from the sign bit down, each one count a row of the keys ≥ the
+  candidate, kept where that count is still ≥ ``topk``; then one pass
+  counts the keys above ``tau`` and on it. Where some row of the group
+  has more keys on ``tau`` than the ``room`` left (the tie rule settles
+  it), a second bisection over the column index finds the room-th one,
+  lowest first, as ``lax.top_k`` takes them; where none has, it is
+  skipped and ``last`` is the last column. A row that sees no more than
+  ``topk`` keys keeps them all (``tau`` −inf).
+
+No product, so no pass count: the result is exact under every precision.
+CPU tests run both bodies interpreted (``pltpu.force_tpu_interpret_mode()``,
+``tests/test_pallas_index.py``, ``tests/test_topk_threshold.py``).
 """
 from __future__ import annotations
 
@@ -58,9 +83,11 @@ NAME = 'index_scores'
 # keys a grid step scores: 9.15 ms a window-layer at the cell's shapes, 9.28
 # at 512 (one v5e chip, PERF.md §6)
 KEY_TILE = 256
-# a block's float32 queries double-buffered and their packed bf16 parts
-# (64 heads × 128 × 256: 16.8 + 12.6 MB at three passes in the cell), beside
-# the key tile, the weights, the accumulator and a head's product
+# index_scores: a block's float32 queries double-buffered and their packed
+# bf16 parts (64 heads × 128 × 256: 16.8 + 12.6 MB at three passes in the
+# cell), beside the key tile, the weights, the accumulator and a head's
+# product; topk_threshold: a block's scores twice and its keys (25.2 MB in
+# the cell), beside its output block and a row group's counts
 VMEM_LIMIT_BYTES = 64 * 2 ** 20
 QUERY_VMEM_BYTES = 40 * 2 ** 20
 
@@ -155,3 +182,158 @@ def index_scores(q: jax.Array, k: jax.Array, w: jax.Array,
         name=NAME,
     )(qb, kt, q.astype(f32).transpose(1, 2, 0), k.astype(f32),
       w.astype(f32).T[:, None, :])
+
+
+# -- the threshold of each row's top-k ---------------------------------------
+
+THRESHOLD_NAME = 'topk_threshold'
+# rows the bisection counts at a time: a group's counts stay in vregs
+GROUP = 32
+# a block's (rows, keys) scores twice over (the one counted, the next
+# copying) and as order keys
+THRESHOLD_VMEM_BYTES = 48 * 2 ** 20
+_INT_MIN = -2 ** 31
+
+
+def threshold_vmem_bytes(s: int, block: int) -> int:
+    """VMEM a block of ``block`` rows × ``s`` keys takes: its float32
+    scores twice, its int32 keys once."""
+    return 3 * block * s * 4
+
+
+def _order_key(bits: jax.Array) -> jax.Array:
+    """float32 bits (as int32) → int32 keys in the floats' order: a negative
+    float's bits below the sign run backwards; −0.0 takes +0.0's key, so a
+    tie of keys is a tie of floats."""
+    key = jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+    return jnp.where(key == -1, 0, key)
+
+
+def _threshold_kernel(table_ref, out_ref, buf, keys_ref, sem, *,
+                      first: int, topk: int, bits: int):
+    i = pl.program_id(0)
+    slot = i % 2
+    block, s = buf.shape[1:]
+
+    def copies(step, into, start: bool):
+        """The DMAs of scored block ``step``'s keys, a (block, block) tile
+        at a time up to its last row: columns past it are not read."""
+        rows = pl.ds(pl.multiple_of(step * block, block), block)
+
+        def tile(c, carry):
+            cols = pl.ds(pl.multiple_of(c * block, block), block)
+            copy = pltpu.make_async_copy(table_ref.at[rows, cols],
+                                         buf.at[into, :, cols], sem.at[into])
+            if start:
+                copy.start()
+            else:
+                copy.wait()
+            return carry
+        lax.fori_loop(0, first + step + 1, tile, 0)
+
+    @pl.when(i == 0)
+    def _():
+        copies(0, 0, True)
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _():
+        copies(i + 1, 1 - slot, True)
+
+    copies(i, slot, False)
+    b = first + i
+    tiles = b + 1
+
+    # the scores → order keys, once; the lowest key where a key lies after
+    # the row, which no candidate reaches
+    def to_keys(c, carry):
+        cols = pl.ds(pl.multiple_of(c * block, block), block)
+        keys_ref[:, cols] = _order_key(lax.bitcast_convert_type(
+            buf[slot, :, cols], jnp.int32))
+        return carry
+    lax.fori_loop(0, tiles, to_keys, 0)
+    diag = pl.ds(pl.multiple_of(b * block, block), block)
+    row = lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    col = lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    keys_ref[:, diag] = jnp.where(col <= row, keys_ref[:, diag], _INT_MIN)
+
+    def count(rows, *tests):
+        """Per row of the group, how many of its keys pass each test
+        (keys, the tile's first column) → bool."""
+        def tile(c, accs):
+            start = pl.multiple_of(c * block, block)
+            x = keys_ref[rows, pl.ds(start, block)]
+            return tuple(a + t(x, start).astype(jnp.int32)
+                         for a, t in zip(accs, tests))
+        zero = jnp.zeros((GROUP, block), jnp.int32)
+        accs = lax.fori_loop(0, tiles, tile, (zero,) * len(tests))
+        return [jnp.sum(a, axis=1, keepdims=True) for a in accs]
+
+    lane = lax.broadcasted_iota(jnp.int32, (GROUP, out_ref.shape[1]), 1)
+
+    def group(g, carry):
+        rows = pl.ds(pl.multiple_of(g * GROUP, GROUP), GROUP)
+
+        # the largest key v with topk keys ≥ v, from the sign bit down; it
+        # stays at the bottom where a row sees no more than topk keys (and
+        # keeps them all)
+        def value_bit(r, v):
+            cand = v ^ lax.shift_left(jnp.int32(1), 31 - r)
+            n, = count(rows, lambda x, _: x >= cand)
+            return jnp.where(n >= topk, cand, v)
+        v = lax.fori_loop(0, 32, value_bit,
+                          jnp.full((GROUP, 1), _INT_MIN, jnp.int32))
+        above, on = count(rows, lambda x, _: x > v, lambda x, _: x == v)
+        room = topk - above
+        tied = (v != _INT_MIN) & (on > room)
+
+        # the room-th key on v by its column, lowest first
+        def column_bit(r, p):
+            cand = p | lax.shift_left(jnp.int32(1), bits - 1 - r)
+            n, = count(rows, lambda x, start: (x == v) & (
+                start + lax.broadcasted_iota(jnp.int32, x.shape, 1) < cand))
+            return jnp.where(n < room, cand, p)
+
+        def settle():
+            p = lax.fori_loop(0, bits, column_bit,
+                              jnp.zeros((GROUP, 1), jnp.int32))
+            return jnp.where(tied, p, s - 1)
+        last = lax.cond(jnp.max(tied.astype(jnp.int32)) > 0, settle,
+                        lambda: jnp.full((GROUP, 1), s - 1, jnp.int32))
+        # v's float bits (−inf at the bottom)
+        tau = jnp.where(v == _INT_MIN, -8388608,
+                        jnp.where(v < 0, v ^ 0x7FFFFFFF, v))
+        out_ref[rows, :] = jnp.where(
+            lane == 0, tau, jnp.where(lane == 1, last, tied.astype(jnp.int32)))
+        return carry
+    lax.fori_loop(0, block // GROUP, group, 0)
+
+
+def topk_threshold(table: jax.Array, blocks: Sequence[int], block: int,
+                   topk: int) -> tuple:
+    """Each scored row's threshold from ``index_scores``' table (the query
+    blocks ``blocks``, ascending and consecutive, ``block`` rows each, their
+    scores against every key up to each block's last row; −inf above the
+    diagonal): ``(tau, last, tied)`` — (rows, 1) float32 the ``topk``-th
+    largest score (−inf where a row sees no more than ``topk`` keys), (rows,
+    1) int32 the highest column kept among the keys on ``tau``
+    (``lax.top_k``'s rule: the lowest first), and (rows,) bool the rows
+    with more keys on ``tau`` than it keeps."""
+    rows, s = table.shape
+    out = pl.pallas_call(
+        partial(_threshold_kernel, first=blocks[0], topk=topk,
+                bits=s.bit_length()),
+        grid=(len(blocks),),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((block, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((2, block, s), jnp.float32),
+                        pltpu.VMEM((block, s), jnp.int32),
+                        pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(
+            # a step copies the next one's scores while it counts its own
+            dimension_semantics=('arbitrary',),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name=THRESHOLD_NAME,
+    )(table)
+    return (lax.bitcast_convert_type(out[:, :1], jnp.float32), out[:, 1:2],
+            out[:, 2] > 0)

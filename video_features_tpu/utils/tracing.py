@@ -71,6 +71,7 @@ STAGES = (
     'ssd_kernel',         # chunks scanned through the ssd_scan kernel ÷ all
     # ... and the lightning indexer of the sparse latent trunk
     'index_kernel',       # query blocks scored by the index_scores kernel ÷ all
+    'topk_ties',          # scored rows whose threshold the tie rule settled ÷ all
 )
 
 
